@@ -29,12 +29,9 @@ Two claims under measurement:
    live compiles — spin-up is a disk read from the shared
    FunctionStore, not a compile storm.
 
-Methodology is bench.py's median-of->=5-windows + recorded-spread
-(VERDICT r4: a point sample of a +-20%-noise distribution is not a
-measurement) for BOTH metrics. `scripts/check_bench_regression.py`
-gates successive BENCH_FLEET_* artifacts on the headline via its
-`paths` knob (MULTIHOST/PAGED precedent — a ~1.0x overhead ratio must
-never compete with img/s headlines in the default BENCH_* trajectory).
+Methodology is bench.py's median-of->=5-windows + recorded-spread (a
+point sample of a +-20%-noise distribution is not a measurement) for
+BOTH metrics.
 
 Run:  JAX_PLATFORMS=cpu python bench_fleet.py
 """

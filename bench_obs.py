@@ -126,9 +126,6 @@ def _run_child(workload, tree, exec_cache):
     env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = tree
     env["DL4J_OBS_EXEC_CACHE"] = exec_cache
-    # share the persistent XLA compile cache across windows of one arm
-    env.setdefault("DL4J_COMPILE_CACHE",
-                   os.path.join(exec_cache, "xla"))
     out = subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--child", workload],
         env=env, cwd=tempfile.gettempdir(), capture_output=True,

@@ -35,10 +35,7 @@ AND int8 page costs — int8 pages halve again on top of paging) and the
 cross-arm token-identity verdict: the paged streams must equal the
 dense streams token for token (greedy streams are a pure function of
 the prompt, so they must survive the layout change AND the different
-slot count bit-exactly). `scripts/check_bench_regression.py` gates
-successive BENCH_PAGED_* artifacts on the headline via its `paths`
-knob (MULTIHOST_r01 precedent — a 6x capacity ratio must never
-compete with img/s headlines in the default BENCH_* trajectory).
+slot count bit-exactly).
 
 Run:  JAX_PLATFORMS=cpu python bench_paged.py
 """

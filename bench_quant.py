@@ -43,7 +43,7 @@ def _build_pointwise_resnet(wide, narrow, blocks, hw, seed=0):
     """ResNet-style bottleneck bodies made of the ops this PR diets:
     1×1 conv (wide→narrow) + BN/relu, 1×1 conv (narrow→wide) + BN,
     residual add, relu — the exact shape of ResNet-50's res-stage 1×1
-    pairs, which is where BENCH_r04 located the HBM-bound traffic."""
+    pairs, which is where the round-4 profile located the HBM-bound traffic."""
     from deeplearning4j_tpu.nn import InputType, NeuralNetConfiguration
     from deeplearning4j_tpu.nn.conf.graph_vertices import ElementWiseVertex
     from deeplearning4j_tpu.nn.conf.layers import (ActivationLayer,
@@ -197,26 +197,23 @@ def bench_remat(wide=64, narrow=16, blocks=8, hw=28, batch=32):
         "grads_equal": grads_match,
     }
     # secondary evidence: the compiled backward's OWN temp-buffer peak
-    # (XLA memory analysis; best-effort — not all backends report it)
-    try:
-        def step(net):
-            return jax.jit(lambda p: jax.grad(
-                lambda pp: net._loss(pp, net._state, ins, labels, None,
-                                     None, key)[0])(p)) \
-                .lower(net._params).compile()
-        mp = step(plain).memory_analysis()
-        mr = step(remat).memory_analysis()
-        out["xla_temp_bytes_plain"] = int(mp.temp_size_in_bytes)
-        out["xla_temp_bytes_remat"] = int(mr.temp_size_in_bytes)
-        out["xla_temp_reduction_pct"] = round(
-            (1 - mr.temp_size_in_bytes / mp.temp_size_in_bytes) * 100, 1)
-        out["xla_note"] = (
-            "XLA:CPU temp is total scratch under aggressive buffer "
-            "reuse, not the saved-activation watermark — the "
-            "policy-relative ledger above is the acceptance number; "
-            "this field is advisory")
-    except Exception as e:  # noqa: BLE001 — advisory field only
-        out["xla_memory_analysis"] = f"unavailable: {str(e)[:120]}"
+    # (XLA memory analysis)
+    def step(net):
+        return jax.jit(lambda p: jax.grad(
+            lambda pp: net._loss(pp, net._state, ins, labels, None,
+                                 None, key)[0])(p)) \
+            .lower(net._params).compile()
+    mp = step(plain).memory_analysis()
+    mr = step(remat).memory_analysis()
+    out["xla_temp_bytes_plain"] = int(mp.temp_size_in_bytes)
+    out["xla_temp_bytes_remat"] = int(mr.temp_size_in_bytes)
+    out["xla_temp_reduction_pct"] = round(
+        (1 - mr.temp_size_in_bytes / mp.temp_size_in_bytes) * 100, 1)
+    out["xla_note"] = (
+        "XLA:CPU temp is total scratch under aggressive buffer "
+        "reuse, not the saved-activation watermark — the "
+        "policy-relative ledger above is the acceptance number; "
+        "this field is advisory")
     return out
 
 

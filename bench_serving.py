@@ -6,9 +6,9 @@ Three measurements, one JSON line:
 
 - **cold_start_s** — construct `ParallelInference` over an EMPTY
   executable cache, `warmup()` the bucket ladder (every rung pays a
-  live trace + XLA compile), then serve the first request. This is the
-  BENCH_r02 pathology (42.7 s of warmup+compile before the first
-  served step) scaled to a CPU-sized model. Model construction is
+  live trace + XLA compile), then serve the first request: the
+  compile-before-the-first-served-step cost, scaled to a CPU-sized
+  model. Model construction is
   reported separately (`model_build_s`) — a real replica restores a
   checkpoint; the cache's job is the compile side of cold start.
 - **warm_start_s** — a "restarted replica": fresh model object, fresh
@@ -76,11 +76,11 @@ def run(requests=200, seed=0):
     from deeplearning4j_tpu import monitoring as mon
     ladder = [1, 2, 4, 8, 16, 32]
     work = tempfile.mkdtemp(prefix="dl4j-bench-serving-")
-    # both jax's persistent cache and the executable cache start EMPTY
-    # so the cold arm is honestly cold
-    prev_cc = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.join(work, "jaxcc"))
+    # the executable store starts EMPTY and jax's persistent compile
+    # cache is OFF for the run, so the cold arm is honestly cold
+    from jax.experimental.compilation_cache import compilation_cache as _cc
+    jax.config.update("jax_enable_compilation_cache", False)
+    _cc.reset_cache()
     exec_dir = os.path.join(work, "exec")
     try:
         pi, build_cold, cold_s, cold_stats = _start_replica(exec_dir,
@@ -130,12 +130,8 @@ def run(requests=200, seed=0):
             "exec_cache_entries": len(ladder),
         }
     finally:
-        jax.config.update("jax_compilation_cache_dir", prev_cc)
-        try:
-            from jax._src import compilation_cache as _cc
-            _cc.reset_cache()
-        except Exception:  # noqa: BLE001
-            pass
+        jax.config.update("jax_enable_compilation_cache", True)
+        _cc.reset_cache()
         shutil.rmtree(work, ignore_errors=True)
 
 

@@ -31,11 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# version compat: newer jax renamed TPUCompilerParams -> CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
-
 _NEG_INF = -1e30
 
 
@@ -180,7 +175,7 @@ def _flash_forward(q, k, v, q_mask, kv_mask, causal, block_q, block_k,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
@@ -361,7 +356,7 @@ def _flash_backward(q, k, v, q_mask, kv_mask, o, lse, g, causal, block_q,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
@@ -387,7 +382,7 @@ def _flash_backward(q, k, v, q_mask, kv_mask, o, lse, g, causal, block_q,
                    jax.ShapeDtypeStruct((b * h, tk, d), v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*operands2)

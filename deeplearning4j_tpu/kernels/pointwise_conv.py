@@ -3,7 +3,7 @@
 A 1x1 conv in NHWC is a GEMM over the flattened spatial axis:
 y[M, N] = x[M, K] @ w[K, N] with M = B*H*W. In ResNet-class nets every
 1x1 conv is immediately followed by BatchNorm, and the xplane profile of
-the ResNet-50 bench step (BENCH.md) shows the step is HBM-bound with the
+the ResNet-50 bench step (round 4) showed the step is HBM-bound with the
 BN stat/grad passes around those GEMMs costing whole extra reads/writes
 of the largest activations. These kernels remove the removable passes
 (the reference instead hands conv+BN to cuDNN fused helpers —
@@ -103,7 +103,7 @@ def matmul_stats(x, w, block_m=256, interpret=None):
 # ---------------------------------------------------------------------------
 # inference epilogue fusion: affine (+ residual) (+ act) INSIDE the GEMM
 # ---------------------------------------------------------------------------
-# BENCH.md round 3's post-mortem of the standalone fusion attempt: a
+# Round 3's post-mortem of the standalone fusion attempt: a
 # Pallas custom-call is a fusion BARRIER, so removing one pass by hand
 # while breaking XLA's own elementwise merges was a net loss. The shape
 # that does win is the epilogue — the affine/residual/activation tail

@@ -16,8 +16,8 @@ unchanged — `mark_conv1x1_bn_fusions` just annotates node pairs, and the
 graph executor routes the pair through `fused_apply` at train time.
 
 OFF by default (opt in with DL4J_TPU_FUSE_CONV_BN=1): measured on the
-v5e ResNet-50 headline bench the fused step is SLOWER (179 ms vs 99 ms,
-BENCH.md "negative result") — Pallas custom-calls are fusion barriers,
+v5e ResNet-50 headline bench the fused step was SLOWER (179 ms vs 99 ms,
+round 3, 2026-07, other JAX — a negative result) — Pallas custom-calls are fusion barriers,
 so the BN-apply/relu passes XLA used to merge with neighbours become
 standalone, and the row-major GEMM operands force relayout copies
 against XLA's batch-minor conv layouts. The kernels stay correct,
@@ -174,7 +174,7 @@ def fused_apply(conv_layer, bn_layer, p_conv, p_bn, s_bn, x, train,
         # per-channel affine of the RUNNING stats — fold it (plus the
         # relu) into the GEMM's epilogue so the conv output tile is
         # normalized while still in VMEM instead of in a standalone
-        # BN-apply pass (the shape BENCH.md round 3 concluded is the
+        # BN-apply pass (the shape the round-3 chip runs concluded is the
         # only fusion that wins). _eval_epilogue carries a custom VJP
         # (recompute-based closed form), so autodiff THROUGH an eval
         # forward (input saliency etc.) keeps working. The

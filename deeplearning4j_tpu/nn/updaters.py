@@ -46,7 +46,7 @@ class Sgd(Updater):
 class Nesterovs(Updater):
     """≡ learning.config.Nesterovs. `momentumDtype="bfloat16"` keeps the
     momentum buffer in bf16 — halves the optimizer-state HBM traffic per
-    step on TPU (the ResNet step is HBM-bound; see BENCH.md). Parameters
+    step on TPU (the ResNet step is HBM-bound). Parameters
     stay fp32 masters; only the velocity accumulator is cast."""
 
     def __init__(self, learningRate=0.1, momentum=0.9, momentumDtype=None):

@@ -258,3 +258,52 @@ def check_overlap_structure(hlo_text, num_buckets,
                 f"{coll_pos[b - 1]}) — the exchange is serialized after "
                 f"all compute, nothing can overlap")
     return problems
+
+
+_DEF_RE = re.compile(r"\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=")
+_NAME_RE = re.compile(r"%?([\w.\-]+)")
+
+
+def check_exchange_independence(hlo_text, num_buckets):
+    """What makes the bucketed exchange OVERLAPPABLE, read from data
+    dependence and not from the order in which a backend happens to
+    print its schedule:
+
+    1. one collective per bucket exists (`dl4j_bucket{k}_exchange`);
+    2. bucket k's collective does not depend on the encode of any LATER
+       bucket — so a scheduler is free to issue it while those encodes
+       still compute. (Whether it does is the backend's choice and a
+       chip measurement; `check_overlap_structure` reads one backend's
+       answer off its schedule text.)
+
+    Returns a list of human-readable violations (empty == pass)."""
+    defs = {}       # instruction name -> (line, operand names)
+    for ln in _entry_lines(hlo_text):
+        m = _DEF_RE.match(ln)
+        if m:
+            rhs = ln[m.end():].split(", metadata=")[0]
+            defs[m.group(1)] = (ln, _NAME_RE.findall(rhs))
+    if not defs:
+        return ["no ENTRY computation found in HLO text"]
+    problems = []
+    for b in range(num_buckets):
+        colls = [n for n, (ln, _) in defs.items()
+                 if _COLLECTIVE_RE.search(ln)
+                 and EXCHANGE_SCOPE.format(b=b) in ln]
+        if not colls:
+            problems.append(f"no collective found for bucket {b} (split "
+                            f"failed or scopes were fused away)")
+            continue
+        seen, stack = set(), list(colls)
+        while stack:
+            for o in defs[stack.pop()][1]:
+                if o in defs and o not in seen:
+                    seen.add(o)
+                    stack.append(o)
+        for later in range(b + 1, num_buckets):
+            scope = ENCODE_SCOPE.format(b=later)
+            if any(scope in defs[n][0] for n in seen):
+                problems.append(
+                    f"bucket {b}'s collective depends on bucket {later}'s "
+                    f"encode — it cannot issue until that finishes")
+    return problems

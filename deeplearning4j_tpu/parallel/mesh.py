@@ -20,49 +20,13 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 def shard_map(f, mesh=None, in_specs=None, out_specs=None, check_vma=None,
               **kw):
-    """Version-compat `shard_map`: newer jax exposes `jax.shard_map`
-    (with `check_vma=`), older releases only ship
-    `jax.experimental.shard_map.shard_map` (whose equivalent kwarg is
-    `check_rep=`). Every call site in this repo (and the tests) routes
-    through here so a jax upgrade/downgrade is a one-line change."""
-    native = getattr(jax, "shard_map", None)
-    if native is not None:
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        return native(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
+    """`jax.shard_map` with `check_vma` left at jax's default unless
+    given. Every call site in this repo (and the tests) routes through
+    here."""
     if check_vma is not None:
-        kw.setdefault("check_rep", check_vma)
-    mapped = _shard_map(f, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, **kw)
-
-    # the experimental wrapper only takes positional args, but callers
-    # that introspect `f` (models/bert.py mask guards) legitimately call
-    # by keyword — rebind keywords to f's positional order
-    import functools
-    import inspect
-
-    @functools.wraps(f)
-    def call(*args, **kwargs):
-        if kwargs:
-            ba = inspect.signature(f).bind(*args, **kwargs)
-            # fill defaulted gaps so a keyword after one (f(q, k, v,
-            # causal=False, mask=None) called with mask=...) becomes
-            # positional instead of silently staying in ba.kwargs and
-            # being DROPPED — an arg-count mismatch with in_specs then
-            # fails loudly inside shard_map, never silently
-            ba.apply_defaults()
-            if ba.kwargs:
-                raise TypeError(
-                    f"shard_map compat wrapper cannot pass keyword-only "
-                    f"args {sorted(ba.kwargs)} positionally to the "
-                    f"experimental shard_map; make them positional-or-"
-                    f"keyword on {getattr(f, '__name__', f)!r}")
-            return mapped(*ba.args)
-        return mapped(*args)
-
-    return call
+        kw["check_vma"] = check_vma
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 class DeviceMesh:

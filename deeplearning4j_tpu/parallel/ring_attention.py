@@ -311,8 +311,8 @@ def make_ring_attention(mesh, axis_name="sp", causal=False, use_flash=None,
     accumulator. Causal can ride the same kernels (round-4: diagonal ring
     step → causal kernel, past steps → full kernel, future steps skipped)
     but stays OPT-IN (use_flash=True) until it has an on-chip smoke run —
-    interpret-mode tests don't validate Mosaic lowering (BENCH.md
-    round-3 lesson).
+    interpret-mode tests don't validate Mosaic lowering (the round-3
+    lesson).
 
     Padded batches: BOTH paths take kv_mask (a local (B, T/n) slice
     that rotates with its K/V block). The masked FLASH ring (round-5)

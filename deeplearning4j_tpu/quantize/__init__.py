@@ -1,7 +1,7 @@
 """Quantization + memory-traffic diet (ROADMAP item 3).
 
 The ResNet-50 step measured at 93.7% of the HBM-bandwidth roof
-(BENCH_r04_local) — XLA knobs exhausted; the remaining single-chip
+(round 4, builder-local) — XLA knobs exhausted; the remaining single-chip
 lever is moving fewer bytes. This package is that lever:
 
 - `core` — symmetric int8 primitives: per-channel scales, quantize /

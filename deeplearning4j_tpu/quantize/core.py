@@ -2,7 +2,7 @@
 quantize/dequantize, straight-through-estimator fake-quant, and the int8
 GEMM with a fused dequant epilogue.
 
-Why this exists (ROADMAP item 3, BENCH_r04): the ResNet-50 step runs at
+Why this exists (ROADMAP item 3, round-4 chip runs): the ResNet-50 step runs at
 93.7% of the HBM-bandwidth roof — XLA knobs are exhausted, the remaining
 lever is moving FEWER BYTES. The cuDNN paper's precision argument applies
 directly: half (or a quarter) of the activation bytes is half (a quarter)

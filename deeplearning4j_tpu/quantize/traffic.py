@@ -1,7 +1,7 @@
 """Activation-traffic estimator: how many bytes does a model's forward
 (and backward) actually move, under which precision/remat policy?
 
-The BENCH roofline work (BENCH_r04_local: 93.7% of the HBM bound) made
+The round-4 roofline work (93.7% of the HBM bound, builder-local) made
 bytes the currency of this repo's perf axis — so the diet needs a
 ledger. This module walks a built configuration and prices every
 activation tensor at its policy-resolved width:

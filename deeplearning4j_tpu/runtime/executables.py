@@ -1,12 +1,11 @@
 """Serving-grade AOT executable store + bucket ladder + staging ring.
 
-BENCH_r02 measured 42.7 s of warmup+compile before the first served
-step: every novel input shape paid a live `jax.jit` trace on the
-request path. This module removes host compiles (and host-owned input
-aliasing) from serving entirely — the JAX analog of pre-captured CUDA
-graphs (PAPERS.md "Hybrid JIT-CUDA Graph Optimization"), with
-µ-cuDNN-style micro-batching (fixed shape buckets, split oversized
-work) so the executable set is closed and finite.
+Without it every novel input shape pays a live `jax.jit` trace and
+compile on the request path. This module removes host compiles (and
+host-owned input aliasing) from serving entirely — the JAX analog of
+pre-captured CUDA graphs (PAPERS.md "Hybrid JIT-CUDA Graph
+Optimization"), with µ-cuDNN-style micro-batching (fixed shape buckets,
+split oversized work) so the executable set is closed and finite.
 
 Three pieces:
 
@@ -21,9 +20,9 @@ Three pieces:
   seconds — deserialize, no XLA compile. Entries that fail to load
   (corrupt, version/backend mismatch) fall back to a live compile and
   are rewritten; they NEVER crash serving. JAX's persistent
-  compilation cache (`DL4J_COMPILE_CACHE`, wired via
-  `configure_persistent_cache()`) backs live compiles as a third
-  tier, shared with training jit misses.
+  compilation cache (placed by `util.hostkey.enable_compile_cache`,
+  wired via `configure_persistent_cache()`) backs live compiles as a
+  third tier, shared with training jit misses.
 
 - **`BucketLadder`** — the closed shape vocabulary: a sorted tuple of
   batch buckets (and, for sequence models, length buckets). Requests
@@ -43,7 +42,7 @@ enabled-guard) + `GET /executables` on the UIServer via `status()`.
 
 Cache layout (versioned; bump LAYOUT_VERSION to invalidate):
 
-    <DL4J_EXEC_CACHE>/v1/<device-flavour>/<model-fingerprint>/<sig>.exe
+    <DL4J_EXEC_CACHE>/v2/<device-flavour>/<model-fingerprint>/<sig>.exe
 
 - device-flavour: backend + device_kind (+ host CPU feature hash on
   CPU — XLA:CPU serializes machine code; a foreign host must MISS,
@@ -51,8 +50,11 @@ Cache layout (versioned; bump LAYOUT_VERSION to invalidate):
 - model-fingerprint: conf JSON + param/state shape-dtype trees + jax
   version, so a retrained SAME architecture reuses its executables but
   any structural change misses;
-- <sig>.exe: pickled {"meta": ..., "blob": (payload, in_tree,
-  out_tree)}; meta re-checked at load, mismatch → treated as corrupt.
+- <sig>.exe: pickled {"meta": ..., "devices": [ids], "blob": (payload,
+  in_tree, out_tree)}; meta re-checked at load, mismatch → treated as
+  corrupt. `devices` are the ids of the devices the executable was
+  compiled for: it is loaded back onto exactly those, and an entry
+  whose devices this host does not have is a miss.
 """
 from __future__ import annotations
 
@@ -80,11 +82,9 @@ __all__ = [
 ]
 
 #: bump to invalidate every on-disk serialized executable at once
-LAYOUT_VERSION = "v1"
+LAYOUT_VERSION = "v2"
 #: on-disk serialized-executable cache root ("" → in-process tiers only)
 ENV_CACHE_DIR = "DL4J_EXEC_CACHE"
-#: jax persistent compilation cache dir (third tier, shared w/ training)
-ENV_COMPILE_CACHE = "DL4J_COMPILE_CACHE"
 
 _STORES = weakref.WeakSet()   # live stores, aggregated by status()
 
@@ -128,39 +128,23 @@ def _on_jax_cache_event(name, **kw):
         _mon.get_registry().counter(which, help=help_).inc()
 
 
-def configure_persistent_cache(directory=None, force=False):
+def configure_persistent_cache():
     """Idempotently wire jax's persistent compilation cache.
 
-    `directory` (or $DL4J_COMPILE_CACHE) becomes
-    `jax_compilation_cache_dir`; an already-configured dir is respected
-    unless `force`. Always registers the cache-event listener so
-    `dl4j.jit.persistent_{hits,misses}` count the first-tier vs
-    persistent-tier split for EVERY jit in the process (training
-    included). Returns the effective cache dir (None = cache off)."""
+    The directory follows the one rule of
+    `util.hostkey.enable_compile_cache` ($JAX_COMPILATION_CACHE_DIR
+    where set, else the checkout's `.jax_cache/`). Registers the
+    cache-event listener so `dl4j.jit.persistent_{hits,misses}` count
+    the first-tier vs persistent-tier split for EVERY jit in the process
+    (training included). Returns the effective cache dir."""
     global _pcache_configured
     with _pcache_lock:
         if not _pcache_configured:
-            try:
-                # jax-internal hook: losing it on a future jax only
-                # loses the hit/miss SPLIT, never the cache itself
-                from jax._src import monitoring as _jmon
-                _jmon.register_event_listener(_on_jax_cache_event)
-            except Exception:  # noqa: BLE001
-                pass
+            jax.monitoring.register_event_listener(_on_jax_cache_event)
+            from deeplearning4j_tpu.util.hostkey import enable_compile_cache
+            enable_compile_cache()
             _pcache_configured = True
-        directory = directory or os.environ.get(ENV_COMPILE_CACHE) or None
-        current = jax.config.jax_compilation_cache_dir
-        if directory and (force or not current) and directory != current:
-            jax.config.update("jax_compilation_cache_dir", directory)
-            try:
-                # jax binds the cache object at first use; re-point it
-                # or a pre-initialized cache keeps the old directory
-                from jax._src import compilation_cache as _cc
-                _cc.reset_cache()
-            except Exception:  # noqa: BLE001 — best effort across jax
-                pass
-            current = directory
-        return current
+        return jax.config.jax_compilation_cache_dir
 
 
 def persistent_cache_stats():
@@ -417,9 +401,16 @@ class _AotStoreBase:
                 rec = pickle.load(f)
             if rec.get("meta") != self._meta():
                 raise ValueError(f"cache meta mismatch: {rec.get('meta')}")
+            local = {d.id: d for d in jax.local_devices()}
+            if not all(i in local for i in rec["devices"]):
+                # compiled for devices this host does not have: a miss,
+                # not a corrupt entry (another host may still load it)
+                return None
             from jax.experimental import serialize_executable as _se
             payload, in_tree, out_tree = rec["blob"]
-            call = _se.deserialize_and_load(payload, in_tree, out_tree)
+            call = _se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=[local[i] for i in rec["devices"]])
             self.stats["disk_hits"] += 1
             self._count(_mon.EXEC_DISK_HITS,
                         "serving executables deserialized from the "
@@ -438,8 +429,12 @@ class _AotStoreBase:
 
     def _compile_live(self, key, lower_fn, path):
         t0 = time.perf_counter()
+        pcache_hits = _pcache_counts["hits"]
         compiled = lower_fn().compile()
         dt = time.perf_counter() - t0
+        # served from jax's persistent cache, not compiled (another
+        # thread's hit in the same instant only costs a recompile)
+        from_pcache = _pcache_counts["hits"] > pcache_hits
         self.stats["compiles"] += 1
         if _mon.enabled():
             reg = _mon.get_registry()
@@ -450,12 +445,23 @@ class _AotStoreBase:
                           help="wall time of live serving compiles") \
                .observe(dt)
         e = _Entry(compiled, "compile")
-        if path is not None and self._persist(key, path,
-                                              compiled) == "broken":
-            # A compile served from jax's persistent kernel cache
-            # serializes an INCOMPLETE payload on XLA:CPU (the object
-            # code is not re-embedded: "Symbols not found" at reload —
-            # the round-trip check in _persist catches it in-process).
+        if path is None:
+            return e
+        # A compile served from jax's persistent kernel cache
+        # serializes an INCOMPLETE payload on XLA:CPU (the object code
+        # is not re-embedded). Some such payloads fail at reload
+        # ("Symbols not found" — the round-trip check in _persist
+        # catches those in-process); on the installed XLA others load
+        # and fail only when RUN ("Function ... not found"), which no
+        # load-time check sees — so on the CPU backend a cache-served
+        # executable is never persisted at all. (On the TPU the same
+        # round trip was run end to end: chip_smoke.py's warm restart
+        # loads and serves entries serialized from cache hits.)
+        if from_pcache and jax.default_backend() == "cpu":
+            verdict = "broken"
+        else:
+            verdict = self._persist(key, path, compiled)
+        if verdict == "broken":
             # Force ONE fresh compile outside that cache and persist
             # it, so a restarted replica really does warm from disk
             # with zero compiles instead of silently degrading. Only
@@ -509,15 +515,21 @@ class _AotStoreBase:
         except Exception:  # noqa: BLE001 — backend may not serialize
             self._count_serialize_failure()
             return False
+        # the devices this executable was compiled for: a reload must
+        # run on exactly these (jax's default is every local device,
+        # which breaks a one-device executable on a multi-device host
+        # at its first call)
+        devices = compiled.runtime_executable().local_devices()
         try:
             # round-trip check: deserialization failures surface HERE,
             # at persist time, not as a mystery on the next replica
-            _se.deserialize_and_load(*blob)
+            _se.deserialize_and_load(*blob, execution_devices=devices)
         except Exception:  # noqa: BLE001 — incomplete payload
             self._count_serialize_failure()
             return "broken"
         try:
-            rec = {"meta": self._meta(), "key": key, "blob": blob}
+            rec = {"meta": self._meta(), "key": key, "blob": blob,
+                   "devices": [d.id for d in devices]}
             os.makedirs(os.path.dirname(path), exist_ok=True)
             tmp = f"{path}.tmp.{os.getpid()}"
             with open(tmp, "wb") as f:

@@ -26,9 +26,9 @@ class OpExecutioner:
         self.op_times = collections.defaultdict(float)
         # (registry, generation, dispatches, misses, compile_hist)
         self._mon_handles = None
-        # cross-process warm compiles: point jax's persistent
-        # compilation cache at $DL4J_COMPILE_CACHE (respecting an
-        # already-configured dir) and bridge its hit/miss events onto
+        # cross-process warm compiles: place jax's persistent
+        # compilation cache by the one rule of util/hostkey.py and
+        # bridge its hit/miss events onto
         # dl4j.jit.persistent_{hits,misses} — every dl4j.jit.cache_miss
         # then splits into "paid a live XLA compile" vs "deserialized
         # from the persistent tier" (runtime/executables.py)

@@ -9,6 +9,7 @@ same overlap from its javacpp worker threads).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -18,16 +19,43 @@ import numpy as np
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "native", "dl4j_native.cpp")
 _SO = os.path.join(_HERE, "native", "libdl4j_native.so")
+#: sha256 of the source the library beside it was built from. File times
+#: do not survive a checkout or a copy, so the rebuild is keyed on this.
+_SO_HASH = _SO + ".sha256"
 
 _lib = None
 _lock = threading.Lock()
 _build_failed = False
 
 
-def _build():
+def _source_hash():
+    with open(_SRC, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
+def _built_from(src_hash):
+    try:
+        with open(_SO_HASH) as f:
+            return os.path.exists(_SO) and f.read().strip() == src_hash
+    except OSError:
+        return False
+
+
+def _build(src_hash):
+    # build beside the target and rename: concurrent builders (test
+    # workers) each publish a whole library, never a half-written one
+    tmp = f"{_SO}.tmp.{os.getpid()}"
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
-           _SRC, "-o", _SO]
-    subprocess.run(cmd, check=True, capture_output=True)
+           _SRC, "-o", tmp]
+    try:
+        subprocess.run(cmd, check=True, capture_output=True)
+        os.replace(tmp, _SO)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
+    with open(f"{_SO_HASH}.tmp.{os.getpid()}", "w") as f:
+        f.write(src_hash)
+    os.replace(f.name, _SO_HASH)
 
 
 def get_lib():
@@ -36,9 +64,9 @@ def get_lib():
         if _lib is not None or _build_failed:
             return _lib
         try:
-            if (not os.path.exists(_SO)
-                    or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-                _build()
+            src_hash = _source_hash()
+            if not _built_from(src_hash):
+                _build(src_hash)
             lib = ctypes.CDLL(_SO)
         except Exception:
             _build_failed = True

@@ -1,6 +1,6 @@
 """Host pipeline: async dispatch + device staging prefetch.
 
-BENCH.md's profile puts single-chip XLA fusions within ~1.5x of the HBM
+The round-4 chip profile put single-chip XLA fusions within ~1.5x of the HBM
 bound, so the remaining throughput lever is the HOST side. Two host
 pathologies starved the device in the pre-pipeline fit loops (the same
 per-step host round-trips PAPERS.md's PyGraph analysis shows killing

@@ -36,16 +36,24 @@ def host_cpu_key() -> str:
     return hashlib.sha256(feats.encode()).hexdigest()[:12]
 
 
-def cache_dir(root: str) -> str:
-    """Per-host-flavour jax compilation cache dir under `root`."""
-    return os.path.join(root, ".jax_cache", f"cpu-{host_cpu_key()}")
+def compile_cache_dir() -> str:
+    """This checkout's fixed compile-cache directory: `.jax_cache/` beside
+    the package, one sub-directory per host flavour. Derived from the
+    package's own location and nothing that changes between runs — the
+    path is part of jax's cache key, so a directory that moves never
+    hits."""
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    return os.path.join(root, ".jax_cache", f"host-{host_cpu_key()}")
 
 
-def enable_compile_cache(root: str, min_compile_secs: float = 2.0) -> None:
-    """Point jax's persistent compilation cache at cache_dir(root).
-
-    Single definition shared by bench.py and exp_tpu_r4.py so the two
-    chip-facing entry points can never silently diverge on cache policy.
+def enable_compile_cache(min_compile_secs: float = 2.0) -> str:
+    """THE compile-cache rule, shared by chip_smoke.py, bench.py,
+    __graft_entry__.py, tests/conftest.py and
+    runtime.executables.configure_persistent_cache: where
+    JAX_COMPILATION_CACHE_DIR is set, jax already uses that directory and
+    none is set in code; where it is not, the cache lives in
+    compile_cache_dir(). Returns the directory in effect.
 
     min_compile_secs floor of 2.0 is deliberate: XLA:CPU's serialized
     executable for at least one borderline-fast (~1 s) compile in this
@@ -56,6 +64,16 @@ def enable_compile_cache(root: str, min_compile_secs: float = 2.0) -> None:
     definition) and keeps the poison class off disk entirely."""
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", cache_dir(root))
+    directory = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not directory:
+        directory = compile_cache_dir()
+        if jax.config.jax_compilation_cache_dir != directory:
+            jax.config.update("jax_compilation_cache_dir", directory)
+            # jax binds the cache object at first use; re-point it or a
+            # cache initialized earlier keeps its old directory
+            from jax.experimental.compilation_cache import \
+                compilation_cache as _cc
+            _cc.reset_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       min_compile_secs)
+    return directory

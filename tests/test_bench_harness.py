@@ -1,44 +1,11 @@
-"""bench.py parent-harness unit tests — pure host logic, no device.
-
-The measurement child is exercised on the real chip by the driver; these
-cover the salvage path that turns a killed-mid-extras attempt into a
-partial artifact instead of a zeroed one (BENCH.md round-4 notes).
-"""
+"""Root-script unit tests — pure host logic, no device."""
 import json
 import os
+import subprocess
 import sys
-
-import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(__file__)))
 import bench  # noqa: E402
-
-
-@pytest.mark.smoke
-class TestLastPartial:
-    def test_picks_last_checkpoint(self):
-        out = "\n".join([
-            "# noise",
-            '#partial# {"value": 1.0}',
-            'not json',
-            '#partial# {"value": 2.0, "vgg16_img_s": 3.0}',
-        ])
-        assert bench._last_partial(out) == {"value": 2.0,
-                                            "vgg16_img_s": 3.0}
-
-    def test_none_when_absent_or_malformed(self):
-        assert bench._last_partial("") is None
-        assert bench._last_partial("#partial# {bad json") is None
-
-    def test_final_json_line_not_confused_with_partial(self):
-        # the success path scans for lines starting "{" — partials must
-        # never match it, and _last_partial must never match the final line
-        final = json.dumps({"metric": "m", "value": 5.0})
-        out = '#partial# {"value": 4.0}\n' + final
-        assert bench._last_partial(out) == {"value": 4.0}
-        first_brace = next(line for line in out.splitlines()
-                           if line.strip().startswith("{"))
-        assert json.loads(first_brace)["value"] == 5.0
 
 
 def test_median_of_windows_extends_on_spread():
@@ -64,3 +31,20 @@ def test_median_of_windows_extends_on_spread():
     assert len(vals2) == 9          # capped, never infinite
     assert spread2 > 0.2            # honestly recorded even at the cap
     assert med2 in (100.0, 150.0, 200.0)
+
+
+def test_chip_smoke_refuses_the_cpu_before_building_anything(tmp_path):
+    """Off the chip every kernel picks its interpreter and every caller
+    its dense reference, so a smoke run that landed on the CPU would pass
+    on the reference: it must exit non-zero, print NO result on stdout,
+    and say what it found — before any phase starts."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run(
+        [sys.executable, os.path.join(repo, "chip_smoke.py")],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=str(tmp_path),
+        capture_output=True, text=True, timeout=120)
+    assert out.returncode != 0
+    assert out.stdout.strip() == ""       # no result, no phase output
+    verdict = json.loads(out.stderr.strip().splitlines()[-1])
+    assert verdict["ok"] is False
+    assert verdict["device"]["platform"] == "cpu"

@@ -467,45 +467,3 @@ def test_flash_cross_length_all_padded_kv_example():
     for g in (gq, gk, gv):
         assert np.all(np.asarray(g)[0] == 0)
         assert np.any(np.asarray(g)[1] != 0)
-
-
-class TestResidualBlockKernel:
-    """Round-5 pass-removal experiment kernel (kernels/residual_block.py):
-    the fused bottleneck must equal the XLA composition exactly."""
-
-    def _mats(self, rng, B, H, W, C, M, dtype=np.float32):
-        import jax.numpy as jnp
-        mk = lambda *s: jnp.asarray(rng.normal(size=s).astype(dtype) * 0.2)
-        return (mk(B, H, W, C), mk(C, M), mk(M), jnp.asarray(
-            rng.normal(size=(3, 3, M, M)).astype(dtype) * 0.2), mk(M),
-            mk(M, C), mk(C))
-
-    def test_matches_xla_composition(self):
-        from deeplearning4j_tpu.kernels.residual_block import (
-            bottleneck_block, bottleneck_block_xla)
-        rng = np.random.default_rng(0)
-        x, w1, b1, w2, b2, w3, b3 = self._mats(rng, 4, 6, 6, 32, 16)
-        got = np.asarray(bottleneck_block(x, w1, b1, w2, b2, w3, b3,
-                                          block_b=2, interpret=True))
-        want = np.asarray(bottleneck_block_xla(x, w1, b1, w2, b2, w3, b3))
-        np.testing.assert_allclose(got, want, atol=2e-6)
-
-    def test_batch_tiling_invariant(self):
-        from deeplearning4j_tpu.kernels.residual_block import \
-            bottleneck_block
-        rng = np.random.default_rng(1)
-        x, w1, b1, w2, b2, w3, b3 = self._mats(rng, 8, 5, 5, 16, 8)
-        a = np.asarray(bottleneck_block(x, w1, b1, w2, b2, w3, b3,
-                                        block_b=8, interpret=True))
-        b = np.asarray(bottleneck_block(x, w1, b1, w2, b2, w3, b3,
-                                        block_b=2, interpret=True))
-        np.testing.assert_allclose(a, b, atol=2e-6)
-
-    def test_rejects_indivisible_batch(self):
-        from deeplearning4j_tpu.kernels.residual_block import \
-            bottleneck_block
-        rng = np.random.default_rng(2)
-        x, w1, b1, w2, b2, w3, b3 = self._mats(rng, 6, 4, 4, 8, 8)
-        with pytest.raises(ValueError, match="divisible"):
-            bottleneck_block(x, w1, b1, w2, b2, w3, b3, block_b=4,
-                             interpret=True)

@@ -20,6 +20,8 @@ The contract under test, at every layer:
   compiles and adds ZERO host syncs — page bookkeeping is pure host
   numpy on the existing dispatch/fetch boundaries.
 """
+import time
+
 import numpy as np
 import pytest
 
@@ -334,8 +336,19 @@ def test_paged_steady_state_zero_compiles_zero_new_syncs(bert,
         assert len(r1.result(timeout=120)) == 6
         assert len(r2.result(timeout=120)) == 4
         assert srv._store.trace_calls == traces
-        assert (srv.token_fetches - fetches0
-                == (srv.stats["steps"] - steps0) + 2)
+
+        def balanced():
+            return (srv.token_fetches - fetches0
+                    == (srv.stats["steps"] - steps0) + 2)
+
+        # result() returns from INSIDE the last block's delivery, after
+        # its fetch was counted and before its step is: read the ledger
+        # once the decode thread has finished that block
+        deadline = time.monotonic() + 5.0
+        while not balanced() and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert balanced(), (srv.token_fetches - fetches0,
+                            srv.stats["steps"] - steps0)
         assert srv._pages.stats["cow_copies"] >= 1  # CoW did happen
     finally:
         srv.shutdown()

@@ -249,6 +249,58 @@ def test_meta_mismatch_treated_as_corrupt(tmp_path):
     assert store2.stats["deserialize_failures"] == 1
 
 
+def test_disk_entry_reloads_onto_its_own_devices_and_runs(tmp_path):
+    """A one-device executable persisted by one store, loaded by a second
+    store on the same directory under the 8-device test mesh, must load
+    onto the device it was compiled for and RUN: jax's default loads it
+    onto every local device, which only fails at the first call
+    ('Expected args ... to have 8 shards') — past every load-time
+    check."""
+    assert len(jax.local_devices()) == 8
+    d = str(tmp_path / "exec")
+    x = jnp.arange(16, dtype=jnp.float32).reshape(4, 4)
+
+    def build():
+        return exe.FunctionStore("reload-devices", directory=d).register(
+            "affine", lambda a: a * 2.0 + 1.0)
+
+    first = build().load_or_compile(("affine", 4), (x,))
+    assert first.source == "compile"
+    store2 = build()
+    e = store2.load_or_compile(("affine", 4), (x,))
+    assert e.source == "disk" and store2.stats["compiles"] == 0
+    out = e.call(x)                         # the call is the test
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(x) * 2 + 1)
+    assert out.devices() == {jax.local_devices()[0]}
+    with open(store2._entry_path(("affine", 4)), "rb") as f:
+        assert pickle.load(f)["devices"] == [jax.local_devices()[0].id]
+
+
+def test_disk_entry_for_devices_this_host_lacks_is_a_miss(tmp_path):
+    """An entry compiled for devices this host cannot supply is neither
+    loaded nor counted corrupt: the store compiles live."""
+    d = str(tmp_path / "exec")
+    x = jnp.ones((4,), jnp.float32)
+
+    def build():
+        return exe.FunctionStore("foreign-devices", directory=d).register(
+            "inc", lambda a: a + 1.0)
+
+    store1 = build()
+    store1.load_or_compile(("inc",), (x,))
+    path = store1._entry_path(("inc",))
+    with open(path, "rb") as f:
+        rec = pickle.load(f)
+    rec["devices"] = [10 ** 6]
+    with open(path, "wb") as f:
+        pickle.dump(rec, f)
+    store2 = build()
+    e = store2.load_or_compile(("inc",), (x,))
+    assert e.source == "compile"
+    assert store2.stats["deserialize_failures"] == 0
+    np.testing.assert_array_equal(np.asarray(e.call(x)), 2.0)
+
+
 def test_different_architecture_different_fingerprint(tmp_path, net):
     other = (NeuralNetConfiguration.Builder().seed(3).updater(Sgd(0.1))
              .list()
@@ -469,25 +521,91 @@ def test_persistent_cache_tier_counters():
     assert _counter(mon.JIT_PERSISTENT_HITS) > jit0
 
 
-def test_compile_cache_env_var_respected(tmp_path, monkeypatch):
-    """DL4J_COMPILE_CACHE wires jax_compilation_cache_dir (unless one
-    is already configured — force=True overrides for the test)."""
-    d = str(tmp_path / "cc")
-    monkeypatch.setenv(exe.ENV_COMPILE_CACHE, d)
-    prev = jax.config.jax_compilation_cache_dir
+def test_store_never_persists_a_cache_served_cpu_executable(tmp_path,
+                                                           monkeypatch):
+    """XLA:CPU re-serializes an executable it was SERVED from jax's
+    persistent cache into an incomplete payload that can load and then
+    fail when run; the store must recompile such an entry outside the
+    cache before persisting it — and the reloaded entry must run."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev_dir = jax.config.jax_compilation_cache_dir
     prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    # a private cache dir: sub-2 s entries must never reach the shared one
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "cc"))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    cc.reset_cache()
+    uncached = []
+    real = exe._AotStoreBase._compile_uncached
+    monkeypatch.setattr(
+        exe._AotStoreBase, "_compile_uncached",
+        staticmethod(lambda fn: uncached.append(1) or real(fn)))
+    x = jnp.arange(12, dtype=jnp.float32).reshape(3, 4)
+
+    def build(d):
+        return exe.FunctionStore("pcache-served", directory=str(
+            tmp_path / d)).register("f", lambda a: (a.T @ a) * 0.5)
     try:
-        assert exe.configure_persistent_cache(force=True) == d
-        jax.clear_caches()
-        jax.jit(lambda x: x - 2.0)(jnp.zeros((3,)))
-        assert os.listdir(d)            # entries landed in the new dir
+        build("a").load_or_compile(("f",), (x,))    # writes the jax cache
+        assert uncached == []
+        jax.clear_caches()                          # next compile: a HIT
+        build("b").load_or_compile(("f",), (x,))
+        assert uncached == [1]
+        e = build("b").load_or_compile(("f",), (x,))
+        assert e.source == "disk"
+        np.testing.assert_allclose(np.asarray(e.call(x)),
+                                   np.asarray(x).T @ np.asarray(x) * 0.5,
+                                   rtol=1e-6)
     finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
+        jax.config.update("jax_compilation_cache_dir", prev_dir)
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           prev_min)
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()   # re-binds to the restored directory
+        cc.reset_cache()
+
+
+_CACHE_RULE_CHILD = """
+import json, jax
+from deeplearning4j_tpu.util.hostkey import enable_compile_cache
+d = enable_compile_cache(min_compile_secs=0.0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+if {compile}:
+    jax.jit(lambda x: x - 2.0)(jax.numpy.zeros((3,)))
+print(json.dumps([d, jax.config.jax_compilation_cache_dir]))
+"""
+
+
+@pytest.mark.parametrize("env_set", [True, False],
+                         ids=["JAX_COMPILATION_CACHE_DIR-set", "unset"])
+def test_compile_cache_placement_rule(tmp_path, env_set):
+    """The one compile-cache rule (util/hostkey.enable_compile_cache), in
+    a fresh interpreter: with JAX_COMPILATION_CACHE_DIR set no directory
+    is set in code and the entries land THERE; without it the cache is
+    the checkout's fixed .jax_cache/host-<key>, derived from the
+    package's location, never a temporary name."""
+    import json
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=repo)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    d = str(tmp_path / "cc")
+    if env_set:
+        env["JAX_COMPILATION_CACHE_DIR"] = d
+    # the unset arm does not compile: a sub-2 s entry must never land in
+    # the shared checkout cache (conftest's poison note)
+    out = subprocess.run(
+        [sys.executable, "-c", _CACHE_RULE_CHILD.format(compile=env_set)],
+        env=env, cwd=str(tmp_path), capture_output=True, text=True,
+        timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    returned, configured = json.loads(out.stdout.strip().splitlines()[-1])
+    assert returned == configured
+    if env_set:
+        assert configured == d
+        assert os.listdir(d)            # entries landed where jax was told
+    else:
+        from deeplearning4j_tpu.util.hostkey import host_cpu_key
+        assert configured == os.path.join(repo, ".jax_cache",
+                                          f"host-{host_cpu_key()}")
 
 
 # ===================== status endpoint =====================

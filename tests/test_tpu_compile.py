@@ -1,0 +1,179 @@
+"""The main path's Pallas kernels, compiled for a TPU v5e that is described
+and not attached (on-chip-measurement guide, section 2).
+
+Interpret-mode tests cannot see what Mosaic refuses — a slice not aligned
+to the (8, 128) tiling, more VMEM than a kernel may use. These compile each
+kernel at the real width it has in ResNet-50 training and BERT-base serving
+with `interpret=False` passed explicitly (under JAX_PLATFORMS=cpu the
+kernels would otherwise pick the interpreter) and assert the kernel is in
+the compiled program. A compile that passes is not a chip run: nothing
+executes here.
+
+Only one process may hold libtpu, so the topology is described inside a
+fixture of THIS file (never at import, in a skipif or in conftest.py) and
+the compile runs in the test's own process.
+"""
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu / lock held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def compile_for_chip(one_chip):
+    """compile(fn, *shape_dtype_pairs) -> compiled text, with jax's
+    persistent compile cache off around it: an entry written for a
+    described chip cannot be read back without one, and the next run
+    would warn and recompile."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+
+    def compile_(fn, *specs):
+        args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                for shape, dtype in specs]
+        # production numerics: conftest's "highest" matmul precision is
+        # for the CPU oracles, not for what the chip would compile
+        with jax.default_matmul_precision("default"):
+            return jax.jit(fn).lower(*args).compile().as_text()
+
+    yield compile_
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+bf16, f32, i32, i8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
+
+# BERT-base attention: 12 heads of width 64; training batch 32 at seq 128,
+# serving 8 slots with cache rungs up to 512, pages of 16 rows
+_QKV_TRAIN = ((32, 12, 128, 64), bf16)
+_QKV_PREFILL = ((8, 12, 512, 64), bf16)
+_POOL = ((257, 12, 16, 64), bf16)        # 8 slots x 512 rows + null page
+
+
+def _flash_fwd(q, k, v, m):
+    from deeplearning4j_tpu.kernels import flash_attention
+    return flash_attention(q, k, v, kv_mask=m, interpret=False)
+
+
+def _flash_fwd_bwd(q, k, v, m):
+    return jax.grad(lambda *a: _flash_fwd(*a, m).astype(f32).sum(),
+                    argnums=(0, 1, 2))(q, k, v)
+
+
+def _flash_causal(q, k, v):
+    from deeplearning4j_tpu.kernels import flash_attention
+    return flash_attention(q, k, v, causal=True, interpret=False)
+
+
+def _decode(q, k, v, m):
+    from deeplearning4j_tpu.kernels import flash_attention_decode
+    return flash_attention_decode(q, k, v, m, impl="pallas",
+                                  interpret=False)
+
+
+def _decode_paged(q, kp, vp, ptab, m):
+    from deeplearning4j_tpu.kernels import flash_attention_decode_paged
+    return flash_attention_decode_paged(q, kp, vp, ptab, m, impl="pallas",
+                                        interpret=False)
+
+
+def _layernorm(x, g, b):
+    from deeplearning4j_tpu.kernels import fused_layernorm
+    return fused_layernorm(x, g, b, 1e-12, 128, False)
+
+
+def _layernorm_grad(x, g, b):
+    return jax.grad(lambda *a: _layernorm(*a).astype(f32).sum(),
+                    argnums=(0, 1, 2))(x, g, b)
+
+
+def _matmul_stats(x, w):
+    from deeplearning4j_tpu.kernels.pointwise_conv import matmul_stats
+    return matmul_stats(x, w, interpret=False)
+
+
+def _conv1x1_bn_fwd_bwd(x, w, g, b):
+    from deeplearning4j_tpu.kernels.pointwise_conv import fused_conv1x1_bn
+
+    def loss(x, w, g, b):
+        out = fused_conv1x1_bn(x, w, g, b, 1e-5, "relu", False)
+        return out[0].astype(f32).sum()
+    return jax.value_and_grad(loss, argnums=(0, 1, 2, 3))(x, w, g, b)
+
+
+def _epilogue(x, w, s, t, r):
+    from deeplearning4j_tpu.kernels import matmul_epilogue
+    return matmul_epilogue(x, w, s, t, residual=r, act="relu",
+                           interpret=False)
+
+
+def _epilogue_int8(x, w, s, t, r):
+    from deeplearning4j_tpu.kernels import int8_matmul_epilogue
+    return int8_matmul_epilogue(x, w, s, t, residual=r, act="relu",
+                                out_dtype=bf16, interpret=False)
+
+
+def _conv_specs(m, k, n):
+    return [((m, k), bf16), ((k, n), bf16), ((n,), f32), ((n,), f32)]
+
+
+_M1 = 64 * 56 * 56      # ResNet-50 stage-1 rows at batch 64
+_M4 = 64 * 7 * 7        # stage-4 rows
+
+#: (id, fn, argument (shape, dtype) pairs, kernel expected in the program)
+CASES = [
+    ("flash_fwd_mask_b32_t128", _flash_fwd,
+     [_QKV_TRAIN] * 3 + [((32, 128), i32)], True),
+    ("flash_fwd_bwd_mask_b32_t128", _flash_fwd_bwd,
+     [_QKV_TRAIN] * 3 + [((32, 128), i32)], True),
+    ("flash_causal_prefill_b8_t512", _flash_causal, [_QKV_PREFILL] * 3,
+     True),
+    ("decode_pallas_c512_bf16", _decode,
+     [((8, 12, 64), bf16)] + [_QKV_PREFILL] * 2 + [((8, 512), i32)], True),
+    ("decode_pallas_c512_f32", _decode,
+     [((8, 12, 64), f32)] + [((8, 12, 512, 64), f32)] * 2
+     + [((8, 512), i32)], True),
+    ("decode_paged_pool257_ps16", _decode_paged,
+     [((8, 12, 64), bf16), _POOL, _POOL, ((8, 32), i32), ((8, 512), i32)],
+     True),
+    ("layernorm_fwd_4096x768", _layernorm,
+     [((4096, 768), bf16), ((768,), f32), ((768,), f32)], True),
+    # under autodiff both VJP rules (kernels/layernorm.py) are plain jnp:
+    # the Pallas kernel is the inference path and is not in this program
+    ("layernorm_grad_4096x768", _layernorm_grad,
+     [((4096, 768), bf16), ((768,), f32), ((768,), f32)], False),
+    ("matmul_stats_stage1", _matmul_stats,
+     [((_M1, 64), bf16), ((64, 256), bf16)], True),
+    ("conv1x1_bn_fwd_bwd_stage1_64to256", _conv1x1_bn_fwd_bwd,
+     _conv_specs(_M1, 64, 256), True),
+    ("conv1x1_bn_fwd_bwd_stage4_2048to512", _conv1x1_bn_fwd_bwd,
+     _conv_specs(_M4, 2048, 512), True),
+    ("matmul_epilogue_stage1", _epilogue,
+     _conv_specs(_M1, 64, 256) + [((_M1, 256), bf16)], True),
+    ("int8_matmul_epilogue_stage1", _epilogue_int8,
+     [((_M1, 64), i8), ((64, 256), i8), ((256,), f32), ((256,), f32),
+      ((_M1, 256), bf16)], True),
+]
+
+
+@pytest.mark.parametrize("fn,specs,has_kernel",
+                         [c[1:] for c in CASES], ids=[c[0] for c in CASES])
+def test_kernel_compiles_for_v5e(compile_for_chip, fn, specs, has_kernel):
+    text = compile_for_chip(fn, *specs)
+    assert ("tpu_custom_call" in text) == has_kernel
