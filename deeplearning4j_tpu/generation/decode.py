@@ -240,7 +240,8 @@ class BertDecoder:
         clamped index would corrupt a live row)."""
         (params,) = margs
         cfg = self.cfg
-        x = self._embed(params, tokens, pos)            # (S, H)
+        with jax.named_scope("embed"):
+            x = self._embed(params, tokens, pos)        # (S, H)
         kc, vc = cache["k"], cache["v"]
         int8_kv = self.kv_dtype == "int8"
         ks = cache.get("ks")
@@ -256,51 +257,58 @@ class BertDecoder:
             poff = pos % psz
             phys = ptab[ar, jnp.minimum(pos // psz, maxp - 1)]
             wphys = jnp.where(pos < c, phys, 0)         # (S,)
+            # the write index of the new row: (page, offset) or
+            # (slot, position)
+            wi, wj = wphys, poff
         else:
             c = kc.shape[3]
+            wi, wj = ar, pos
         # rows 0..pos are valid (the current write included)
         cmask = jnp.arange(c)[None, :] <= pos[:, None]  # (S, C)
         dt = x.dtype
+        # the stages of a layer are named scopes (compile-time metadata:
+        # a profiler trace attributes the step's device time to them)
         for li, layer in enumerate(params["layers"]):
-            qkv = x @ layer["qkv_W"].astype(dt) \
-                + layer["qkv_b"].astype(dt)             # (S, 3H)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            q = q.reshape(s, nh, hd)
-            k = k.reshape(s, nh, hd)
-            v = v.reshape(s, nh, hd)
-            if int8_kv:
-                from deeplearning4j_tpu.quantize.kvcache import \
-                    quantize_rows
-                k, k_sc = quantize_rows(k)
-                v, v_sc = quantize_rows(v)
-                if paged:
-                    ks = ks.at[li, wphys, :, poff].set(k_sc)
-                    vs = vs.at[li, wphys, :, poff].set(v_sc)
-                else:
-                    ks = ks.at[li, ar, :, pos].set(k_sc)
-                    vs = vs.at[li, ar, :, pos].set(v_sc)
-            if paged:
-                kc = kc.at[li, wphys, :, poff].set(k.astype(kc.dtype))
-                vc = vc.at[li, wphys, :, poff].set(v.astype(vc.dtype))
-                ctx = self._decode_attn_paged(
-                    q, kc[li], vc[li], ptab, cmask,
-                    ks[li] if int8_kv else None,
-                    vs[li] if int8_kv else None).astype(dt)
-            else:
-                kc = kc.at[li, ar, :, pos].set(k.astype(kc.dtype))
-                vc = vc.at[li, ar, :, pos].set(v.astype(vc.dtype))
-                ctx = self._decode_attn(
-                    q, kc[li], vc[li], cmask,
-                    ks[li] if int8_kv else None,
-                    vs[li] if int8_kv else None).astype(dt)
-            a = ctx.reshape(s, cfg.hidden_size) \
-                @ layer["proj_W"].astype(dt) + layer["proj_b"].astype(dt)
-            x = _layer_norm(x + a, layer["ln1_scale"], layer["ln1_bias"],
-                            cfg.layer_norm_eps)
-            f = _ffn(cfg, layer, x, False, None)
-            x = _layer_norm(x + f, layer["ln2_scale"], layer["ln2_bias"],
-                            cfg.layer_norm_eps)
-        logits = bert_mlm_logits(cfg, params, x[:, None, :])[:, 0]
+            with jax.named_scope(f"layer{li}"):
+                with jax.named_scope("qkv"):
+                    qkv = x @ layer["qkv_W"].astype(dt) \
+                        + layer["qkv_b"].astype(dt)     # (S, 3H)
+                    q, k, v = jnp.split(qkv, 3, axis=-1)
+                    q = q.reshape(s, nh, hd)
+                    k = k.reshape(s, nh, hd)
+                    v = v.reshape(s, nh, hd)
+                with jax.named_scope("kv_write"):
+                    if int8_kv:
+                        from deeplearning4j_tpu.quantize.kvcache import \
+                            quantize_rows
+                        k, k_sc = quantize_rows(k)
+                        v, v_sc = quantize_rows(v)
+                        ks = ks.at[li, wi, :, wj].set(k_sc)
+                        vs = vs.at[li, wi, :, wj].set(v_sc)
+                    kc = kc.at[li, wi, :, wj].set(k.astype(kc.dtype))
+                    vc = vc.at[li, wi, :, wj].set(v.astype(vc.dtype))
+                with jax.named_scope("attn"):
+                    lks = ks[li] if int8_kv else None
+                    lvs = vs[li] if int8_kv else None
+                    if paged:
+                        ctx = self._decode_attn_paged(
+                            q, kc[li], vc[li], ptab, cmask, lks, lvs)
+                    else:
+                        ctx = self._decode_attn(q, kc[li], vc[li], cmask,
+                                                lks, lvs)
+                    ctx = ctx.astype(dt)
+                with jax.named_scope("proj"):
+                    a = ctx.reshape(s, cfg.hidden_size) \
+                        @ layer["proj_W"].astype(dt) \
+                        + layer["proj_b"].astype(dt)
+                    x = _layer_norm(x + a, layer["ln1_scale"],
+                                    layer["ln1_bias"], cfg.layer_norm_eps)
+                with jax.named_scope("ffn"):
+                    f = _ffn(cfg, layer, x, False, None)
+                    x = _layer_norm(x + f, layer["ln2_scale"],
+                                    layer["ln2_bias"], cfg.layer_norm_eps)
+        with jax.named_scope("logits"):
+            logits = bert_mlm_logits(cfg, params, x[:, None, :])[:, 0]
         out = {"k": kc, "v": vc}
         if int8_kv:
             out["ks"] = ks
@@ -334,7 +342,8 @@ class BertDecoder:
         d = 1 + draft.shape[1]
         tok_block = jnp.concatenate([tokens[:, None], draft], axis=1)
         pos_block = pos[:, None] + jnp.arange(d)[None, :]   # (S, d)
-        x = self._embed(params, tok_block, pos_block)       # (S, d, H)
+        with jax.named_scope("embed"):
+            x = self._embed(params, tok_block, pos_block)   # (S, d, H)
         kc, vc = cache["k"], cache["v"]
         ar = jnp.arange(s)
         nh, hd = cfg.num_heads, cfg.head_dim
@@ -347,40 +356,49 @@ class BertDecoder:
             phys = ptab[ar[:, None],
                         jnp.minimum(pos_block // psz, maxp - 1)]
             wphys = jnp.where(pos_block < c, phys, 0)       # (S, d)
+            wi, wj = wphys, poff
         else:
             c = kc.shape[3]
+            wi, wj = ar[:, None], pos_block
         # query j sees rows 0..pos+j (its own write included)
         qmask = jnp.arange(c)[None, None, :] <= pos_block[:, :, None]
         dt = x.dtype
         for li, layer in enumerate(params["layers"]):
-            qkv = x @ layer["qkv_W"].astype(dt) \
-                + layer["qkv_b"].astype(dt)                 # (S, d, 3H)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            q = q.reshape(s, d, nh, hd).transpose(0, 2, 1, 3)
-            k = k.reshape(s, d, nh, hd)                     # (S, d, H, Dh)
-            v = v.reshape(s, d, nh, hd)
-            # advanced-index write: rows pos..pos+d-1 of every slot
-            # (the advanced (S, d) block leads, then the H and Dh dims)
-            if paged:
-                kc = kc.at[li, wphys, :, poff].set(k.astype(kc.dtype))
-                vc = vc.at[li, wphys, :, poff].set(v.astype(vc.dtype))
-                ctx = flash_attention_decode_mq_paged(
-                    q, kc[li], vc[li], ptab, qmask).astype(dt)
-            else:
-                kc = kc.at[li, ar[:, None], :, pos_block].set(
-                    k.astype(kc.dtype))
-                vc = vc.at[li, ar[:, None], :, pos_block].set(
-                    v.astype(vc.dtype))
-                ctx = flash_attention_decode_mq(q, kc[li], vc[li],
-                                                qmask).astype(dt)
-            a = ctx.transpose(0, 2, 1, 3).reshape(s, d, cfg.hidden_size) \
-                @ layer["proj_W"].astype(dt) + layer["proj_b"].astype(dt)
-            x = _layer_norm(x + a, layer["ln1_scale"], layer["ln1_bias"],
-                            cfg.layer_norm_eps)
-            f = _ffn(cfg, layer, x, False, None)
-            x = _layer_norm(x + f, layer["ln2_scale"], layer["ln2_bias"],
-                            cfg.layer_norm_eps)
-        logits = bert_mlm_logits(cfg, params, x)            # (S, d, V)
+            with jax.named_scope(f"layer{li}"):
+                with jax.named_scope("qkv"):
+                    qkv = x @ layer["qkv_W"].astype(dt) \
+                        + layer["qkv_b"].astype(dt)         # (S, d, 3H)
+                    q, k, v = jnp.split(qkv, 3, axis=-1)
+                    q = q.reshape(s, d, nh, hd).transpose(0, 2, 1, 3)
+                    k = k.reshape(s, d, nh, hd)             # (S, d, H, Dh)
+                    v = v.reshape(s, d, nh, hd)
+                with jax.named_scope("kv_write"):
+                    # advanced-index write: rows pos..pos+d-1 of every
+                    # slot (the advanced (S, d) block leads, then the H
+                    # and Dh dims)
+                    kc = kc.at[li, wi, :, wj].set(k.astype(kc.dtype))
+                    vc = vc.at[li, wi, :, wj].set(v.astype(vc.dtype))
+                with jax.named_scope("attn"):
+                    if paged:
+                        ctx = flash_attention_decode_mq_paged(
+                            q, kc[li], vc[li], ptab, qmask)
+                    else:
+                        ctx = flash_attention_decode_mq(q, kc[li], vc[li],
+                                                        qmask)
+                    ctx = ctx.astype(dt)
+                with jax.named_scope("proj"):
+                    a = ctx.transpose(0, 2, 1, 3).reshape(
+                        s, d, cfg.hidden_size) \
+                        @ layer["proj_W"].astype(dt) \
+                        + layer["proj_b"].astype(dt)
+                    x = _layer_norm(x + a, layer["ln1_scale"],
+                                    layer["ln1_bias"], cfg.layer_norm_eps)
+                with jax.named_scope("ffn"):
+                    f = _ffn(cfg, layer, x, False, None)
+                    x = _layer_norm(x + f, layer["ln2_scale"],
+                                    layer["ln2_bias"], cfg.layer_norm_eps)
+        with jax.named_scope("logits"):
+            logits = bert_mlm_logits(cfg, params, x)        # (S, d, V)
         return logits, {"k": kc, "v": vc}
 
     def _write_prompt_pages(self, pool, block, wrow, li):
@@ -420,10 +438,11 @@ class BertDecoder:
         cfg = self.cfg
         p_len = prompt.shape[0]
         emb = params["embeddings"]
-        x = jnp.take(emb["word"], prompt[None], axis=0) \
-            + emb["position"][None, :p_len]
-        x = _layer_norm(x.astype(cfg.compute_dtype), emb["ln_scale"],
-                        emb["ln_bias"], cfg.layer_norm_eps)
+        with jax.named_scope("embed"):
+            x = jnp.take(emb["word"], prompt[None], axis=0) \
+                + emb["position"][None, :p_len]
+            x = _layer_norm(x.astype(cfg.compute_dtype), emb["ln_scale"],
+                            emb["ln_bias"], cfg.layer_norm_eps)
         kc, vc = cache["k"], cache["v"]
         int8_kv = self.kv_dtype == "int8"
         paged = self.paged
@@ -431,53 +450,53 @@ class BertDecoder:
         vs = cache.get("vs")
         nh, hd = cfg.num_heads, cfg.head_dim
         dt = x.dtype
+
+        def heads(t):
+            return t.reshape(1, p_len, nh, hd).transpose(0, 2, 1, 3)
+
+        def write(pool, block, li):
+            """One layer's (1, nh, P[, hd]) block into the slot's rows,
+            or through the page redirect."""
+            if paged:
+                return self._write_prompt_pages(pool, block[0], wrow, li)
+            return lax.dynamic_update_slice(
+                pool, block[None].astype(pool.dtype),
+                (li, slot) + (0,) * (pool.ndim - 2))
+
         for li, layer in enumerate(params["layers"]):
-            qkv = x @ layer["qkv_W"].astype(dt) \
-                + layer["qkv_b"].astype(dt)             # (1, P, 3H)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-
-            def heads(t):
-                return t.reshape(1, p_len, nh, hd).transpose(0, 2, 1, 3)
-
-            q, k, v = heads(q), heads(k), heads(v)      # (1, nh, P, hd)
-            if int8_kv:
-                from deeplearning4j_tpu.quantize.kvcache import \
-                    quantize_rows
-                kq, k_sc = quantize_rows(k)             # (1, nh, P)
-                vq, v_sc = quantize_rows(v)
-                if paged:
-                    kc = self._write_prompt_pages(kc, kq[0], wrow, li)
-                    vc = self._write_prompt_pages(vc, vq[0], wrow, li)
-                    ks = self._write_prompt_pages(ks, k_sc[0], wrow, li)
-                    vs = self._write_prompt_pages(vs, v_sc[0], wrow, li)
-                else:
-                    kc = lax.dynamic_update_slice(
-                        kc, kq[None], (li, slot, 0, 0, 0))
-                    vc = lax.dynamic_update_slice(
-                        vc, vq[None], (li, slot, 0, 0, 0))
-                    ks = lax.dynamic_update_slice(
-                        ks, k_sc[None], (li, slot, 0, 0))
-                    vs = lax.dynamic_update_slice(
-                        vs, v_sc[None], (li, slot, 0, 0))
-            elif paged:
-                kc = self._write_prompt_pages(kc, k[0], wrow, li)
-                vc = self._write_prompt_pages(vc, v[0], wrow, li)
-            else:
-                kc = lax.dynamic_update_slice(
-                    kc, k[None].astype(kc.dtype), (li, slot, 0, 0, 0))
-                vc = lax.dynamic_update_slice(
-                    vc, v[None].astype(vc.dtype), (li, slot, 0, 0, 0))
-            ctx = self._prefill_attn(q, k, v)
-            a = ctx.transpose(0, 2, 1, 3).reshape(1, p_len,
-                                                  cfg.hidden_size) \
-                @ layer["proj_W"].astype(dt) + layer["proj_b"].astype(dt)
-            x = _layer_norm(x + a, layer["ln1_scale"], layer["ln1_bias"],
-                            cfg.layer_norm_eps)
-            f = _ffn(cfg, layer, x, False, None)
-            x = _layer_norm(x + f, layer["ln2_scale"], layer["ln2_bias"],
-                            cfg.layer_norm_eps)
-        h_last = jnp.take(x[0], plen - 1, axis=0)       # (H,)
-        logits = bert_mlm_logits(cfg, params, h_last[None, None, :])[0, 0]
+            with jax.named_scope(f"layer{li}"):
+                with jax.named_scope("qkv"):
+                    qkv = x @ layer["qkv_W"].astype(dt) \
+                        + layer["qkv_b"].astype(dt)     # (1, P, 3H)
+                    q, k, v = jnp.split(qkv, 3, axis=-1)
+                    q, k, v = heads(q), heads(k), heads(v)  # (1,nh,P,hd)
+                with jax.named_scope("kv_write"):
+                    if int8_kv:
+                        from deeplearning4j_tpu.quantize.kvcache import \
+                            quantize_rows
+                        kq, k_sc = quantize_rows(k)     # (1, nh, P)
+                        vq, v_sc = quantize_rows(v)
+                        kc, vc = write(kc, kq, li), write(vc, vq, li)
+                        ks, vs = write(ks, k_sc, li), write(vs, v_sc, li)
+                    else:
+                        kc, vc = write(kc, k, li), write(vc, v, li)
+                with jax.named_scope("attn"):
+                    ctx = self._prefill_attn(q, k, v)
+                with jax.named_scope("proj"):
+                    a = ctx.transpose(0, 2, 1, 3).reshape(
+                        1, p_len, cfg.hidden_size) \
+                        @ layer["proj_W"].astype(dt) \
+                        + layer["proj_b"].astype(dt)
+                    x = _layer_norm(x + a, layer["ln1_scale"],
+                                    layer["ln1_bias"], cfg.layer_norm_eps)
+                with jax.named_scope("ffn"):
+                    f = _ffn(cfg, layer, x, False, None)
+                    x = _layer_norm(x + f, layer["ln2_scale"],
+                                    layer["ln2_bias"], cfg.layer_norm_eps)
+        with jax.named_scope("logits"):
+            h_last = jnp.take(x[0], plen - 1, axis=0)   # (H,)
+            logits = bert_mlm_logits(cfg, params,
+                                     h_last[None, None, :])[0, 0]
         out = {"k": kc, "v": vc}
         if int8_kv:
             out["ks"] = ks

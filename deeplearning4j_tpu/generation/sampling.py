@@ -45,8 +45,10 @@ def split_keys(keys):
     return s[:, 0], s[:, 1]
 
 
+@jax.named_scope("sample")
 def sample_step(logits, keys, method, temperature, top_k):
-    """One batched sampling step.
+    """One batched sampling step (scope `sample` in a profiler trace:
+    the full-vocabulary `sort` below is its cost).
 
     - logits: (S, V) float32
     - keys: (S, 2) uint32 per-slot rng keys

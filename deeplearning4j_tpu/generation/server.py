@@ -121,6 +121,7 @@ loop at zero disabled-path cost.
 from __future__ import annotations
 
 import collections
+import itertools
 import queue
 import threading
 import time
@@ -177,6 +178,11 @@ class GenerationRequest:
         #: monitoring off — every append below is one is-None branch
         self.trace = None
         self.trace_id = None
+        #: the server's sequence number (`req` on every `serve.*` span
+        #: of this request) and the clock reading `submit()` took, from
+        #: which `serve.admit` reckons `queue_wait_us`
+        self.seq = 0
+        self.t_submit = None
         self._done = threading.Event()
         self._stream = queue.Queue()
 
@@ -277,17 +283,22 @@ class _Block:
     dispatch time (delivery must never hand a stale token to a slot
     re-admitted since), and the timing anchors for the per-token and
     fetch-overlap metrics. `proposed` is the per-slot draft-proposal
-    count (drafting rounds only)."""
+    count (drafting rounds only); `step` the dispatch's number, which
+    its `serve.dispatch`, `serve.fetch` and `serve.deliver` spans
+    share."""
 
-    __slots__ = ("tokens", "recs", "k", "t0", "t_copy", "proposed")
+    __slots__ = ("tokens", "recs", "k", "t0", "t_copy", "proposed",
+                 "step")
 
-    def __init__(self, tokens, recs, k, t0, t_copy, proposed=None):
+    def __init__(self, tokens, recs, k, t0, t_copy, proposed=None,
+                 step=0):
         self.tokens = tokens
         self.recs = recs
         self.k = k
         self.t0 = t0
         self.t_copy = t_copy
         self.proposed = proposed
+        self.step = step    # dispatch number: its spans share it
 
 
 def _ngram_propose(history, nd, n=3):
@@ -451,6 +462,8 @@ class GenerationServer:
         self._replaying = []         # journals awaiting re-admission
         self._free = list(range(self.slots))
         self._counter = 0            # admission counter (rng derivation)
+        self._seq = itertools.count(1)   # request numbers (span `req`)
+        self._step_seq = 0           # dispatch numbers (span `step`)
         # RLock: recovery replays deliveries (user on_token callbacks)
         # under the lock; a callback calling submit() must not deadlock
         self._lock = threading.RLock()
@@ -772,6 +785,14 @@ class GenerationServer:
         Admission is bounded: a full queue sheds with
         InferenceOverloadedError after the enqueue timeout; a dead
         server refuses with the latched ServerDeadError."""
+        seq = next(self._seq)
+        with _mon.span("serve.submit", req=seq):
+            return self._submit(seq, prompt, max_new_tokens, eos_id,
+                                method, temperature, top_k, on_token,
+                                timeout_ms)
+
+    def _submit(self, seq, prompt, max_new_tokens, eos_id, method,
+                temperature, top_k, on_token, timeout_ms):
         from deeplearning4j_tpu.parallel.inference import bounded_enqueue
         if not self._warm:
             self.warmup()
@@ -798,6 +819,8 @@ class GenerationServer:
              else temperature),
             self.default_top_k if top_k is None else top_k,
             on_token=on_token)
+        req.seq = seq
+        req.t_submit = time.perf_counter()
         deadline = (None if timeout_ms is None
                     else time.monotonic() + float(timeout_ms) / 1e3)
         req.trace = _req.start("generation", meta={
@@ -875,6 +898,9 @@ class GenerationServer:
                 f"({req.max_new_tokens}) exceeds the top cache rung "
                 f"{self.cache_lengths[-1]}")
         rec = _SlotJournal(req, int(admit_id))
+        if not req.seq:     # built by a router, not by submit()
+            req.seq = next(self._seq)
+        req.t_submit = time.perf_counter()
         deadline = (None if timeout_ms is None
                     else time.monotonic() + float(timeout_ms) / 1e3)
         # same locked liveness check + bounded enqueue as submit(): the
@@ -909,7 +935,9 @@ class GenerationServer:
                         # growth attempts: wall-clock relief must fire
                         # from here or /health stays degraded forever
                         self._maybe_relieve_by_time()
-                    if not self._work.wait(timeout=0.05):
+                    with _mon.span("serve.idle"):
+                        woken = self._work.wait(timeout=0.05)
+                    if not woken:
                         continue
                     self._work.clear()
             except Exception as e:  # noqa: BLE001 — replay, stay up
@@ -1018,6 +1046,16 @@ class GenerationServer:
         req = rec.req
         plen = int(prompt.size)
         pbucket = next(p for p in self.prompt_buckets if p >= plen)
+        # how long the request sat in the queue: from submit()'s stamp
+        # (a replayed record is admitted again: its wait is since then)
+        waited = (-1 if req.t_submit is None else
+                  int((time.perf_counter() - req.t_submit) * 1e6))
+        with _mon.span("serve.admit", req=req.seq, prompt_len=plen,
+                       bucket=pbucket, queue_wait_us=waited):
+            self._admit_in_slot(rec, prompt, key, plen, pbucket)
+
+    def _admit_in_slot(self, rec, prompt, key, plen, pbucket):
+        req = rec.req
         needed = int(req.prompt.size) + req.max_new_tokens
         rung = self._rung
         if needed > rung or pbucket > rung:
@@ -1079,7 +1117,7 @@ class GenerationServer:
         self._state = tuple(out[:8])
         if self._pages is not None:
             self._emit_page_metrics()
-        first = int(self._fetch_tokens(out[8]))
+        first = int(self._fetch_tokens(out[8], req=req.seq))
         self._deliver(slot, rec, first)
 
     def _admit_key(self, admit_id):
@@ -1203,6 +1241,17 @@ class GenerationServer:
         its sampled-token output, then deliver the PREVIOUS block while
         this one computes — the journal append and stream delivery run
         behind compute instead of gating it."""
+        self._step_seq += 1
+        k = self.draft + 1 if self.draft else self.superstep
+        with _mon.span("serve.dispatch", step=self._step_seq, k=k,
+                       active=len(self._slot_req)):
+            prev = self._dispatch(k)
+        if prev is not None:
+            self._deliver_block(prev)
+
+    def _dispatch(self, k):
+        """The dispatch itself; returns the block that was in flight
+        before it, for `_dispatch_block` to deliver."""
         t0 = time.perf_counter()
         if _faults.ACTIVE is not None:
             # multi-token block dispatches (superstep scans AND
@@ -1213,35 +1262,37 @@ class GenerationServer:
                                 if self.superstep > 1 or self.draft
                                 else _faults.GENERATION_STEP)
         eos, budget = self._superstep_args()
-        ptab = (() if self._pages is None else
-                (self._page_args(self.draft + 1 if self.draft
-                                 else self.superstep),))
+        ptab = (() if self._pages is None else (self._page_args(k),))
         if self.draft:
             draft, dlen = self._propose_drafts()
             call = self._exes[("verify", self._rung, self.draft)]
             out = call(*self._margs, *self._state, eos, budget, draft,
                        dlen, *ptab)
-            k, proposed = self.draft + 1, dlen
+            proposed = dlen
         else:
             call = self._exes[("superstep", self._rung,
                                self.superstep)]
             out = call(*self._margs, *self._state, eos, budget, *ptab)
-            k, proposed = self.superstep, None
+            proposed = None
         self._state = tuple(out[:8])
         block = self._start_fetch(out[8])
         prev, self._inflight = self._inflight, _Block(
             block, dict(self._slot_req), k, t0, time.perf_counter(),
-            proposed)
-        if prev is not None:
-            self._deliver_block(prev)
+            proposed, self._step_seq)
+        return prev
 
     def _deliver_block(self, blk):
         """Materialize one sampled-token block (THE host sync) and
         deliver it step-major: -1 marks a frozen/empty lane; a slot
         retired or re-admitted since the block's dispatch is skipped
         (its journal snapshot no longer owns the slot)."""
+        with _mon.span("serve.deliver", step=blk.step) as sp:
+            sp.set_metadata(tokens=self._deliver_tokens(blk))
+
+    def _deliver_tokens(self, blk):
+        """`_deliver_block`'s work; returns the live tokens delivered."""
         overlap_ms = (time.perf_counter() - blk.t_copy) * 1e3
-        toks = self._fetch_tokens(blk.tokens)         # (k, S)
+        toks = self._fetch_tokens(blk.tokens, step=blk.step)  # (k, S)
         dt_ms = (time.perf_counter() - blk.t0) * 1e3
         # request timelines: one "block" event per still-owned slot —
         # appended HERE, on the existing fetch boundary (toks is host
@@ -1326,6 +1377,7 @@ class GenerationServer:
                             help="draft tokens proposed but not "
                                  "delivered (mismatch or EOS/budget "
                                  "truncation)").inc(rejects)
+        return live
 
     def _start_fetch(self, arr):
         """Start the NON-BLOCKING device→host copy of a sampled-token
@@ -1339,13 +1391,16 @@ class GenerationServer:
             pass                    # _fetch_tokens blocks as before
         return arr
 
-    def _fetch_tokens(self, arr):
+    def _fetch_tokens(self, arr, **ids):
         """THE per-superstep host sync: materialize the sampled-token
         block. The journal append rides this same boundary — `_deliver`
         stores the fetched tokens on the request's host-side list, so
-        crash-replay costs zero extra syncs."""
+        crash-replay costs zero extra syncs. `ids` name whose fetch it
+        is on the `serve.fetch` span: a superstep's `step`, an
+        admission's `req`."""
         self.token_fetches += 1
-        return np.asarray(arr)
+        with _mon.span("serve.fetch", **ids):
+            return np.asarray(arr)
 
     def _deliver(self, slot, rec, tok):
         req = rec.req

@@ -178,6 +178,7 @@ def _flash_forward(q, k, v, q_mask, kv_mask, causal, block_q, block_k,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(*operands)
     lse = lse[:, 0]
     out = out[:, :tq_a, :].reshape(b, h, tq_a, d)
@@ -359,6 +360,7 @@ def _flash_backward(q, k, v, q_mask, kv_mask, o, lse, g, causal, block_q,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(*operands)
 
     # dk/dv: swap the roles — k tiles outer, q tiles innermost
@@ -385,6 +387,7 @@ def _flash_backward(q, k, v, q_mask, kv_mask, o, lse, g, causal, block_q,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(*operands2)
 
     dq = dq[:, :tq_a, :].reshape(b, h, tq_a, d)
@@ -517,6 +520,7 @@ def _decode_reference_quantized(q, k_cache, v_cache, cache_mask,
     return jnp.where(any_valid[:, None, None, None], out, 0)
 
 
+@jax.named_scope("flash_decode")
 def flash_attention_decode_mq(q, k_cache, v_cache, q_mask, impl="auto"):
     """Multi-query decode attention: a DRAFT block of queries per
     sequence attends the cached K/V under a per-query validity mask.
@@ -572,6 +576,7 @@ def flash_attention_decode_mq(q, k_cache, v_cache, q_mask, impl="auto"):
     return jnp.where(any_valid[:, None, :, None], out, 0)
 
 
+@jax.named_scope("flash_decode")
 def flash_attention_decode(q1, k_cache, v_cache, cache_mask, impl="auto",
                            block_k=128, interpret=None, k_scale=None,
                            v_scale=None):
@@ -726,12 +731,13 @@ def flash_attention_decode_paged(q1, k_pool, v_pool, page_table,
     if (k_scale_pool is None) != (v_scale_pool is None):
         raise ValueError(
             "k_scale_pool and v_scale_pool must be given together")
-    kc = gather_kv_pages(k_pool, page_table)
-    vc = gather_kv_pages(v_pool, page_table)
     ks = vs = None
-    if k_scale_pool is not None:
-        ks = gather_scale_pages(k_scale_pool, page_table)
-        vs = gather_scale_pages(v_scale_pool, page_table)
+    with jax.named_scope("flash_decode"):    # the gathers are its work
+        kc = gather_kv_pages(k_pool, page_table)
+        vc = gather_kv_pages(v_pool, page_table)
+        if k_scale_pool is not None:
+            ks = gather_scale_pages(k_scale_pool, page_table)
+            vs = gather_scale_pages(v_scale_pool, page_table)
     return flash_attention_decode(q1, kc, vc, cache_mask, impl=impl,
                                   block_k=block_k, interpret=interpret,
                                   k_scale=ks, v_scale=vs)
@@ -748,6 +754,7 @@ def flash_attention_decode_mq_paged(q, k_pool, v_pool, page_table,
         raise ValueError(
             f"k_pool/v_pool must match as (P, H, ps, D): "
             f"{k_pool.shape} vs {v_pool.shape}")
-    kc = gather_kv_pages(k_pool, page_table)
-    vc = gather_kv_pages(v_pool, page_table)
+    with jax.named_scope("flash_decode"):    # the gathers are its work
+        kc = gather_kv_pages(k_pool, page_table)
+        vc = gather_kv_pages(v_pool, page_table)
     return flash_attention_decode_mq(q, kc, vc, q_mask, impl=impl)

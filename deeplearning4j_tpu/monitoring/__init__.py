@@ -1,6 +1,6 @@
 """Unified host-side metrics + span tracing (the monitoring subsystem).
 
-Dependency-free, disabled by default, and wired through the trainers
+Disabled by default, and wired through the trainers
 (`nn/multilayer.py`, `nn/graph.py`), the parallel stack
 (`parallel/wrapper.py`, `parallel/sharded_trainer.py`,
 `parallel/inference.py`), the executioner (`runtime/executioner.py`),
@@ -20,16 +20,24 @@ or explicitly:
     monitoring.export_chrome_trace("/tmp/fit_trace.json")  # Perfetto
     print(monitoring.get_registry().prometheus_text())
 
-Scope split across the repo's three observability layers:
-- monitoring (this package) — HOST-side: where did the step's wall time
-  go (data-iter / stage / dispatch / listeners / eval / checkpoint
-  spans), jit compile events, transfer bytes, the step-time attribution
-  flight recorder (`steps.py`, `GET /steps`), and device memory
-  telemetry + OOM forensics (`memory.py`);
-- `profiler.ProfileSession` + `optimize/xplane.py` — DEVICE-side: an
-  on-demand jax.profiler window over the next k steps decoded to a
-  per-op self-time/FLOPs/bytes table (`profile_next_steps(k)` /
-  `POST /profile?steps=k`; subsumes the old ProfilerListener window);
+ONE trace, host spans and device operations on one clock:
+- `span()` (`tracing.py`) is the program's one instrumentation point.
+  Every span — `fit.*` / `train.*` / `pipeline.*` in the trainers and
+  the input pipeline, `serve.*` in `generation/server.py`, `exec.*` on
+  the executable stores' miss path — is written into `jax.profiler`'s
+  trace as `dl4j.<name>` with its counts as stats, whether monitoring
+  is enabled or not; what the device runs carries stable names beside
+  them (`jit_superstep`, `jit_graph_train_step`, the `flash_*` kernels,
+  the decoder's `layer<i>/attn` scopes). So any profiler session —
+  `profiler.ProfileSession` (`profile_next_steps(k)` /
+  `POST /profile?steps=k`, decoded by `optimize/xplane.py` into a
+  per-op table), or `benchmarks/run.py --trace 1` — yields a trace in
+  which a gap on the device names what the host was doing in it.
+- with monitoring ENABLED the same spans also land in the in-memory
+  `Tracer` (Chrome trace JSON) and the step-time attribution flight
+  recorder (`steps.py`, `GET /steps`), next to the registry's metrics,
+  jit compile events, transfer bytes and device memory telemetry + OOM
+  forensics (`memory.py`);
 - `ui/stats.StatsListener` — LEARNING diagnostics: score curves, update
   ratios, activation histograms.
 """
@@ -106,8 +114,8 @@ from deeplearning4j_tpu.monitoring.registry import (  # noqa: F401
     bootstrap_core_metrics, collect_device_memory, get_registry,
     record_transfer)
 from deeplearning4j_tpu.monitoring.tracing import (  # noqa: F401
-    NULL_SPAN, Span, Tracer, export_chrome_trace, get_tracer, span,
-    traced_iter)
+    PROFILER_PREFIX, Span, Tracer, export_chrome_trace, get_tracer,
+    span, traced_iter)
 
 __all__ = [
     "enable", "disable", "enabled", "span", "traced_iter",
@@ -183,7 +191,8 @@ def enable():
 
 
 def disable():
-    """Back to the zero-overhead default (one branch per call site)."""
+    """Back to the default: registry calls one branch per call site, a
+    span one profiler annotation that records nothing."""
     STATE.enabled = False
 
 

@@ -1,16 +1,24 @@
-"""Lightweight span tracing with Chrome trace-event JSON export.
+"""The program's one span layer: `span("name", **values)`.
 
-`span("name")` is a context manager; nesting is tracked per thread, and
-the recorded events are Chrome trace-event "X" (complete) events, so the
-export loads directly into Perfetto / `chrome://tracing` and shows the
-host-side phase structure of a `fit()` — data-iter / dispatch / listener
-/ eval / checkpoint — that the device-side xplane trace
-(`optimize/xplane.py`) cannot see.
+`span()` is a context manager around one phase of host work. Every span
+is written into `jax.profiler`'s trace as `dl4j.<name>` (a
+`jax.profiler.TraceAnnotation`; the keyword values become the event's
+stats), so a profiler session — the benchmark's `--trace 1`, an
+operator's `POST /profile?steps=k` — holds the host's phases and the
+device's operations in ONE trace on ONE clock: a gap on the device
+names what the host was doing in it.
 
-Disabled fast path: `span()` returns a shared no-op singleton after ONE
-flag check — no allocation, nothing recorded. Event storage is bounded
-(`max_events`), so a forgotten `enable()` cannot leak memory over a long
-training run.
+With monitoring enabled a span is also recorded into the in-memory
+`Tracer` (per-thread nesting, Chrome trace-event "X" events for
+Perfetto / `chrome://tracing`, the feed of the step-attribution
+recorder in `monitoring/steps.py`) under its bare name.
+
+Disabled path: `span()` returns ONE annotation that records nothing —
+outside a profiler session a `TraceAnnotation` is a flag test in C++,
+so the cost is its construction and the `with` (0.6 us on the chip
+machine's host, 1.0 us with three values: PERF.md Findings PR 26), and
+nothing reaches the `Tracer`. Event storage is bounded (`max_events`),
+so a forgotten `enable()` cannot leak memory over a long training run.
 """
 from __future__ import annotations
 
@@ -19,41 +27,44 @@ import os
 import threading
 import time
 
+from jax.profiler import TraceAnnotation as _Annotation
+
 from deeplearning4j_tpu.monitoring.state import STATE
 from deeplearning4j_tpu.monitoring import steps as _steps
 
-
-class _NullSpan:
-    """Shared do-nothing span for the disabled path."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-NULL_SPAN = _NullSpan()
+#: what every span's name starts with in the profiler's trace (added
+#: here, where the annotation is written: the Tracer, `on_span` and the
+#: call sites know the bare names)
+PROFILER_PREFIX = "dl4j."
 
 
 class Span:
-    __slots__ = ("name", "args", "_tracer", "_t0")
+    __slots__ = ("name", "args", "_tracer", "_t0", "_note")
 
     def __init__(self, tracer, name, args=None):
         self._tracer = tracer
         self.name = name
         self.args = args
         self._t0 = 0
+        self._note = _Annotation(PROFILER_PREFIX + name, **(args or {}))
+
+    def set_metadata(self, **values):
+        """Values known only inside the span (a count of what it
+        delivered): added to the profiler event's stats and the Tracer
+        event's args, as if given to `span()`. A disabled span is the
+        bare annotation, which has this method of its own."""
+        self.args = {**self.args, **values} if self.args else values
+        self._note.set_metadata(**values)
 
     def __enter__(self):
         self._tracer._local.stack.append(self.name)
+        self._note.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter_ns()
+        self._note.__exit__(exc_type, exc, tb)
         local = self._tracer._local
         stack = local.stack
         if stack and stack[-1] == self.name:
@@ -118,9 +129,9 @@ class Tracer:
             else:
                 self._dropped += 1
         # feed the step-attribution flight recorder (monitoring/steps.py):
-        # reached only when monitoring is enabled (disabled spans are the
-        # shared NULL_SPAN and never get here), and on_span is one dict
-        # lookup for spans it doesn't track
+        # reached only when monitoring is enabled (a disabled span is a
+        # bare profiler annotation and never gets here), and on_span is
+        # one dict lookup for spans it doesn't track
         _steps.recorder().on_span(span.name, (t1_ns - t0_ns) / 1e6)
 
     def current_stack(self):
@@ -230,13 +241,18 @@ def get_tracer():
     return _global_tracer
 
 
-def span(name, args=None):
+def span(name, args=None, **values):
     """THE instrumentation point: a context manager timing one phase.
+    Keyword `values` (counts taken at the same boundary: `step`, `req`,
+    `active`, `bytes`...) ride along as the profiler event's stats and
+    the Tracer event's args.
 
-    Disabled (the default): one flag check, returns the shared no-op
-    singleton — no allocation, no lock, nothing recorded."""
+    Disabled (the default): one annotation that records nothing unless
+    a profiler session is on — no lock, nothing in the Tracer."""
     if not STATE.enabled:
-        return NULL_SPAN
+        return _Annotation(PROFILER_PREFIX + name, **values)
+    if values:
+        args = {**args, **values} if args else values
     return _global_tracer.span(name, args)
 
 
@@ -247,10 +263,8 @@ def export_chrome_trace(path):
 
 def traced_iter(iterable, name="fit.data_next"):
     """Wrap data iteration so time spent PULLING batches (host input
-    pipeline) shows as its own span per batch. Disabled → returns the
-    iterable untouched (zero cost)."""
-    if not STATE.enabled:
-        return iterable
+    pipeline) shows as its own span per batch — `span()`'s rule: always
+    in a profiler session's trace, in the Tracer only when enabled."""
 
     def gen():
         it = iter(iterable)

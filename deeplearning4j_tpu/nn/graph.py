@@ -487,7 +487,8 @@ class ComputationGraph:
         tx = self._tx
 
         @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
-        def step(params, opt_state, state, inputs, labels, fmasks, lmasks, rng):
+        def graph_train_step(params, opt_state, state, inputs, labels, fmasks,
+                             lmasks, rng):
             (loss, new_state), grads = jax.value_and_grad(
                 lambda p: self._loss(p, state, inputs, labels, fmasks, lmasks,
                                      rng), has_aux=True)(params)
@@ -496,7 +497,7 @@ class ComputationGraph:
             params = self._apply_constraints(params)
             return params, opt_state, new_state, loss
 
-        return step
+        return graph_train_step
 
     @functools.cached_property
     def _train_step_guarded(self):
@@ -508,8 +509,8 @@ class ComputationGraph:
         tx = self._tx
 
         @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
-        def step(params, opt_state, state, inputs, labels, fmasks,
-                 lmasks, rng, lr_scale, max_gnorm):
+        def graph_train_step_guarded(params, opt_state, state, inputs, labels,
+                                     fmasks, lmasks, rng, lr_scale, max_gnorm):
             (loss, new_state), grads = jax.value_and_grad(
                 lambda p: self._loss(p, state, inputs, labels, fmasks,
                                      lmasks, rng), has_aux=True)(params)
@@ -520,7 +521,7 @@ class ComputationGraph:
                     extra=((new_state, state),))
             return params, opt_state, state, loss, gnorm, ok
 
-        return step
+        return graph_train_step_guarded
 
     def _apply_constraints(self, params):
         """Post-update constraints per layer vertex (≡ BaseConstraint)."""
@@ -617,8 +618,8 @@ class ComputationGraph:
         tx = self._tx
 
         @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
-        def scan_steps(params, opt_state, state, ins, labels, fmasks,
-                       lmasks, rngs):
+        def graph_train_step_scan(params, opt_state, state, ins, labels,
+                                  fmasks, lmasks, rngs):
             def body(carry, inp):
                 p, o, s = carry
                 i_, l_, fm, lm, rng = inp
@@ -635,7 +636,7 @@ class ComputationGraph:
                 (ins, labels, fmasks, lmasks, rngs))
             return params, opt_state, state, losses
 
-        return scan_steps
+        return graph_train_step_scan
 
     def _fit_batches_scanned(self, unpacked):
         """Flush a group of already-unpacked same-structure batches. Only
@@ -691,8 +692,8 @@ class ComputationGraph:
         tx = self._tx
 
         @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
-        def step(params, opt_state, state, ins, labels, fmasks, lmasks,
-                 rngs):
+        def graph_train_step_accum(params, opt_state, state, ins, labels,
+                                   fmasks, lmasks, rngs):
             grads, loss, _, state = _accum.accum_scan(
                 self._accum_grad_fn, params, state,
                 (ins, labels, fmasks, lmasks, rngs))
@@ -701,7 +702,7 @@ class ComputationGraph:
             params = self._apply_constraints(params)
             return params, opt_state, state, loss
 
-        return step
+        return graph_train_step_accum
 
     def _accum_grad_fn(self, params, state, inp):
         """One microbatch's ((loss, new_state), grads) for accum_scan."""
@@ -720,8 +721,9 @@ class ComputationGraph:
         tx = self._tx
 
         @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
-        def step(params, opt_state, state, ins, labels, fmasks, lmasks,
-                 rngs, lr_scale, max_gnorm):
+        def graph_train_step_accum_guarded(params, opt_state, state, ins,
+                                           labels, fmasks, lmasks, rngs,
+                                           lr_scale, max_gnorm):
             grads, loss, micro_ok, new_state = _accum.accum_scan(
                 self._accum_grad_fn, params, state,
                 (ins, labels, fmasks, lmasks, rngs))
@@ -733,7 +735,7 @@ class ComputationGraph:
                     extra=((new_state, state),))
             return params, opt_state, state, loss, gnorm, ok
 
-        return step
+        return graph_train_step_accum_guarded
 
     def _fit_batches_accum(self, group):
         """Flush a FULL G-batch group of unpacked batches through one

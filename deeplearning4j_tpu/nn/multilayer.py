@@ -407,7 +407,8 @@ class MultiLayerNetwork:
         tx = self._tx
 
         @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
-        def step(params, opt_state, state, x, y, fmask, lmask, rng):
+        def multilayer_train_step(params, opt_state, state, x, y, fmask, lmask,
+                                  rng):
             (loss, (new_state, _)), grads = jax.value_and_grad(
                 lambda p: self._loss(p, state, x, y, fmask, lmask, rng),
                 has_aux=True)(params)
@@ -416,7 +417,7 @@ class MultiLayerNetwork:
             params = self._apply_constraints(params)
             return params, opt_state, new_state, loss
 
-        return step
+        return multilayer_train_step
 
     @functools.cached_property
     def _train_step_guarded(self):
@@ -433,8 +434,9 @@ class MultiLayerNetwork:
         tx = self._tx
 
         @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
-        def step(params, opt_state, state, x, y, fmask, lmask, rng,
-                 lr_scale, max_gnorm):
+        def multilayer_train_step_guarded(params, opt_state, state, x, y,
+                                          fmask, lmask, rng, lr_scale,
+                                          max_gnorm):
             (loss, (new_state, _)), grads = jax.value_and_grad(
                 lambda p: self._loss(p, state, x, y, fmask, lmask, rng),
                 has_aux=True)(params)
@@ -445,7 +447,7 @@ class MultiLayerNetwork:
                     extra=((new_state, state),))
             return params, opt_state, state, loss, gnorm, ok
 
-        return step
+        return multilayer_train_step_guarded
 
     @functools.cached_property
     def _train_scan(self):
@@ -461,8 +463,8 @@ class MultiLayerNetwork:
         tx = self._tx
 
         @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
-        def scan_steps(params, opt_state, state, xs, ys, fmasks, lmasks,
-                       rngs):
+        def multilayer_train_step_scan(params, opt_state, state, xs, ys,
+                                       fmasks, lmasks, rngs):
             def body(carry, inp):
                 p, o, s = carry
                 x, y, fm, lm, rng = inp
@@ -479,7 +481,7 @@ class MultiLayerNetwork:
                 (xs, ys, fmasks, lmasks, rngs))
             return params, opt_state, state, losses
 
-        return scan_steps
+        return multilayer_train_step_scan
 
     def _fit_batches_scanned(self, group):
         """Flush a same-shape batch group through ONE scanned dispatch.
@@ -544,7 +546,8 @@ class MultiLayerNetwork:
         tx = self._tx
 
         @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
-        def step(params, opt_state, state, xs, ys, fmasks, lmasks, rngs):
+        def multilayer_train_step_accum(params, opt_state, state, xs, ys,
+                                        fmasks, lmasks, rngs):
             grads, loss, _, state = _accum.accum_scan(
                 self._accum_grad_fn, params, state,
                 (xs, ys, fmasks, lmasks, rngs))
@@ -553,7 +556,7 @@ class MultiLayerNetwork:
             params = self._apply_constraints(params)
             return params, opt_state, state, loss
 
-        return step
+        return multilayer_train_step_accum
 
     def _accum_grad_fn(self, params, state, inp):
         """One microbatch's ((loss, new_state), grads) for accum_scan
@@ -579,8 +582,9 @@ class MultiLayerNetwork:
         tx = self._tx
 
         @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
-        def step(params, opt_state, state, xs, ys, fmasks, lmasks, rngs,
-                 lr_scale, max_gnorm):
+        def multilayer_train_step_accum_guarded(params, opt_state, state, xs,
+                                                ys, fmasks, lmasks, rngs,
+                                                lr_scale, max_gnorm):
             grads, loss, micro_ok, new_state = _accum.accum_scan(
                 self._accum_grad_fn, params, state,
                 (xs, ys, fmasks, lmasks, rngs))
@@ -592,7 +596,7 @@ class MultiLayerNetwork:
                     extra=((new_state, state),))
             return params, opt_state, state, loss, gnorm, ok
 
-        return step
+        return multilayer_train_step_accum_guarded
 
     def _fit_batches_accum(self, group):
         """Flush a FULL G-batch group through one accumulated optimizer
@@ -660,7 +664,8 @@ class MultiLayerNetwork:
         tx = self._tx
 
         @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
-        def step(params, opt_state, state, carries, x, y, fmask, lmask, rng):
+        def multilayer_train_step_tbptt(params, opt_state, state, carries, x,
+                                        y, fmask, lmask, rng):
             def lossf(p):
                 loss, (new_state, new_carries) = self._loss(
                     p, state, x, y, fmask, lmask, rng, carries=carries)
@@ -674,7 +679,7 @@ class MultiLayerNetwork:
             params = self._apply_constraints(params)
             return params, opt_state, new_state, new_carries, loss
 
-        return step
+        return multilayer_train_step_tbptt
 
     @functools.cached_property
     def _train_step_tbptt_guarded(self):
@@ -689,8 +694,9 @@ class MultiLayerNetwork:
         tx = self._tx
 
         @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
-        def step(params, opt_state, state, carries, x, y, fmask, lmask,
-                 rng, lr_scale, max_gnorm):
+        def multilayer_train_step_tbptt_guarded(params, opt_state, state,
+                                                carries, x, y, fmask, lmask,
+                                                rng, lr_scale, max_gnorm):
             def lossf(p):
                 loss, (new_state, new_carries) = self._loss(
                     p, state, x, y, fmask, lmask, rng, carries=carries)
@@ -706,7 +712,7 @@ class MultiLayerNetwork:
                     extra=((new_state, state), (new_carries, carries)))
             return params, opt_state, state, carries, loss, gnorm, ok
 
-        return step
+        return multilayer_train_step_tbptt_guarded
 
     def _zero_carries(self, batch):
         carries = {}
@@ -819,7 +825,7 @@ class MultiLayerNetwork:
         opt_state = tx.init(self._params[key])
 
         @jax.jit
-        def step(p, opt, x, rng):
+        def multilayer_pretrain_step(p, opt, x, rng):
             loss, grads = jax.value_and_grad(layer.pretrain_loss)(p, x, rng)
             updates, opt = tx.update(grads, opt, p)
             return optax.apply_updates(p, updates), opt, loss
@@ -843,7 +849,8 @@ class MultiLayerNetwork:
                 if pp is not None:
                     feats = pp.preProcess(feats)
                 self._rng_key, sub = jax.random.split(self._rng_key)
-                p, opt_state, loss = step(p, opt_state, feats, sub)
+                p, opt_state, loss = multilayer_pretrain_step(
+                    p, opt_state, feats, sub)
                 self._score = loss    # lazy; score() floats on demand
         self._params[key] = p
         self._build_optimizer()  # opt state shapes unchanged but refresh

@@ -42,7 +42,7 @@ enabled-guard) + `GET /executables` on the UIServer via `status()`.
 
 Cache layout (versioned; bump LAYOUT_VERSION to invalidate):
 
-    <DL4J_EXEC_CACHE>/v2/<device-flavour>/<model-fingerprint>/<sig>.exe
+    <DL4J_EXEC_CACHE>/v3/<device-flavour>/<model-fingerprint>/<sig>.exe
 
 - device-flavour: backend + device_kind (+ host CPU feature hash on
   CPU — XLA:CPU serializes machine code; a foreign host must MISS,
@@ -58,6 +58,7 @@ Cache layout (versioned; bump LAYOUT_VERSION to invalidate):
 """
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import os
 import pickle
@@ -82,7 +83,9 @@ __all__ = [
 ]
 
 #: bump to invalidate every on-disk serialized executable at once
-LAYOUT_VERSION = "v2"
+#: (v3: programs carry their registered names — `jit_superstep`, not
+#: `jit_run` — and an entry written before would load under the old one)
+LAYOUT_VERSION = "v3"
 #: on-disk serialized-executable cache root ("" → in-process tiers only)
 ENV_CACHE_DIR = "DL4J_EXEC_CACHE"
 
@@ -312,8 +315,12 @@ class _AotStoreBase:
         self.fingerprint = fingerprint
         self.flavour = device_flavour()
         self.trace_calls = 0        # times a python fn was traced
+        # `load_seconds` / `compile_seconds` sum the miss path's two
+        # ways out (always on: they cost the hot path nothing), so
+        # "time to resume from the on-disk store" is a number
         self.stats = {"memory_hits": 0, "disk_hits": 0, "compiles": 0,
-                      "deserialize_failures": 0, "serialize_failures": 0}
+                      "deserialize_failures": 0, "serialize_failures": 0,
+                      "load_seconds": 0.0, "compile_seconds": 0.0}
         self._mem = {}
         self._lock = threading.Lock()
         # third tier: live compiles (cache-layout misses) still warm
@@ -321,10 +328,14 @@ class _AotStoreBase:
         configure_persistent_cache()
         _STORES.add(self)
 
-    def _counted(self, fwd):
+    def _counted(self, fwd, name):
+        """`fwd` with its traces counted, under `name`: jax names the
+        compiled module after the function (`jit_<name>`), which is how
+        a profiler trace tells the store's programs apart."""
         def run(*args):
             self.trace_calls += 1   # once per TRACE, never per call
             return fwd(*args)
+        run.__name__ = run.__qualname__ = name
         return run
 
     # -- hot path ---------------------------------------------------------
@@ -385,15 +396,29 @@ class _AotStoreBase:
                 _faults.ACTIVE.fire(_faults.EXECUTABLES_LOAD)
             path = (self._entry_path(key) if self.directory else None)
             if path is not None and os.path.exists(path):
-                e = self._load_disk(key, path)
+                with self._miss("load", key):
+                    e = self._load_disk(key, path)
                 if e is not None:
                     self._mem[key] = e
                     self._note_cost(key, e)
                     return e
-            e = self._compile_live(key, lower_fn, path)
+            with self._miss("compile", key):
+                e = self._compile_live(key, lower_fn, path)
             self._mem[key] = e
             self._note_cost(key, e)
             return e
+
+    @contextlib.contextmanager
+    def _miss(self, what, key):
+        """One way out of a miss, `load` or `compile`: a span
+        `exec.<what>` with the store key, and its seconds summed into
+        `stats["<what>_seconds"]`."""
+        t0 = time.perf_counter()
+        try:
+            with _mon.span("exec." + what, key=repr(key)):
+                yield
+        finally:
+            self.stats[what + "_seconds"] += time.perf_counter() - t0
 
     def _load_disk(self, key, path):
         try:
@@ -587,8 +612,10 @@ class ExecutableStore(_AotStoreBase):
         # masked variant: (B, T) validity mask appended after the
         # inputs (length-bucketed sequence serving pads the time axis)
         self._fwds = {
-            False: self._counted(forward_fn(model, with_mask=False)),
-            True: self._counted(forward_fn(model, with_mask=True))}
+            False: self._counted(forward_fn(model, with_mask=False),
+                                 "forward"),
+            True: self._counted(forward_fn(model, with_mask=True),
+                                "forward_masked")}
 
     # -- hot path ---------------------------------------------------------
     def lookup(self, sig, with_mask=False):
@@ -674,7 +701,8 @@ class FunctionStore(_AotStoreBase):
         self._fns = {}
 
     def register(self, name, fn, donate_argnums=()):
-        self._fns[name] = (self._counted(fn), tuple(donate_argnums))
+        self._fns[name] = (self._counted(fn, name),
+                           tuple(donate_argnums))
         return self
 
     # -- miss path (boundary: the lint stops descending here) -------------

@@ -235,11 +235,31 @@ def _host_floats_finite(arrays):
     return True
 
 
+def _host_bytes(arrays):
+    """Bytes `_owned` will copy to the device: what is not there yet."""
+    return sum(int(getattr(a, "nbytes", 0)) for a in arrays
+               if a is not None and not isinstance(a, jax.Array))
+
+
 def stage_dataset(ds, check_finite=False):
     """Stage one DataSet/MultiDataSet onto the device through XLA-owned
     copies. Runs on the prefetch worker thread, overlapping the NEXT
-    step's H2D conversion with the current step's compute."""
+    step's H2D conversion with the current step's compute. The
+    `pipeline.stage` span (with the `bytes` it staged) lands on
+    whichever thread runs it: the worker's lane, or the caller's."""
     multi = isinstance(getattr(ds, "features", None), (list, tuple))
+    if multi:
+        arrays = [*ds.features, *(ds.labels or ()),
+                  *(ds.featuresMasks or ()), *(ds.labelsMasks or ())]
+    else:
+        arrays = [ds.features, ds.labels,
+                  getattr(ds, "featuresMask", None),
+                  getattr(ds, "labelsMask", None)]
+    with _mon.span("pipeline.stage", bytes=_host_bytes(arrays)):
+        return _stage_dataset(ds, multi, check_finite)
+
+
+def _stage_dataset(ds, multi, check_finite):
     if multi:
         arrays = list(ds.features) + list(ds.labels or [])
         finite = _host_floats_finite(arrays) if check_finite else None
@@ -362,9 +382,21 @@ class PrefetchIterator:
 
     def _get_item(self):
         self._ensure_thread()
+        with _mon.span("pipeline.wait"):
+            item = self._take()
+        if STATE.enabled:
+            _mon.get_registry().gauge(
+                _mon.PIPELINE_PREFETCH_DEPTH,
+                help="staged batches waiting in the prefetch queue "
+                     "(0 = device waiting on the loader)") \
+                .set(self._queue.qsize())
+        return item
+
+    def _take(self):
+        """The consumer's blocking take (the `pipeline.wait` span)."""
         while True:
             try:
-                item = self._queue.get(timeout=self._POLL_S)
+                return self._queue.get(timeout=self._POLL_S)
             except _queue.Empty:
                 t = self._thread
                 if t is not None and t.is_alive():
@@ -373,20 +405,13 @@ class PrefetchIterator:
                 # where it posted between our get timing out and the
                 # liveness check
                 try:
-                    item = self._queue.get_nowait()
+                    return self._queue.get_nowait()
                 except _queue.Empty:
                     if self._error is not None:
                         raise self._error
                     raise RuntimeError(
                         "prefetch worker died without delivering a batch, "
                         "an error, or end-of-stream")
-            if STATE.enabled:
-                _mon.get_registry().gauge(
-                    _mon.PIPELINE_PREFETCH_DEPTH,
-                    help="staged batches waiting in the prefetch queue "
-                         "(0 = device waiting on the loader)") \
-                    .set(self._queue.qsize())
-            return item
 
     def hasNext(self):
         if self._peek is self._EMPTY:

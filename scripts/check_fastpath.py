@@ -2,15 +2,20 @@
 """Fast-path lint: instrumented hot-path modules must not call the
 metrics registry outside an enabled-guard.
 
-The monitoring contract since PR 1 is ONE branch on the disabled path:
-every `registry.counter(...)` / `.gauge(...)` / `.histogram(...)` /
+The monitoring contract on the disabled path: every
+`registry.counter(...)` / `.gauge(...)` / `.histogram(...)` /
 `get_registry()` reachable per-step must sit inside the
 `if _mon.enabled():` / `if STATE.enabled:` guard pattern (or behind an
-early `if not ...enabled...: return`). A bare registry call costs a
-lock + dict lookup + possible allocation per step even with monitoring
-off — exactly the always-on overhead the disabled-by-default design
-exists to prevent, and the kind of regression that creeps in silently
-with new instrumentation.
+early `if not ...enabled...: return`) — ONE branch. A bare registry call
+costs a lock + dict lookup + possible allocation per step even with
+monitoring off — exactly the always-on overhead the disabled-by-default
+design exists to prevent, and the kind of regression that creeps in
+silently with new instrumentation. `_mon.span(...)` is the one thing
+allowed outside the guard: disabled, it is ONE profiler annotation that
+records nothing (`jax.profiler.TraceAnnotation`, a flag test in C++
+unless a profiler session is on; measured cost in PERF.md, Findings
+PR 26), which is what puts the program's phases into a profiler trace
+beside the device's operations.
 
 This script AST-walks the hot-path modules and reports violations;
 `tests/test_fastpath_lint.py` runs it in tier-1 so a violating PR fails
@@ -18,8 +23,8 @@ CI. Run manually:  python scripts/check_fastpath.py  (exit 1 on
 violations).
 
 Intentionally NOT linted: `monitoring/` internals (they ARE the guard),
-`_mon.span(...)` / `record_transfer(...)` / `step_recorder()` (each
-internally one flag check), and cold-path modules (listeners, ui,
+`_mon.span(...)` (above), `record_transfer(...)` / `step_recorder()`
+(each internally one flag check), and cold-path modules (listeners, ui,
 resilience policies) where a per-call registry lookup is irrelevant.
 """
 from __future__ import annotations

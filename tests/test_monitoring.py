@@ -128,19 +128,26 @@ def test_prometheus_text_format():
 
 # -- disabled fast path ----------------------------------------------------
 def test_disabled_span_is_shared_noop_singleton():
+    """The disabled contract since ISSUE 26: a span is ONE profiler
+    annotation (a flag test in C++ outside a profiler session) that
+    records nothing in the Tracer — not a Span, no lock, no event."""
     mon.disable()
     s1 = mon.span("a")
-    s2 = mon.span("b")
-    assert s1 is s2 is mon.NULL_SPAN               # no per-call allocation
+    s2 = mon.span("b", step=3)                     # values ride along
+    assert not isinstance(s1, mon.Span) and type(s1) is type(s2)
     with s1:
-        pass
+        with s2 as inner:
+            inner.set_metadata(tokens=1)           # same surface as Span
     assert mon.get_tracer().events() == []
+    assert mon.get_tracer().current_stack() == []
 
 
 def test_disabled_traced_iter_and_transfer_are_noops():
     mon.disable()
     data = [1, 2, 3]
-    assert mon.traced_iter(data) is data           # untouched iterable
+    # same items, one annotation per pull, nothing in the Tracer
+    assert list(mon.traced_iter(data)) == data
+    assert mon.get_tracer().events() == []
     reg = MetricsRegistry()
     mon.record_transfer(1 << 20, registry=reg)
     assert reg.get(mon.TRANSFER_H2D_BYTES) is None  # nothing created

@@ -177,3 +177,16 @@ CASES = [
 def test_kernel_compiles_for_v5e(compile_for_chip, fn, specs, has_kernel):
     text = compile_for_chip(fn, *specs)
     assert ("tpu_custom_call" in text) == has_kernel
+
+
+def test_decode_kernel_carries_its_names_for_v5e(compile_for_chip):
+    """What a profiler trace on the chip tells the decode kernel by: the
+    custom call is named after the `pallas_call`'s `name`, under the entry
+    point's scope (benchmarks/readers/program_span.py reads both)."""
+    text = compile_for_chip(_decode, ((8, 12, 64), f32),
+                            *[((8, 12, 512, 64), f32)] * 2,
+                            ((8, 512), i32))
+    call, = [l for l in text.splitlines()
+             if "tpu_custom_call" in l and " custom-call(" in l]
+    assert call.lstrip().lstrip("%").startswith("flash_fwd")
+    assert "flash_decode/flash_fwd" in call
