@@ -4,14 +4,21 @@ donated device state.
 Two adapters expose one contract to the GenerationServer:
 
 - **BertDecoder** — transformer stacks built on `models/bert.py` params:
-  per-layer K/V caches `(L, S, H, C, Dh)` (C = cache-length rung) with a
-  rolling per-slot position index. `step` embeds the current token at
-  its slot position, writes its K/V row, and attends the single query
-  against the cached keys via `flash_attention_decode` (Pallas kernel on
-  TPU, einsum elsewhere) — O(C) work per token instead of the O(T²)
-  full-sequence re-forward. `prefill` runs the causal full forward over
-  a length-bucketed prompt and writes the whole K/V block into the
-  slot's cache rows in one shot.
+  one K and one V cache leaf A LAYER, `(S, C, H·Dh)` (S slots, C =
+  cache-length rung), with a rolling per-slot position index. Rows are
+  major and the hidden width is the minor dimension — a whole number of
+  128-lane tiles — so the donated state, the row write and the decode
+  kernel all take a leaf as it lies and the compiled step never re-lays
+  or slices the cache (with Dh = 64 minor, half a lane tile, the TPU
+  picks a layout per use and copies the whole cache between them; a
+  stacked layer axis adds a slice per layer). `step` embeds the current token at
+  its slot position, writes its K/V row (S whole rows of H·Dh lanes),
+  and attends the single query against the cached keys via
+  `flash_attention_decode` (Pallas kernel on TPU, einsum elsewhere) —
+  O(C) work per token instead of the O(T²) full-sequence re-forward.
+  `prefill` runs the causal full forward over a length-bucketed prompt
+  and writes the whole `(P, H·Dh)` K/V block into the slot's cache rows
+  in one shot.
 
 - **RecurrentDecoder** — LSTM/GRU-style `MultiLayerNetwork`s
   (TextGenerationLSTM and friends): the decode state is the per-layer
@@ -28,9 +35,16 @@ server — nothing here may touch the host):
     grow(cache, new_len)          -> cache padded to a longer rung
     init_cache(slots, cache_len)  -> donated cache pytree
 
-PAGED mode (`BertDecoder(..., page_size=ps, pool_pages=P)`): the cache
-pytree becomes a pooled layout `(L, P, H, ps, Dh)` — P fixed-size pages
-shared by every slot — and `step`/`verify`/`prefill` take the per-slot
+The BertDecoder cache pytree: `{"k": [L leaves], "v": [L leaves]}`, each
+leaf `(S, C, H·Dh)` in the compute dtype; `kv_dtype="int8"` stores the
+leaves int8 and adds `"ks"`/`"vs"`, L leaves `(S, C, H)` of float32
+per-(row, head) scales. The server treats it as opaque.
+
+PAGED mode (`BertDecoder(..., page_size=ps, pool_pages=P)`): each leaf
+becomes a pool `(P, ps, H·Dh)` (scales `(P, ps, H)`) — P fixed-size pages
+shared by every slot, rows major like the dense leaf, so a slot's
+gathered view `(S, C, H·Dh)` is a gather and a free reshape — and
+`step`/`verify`/`prefill` take the per-slot
 page index the host allocator (generation/paging.py) computes between
 dispatches (`ptab` (S, rung//ps) for decode reads/writes, `wrow`
 (ceil(P_bucket/ps),) write-redirect for prefill). Physical page 0 is the
@@ -135,9 +149,14 @@ class BertDecoder:
             self.page_size = self.pool_pages = None
 
     def fingerprint(self):
+        # the cache tree is part of every executable's signature: a
+        # program stored for another cache layout must not be handed
+        # this one's state
         parts = ("bert-decode", repr(self.cfg), self.attn_impl,
                  self.kv_dtype, self.page_size, self.pool_pages,
-                 _shape_tree_repr(self.params))
+                 _shape_tree_repr(self.params),
+                 _shape_tree_repr(
+                     jax.eval_shape(lambda: self.init_cache(1, 1))))
         return hashlib.sha256(repr(parts).encode()).hexdigest()[:16]
 
     def model_args(self):
@@ -145,47 +164,49 @@ class BertDecoder:
 
     def init_cache(self, slots, cache_len):
         cfg = self.cfg
-        if self.paged:
-            # pooled pages, slot- and rung-independent: the rung only
-            # sets the gathered view width (ptab columns); HBM is
-            # pool_pages × page_size rows, int8 halving page bytes
-            shape = (cfg.num_layers, self.pool_pages, cfg.num_heads,
-                     self.page_size, cfg.head_dim)
-        else:
-            shape = (cfg.num_layers, slots, cfg.num_heads, cache_len,
-                     cfg.head_dim)
+        # pooled pages are slot- and rung-independent: the rung only
+        # sets the gathered view width (ptab columns); HBM is
+        # pool_pages × page_size rows, int8 halving page bytes
+        lead = ((self.pool_pages, self.page_size) if self.paged
+                else (slots, cache_len))
+
+        def leaves(width, dtype, make=jnp.zeros):
+            return [make(lead + (width,), dtype)
+                    for _ in range(cfg.num_layers)]
+
+        hidden = cfg.num_heads * cfg.head_dim
         if self.kv_dtype == "int8":
-            return {"k": jnp.zeros(shape, jnp.int8),
-                    "v": jnp.zeros(shape, jnp.int8),
-                    "ks": jnp.ones(shape[:4], jnp.float32),
-                    "vs": jnp.ones(shape[:4], jnp.float32)}
-        dt = cfg.compute_dtype
-        return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+            return {"k": leaves(hidden, jnp.int8),
+                    "v": leaves(hidden, jnp.int8),
+                    "ks": leaves(cfg.num_heads, jnp.float32, jnp.ones),
+                    "vs": leaves(cfg.num_heads, jnp.float32, jnp.ones)}
+        return {"k": leaves(hidden, cfg.compute_dtype),
+                "v": leaves(hidden, cfg.compute_dtype)}
 
     def grow(self, cache, new_len):
         if self.paged:      # the pool is rung-independent
             return cache
-        pad = [(0, 0)] * 5
-        pad[3] = (0, int(new_len) - cache["k"].shape[3])
-        out = {"k": jnp.pad(cache["k"], pad),
-               "v": jnp.pad(cache["v"], pad)}
-        if "ks" in cache:   # scale rows pad at 1 (zero rows round-trip)
-            out["ks"] = jnp.pad(cache["ks"], pad[:4], constant_values=1.0)
-            out["vs"] = jnp.pad(cache["vs"], pad[:4], constant_values=1.0)
-        return out
+
+        def pad(t, fill):
+            return jnp.pad(t, ((0, 0), (0, int(new_len) - t.shape[1]),
+                               (0, 0)), constant_values=fill)
+
+        # scale rows pad at 1 (zero rows round-trip)
+        return {name: [pad(t, 1 if name in ("ks", "vs") else 0)
+                       for t in leaves]
+                for name, leaves in cache.items()}
 
     def page_copy(self, cache, src, dst):
-        """Copy physical page `src` over `dst` across every layer and
-        pool leaf — the copy-on-write primitive: the host allocator
+        """Copy physical page `src` over `dst` in every layer's pool
+        leaves — the copy-on-write primitive: the host allocator
         dispatches this (pre-compiled, donated) before the first block
         that would write into a shared page."""
-        out = {}
-        for name, t in cache.items():
-            zeros = (0,) * (t.ndim - 2)
-            pg = lax.dynamic_slice(
-                t, (0, src) + zeros, (t.shape[0], 1) + t.shape[2:])
-            out[name] = lax.dynamic_update_slice(t, pg, (0, dst) + zeros)
-        return out
+        def copy(pool):
+            page = lax.dynamic_slice(pool, (src, 0, 0),
+                                     (1,) + pool.shape[1:])
+            return lax.dynamic_update_slice(pool, page, (dst, 0, 0))
+
+        return jax.tree_util.tree_map(copy, cache)
 
     def _embed(self, params, tokens, pos):
         """Token + position embedding at per-slot positions (mirrors
@@ -197,7 +218,9 @@ class BertDecoder:
                            emb["ln_scale"], emb["ln_bias"],
                            self.cfg.layer_norm_eps)
 
-    def _decode_attn(self, q, kc, vc, cmask, ks=None, vs=None):
+    def _decode_attn(self, q, kc, vc, cmask, ptab=None, ks=None, vs=None):
+        """One layer's decode attention over its cache leaves — through
+        the page index when `ptab` is given."""
         impl = self.attn_impl
         if impl == "auto":
             # int8 cache: the quantized decode GEMV reads the cache at
@@ -206,18 +229,12 @@ class BertDecoder:
             # 'pallas' + int8 is rejected at construction)
             impl = ("pallas" if jax.default_backend() == "tpu"
                     and ks is None else "dense")
+        if ptab is not None:
+            return flash_attention_decode_paged(
+                q, kc, vc, ptab, cmask, impl=impl, k_scale_pool=ks,
+                v_scale_pool=vs)
         return flash_attention_decode(q, kc, vc, cmask, impl=impl,
                                       k_scale=ks, v_scale=vs)
-
-    def _decode_attn_paged(self, q, kp, vp, ptab, cmask, ks=None,
-                           vs=None):
-        impl = self.attn_impl
-        if impl == "auto":
-            impl = ("pallas" if jax.default_backend() == "tpu"
-                    and ks is None else "dense")
-        return flash_attention_decode_paged(q, kp, vp, ptab, cmask,
-                                            impl=impl, k_scale_pool=ks,
-                                            v_scale_pool=vs)
 
     def _prefill_attn(self, q, k, v):
         if self.attn_impl == "pallas" or (
@@ -226,43 +243,65 @@ class BertDecoder:
             return flash_attention(q, k, v, causal=True)
         return dense_attention(q, k, v, causal=True)
 
+    def _quantize_heads(self, rows):
+        """(..., H·Dh) K or V rows -> (int8 rows (..., H·Dh), float32
+        scales (..., H)): every head quantizes its Dh lanes of a row
+        against that row's own absmax (quantize/kvcache.py)."""
+        from deeplearning4j_tpu.quantize.kvcache import quantize_rows
+        nh, hd = self.cfg.num_heads, self.cfg.head_dim
+        q, scale = quantize_rows(rows.reshape(rows.shape[:-1] + (nh, hd)))
+        return q.reshape(rows.shape), scale
+
+    def _write_rows(self, cache, li, wi, wj, k, v):
+        """Layer `li`'s new K/V rows `k`/`v` (..., H·Dh) into its leaves
+        at (`wi`, `wj`) = (slot, position), or (page, offset) when paged
+        — whole rows of H·Dh lanes, quantized first for an int8 cache.
+        `cache` is a dict of per-layer leaf LISTS, updated in place."""
+        if self.kv_dtype == "int8":
+            k, k_sc = self._quantize_heads(k)
+            v, v_sc = self._quantize_heads(v)
+            cache["ks"][li] = cache["ks"][li].at[wi, wj].set(k_sc)
+            cache["vs"][li] = cache["vs"][li].at[wi, wj].set(v_sc)
+        kc, vc = cache["k"][li], cache["v"][li]
+        cache["k"][li] = kc.at[wi, wj].set(k.astype(kc.dtype))
+        cache["v"][li] = vc.at[wi, wj].set(v.astype(vc.dtype))
+
+    def _write_index(self, cache, pos, ptab):
+        """Where rows at per-slot positions `pos` ((S,) or an (S, d)
+        draft block) land: (`wi`, `wj`) = (slot, position), or (page,
+        offset) through `ptab` when paged — and the row count C of the
+        view attention reads. Paged writes past the mapped view (a
+        frozen lane at pos == C) are redirected to the null page: a
+        dense cache silently DROPS that out-of-range scatter; pages must
+        redirect it explicitly or the clamped index would corrupt a
+        live row."""
+        ar = jnp.arange(pos.shape[0]).reshape((-1,) + (1,) * (pos.ndim - 1))
+        if not self.paged:
+            return ar, pos, cache["k"][0].shape[1]
+        psz = self.page_size
+        maxp = ptab.shape[1]
+        c = maxp * psz
+        phys = ptab[ar, jnp.minimum(pos // psz, maxp - 1)]
+        return jnp.where(pos < c, phys, 0), pos % psz, c
+
     def step(self, margs, cache, tokens, pos, ptab=None):
         """One decode step for the whole batch: embed `tokens` at their
         slot positions, write each slot's K/V row at `pos`, attend the
         single query over rows 0..pos, and return next-token logits.
         `pos[s]` = number of already-cached tokens in slot s (the
         position the current token occupies). Paged mode additionally
-        takes `ptab` (S, maxp) int32 — reads gather through it, the
-        row write lands in page `pos // ps` at offset `pos % ps`, and
-        frozen-lane writes past the mapped view (pos == C) are
-        redirected to the null page (a dense cache silently DROPS that
-        out-of-range scatter; pages must redirect it explicitly or the
-        clamped index would corrupt a live row)."""
+        takes `ptab` (S, maxp) int32 — reads gather through it and the
+        row write lands in page `pos // ps` at offset `pos % ps`
+        (`_write_index`)."""
         (params,) = margs
         cfg = self.cfg
         with jax.named_scope("embed"):
             x = self._embed(params, tokens, pos)        # (S, H)
-        kc, vc = cache["k"], cache["v"]
+        cache = {name: list(leaves) for name, leaves in cache.items()}
         int8_kv = self.kv_dtype == "int8"
-        ks = cache.get("ks")
-        vs = cache.get("vs")
         s = tokens.shape[0]
-        ar = jnp.arange(s)
         nh, hd = cfg.num_heads, cfg.head_dim
-        paged = self.paged
-        if paged:
-            psz = self.page_size
-            maxp = ptab.shape[1]
-            c = maxp * psz
-            poff = pos % psz
-            phys = ptab[ar, jnp.minimum(pos // psz, maxp - 1)]
-            wphys = jnp.where(pos < c, phys, 0)         # (S,)
-            # the write index of the new row: (page, offset) or
-            # (slot, position)
-            wi, wj = wphys, poff
-        else:
-            c = kc.shape[3]
-            wi, wj = ar, pos
+        wi, wj, c = self._write_index(cache, pos, ptab)
         # rows 0..pos are valid (the current write included)
         cmask = jnp.arange(c)[None, :] <= pos[:, None]  # (S, C)
         dt = x.dtype
@@ -274,28 +313,14 @@ class BertDecoder:
                     qkv = x @ layer["qkv_W"].astype(dt) \
                         + layer["qkv_b"].astype(dt)     # (S, 3H)
                     q, k, v = jnp.split(qkv, 3, axis=-1)
-                    q = q.reshape(s, nh, hd)
-                    k = k.reshape(s, nh, hd)
-                    v = v.reshape(s, nh, hd)
                 with jax.named_scope("kv_write"):
-                    if int8_kv:
-                        from deeplearning4j_tpu.quantize.kvcache import \
-                            quantize_rows
-                        k, k_sc = quantize_rows(k)
-                        v, v_sc = quantize_rows(v)
-                        ks = ks.at[li, wi, :, wj].set(k_sc)
-                        vs = vs.at[li, wi, :, wj].set(v_sc)
-                    kc = kc.at[li, wi, :, wj].set(k.astype(kc.dtype))
-                    vc = vc.at[li, wi, :, wj].set(v.astype(vc.dtype))
+                    self._write_rows(cache, li, wi, wj, k, v)
                 with jax.named_scope("attn"):
-                    lks = ks[li] if int8_kv else None
-                    lvs = vs[li] if int8_kv else None
-                    if paged:
-                        ctx = self._decode_attn_paged(
-                            q, kc[li], vc[li], ptab, cmask, lks, lvs)
-                    else:
-                        ctx = self._decode_attn(q, kc[li], vc[li], cmask,
-                                                lks, lvs)
+                    ctx = self._decode_attn(
+                        q.reshape(s, nh, hd), cache["k"][li],
+                        cache["v"][li], cmask, ptab,
+                        cache["ks"][li] if int8_kv else None,
+                        cache["vs"][li] if int8_kv else None)
                     ctx = ctx.astype(dt)
                 with jax.named_scope("proj"):
                     a = ctx.reshape(s, cfg.hidden_size) \
@@ -309,11 +334,7 @@ class BertDecoder:
                                     layer["ln2_bias"], cfg.layer_norm_eps)
         with jax.named_scope("logits"):
             logits = bert_mlm_logits(cfg, params, x[:, None, :])[:, 0]
-        out = {"k": kc, "v": vc}
-        if int8_kv:
-            out["ks"] = ks
-            out["vs"] = vs
-        return logits, out
+        return logits, cache
 
     @property
     def supports_draft(self):
@@ -344,22 +365,10 @@ class BertDecoder:
         pos_block = pos[:, None] + jnp.arange(d)[None, :]   # (S, d)
         with jax.named_scope("embed"):
             x = self._embed(params, tok_block, pos_block)   # (S, d, H)
-        kc, vc = cache["k"], cache["v"]
-        ar = jnp.arange(s)
+        cache = {name: list(leaves) for name, leaves in cache.items()}
         nh, hd = cfg.num_heads, cfg.head_dim
-        paged = self.paged
-        if paged:
-            psz = self.page_size
-            maxp = ptab.shape[1]
-            c = maxp * psz
-            poff = pos_block % psz                          # (S, d)
-            phys = ptab[ar[:, None],
-                        jnp.minimum(pos_block // psz, maxp - 1)]
-            wphys = jnp.where(pos_block < c, phys, 0)       # (S, d)
-            wi, wj = wphys, poff
-        else:
-            c = kc.shape[3]
-            wi, wj = ar[:, None], pos_block
+        # rows pos..pos+d-1 of every slot
+        wi, wj, c = self._write_index(cache, pos_block, ptab)
         # query j sees rows 0..pos+j (its own write included)
         qmask = jnp.arange(c)[None, None, :] <= pos_block[:, :, None]
         dt = x.dtype
@@ -370,21 +379,15 @@ class BertDecoder:
                         + layer["qkv_b"].astype(dt)         # (S, d, 3H)
                     q, k, v = jnp.split(qkv, 3, axis=-1)
                     q = q.reshape(s, d, nh, hd).transpose(0, 2, 1, 3)
-                    k = k.reshape(s, d, nh, hd)             # (S, d, H, Dh)
-                    v = v.reshape(s, d, nh, hd)
                 with jax.named_scope("kv_write"):
-                    # advanced-index write: rows pos..pos+d-1 of every
-                    # slot (the advanced (S, d) block leads, then the H
-                    # and Dh dims)
-                    kc = kc.at[li, wi, :, wj].set(k.astype(kc.dtype))
-                    vc = vc.at[li, wi, :, wj].set(v.astype(vc.dtype))
+                    self._write_rows(cache, li, wi, wj, k, v)
                 with jax.named_scope("attn"):
-                    if paged:
+                    kc, vc = cache["k"][li], cache["v"][li]
+                    if self.paged:
                         ctx = flash_attention_decode_mq_paged(
-                            q, kc[li], vc[li], ptab, qmask)
+                            q, kc, vc, ptab, qmask)
                     else:
-                        ctx = flash_attention_decode_mq(q, kc[li], vc[li],
-                                                        qmask)
+                        ctx = flash_attention_decode_mq(q, kc, vc, qmask)
                     ctx = ctx.astype(dt)
                 with jax.named_scope("proj"):
                     a = ctx.transpose(0, 2, 1, 3).reshape(
@@ -399,28 +402,23 @@ class BertDecoder:
                                     layer["ln2_bias"], cfg.layer_norm_eps)
         with jax.named_scope("logits"):
             logits = bert_mlm_logits(cfg, params, x)        # (S, d, V)
-        return logits, {"k": kc, "v": vc}
+        return logits, cache
 
-    def _write_prompt_pages(self, pool, block, wrow, li):
+    def _write_prompt_pages(self, pool, block, wrow):
         """Scatter a prefill K/V (or scale) block into pool pages:
-        `pool` is the full (L, P, nh, ps, ...) pool, `block` the layer's
-        (nh, P_bucket, ...) rows, `wrow[j]` the physical page logical
-        page j writes into — 0 (the null page) for pages whose bytes
-        already exist on device (shared-prefix hit) or that hold only
-        bucket padding, so redundant writes are discarded without
-        branching."""
+        `pool` is one layer's (P, ps, W) pool leaf, `block` the prompt's
+        (P_bucket, W) rows, `wrow[j]` the physical page logical page j
+        writes into — 0 (the null page) for pages whose bytes already
+        exist on device (shared-prefix hit) or that hold only bucket
+        padding, so redundant writes are discarded without branching."""
         psz = self.page_size
         npp = wrow.shape[0]
-        pad = [(0, 0)] * block.ndim
-        pad[1] = (0, npp * psz - block.shape[1])
-        # (nh, npp·ps, ...) -> per-page (1, 1, nh, ps, ...) updates
-        pages = jnp.pad(block, pad).reshape(
-            (block.shape[0], npp, psz) + block.shape[2:])
+        pages = jnp.pad(
+            block, ((0, npp * psz - block.shape[0]), (0, 0))
+        ).reshape(npp, psz, block.shape[1]).astype(pool.dtype)
         for j in range(npp):
-            upd = pages[:, j][None, None]
-            pool = lax.dynamic_update_slice(
-                pool, upd.astype(pool.dtype),
-                (li, wrow[j]) + (0,) * (pool.ndim - 2))
+            pool = lax.dynamic_update_slice(pool, pages[j][None],
+                                            (wrow[j], 0, 0))
         return pool
 
     def prefill(self, margs, cache, slot, prompt, plen, wrow=None):
@@ -443,25 +441,24 @@ class BertDecoder:
                 + emb["position"][None, :p_len]
             x = _layer_norm(x.astype(cfg.compute_dtype), emb["ln_scale"],
                             emb["ln_bias"], cfg.layer_norm_eps)
-        kc, vc = cache["k"], cache["v"]
+        cache = {name: list(leaves) for name, leaves in cache.items()}
         int8_kv = self.kv_dtype == "int8"
-        paged = self.paged
-        ks = cache.get("ks")
-        vs = cache.get("vs")
         nh, hd = cfg.num_heads, cfg.head_dim
         dt = x.dtype
 
-        def heads(t):
+        def heads(t):       # the prefill attention's view only
             return t.reshape(1, p_len, nh, hd).transpose(0, 2, 1, 3)
 
-        def write(pool, block, li):
-            """One layer's (1, nh, P[, hd]) block into the slot's rows,
-            or through the page redirect."""
-            if paged:
-                return self._write_prompt_pages(pool, block[0], wrow, li)
-            return lax.dynamic_update_slice(
-                pool, block[None].astype(pool.dtype),
-                (li, slot) + (0,) * (pool.ndim - 2))
+        def write(name, li, block):
+            """The prompt's (P, W) block into the slot's rows of leaf
+            `name`, as it is — or through the page redirect."""
+            leaf = cache[name][li]
+            if self.paged:
+                cache[name][li] = self._write_prompt_pages(leaf, block,
+                                                           wrow)
+            else:
+                cache[name][li] = lax.dynamic_update_slice(
+                    leaf, block[None].astype(leaf.dtype), (slot, 0, 0))
 
         for li, layer in enumerate(params["layers"]):
             with jax.named_scope(f"layer{li}"):
@@ -469,19 +466,17 @@ class BertDecoder:
                     qkv = x @ layer["qkv_W"].astype(dt) \
                         + layer["qkv_b"].astype(dt)     # (1, P, 3H)
                     q, k, v = jnp.split(qkv, 3, axis=-1)
-                    q, k, v = heads(q), heads(k), heads(v)  # (1,nh,P,hd)
                 with jax.named_scope("kv_write"):
+                    kw, vw = k[0], v[0]                 # (P, H·Dh)
                     if int8_kv:
-                        from deeplearning4j_tpu.quantize.kvcache import \
-                            quantize_rows
-                        kq, k_sc = quantize_rows(k)     # (1, nh, P)
-                        vq, v_sc = quantize_rows(v)
-                        kc, vc = write(kc, kq, li), write(vc, vq, li)
-                        ks, vs = write(ks, k_sc, li), write(vs, v_sc, li)
-                    else:
-                        kc, vc = write(kc, k, li), write(vc, v, li)
+                        kw, k_sc = self._quantize_heads(kw)
+                        vw, v_sc = self._quantize_heads(vw)
+                        write("ks", li, k_sc)
+                        write("vs", li, v_sc)
+                    write("k", li, kw)
+                    write("v", li, vw)
                 with jax.named_scope("attn"):
-                    ctx = self._prefill_attn(q, k, v)
+                    ctx = self._prefill_attn(heads(q), heads(k), heads(v))
                 with jax.named_scope("proj"):
                     a = ctx.transpose(0, 2, 1, 3).reshape(
                         1, p_len, cfg.hidden_size) \
@@ -497,11 +492,7 @@ class BertDecoder:
             h_last = jnp.take(x[0], plen - 1, axis=0)   # (H,)
             logits = bert_mlm_logits(cfg, params,
                                      h_last[None, None, :])[0, 0]
-        out = {"k": kc, "v": vc}
-        if int8_kv:
-            out["ks"] = ks
-            out["vs"] = vs
-        return out, logits
+        return cache, logits
 
 
 class RecurrentDecoder:

@@ -2,7 +2,8 @@
 and hash-of-prefix sharing with copy-on-write.
 
 The device side (decode.py / kernels/flash_attention.py) stores KV in a
-pooled layout `(L, P, H, ps, Dh)` — P physical pages of `ps` rows each —
+pooled layout, a `(P, ps, H·Dh)` leaf a layer — P physical pages of `ps`
+rows each, rows major and the hidden width minor like the dense cache —
 and every read/write goes through a per-slot page index, so a ragged
 request pays `ceil(len/ps)` pages instead of a whole cache rung
 (µ-cuDNN's fixed-block decomposition applied to cache memory). THIS

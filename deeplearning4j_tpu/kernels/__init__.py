@@ -3,7 +3,7 @@ helper layer, rebuilt as TPU VMEM-tiled kernels; interpret-mode on CPU)."""
 from deeplearning4j_tpu.kernels.flash_attention import (
     flash_attention, flash_attention_decode, flash_attention_decode_mq,
     flash_attention_decode_mq_paged, flash_attention_decode_paged,
-    gather_kv_pages, gather_scale_pages)
+    gather_kv_pages)
 from deeplearning4j_tpu.kernels.layernorm import fused_layernorm
 from deeplearning4j_tpu.kernels.pointwise_conv import (
     int8_matmul_epilogue, matmul_epilogue)
@@ -11,5 +11,5 @@ from deeplearning4j_tpu.kernels.pointwise_conv import (
 __all__ = ["flash_attention", "flash_attention_decode",
            "flash_attention_decode_mq",
            "flash_attention_decode_mq_paged", "flash_attention_decode_paged",
-           "gather_kv_pages", "gather_scale_pages",
+           "gather_kv_pages",
            "fused_layernorm", "int8_matmul_epilogue", "matmul_epilogue"]

@@ -478,46 +478,147 @@ def flash_attention(q, k, v, causal=False, block_q=128, block_k=128,
 
 
 # ---------------------------------------------------------------------------
-# decode kernel: one query token against a cached K/V
+# decode: one query token (or a draft block) against a cached K/V
+#
+# Cache operands are ROWS MAJOR, HIDDEN MINOR: (B, C, H·D) — the layout
+# the decode cache keeps on the device (generation/decode.py). Its minor
+# dimension is the model's hidden width, a whole number of 128-lane
+# tiles, so the row write, the kernel and the donated state all take the
+# array as it lies: nothing re-lays the cache between them.
 # ---------------------------------------------------------------------------
-def _decode_reference(q, k_cache, v_cache, cache_mask):
-    """Einsum oracle for the decode path — softmax(q·Kᵀ/√d)·V over the
-    VALID cache rows only. Fully-invalid rows (no cached keys) come back
-    zeroed, matching the Pallas kernel's empty-softmax convention."""
-    d = q.shape[-1]
-    scale = 1.0 / (d ** 0.5)
-    s = jnp.einsum("bhqd,bhcd->bhqc", q.astype(jnp.float32),
-                   k_cache.astype(jnp.float32)) * scale
-    valid = cache_mask.astype(bool)
-    s = jnp.where(valid[:, None, None, :], s, _NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqc,bhcd->bhqd", p,
-                     v_cache.astype(jnp.float32)).astype(q.dtype)
-    any_valid = valid.any(axis=-1)
-    return jnp.where(any_valid[:, None, None, None], out, 0)
+def _split_heads(cache, h):
+    """(B, C, H·D) -> (B, C, H, D): the einsum paths' view of a cache."""
+    b, c, hd = cache.shape
+    return cache.reshape(b, c, h, hd // h)
 
 
-def _decode_reference_quantized(q, k_cache, v_cache, cache_mask,
-                                k_scale, v_scale):
-    """Decode attention over an int8-quantized cache with the dequant
-    FUSED into the contractions: the per-row key scale multiplies the
-    score logits (s·(k_row·ks) = (s·k_row)·ks), the per-row value scale
-    folds onto the softmax weights before the value pass — no
-    dequantized fp cache copy ever materializes; the cache reads stay
-    int8 (quantize/kvcache.py's traffic argument)."""
-    d = q.shape[-1]
+def _check_cache_operands(q, k_cache, v_cache):
+    """q is (B, H, Tq, D); the caches must match as (B, C, H·D)."""
+    if k_cache.shape != v_cache.shape or k_cache.ndim != 3:
+        raise ValueError(
+            f"k_cache/v_cache must match as (B, C, H·D): "
+            f"{k_cache.shape} vs {v_cache.shape}")
+    b, h, _, d = q.shape
+    if k_cache.shape[0] != b or k_cache.shape[2] != h * d:
+        raise ValueError(
+            f"k_cache/v_cache must be (B, C, H·D) = ({b}, C, {h * d}) "
+            f"for q {q.shape}, got {k_cache.shape}")
+
+
+def _masked_attend(q, k_cache, v_cache, valid, k_scale=None, v_scale=None):
+    """The einsum masked softmax every non-kernel decode path runs:
+    softmax(q·Kᵀ/√d)·V over the VALID cache rows of each query.
+
+    - q (B, H, Tq, D); k_cache / v_cache (B, C, H·D)
+    - valid (B, Tq, C) bool
+    - k_scale / v_scale: (B, C, H) float32 row scales of an int8 cache,
+      folded INTO the contractions: the key scale multiplies the score
+      logits (s·(k_row·ks) = (s·k_row)·ks), the value scale folds onto
+      the softmax weights before the value pass — no dequantized copy of
+      the cache materializes and the cache reads stay int8.
+    Queries with no valid row come back zeroed (the kernel's
+    empty-softmax convention)."""
+    h, d = q.shape[1], q.shape[3]
     scale = 1.0 / (d ** 0.5)
-    s = jnp.einsum("bhqd,bhcd->bhqc", q.astype(jnp.float32),
-                   k_cache.astype(jnp.float32)) * scale
-    s = s * k_scale[:, :, None, :].astype(jnp.float32)
-    valid = cache_mask.astype(bool)
-    s = jnp.where(valid[:, None, None, :], s, _NEG_INF)
+    s = jnp.einsum("bhqd,bchd->bhqc", q.astype(jnp.float32),
+                   _split_heads(k_cache, h).astype(jnp.float32)) * scale
+    if k_scale is not None:
+        s = s * k_scale.astype(jnp.float32).transpose(0, 2, 1)[:, :, None]
+    s = jnp.where(valid[:, None, :, :], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    pv = p * v_scale[:, :, None, :].astype(jnp.float32)
-    out = jnp.einsum("bhqc,bhcd->bhqd", pv,
-                     v_cache.astype(jnp.float32)).astype(q.dtype)
-    any_valid = valid.any(axis=-1)
-    return jnp.where(any_valid[:, None, None, None], out, 0)
+    if v_scale is not None:
+        p = p * v_scale.astype(jnp.float32).transpose(0, 2, 1)[:, :, None]
+    out = jnp.einsum("bhqc,bchd->bhqd", p,
+                     _split_heads(v_cache, h).astype(jnp.float32)
+                     ).astype(q.dtype)
+    any_valid = valid.any(axis=-1)                    # (B, Tq)
+    return jnp.where(any_valid[:, None, :, None], out, 0)
+
+
+def _flash_decode_kernel(q_ref, k_ref, v_ref, km_ref, o_ref, acc_ref, l_ref,
+                         m_ref, *, scale, head_dim):
+    """Grid (B, k_tiles), k innermost: one slot's single query row against
+    a (block_k, H·D) tile of its cache rows, read IN PLACE — every head of
+    the slot in one grid step.
+
+    The query row becomes a block-diagonal (Hp, H·D) operand (row h holds
+    head h's D lanes, zeros elsewhere), so `_flash_fwd_kernel`'s own
+    arithmetic — q·Kᵀ, the masked online softmax with float32 o/l/m
+    scratch across the k tiles, p·V — runs once for all heads: scores
+    (Hp, block_k), accumulator (Hp, H·D). Head h's output is the h-th
+    diagonal (1, D) block of the accumulator; the last k step folds the
+    blocks back into one (1, H·D) row."""
+    kj = pl.program_id(1)
+    hp, hd = acc_ref.shape
+    row = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 1)
+    own = (lane >= row * head_dim) & (lane < (row + 1) * head_dim)
+
+    @pl.when(kj == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+
+    q = jnp.where(own, q_ref[0].astype(jnp.float32) * scale, 0.0)
+    s = jax.lax.dot_general(
+        q, k_ref[0].astype(jnp.float32),
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)           # (Hp, block_k)
+    s = jnp.where(km_ref[0] > 0, s, _NEG_INF)         # (1, block_k) mask
+    m_prev, l_prev = m_ref[...], l_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    m_ref[...] = m_new
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p, v_ref[0].astype(jnp.float32),
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)           # (Hp, H·D)
+
+    @pl.when(kj == pl.num_programs(1) - 1)
+    def _finalize():
+        o = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0] = jnp.sum(jnp.where(own, o, 0.0), axis=0,
+                           keepdims=True).astype(o_ref.dtype)
+
+
+def _flash_decode(q, k_cache, v_cache, cache_mask, block_k, interpret):
+    """q (B, H, 1, D) against (B, C, H·D) caches through the Pallas
+    kernel; returns (B, H, 1, D). The caches go in as they are: no pad,
+    no reshape — a rung the k tile does not divide is one whole tile."""
+    b, h, _, d = q.shape
+    c, hd = k_cache.shape[1], k_cache.shape[2]
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    if c % block_k:
+        block_k = c
+    hp = -(-h // 8) * 8            # float32 sublane tile of the scores
+    cache_spec = pl.BlockSpec((1, block_k, hd), lambda i, j: (i, j, 0))
+    row_spec = pl.BlockSpec((1, 1, hd), lambda i, j: (i, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(_flash_decode_kernel, scale=1.0 / (d ** 0.5),
+                          head_dim=d),
+        grid=(b, c // block_k),
+        in_specs=[row_spec, cache_spec, cache_spec,
+                  pl.BlockSpec((1, 1, block_k), lambda i, j: (i, 0, j))],
+        out_specs=row_spec,
+        out_shape=jax.ShapeDtypeStruct((b, 1, hd), q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((hp, hd), jnp.float32),
+            pltpu.VMEM((hp, 1), jnp.float32),
+            pltpu.VMEM((hp, 1), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="flash_fwd",
+    )(q.reshape(b, 1, hd), k_cache, v_cache,
+      cache_mask.astype(jnp.int32)[:, None, :])
+    # a slot with NO valid cache row has no defined softmax: zeros
+    any_valid = cache_mask.astype(bool).any(axis=1)
+    return jnp.where(any_valid[:, None, None], out, 0).reshape(b, h, 1, d)
 
 
 @jax.named_scope("flash_decode")
@@ -535,7 +636,7 @@ def flash_attention_decode_mq(q, k_cache, v_cache, q_mask, impl="auto"):
     the superstep, but with the verification semantics drafting needs.
 
     - q: (B, H, Tq, D) — the draft-block queries (Tq = block length)
-    - k_cache / v_cache: (B, H, C, D)
+    - k_cache / v_cache: (B, C, H·D) — rows major, hidden minor
     - q_mask: (B, Tq, C) truthy — valid cache rows PER QUERY (ragged
       slots and the intra-block causal offset in one mask)
     - impl: 'auto'/'dense' run the einsum contraction; 'pallas' is
@@ -547,11 +648,8 @@ def flash_attention_decode_mq(q, k_cache, v_cache, q_mask, impl="auto"):
     """
     if q.ndim != 4:
         raise ValueError(f"q must be (B, H, Tq, D), got {q.shape}")
-    if k_cache.shape != v_cache.shape or k_cache.ndim != 4:
-        raise ValueError(
-            f"k_cache/v_cache must match as (B, H, C, D): "
-            f"{k_cache.shape} vs {v_cache.shape}")
-    expect = (q.shape[0], q.shape[2], k_cache.shape[2])
+    _check_cache_operands(q, k_cache, v_cache)
+    expect = (q.shape[0], q.shape[2], k_cache.shape[1])
     if tuple(q_mask.shape) != expect:
         raise ValueError(
             f"q_mask must be (B, Tq, C) = {expect}, got {q_mask.shape}")
@@ -563,22 +661,12 @@ def flash_attention_decode_mq(q, k_cache, v_cache, q_mask, impl="auto"):
         raise ValueError(
             f"unknown decode impl {impl!r}; expected 'auto', 'pallas' "
             "or 'dense'")
-    d = q.shape[-1]
-    scale = 1.0 / (d ** 0.5)
-    s = jnp.einsum("bhqd,bhcd->bhqc", q.astype(jnp.float32),
-                   k_cache.astype(jnp.float32)) * scale
-    valid = q_mask.astype(bool)                       # (B, Tq, C)
-    s = jnp.where(valid[:, None, :, :], s, _NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqc,bhcd->bhqd", p,
-                     v_cache.astype(jnp.float32)).astype(q.dtype)
-    any_valid = valid.any(axis=-1)                    # (B, Tq)
-    return jnp.where(any_valid[:, None, :, None], out, 0)
+    return _masked_attend(q, k_cache, v_cache, q_mask.astype(bool))
 
 
 @jax.named_scope("flash_decode")
 def flash_attention_decode(q1, k_cache, v_cache, cache_mask, impl="auto",
-                           block_k=128, interpret=None, k_scale=None,
+                           block_k=512, interpret=None, k_scale=None,
                            v_scale=None):
     """Incremental-decode attention: a SINGLE query block per sequence
     attends over that sequence's cached K/V under a cache-validity mask.
@@ -591,11 +679,15 @@ def flash_attention_decode(q1, k_cache, v_cache, cache_mask, impl="auto",
     per step instead of the O(T²) full-sequence re-forward.
 
     - q1: (B, H, D) or (B, H, 1, D) — current-token query
-    - k_cache / v_cache: (B, H, C, D) — rolling caches (C = cache rung)
+    - k_cache / v_cache: (B, C, H·D) — rolling caches, rows major and
+      the hidden width minor (C = cache rung): one decode-cache leaf as
+      `BertDecoder.init_cache` lays it out, read in place
     - cache_mask: (B, C) truthy — valid cache rows (ragged lengths)
     - impl: 'auto' (Pallas kernel on TPU, einsum elsewhere), 'pallas'
       (force kernel; interpret-mode off-TPU), or 'dense'
-    - k_scale / v_scale: (B, H, C) float32 per-head row scales of an
+    - block_k: cache rows a kernel grid step reads (a rung it does not
+      divide is read as one tile)
+    - k_scale / v_scale: (B, C, H) float32 per-head row scales of an
       int8-quantized cache (quantize/kvcache.py). When given, the
       dequant happens INSIDE the attention contractions — the single-
       query decode pass is a bandwidth-bound GEMV, so reading the
@@ -612,14 +704,11 @@ def flash_attention_decode(q1, k_cache, v_cache, cache_mask, impl="auto",
     if q.ndim != 4 or q.shape[2] != 1:
         raise ValueError(
             f"q1 must be (B, H, D) or (B, H, 1, D), got {q1.shape}")
-    if k_cache.shape != v_cache.shape or k_cache.ndim != 4:
-        raise ValueError(
-            f"k_cache/v_cache must match as (B, H, C, D): "
-            f"{k_cache.shape} vs {v_cache.shape}")
-    if cache_mask.shape != (q.shape[0], k_cache.shape[2]):
+    _check_cache_operands(q, k_cache, v_cache)
+    if cache_mask.shape != (q.shape[0], k_cache.shape[1]):
         raise ValueError(
             f"cache_mask must be (B, C) = "
-            f"{(q.shape[0], k_cache.shape[2])}, got {cache_mask.shape}")
+            f"{(q.shape[0], k_cache.shape[1])}, got {cache_mask.shape}")
     if impl not in ("auto", "pallas", "dense"):
         raise ValueError(
             f"unknown decode impl {impl!r}; expected 'auto', 'pallas' "
@@ -633,27 +722,22 @@ def flash_attention_decode(q1, k_cache, v_cache, cache_mask, impl="auto",
                 "streaming-softmax kernel has no slot for per-row "
                 "scales yet) — use 'auto' or 'dense' with a "
                 "quantized cache")
-        expect = (q.shape[0], q.shape[1], k_cache.shape[2])
+        expect = (q.shape[0], k_cache.shape[1], q.shape[1])
         if tuple(k_scale.shape) != expect \
                 or tuple(v_scale.shape) != expect:
             raise ValueError(
-                f"k_scale/v_scale must be (B, H, C) = {expect}, got "
+                f"k_scale/v_scale must be (B, C, H) = {expect}, got "
                 f"{k_scale.shape} / {v_scale.shape}")
-        out = _decode_reference_quantized(q, k_cache, v_cache,
-                                          cache_mask, k_scale, v_scale)
-        return out[:, :, 0, :] if squeeze else out
+        impl = "dense"
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "dense"
     if impl == "pallas":
-        out, _ = _flash_forward(q, k_cache, v_cache, None, cache_mask,
-                                causal=False, block_q=128, block_k=block_k,
-                                interpret=interpret)
-    elif impl == "dense":
-        out = _decode_reference(q, k_cache, v_cache, cache_mask)
+        out = _flash_decode(q, k_cache, v_cache, cache_mask, block_k,
+                            interpret)
     else:
-        raise ValueError(
-            f"unknown decode impl {impl!r}; expected 'auto', 'pallas' "
-            "or 'dense'")
+        out = _masked_attend(q, k_cache, v_cache,
+                             cache_mask.astype(bool)[:, None, :],
+                             k_scale, v_scale)
     return out[:, :, 0, :] if squeeze else out
 
 
@@ -663,48 +747,34 @@ def flash_attention_decode(q1, k_cache, v_cache, cache_mask, impl="auto",
 def gather_kv_pages(pool, page_table):
     """Materialize the per-slot contiguous cache VIEW from a paged pool.
 
-    - pool: (P, H, ps, D) — one layer's KV page pool (P physical pages
-      of `ps` rows each; page 0 is the null/scratch page by convention)
+    - pool: (P, ps, W) — one layer's page pool: P physical pages of `ps`
+      rows each, rows major like the dense cache (W = H·D for K/V pages,
+      W = H for the int8 pool's per-row scale pages); page 0 is the
+      null/scratch page by convention
     - page_table: (B, n) int32 — physical page id per (slot, logical
       page); unmapped entries point at page 0 and are hidden by the
       caller's cache mask
-    Returns (B, H, n·ps, D) — bit-identical to the slot-contiguous
-    cache layout, so the existing masked-softmax decode arithmetic
-    (and therefore token streams) carries over unchanged.
+    Returns (B, n·ps, W) — bit-identical to the slot-contiguous cache
+    layout, so the existing masked-softmax decode arithmetic (and
+    therefore token streams) carries over unchanged. Pages and rows are
+    both major dimensions, so the view is a gather and a free reshape.
     """
-    if pool.ndim != 4:
-        raise ValueError(f"pool must be (P, H, ps, D), got {pool.shape}")
+    if pool.ndim != 3:
+        raise ValueError(f"pool must be (P, ps, W), got {pool.shape}")
     if page_table.ndim != 2:
         raise ValueError(
             f"page_table must be (B, n_pages), got {page_table.shape}")
     b, n = page_table.shape
-    _, h, ps, d = pool.shape
-    g = jnp.take(pool, page_table, axis=0)      # (B, n, H, ps, D)
-    return g.transpose(0, 2, 1, 3, 4).reshape(b, h, n * ps, d)
-
-
-def gather_scale_pages(scale_pool, page_table):
-    """Per-row scale twin of `gather_kv_pages` for the int8 pool.
-
-    - scale_pool: (P, H, ps) float32 — per-row quantization scales
-    - page_table: (B, n) int32
-    Returns (B, H, n·ps) ready for the scale-folding einsum path.
-    """
-    if scale_pool.ndim != 3:
-        raise ValueError(
-            f"scale_pool must be (P, H, ps), got {scale_pool.shape}")
-    b, n = page_table.shape
-    _, h, ps = scale_pool.shape
-    g = jnp.take(scale_pool, page_table, axis=0)  # (B, n, H, ps)
-    return g.transpose(0, 2, 1, 3).reshape(b, h, n * ps)
+    _, ps, w = pool.shape
+    return jnp.take(pool, page_table, axis=0).reshape(b, n * ps, w)
 
 
 def flash_attention_decode_paged(q1, k_pool, v_pool, page_table,
-                                 cache_mask, impl="auto", block_k=128,
+                                 cache_mask, impl="auto", block_k=512,
                                  interpret=None, k_scale_pool=None,
                                  v_scale_pool=None):
     """`flash_attention_decode` generalized to gather-by-page: the query
-    attends a (B, H, C, D) view gathered from a device-resident page
+    attends a (B, C, H·D) view gathered from a device-resident page
     pool through the per-slot page index, C = n_pages·ps.
 
     Pages let ragged sequences pay for the rows they use instead of a
@@ -712,21 +782,21 @@ def flash_attention_decode_paged(q1, k_pool, v_pool, page_table,
     memory), and let identical prompt prefixes share physical pages.
     The gather feeds the UNCHANGED masked-softmax machinery — einsum
     reference, Pallas kernel, and the int8 scale-folding path all see
-    the same (B, H, C, D) operands as the slot-contiguous layout, so
+    the same (B, C, H·D) operands as the slot-contiguous layout, so
     streams stay bit-identical.
 
     - q1: (B, H, D) or (B, H, 1, D)
-    - k_pool / v_pool: (P, H, ps, D) — pooled pages (int8 under
+    - k_pool / v_pool: (P, ps, H·D) — pooled pages (int8 under
       `kv_dtype="int8"`, halving page bytes)
     - page_table: (B, n_pages) int32 physical page ids
     - cache_mask: (B, n_pages·ps) — valid ROWS of the gathered view
-    - k_scale_pool / v_scale_pool: (P, H, ps) float32 scales of an
+    - k_scale_pool / v_scale_pool: (P, ps, H) float32 scales of an
       int8 pool; folded inside the contractions as in the contiguous
       path
     """
-    if k_pool.shape != v_pool.shape or k_pool.ndim != 4:
+    if k_pool.shape != v_pool.shape or k_pool.ndim != 3:
         raise ValueError(
-            f"k_pool/v_pool must match as (P, H, ps, D): "
+            f"k_pool/v_pool must match as (P, ps, H·D): "
             f"{k_pool.shape} vs {v_pool.shape}")
     if (k_scale_pool is None) != (v_scale_pool is None):
         raise ValueError(
@@ -736,8 +806,8 @@ def flash_attention_decode_paged(q1, k_pool, v_pool, page_table,
         kc = gather_kv_pages(k_pool, page_table)
         vc = gather_kv_pages(v_pool, page_table)
         if k_scale_pool is not None:
-            ks = gather_scale_pages(k_scale_pool, page_table)
-            vs = gather_scale_pages(v_scale_pool, page_table)
+            ks = gather_kv_pages(k_scale_pool, page_table)
+            vs = gather_kv_pages(v_scale_pool, page_table)
     return flash_attention_decode(q1, kc, vc, cache_mask, impl=impl,
                                   block_k=block_k, interpret=interpret,
                                   k_scale=ks, v_scale=vs)
@@ -750,9 +820,9 @@ def flash_attention_decode_mq_paged(q, k_pool, v_pool, page_table,
     every decode mode inherits paging from one gather. Operands as in
     `flash_attention_decode_mq` with (k_pool, v_pool, page_table) in
     place of the contiguous caches."""
-    if k_pool.shape != v_pool.shape or k_pool.ndim != 4:
+    if k_pool.shape != v_pool.shape or k_pool.ndim != 3:
         raise ValueError(
-            f"k_pool/v_pool must match as (P, H, ps, D): "
+            f"k_pool/v_pool must match as (P, ps, H·D): "
             f"{k_pool.shape} vs {v_pool.shape}")
     with jax.named_scope("flash_decode"):    # the gathers are its work
         kc = gather_kv_pages(k_pool, page_table)

@@ -7,10 +7,11 @@ cuts that read to ~¼ (bf16 caches: ~½) at a per-row quantization error
 attention's softmax largely absorbs — the PR 8 decode tests hold the
 int8 token stream to the fp stream within tolerance.
 
-Layout: alongside each `(..., C, D)` cache tensor rides a `(..., C)`
-float32 scale tensor — "per-head scales": every head quantizes each of
-its cached rows against that row's own absmax, so one outlier head (or
-one outlier position) cannot crush the resolution of the rest.
+Layout: alongside each `(..., C, H·D)` cache leaf (rows major, hidden
+minor — generation/decode.py) rides a `(..., C, H)` float32 scale leaf —
+"per-head scales": every head quantizes its D lanes of each cached row
+against their own absmax, so one outlier head (or one outlier position)
+cannot crush the resolution of the rest.
 
 Dequantization happens INSIDE `flash_attention_decode` (the scales ride
 into the attention contraction as epilogue multipliers — for the score
@@ -19,7 +20,7 @@ onto the softmax weights), so no dequantized fp copy of the cache is
 ever materialized in HBM.
 
 The codec composes with the paged KV layout unchanged: an int8 page is
-the same `(H, ps, Dh)` block plus its `(H, ps)` scale page, so paging
+the same `(ps, H·Dh)` block plus its `(ps, H)` scale page, so paging
 halves again on top of the int8 ¼ — `cache_page_bytes` is the one
 place that arithmetic lives (the bench ledger and the pool-sizing docs
 both read it)."""

@@ -104,8 +104,9 @@ def test_flash_attention_decode_matches_reference_ragged():
     rng = np.random.default_rng(0)
     b, h, c, d = 4, 3, 37, 16
     q = jnp.asarray(rng.standard_normal((b, h, d)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((b, h, c, d)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((b, h, c, d)), jnp.float32)
+    # cache operands are rows major, hidden minor: (B, C, H·D)
+    k = jnp.asarray(rng.standard_normal((b, c, h * d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, c, h * d)), jnp.float32)
     lens = np.array([1, 5, 37, 20])   # ragged cache lengths
     mask = jnp.asarray(
         (np.arange(c)[None, :] < lens[:, None]).astype(np.float32))
@@ -116,21 +117,43 @@ def test_flash_attention_decode_matches_reference_ragged():
                                atol=1e-5, rtol=1e-5)
     # reference oracle built independently: masked softmax einsum
     scale = 1.0 / np.sqrt(d)
+    k4 = np.asarray(k).reshape(b, c, h, d)
+    v4 = np.asarray(v).reshape(b, c, h, d)
     for i, ln in enumerate(lens):
-        s = np.einsum("hd,hcd->hc", np.asarray(q[i]),
-                      np.asarray(k[i][:, :ln])) * scale
+        s = np.einsum("hd,chd->hc", np.asarray(q[i]), k4[i, :ln]) * scale
         p = np.exp(s - s.max(-1, keepdims=True))
         p /= p.sum(-1, keepdims=True)
-        o = np.einsum("hc,hcd->hd", p, np.asarray(v[i][:, :ln]))
+        o = np.einsum("hc,chd->hd", p, v4[i, :ln])
         np.testing.assert_allclose(np.asarray(ref[i]), o, atol=1e-5)
+
+
+def test_flash_attention_decode_tiles_the_rung():
+    """A rung of several k tiles: the online softmax across tiles (the
+    scratch carried over the inner grid axis) equals the one-tile read
+    and the einsum — ragged lengths ending inside, at the edge of, and
+    before a tile."""
+    rng = np.random.default_rng(4)
+    b, h, c, d = 5, 4, 64, 32
+    q = jnp.asarray(rng.standard_normal((b, h, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((b, c, h * d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, c, h * d)), jnp.float32)
+    lens = np.array([1, 16, 17, 64, 0])
+    mask = jnp.asarray(np.arange(c)[None, :] < lens[:, None])
+    ref = flash_attention_decode(q, k, v, mask, impl="dense")
+    for block_k in (16, 64, 48):     # 48 does not divide: one tile
+        pal = flash_attention_decode(q, k, v, mask, impl="pallas",
+                                     block_k=block_k, interpret=True)
+        np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
+                                   atol=1e-5, rtol=1e-5)
+    assert np.all(np.asarray(pal[4]) == 0)
 
 
 def test_flash_attention_decode_rank4_and_empty_rows():
     rng = np.random.default_rng(1)
     b, h, c, d = 2, 2, 8, 8
     q = jnp.asarray(rng.standard_normal((b, h, 1, d)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((b, h, c, d)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((b, h, c, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((b, c, h * d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, c, h * d)), jnp.float32)
     mask = jnp.asarray([[1, 1, 0, 0, 0, 0, 0, 0],
                         [0, 0, 0, 0, 0, 0, 0, 0]], jnp.float32)
     out = flash_attention_decode(q, k, v, mask, impl="dense")
@@ -145,14 +168,21 @@ def test_flash_attention_decode_rank4_and_empty_rows():
 def test_flash_attention_decode_validates_shapes():
     z = jnp.zeros
     with pytest.raises(ValueError, match="q1 must be"):
-        flash_attention_decode(z((2, 3, 2, 8)), z((2, 3, 4, 8)),
-                               z((2, 3, 4, 8)), z((2, 4)))
+        flash_attention_decode(z((2, 3, 2, 8)), z((2, 4, 24)),
+                               z((2, 4, 24)), z((2, 4)))
     with pytest.raises(ValueError, match="cache_mask"):
-        flash_attention_decode(z((2, 3, 8)), z((2, 3, 4, 8)),
-                               z((2, 3, 4, 8)), z((2, 5)))
+        flash_attention_decode(z((2, 3, 8)), z((2, 4, 24)),
+                               z((2, 4, 24)), z((2, 5)))
     with pytest.raises(ValueError, match="unknown decode impl"):
+        flash_attention_decode(z((2, 3, 8)), z((2, 4, 24)),
+                               z((2, 4, 24)), z((2, 4)), impl="nope")
+    # the former (B, H, C, D) operand order is refused, not misread
+    with pytest.raises(ValueError, match=r"\(B, C, H·D\)"):
         flash_attention_decode(z((2, 3, 8)), z((2, 3, 4, 8)),
-                               z((2, 3, 4, 8)), z((2, 4)), impl="nope")
+                               z((2, 3, 4, 8)), z((2, 4)))
+    with pytest.raises(ValueError, match=r"\(B, C, H·D\)"):
+        flash_attention_decode(z((2, 3, 8)), z((2, 4, 16)),
+                               z((2, 4, 16)), z((2, 4)))
 
 
 # ===================== causal bert encode =============================
@@ -169,6 +199,56 @@ def test_causal_encode_prefix_invariant(bert):
 
 
 # ===================== decode exactness ===============================
+def test_bert_cache_is_per_layer_rows_major_leaves(bert):
+    """The cache contract: 2·L leaves `(S, C, H·Dh)`, rows major and the
+    hidden width minor — the one layout the donated state, the row
+    write and the decode kernel share — and `grow` pads the row axis,
+    keeping the rows it holds."""
+    cfg, params = bert
+    dec = BertDecoder(cfg, params)
+    cache = dec.init_cache(3, 16)
+    assert sorted(cache) == ["k", "v"]
+    leaves = jax.tree_util.tree_leaves(cache)
+    assert len(leaves) == 2 * cfg.num_layers
+    assert all(l.shape == (3, 16, cfg.num_heads * cfg.head_dim)
+               and l.dtype == cfg.compute_dtype for l in leaves)
+    cache = jax.tree_util.tree_map(
+        lambda l: l + jnp.arange(16, dtype=l.dtype)[None, :, None], cache)
+    grown = dec.grow(cache, 32)
+    assert (jax.tree_util.tree_structure(grown)
+            == jax.tree_util.tree_structure(cache))
+    for old, new in zip(leaves, jax.tree_util.tree_leaves(grown)):
+        assert new.shape == (3, 32, old.shape[2])
+        assert np.array_equal(np.asarray(new[:, :16, 0]),
+                              np.broadcast_to(np.arange(16.0), (3, 16)))
+        assert not np.asarray(new[:, 16:]).any()
+    # the paged pool is rows major too, and slot- and rung-independent
+    paged = BertDecoder(cfg, params, page_size=4, pool_pages=9)
+    pool = paged.init_cache(3, 16)
+    assert all(l.shape == (9, 4, cfg.hidden_size)
+               for l in jax.tree_util.tree_leaves(pool))
+    assert paged.grow(pool, 32) is pool
+
+
+def test_bert_fingerprint_covers_the_cache_tree(bert):
+    """The executable store is keyed by `fingerprint()` + (name, rung,
+    k): a program stored for another cache layout would be handed this
+    one's state, so the cache tree's shapes are part of the fingerprint."""
+    cfg, params = bert
+
+    class Stacked(BertDecoder):      # the layout PR 27 replaced
+        def init_cache(self, slots, cache_len):
+            shape = (self.cfg.num_layers, slots, self.cfg.num_heads,
+                     cache_len, self.cfg.head_dim)
+            return {"k": jnp.zeros(shape), "v": jnp.zeros(shape)}
+
+    base = BertDecoder(cfg, params)
+    assert base.fingerprint() == BertDecoder(cfg, params).fingerprint()
+    assert Stacked(cfg, params).fingerprint() != base.fingerprint()
+    assert BertDecoder(cfg, params, page_size=4,
+                       pool_pages=9).fingerprint() != base.fingerprint()
+
+
 def test_bert_kv_decode_first_step_matches_full_forward(bert):
     """Fast lane of test_bert_kv_decode_matches_full_forward: the
     prefill logits and the FIRST decode step match the full-sequence
@@ -689,8 +769,8 @@ def test_flash_attention_decode_mq_matches_looped_single_query():
     rng = np.random.default_rng(7)
     b, h, tq, c, d = 3, 2, 3, 19, 8
     q = jnp.asarray(rng.standard_normal((b, h, tq, d)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((b, h, c, d)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((b, h, c, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((b, c, h * d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, c, h * d)), jnp.float32)
     base = np.array([4, 11, 0])     # ragged cached lengths per slot
     # query j of slot i sees rows 0 .. base[i]+j (the causal offset)
     qmask = jnp.asarray(

@@ -31,8 +31,7 @@ import jax.numpy as jnp
 from deeplearning4j_tpu import monitoring as mon
 from deeplearning4j_tpu.generation import BertDecoder, GenerationServer
 from deeplearning4j_tpu.generation.paging import NULL_PAGE, PageAllocator
-from deeplearning4j_tpu.kernels import (gather_kv_pages,
-                                        gather_scale_pages)
+from deeplearning4j_tpu.kernels import gather_kv_pages
 from deeplearning4j_tpu.models.bert import bert_tiny, init_bert_params
 from deeplearning4j_tpu.resilience.errors import (MemoryPressureError,
                                                   PagePoolExhaustedError)
@@ -175,20 +174,23 @@ def test_allocator_pbucket_in_dedup_key():
 
 # ===================== kernel gather helpers ==========================
 def test_gather_kv_pages_layout():
+    # rows major, width minor: K/V pages (P, ps, H·D), scale pages
+    # (P, ps, H) — one view helper serves both
     P, H, ps, D = 5, 2, 4, 3
-    pool = jnp.arange(P * H * ps * D, dtype=jnp.float32).reshape(
-        P, H, ps, D)
+    pool = jnp.arange(P * ps * H * D, dtype=jnp.float32).reshape(
+        P, ps, H * D)
     tab = jnp.asarray([[2, 0], [1, 4]], jnp.int32)
     out = gather_kv_pages(pool, tab)
-    assert out.shape == (2, H, 2 * ps, D)
+    assert out.shape == (2, 2 * ps, H * D)
     got = np.asarray(out)
-    assert np.array_equal(got[0, :, :ps], np.asarray(pool[2]))
-    assert np.array_equal(got[1, :, ps:], np.asarray(pool[4]))
-    spool = jnp.arange(P * H * ps, dtype=jnp.float32).reshape(P, H, ps)
-    sout = gather_scale_pages(spool, tab)
-    assert sout.shape == (2, H, 2 * ps)
-    assert np.array_equal(np.asarray(sout)[0, :, :ps],
-                          np.asarray(spool[2]))
+    assert np.array_equal(got[0, :ps], np.asarray(pool[2]))
+    assert np.array_equal(got[1, ps:], np.asarray(pool[4]))
+    spool = jnp.arange(P * ps * H, dtype=jnp.float32).reshape(P, ps, H)
+    sout = gather_kv_pages(spool, tab)
+    assert sout.shape == (2, 2 * ps, H)
+    assert np.array_equal(np.asarray(sout)[0, :ps], np.asarray(spool[2]))
+    with pytest.raises(ValueError, match=r"\(P, ps, W\)"):
+        gather_kv_pages(pool.reshape(P, ps, H, D), tab)
 
 
 # ===================== server bit-identity ============================

@@ -33,7 +33,10 @@ Event = collections.namedtuple("Event", "thread name start end stats")
 
 
 @pytest.fixture(autouse=True)
-def _monitoring_off_after():
+def _monitoring_off_around():
+    # before too: a worker that ran another file first may hold its events
+    mon.disable()
+    mon.get_tracer().clear()
     yield
     mon.disable()
     mon.get_tracer().clear()

@@ -457,10 +457,12 @@ def test_int8_kv_cache_decode_matches_fp(tiny_bert):
     assert q_toks == fp_toks          # greedy stream identical
     for a, b in zip(fp_lgs, q_lgs):
         np.testing.assert_allclose(a, b, atol=2e-3)
-    # cache really is int8 + per-(head, position) scales
+    # cache really is int8 + per-(position, head) scales, a leaf a layer
     cache = q_dec.init_cache(2, 16)
-    assert cache["k"].dtype == jnp.int8
-    assert cache["ks"].shape == cache["k"].shape[:4]
+    assert len(cache["k"]) == len(cache["ks"]) == cfg.num_layers
+    assert cache["k"][0].dtype == jnp.int8
+    assert cache["k"][0].shape == (2, 16, cfg.hidden_size)
+    assert cache["ks"][0].shape == (2, 16, cfg.num_heads)
     # fingerprints differ: quantized executables cache separately
     assert (BertDecoder(cfg, params).fingerprint()
             != q_dec.fingerprint())
@@ -472,10 +474,10 @@ def test_int8_kv_cache_grow_pads_scales(tiny_bert):
     dec = BertDecoder(cfg, params, kv_dtype="int8")
     cache = dec.init_cache(2, 8)
     grown = dec.grow(cache, 16)
-    assert grown["k"].shape[3] == 16
-    assert grown["ks"].shape[3] == 16
+    for name in ("k", "v", "ks", "vs"):
+        assert all(t.shape[:2] == (2, 16) for t in grown[name])
     # padded scale rows are 1.0 (zero rows round-trip exactly)
-    assert float(jnp.min(grown["ks"][:, :, :, 8:])) == 1.0
+    assert all(float(jnp.min(t[:, 8:])) == 1.0 for t in grown["ks"])
 
 
 def test_flash_decode_quantized_matches_dequantized_reference():
@@ -486,26 +488,35 @@ def test_flash_decode_quantized_matches_dequantized_reference():
     rng = np.random.default_rng(10)
     b, h, c, d = 3, 2, 11, 8
     q = jnp.asarray(rng.standard_normal((b, h, d)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((b, h, c, d)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((b, h, c, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((b, c, h, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, c, h, d)), jnp.float32)
     lens = np.array([0, 4, 11])   # incl. an empty-mask row
     mask = jnp.asarray(
         (np.arange(c)[None, :] < lens[:, None]).astype(np.float32))
+    # per-(row, head) scales (B, C, H) beside (B, C, H·D) int8 rows
+
+    def flat(t):
+        return t.reshape(b, c, h * d)
+
     kq, ks = quantize_rows(k)
     vq, vs = quantize_rows(v)
-    fused = flash_attention_decode(q, kq, vq, mask, k_scale=ks,
-                                   v_scale=vs)
+    fused = flash_attention_decode(q, flat(kq), flat(vq), mask,
+                                   k_scale=ks, v_scale=vs)
     # oracle: dequantize the cache, run the stock dense reference
-    ref = flash_attention_decode(q, dequantize_rows(kq, ks),
-                                 dequantize_rows(vq, vs), mask,
+    ref = flash_attention_decode(q, flat(dequantize_rows(kq, ks)),
+                                 flat(dequantize_rows(vq, vs)), mask,
                                  impl="dense")
     np.testing.assert_allclose(np.asarray(fused), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
     assert np.all(np.asarray(fused[0]) == 0)   # empty row zeroed
     with pytest.raises(ValueError, match="k_scale and v_scale"):
-        flash_attention_decode(q, kq, vq, mask, k_scale=ks)
+        flash_attention_decode(q, flat(kq), flat(vq), mask, k_scale=ks)
     with pytest.raises(ValueError, match="must be given together"):
-        flash_attention_decode(q, kq, vq, mask, v_scale=vs)
+        flash_attention_decode(q, flat(kq), flat(vq), mask, v_scale=vs)
+    with pytest.raises(ValueError, match=r"\(B, C, H\)"):
+        flash_attention_decode(q, flat(kq), flat(vq), mask,
+                               k_scale=ks.transpose(0, 2, 1),
+                               v_scale=vs.transpose(0, 2, 1))
 
 
 def test_int8_generation_server_stream(tiny_bert):

@@ -60,10 +60,11 @@ def compile_for_chip(one_chip):
 bf16, f32, i32, i8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
 
 # BERT-base attention: 12 heads of width 64; training batch 32 at seq 128,
-# serving 8 slots with cache rungs up to 512, pages of 16 rows
+# serving 8 slots with cache rungs up to 512, pages of 16 rows. The decode
+# cache operands are rows major, hidden (12 x 64 = 768) minor.
 _QKV_TRAIN = ((32, 12, 128, 64), bf16)
 _QKV_PREFILL = ((8, 12, 512, 64), bf16)
-_POOL = ((257, 12, 16, 64), bf16)        # 8 slots x 512 rows + null page
+_POOL = ((257, 16, 768), bf16)           # 8 slots x 512 rows + null page
 
 
 def _flash_fwd(q, k, v, m):
@@ -145,9 +146,10 @@ CASES = [
     ("flash_causal_prefill_b8_t512", _flash_causal, [_QKV_PREFILL] * 3,
      True),
     ("decode_pallas_c512_bf16", _decode,
-     [((8, 12, 64), bf16)] + [_QKV_PREFILL] * 2 + [((8, 512), i32)], True),
+     [((8, 12, 64), bf16)] + [((8, 512, 768), bf16)] * 2
+     + [((8, 512), i32)], True),
     ("decode_pallas_c512_f32", _decode,
-     [((8, 12, 64), f32)] + [((8, 12, 512, 64), f32)] * 2
+     [((8, 12, 64), f32)] + [((8, 512, 768), f32)] * 2
      + [((8, 512), i32)], True),
     ("decode_paged_pool257_ps16", _decode_paged,
      [((8, 12, 64), bf16), _POOL, _POOL, ((8, 32), i32), ((8, 512), i32)],
@@ -184,9 +186,97 @@ def test_decode_kernel_carries_its_names_for_v5e(compile_for_chip):
     custom call is named after the `pallas_call`'s `name`, under the entry
     point's scope (benchmarks/readers/program_span.py reads both)."""
     text = compile_for_chip(_decode, ((8, 12, 64), f32),
-                            *[((8, 12, 512, 64), f32)] * 2,
+                            *[((8, 512, 768), f32)] * 2,
                             ((8, 512), i32))
     call, = [l for l in text.splitlines()
              if "tpu_custom_call" in l and " custom-call(" in l]
     assert call.lstrip().lstrip("%").startswith("flash_fwd")
     assert "flash_decode/flash_fwd" in call
+
+
+# --- the decode superstep as `bert_serve_decode` runs it --------------------
+_SLOTS, _RUNG = 64, 512
+
+
+def _superstep_for_v5e(one_chip, dtype):
+    """scan(BertDecoder.step + sample_step, length=1) over BERT-base with
+    the cache donated, compiled for the described chip. -> (compiled,
+    elements of one cache leaf)"""
+    import importlib
+
+    from jax import lax
+
+    from deeplearning4j_tpu.generation.decode import BertDecoder
+    from deeplearning4j_tpu.generation.sampling import sample_step
+    from deeplearning4j_tpu.models.bert import BertConfig, init_bert_params
+
+    cfg = BertConfig(dtype=dtype)
+    params = jax.eval_shape(lambda k: init_bert_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    dec = BertDecoder(cfg, params, attn_impl="pallas")
+    cache = jax.eval_shape(lambda: dec.init_cache(_SLOTS, _RUNG))
+
+    def superstep(params, cache, tokens, pos, rng, method, temp, topk):
+        def body(carry, _):
+            cache, tokens, pos, rng = carry
+            logits, cache = dec.step((params,), cache, tokens, pos)
+            with jax.named_scope("sample"):
+                tok, rng = sample_step(logits, rng, method, temp, topk)
+            return (cache, tok, pos + 1, rng), tok
+        return lax.scan(body, (cache, tokens, pos, rng), None, length=1)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype,
+                                           sharding=one_chip), tree)
+
+    slot = jax.ShapeDtypeStruct((_SLOTS,), i32)
+    args = on_chip((params, cache, slot, slot,
+                    jax.ShapeDtypeStruct((_SLOTS, 2), jnp.uint32), slot,
+                    jax.ShapeDtypeStruct((_SLOTS,), f32), slot))
+    # under JAX_PLATFORMS=cpu the kernel would pick the interpreter: steer
+    # it from here, not through an option of the program
+    fa = importlib.import_module(
+        "deeplearning4j_tpu.kernels.flash_attention")
+    real = fa._flash_decode
+    fa._flash_decode = lambda q, k, v, m, bk, _: real(q, k, v, m, bk, False)
+    try:
+        with jax.default_matmul_precision("default"):
+            compiled = jax.jit(superstep, donate_argnums=(1, 2, 3, 4)) \
+                .lower(*args).compile()
+    finally:
+        fa._flash_decode = real
+    return compiled, _SLOTS * _RUNG * cfg.hidden_size
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_decode_superstep_keeps_the_cache_in_place_for_v5e(
+        compile_for_chip, one_chip, dtype):
+    """The cell's decode program moves no cache: every `(S, C, H·Dh)` leaf
+    has one layout at entry, in the row write, in the kernel and at exit
+    (a cache-sized copy or slice costs 0.7 ms a leaf a step on the chip:
+    `PERF.md`, PR 27). `compile_for_chip` is
+    asked for only to keep jax's persistent cache off around the compile."""
+    import re
+    compiled, leaf = _superstep_for_v5e(one_chip, dtype)
+    text = compiled.as_text()
+    moved = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* "
+                     r"(copy|slice|dynamic-slice|transpose)\(", line)
+        if m and m.group(1):
+            n = 1
+            for d in m.group(1).split(","):
+                n *= int(d)
+            if n >= leaf:
+                moved.append(line.strip()[:160])
+    assert not moved, moved
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+    calls = [l for l in text.splitlines()
+             if "tpu_custom_call" in l and " custom-call(" in l]
+    assert len(calls) == 12
+    for li in range(12):
+        assert sum(f"layer{li}/attn/flash_decode/flash_fwd" in c
+                   and c.lstrip().lstrip("%").startswith("flash_fwd")
+                   for c in calls) == 1, li
+        assert f"layer{li}/kv_write" in text
