@@ -1,7 +1,7 @@
 """Decode-mode forwards: incremental single-token model evaluation over
 donated device state.
 
-Two adapters expose one contract to the GenerationServer:
+Three adapters expose one contract to the GenerationServer:
 
 - **BertDecoder** — transformer stacks built on `models/bert.py` params:
   one K and one V cache leaf A LAYER, `(S, C, H·Dh)` (S slots, C =
@@ -26,6 +26,15 @@ Two adapters expose one contract to the GenerationServer:
   `_forward(carries=...)` path, so decode-step numerics are
   BIT-IDENTICAL to the full-sequence scan (tier-1 asserted).
 
+- **NemotronHDecoder** — hybrid stacks built on `models/nemotron_h.py`
+  params, whose donated cache holds TWO kinds of state side by side: K/V
+  leaves `(S, C, Hkv·Dh)` for the attention layers, written and read as
+  BertDecoder's are (grouped-query: the decode kernel maps a query head
+  to its group's lanes), and for the Mamba-2 layers a float32 state leaf
+  `(S, heads, head_dim, state)` and a convolution-tail leaf `(S, K-1,
+  lanes)` that no rung touches. Its expert layers count what they compute
+  on the device (`counter_names`).
+
 The contract (all pure functions, traced into AOT executables by the
 server — nothing here may touch the host):
 
@@ -34,6 +43,21 @@ server — nothing here may touch the host):
     prefill(margs, cache, slot, prompt, plen)  -> (cache', logits (V,))
     grow(cache, new_len)          -> cache padded to a longer rung
     init_cache(slots, cache_len)  -> donated cache pytree
+
+State that has no rung. The cache is the decoder's own pytree and `grow`
+its own function, so a decoder declares per leaf what a rung means: `grow`
+pads the leaves that hold a row a cached position (K/V) and returns every
+other leaf as it is (a recurrent state, a convolution tail, a counter);
+`prefill` grafts one slot's part into both kinds. `uses_cache_rungs` says
+only whether ANY leaf grows (False lets the server keep one rung). The
+server never looks inside.
+
+Device-side counters (optional). A decoder that counts on the device gives
+their names in `counter_names` and keeps the running counts (int32,
+wrapping) in its cache; `counters(cache)` hands them out as a
+`(len(counter_names),)` vector. The server appends that vector to the token
+block of every superstep, so the counts reach the host with the fetch the
+loop makes anyway, and `status()` shows them cumulative.
 
 The BertDecoder cache pytree: `{"k": [L leaves], "v": [L leaves]}`, each
 leaf `(S, C, H·Dh)` in the compute dtype; `kv_dtype="int8"` stores the
@@ -68,11 +92,27 @@ from jax import lax
 from deeplearning4j_tpu.kernels.flash_attention import (
     flash_attention, flash_attention_decode, flash_attention_decode_mq,
     flash_attention_decode_mq_paged, flash_attention_decode_paged)
+from deeplearning4j_tpu.models import nemotron_h
 from deeplearning4j_tpu.models.bert import (_ffn, _layer_norm,
                                             bert_mlm_logits)
 from deeplearning4j_tpu.parallel.ring_attention import dense_attention
 
-__all__ = ["BertDecoder", "RecurrentDecoder"]
+__all__ = ["BertDecoder", "NemotronHDecoder", "RecurrentDecoder"]
+
+
+def _write_kv(cache, li, wi, wj, k, v):
+    """Layer `li`'s K/V rows `k`/`v` (..., Hkv·Dh) into its leaves at
+    (`wi`, `wj`) — whole rows, in place. `cache` is a dict of per-layer
+    leaf LISTS."""
+    kc, vc = cache["k"][li], cache["v"][li]
+    cache["k"][li] = kc.at[wi, wj].set(k.astype(kc.dtype))
+    cache["v"][li] = vc.at[wi, wj].set(v.astype(vc.dtype))
+
+
+def _slot_index(pos):
+    """Rows at per-slot positions `pos` ((S,) or an (S, d) block) of a
+    slot-contiguous cache: the slot index to pair with `pos`."""
+    return jnp.arange(pos.shape[0]).reshape((-1,) + (1,) * (pos.ndim - 1))
 
 
 def _shape_tree_repr(tree):
@@ -262,9 +302,7 @@ class BertDecoder:
             v, v_sc = self._quantize_heads(v)
             cache["ks"][li] = cache["ks"][li].at[wi, wj].set(k_sc)
             cache["vs"][li] = cache["vs"][li].at[wi, wj].set(v_sc)
-        kc, vc = cache["k"][li], cache["v"][li]
-        cache["k"][li] = kc.at[wi, wj].set(k.astype(kc.dtype))
-        cache["v"][li] = vc.at[wi, wj].set(v.astype(vc.dtype))
+        _write_kv(cache, li, wi, wj, k, v)
 
     def _write_index(self, cache, pos, ptab):
         """Where rows at per-slot positions `pos` ((S,) or an (S, d)
@@ -275,7 +313,7 @@ class BertDecoder:
         dense cache silently DROPS that out-of-range scatter; pages must
         redirect it explicitly or the clamped index would corrupt a
         live row."""
-        ar = jnp.arange(pos.shape[0]).reshape((-1,) + (1,) * (pos.ndim - 1))
+        ar = _slot_index(pos)
         if not self.paged:
             return ar, pos, cache["k"][0].shape[1]
         psz = self.page_size
@@ -493,6 +531,168 @@ class BertDecoder:
             logits = bert_mlm_logits(cfg, params,
                                      h_last[None, None, :])[0, 0]
         return cache, logits
+
+
+class NemotronHDecoder:
+    """Decode over a `models/nemotron_h.py` parameter tree: K/V leaves for
+    the attention layers beside Mamba-2 state and convolution-tail leaves,
+    in one donated cache.
+
+    The cache pytree: `{"k": [...], "v": [...]}`, one `(S, C, Hkv·Dh)`
+    leaf an ATTENTION layer in the compute dtype; `{"ssm": [...], "conv":
+    [...]}`, one `(S, heads, head_dim, state)` float32 leaf and one `(S,
+    K-1, conv lanes)` leaf a MAMBA layer; `"counts"`, the expert layers'
+    running counts (`counter_names`). Only the K/V leaves know the rung.
+
+    The full-sequence reference this must match is `nemotron_h.forward`
+    over the same prompt+generated prefix (and, outside the package, the
+    plain reference under `benchmarks/families/`)."""
+
+    uses_cache_rungs = True
+    n_model_args = 1
+    supports_draft = False      # the model's prediction module is not run
+    max_cache_len = None        # no position table bounds the length
+    counter_names = ("moe_pairs", "moe_expert_reads", "moe_pairs_max")
+
+    def __init__(self, cfg, params, attn_impl="auto"):
+        if attn_impl not in ("auto", "dense", "pallas"):
+            raise ValueError(
+                f"attn_impl must be 'auto', 'dense' or 'pallas', "
+                f"got {attn_impl!r}")
+        self.cfg = cfg
+        self.params = params
+        self.attn_impl = attn_impl
+        self.vocab_size = int(cfg.vocab_size)
+        # layer index -> index among the layers of its kind (its leaves)
+        self._n, self._leaf = {}, []
+        for kind in cfg.pattern:
+            self._leaf.append(self._n.get(kind, 0))
+            self._n[kind] = self._leaf[-1] + 1
+
+    def fingerprint(self):
+        parts = ("nemotron-h-decode", repr(self.cfg), self.attn_impl,
+                 _shape_tree_repr(self.params),
+                 _shape_tree_repr(
+                     jax.eval_shape(lambda: self.init_cache(1, 1))))
+        return hashlib.sha256(repr(parts).encode()).hexdigest()[:16]
+
+    def model_args(self):
+        return (self.params,)
+
+    def init_cache(self, slots, cache_len):
+        cfg = self.cfg
+        dt = cfg.compute_dtype
+        na, nm = self._n.get("*", 0), self._n.get("M", 0)
+
+        def leaves(n, shape, dtype):
+            return [jnp.zeros((slots,) + shape, dtype) for _ in range(n)]
+
+        return {"k": leaves(na, (cache_len, cfg.kv_width), dt),
+                "v": leaves(na, (cache_len, cfg.kv_width), dt),
+                "ssm": leaves(nm, (cfg.mamba_num_heads, cfg.mamba_head_dim,
+                                   cfg.ssm_state_size), jnp.float32),
+                "conv": leaves(nm, (cfg.conv_kernel - 1, cfg.conv_dim), dt),
+                "counts": jnp.zeros((len(self.counter_names),), jnp.int32)}
+
+    def grow(self, cache, new_len):
+        """The K/V leaves padded to the longer rung; the state leaves, the
+        tails and the counts as they are."""
+        def pad(t):
+            return jnp.pad(t, ((0, 0), (0, int(new_len) - t.shape[1]),
+                               (0, 0)))
+        return {**cache, "k": [pad(t) for t in cache["k"]],
+                "v": [pad(t) for t in cache["v"]]}
+
+    def counters(self, cache):
+        return cache["counts"]
+
+    def step(self, margs, cache, tokens, pos):
+        """One decode step for the whole batch: each attention layer
+        writes its K/V row at `pos` and attends rows 0..pos; each Mamba-2
+        layer advances its slots' state and tail by the one token; each
+        expert layer routes the S tokens and adds what it computed to the
+        counts. Returns next-token logits (S, V)."""
+        (params,) = margs
+        cfg = self.cfg
+        s = tokens.shape[0]
+        with jax.named_scope("embed"):
+            x = jnp.take(params["embed"], tokens, axis=0)       # (S, H)
+        cache = {name: list(v) if isinstance(v, (list, tuple)) else v
+                 for name, v in cache.items()}
+        wi = _slot_index(pos)
+        if cache["k"]:
+            # rows 0..pos are valid (the current write included)
+            cmask = jnp.arange(cache["k"][0].shape[1])[None, :] \
+                <= pos[:, None]
+        for li, (kind, layer) in enumerate(zip(cfg.pattern,
+                                               params["layers"])):
+            i = self._leaf[li]
+            with jax.named_scope(f"layer{li}"):
+                with jax.named_scope("norm"):
+                    u = nemotron_h.rms_norm(x, layer["norm"], cfg.norm_eps)
+                if kind == "M":
+                    with jax.named_scope("ssm"):
+                        out, cache["ssm"][i], cache["conv"][i] = \
+                            nemotron_h.mamba_step(cfg, layer, u,
+                                                  cache["ssm"][i],
+                                                  cache["conv"][i])
+                elif kind == "*":
+                    with jax.named_scope("attn"):
+                        with jax.named_scope("qkv"):
+                            q, k, v = nemotron_h.attention_qkv(cfg, layer,
+                                                               u)
+                        with jax.named_scope("kv_write"):
+                            _write_kv(cache, i, wi, pos, k, v)
+                        ctx = flash_attention_decode(
+                            q.reshape(s, cfg.num_attention_heads,
+                                      cfg.head_dim),
+                            cache["k"][i], cache["v"][i], cmask,
+                            impl=self.attn_impl)
+                        with jax.named_scope("proj"):
+                            out = ctx.reshape(s, -1).astype(x.dtype) \
+                                @ layer["o"].astype(x.dtype)
+                else:
+                    with jax.named_scope("moe"):
+                        out, counted = nemotron_h.moe_mixer(cfg, layer, u)
+                        cache["counts"] = cache["counts"] + counted
+                x = x + out.astype(x.dtype)
+        return nemotron_h.logits(cfg, params, x), cache
+
+    def prefill(self, margs, cache, slot, prompt, plen):
+        """The full forward over one length-bucketed prompt (1, P), then
+        the graft of ONE slot's part into both kinds of state: the K/V
+        block for rows 0..P-1 (rows beyond plen hold padding garbage that
+        the decode mask hides, as in BertDecoder), and the Mamba-2 state
+        and tail as the last REAL token left them (`mamba_mixer` holds the
+        state through the padding). Returns the logits at plen - 1."""
+        (params,) = margs
+        cfg = self.cfg
+        x, states = nemotron_h.encode(cfg, params, prompt[None],
+                                      jnp.reshape(plen, (1,)))
+        cache = {name: list(v) if isinstance(v, (list, tuple)) else v
+                 for name, v in cache.items()}
+
+        def graft(name, i, part):
+            leaf = cache[name][i]
+            cache[name][i] = lax.dynamic_update_slice(
+                leaf, part.astype(leaf.dtype),
+                (slot,) + (0,) * (leaf.ndim - 1))
+
+        for li, (kind, state) in enumerate(zip(cfg.pattern, states)):
+            i = self._leaf[li]
+            with jax.named_scope(f"layer{li}"):
+                if kind == "*":
+                    with jax.named_scope("attn"), \
+                            jax.named_scope("kv_write"):
+                        graft("k", i, state[0])
+                        graft("v", i, state[1])
+                elif kind == "M":
+                    with jax.named_scope("ssm"), \
+                            jax.named_scope("state_write"):
+                        graft("ssm", i, state[0])
+                        graft("conv", i, state[1])
+        h_last = jnp.take(x[0], plen - 1, axis=0)               # (H,)
+        return cache, nemotron_h.logits(cfg, params, h_last)
 
 
 class RecurrentDecoder:

@@ -449,6 +449,12 @@ class GenerationServer:
                       "replays": 0, "restarts": 0, "degradations": 0,
                       "draft_accepts": 0, "draft_rejects": 0}
         self.token_fetches = 0       # host syncs: ONE per decode block
+        #: counts the decoder keeps on the device (decode.py, "Device-side
+        #: counters"): they ride every superstep's token block to the
+        #: host and are cumulative in `stats`
+        self._counter_names = tuple(getattr(decoder, "counter_names", ()))
+        self.stats.update(dict.fromkeys(self._counter_names, 0))
+        self._counts_seen = None     # the device's counts, last fetched
         self._queue = queue.Queue(maxsize=int(queue_limit))
         self._store = None           # FunctionStore, built at warmup
         self._exec_cache_dir = exec_cache_dir
@@ -624,6 +630,7 @@ class GenerationServer:
 
     def _init_state(self, rung):
         s = self.slots
+        self._counts_seen = np.zeros((len(self._counter_names),), np.int64)
         return (self.decoder.init_cache(s, rung),
                 jnp.zeros((s,), jnp.int32),
                 jnp.zeros((s,), jnp.bool_),
@@ -678,6 +685,11 @@ class GenerationServer:
             (cache, pos, active, tokens, rng, _), outs = lax.scan(
                 body, (cache, pos, active, tokens, rng, budget), None,
                 length=k)
+            if self._counter_names:
+                # one more row a counter, under the k rows of tokens
+                counts = self.decoder.counters(cache).astype(outs.dtype)
+                outs = jnp.concatenate([outs, jnp.broadcast_to(
+                    counts[:, None], (counts.shape[0], outs.shape[1]))])
             return (cache, pos, active, tokens, rng, method, temp,
                     topk, outs)                           # outs (k, S)
 
@@ -1293,6 +1305,8 @@ class GenerationServer:
         """`_deliver_block`'s work; returns the live tokens delivered."""
         overlap_ms = (time.perf_counter() - blk.t_copy) * 1e3
         toks = self._fetch_tokens(blk.tokens, step=blk.step)  # (k, S)
+        if self._counter_names and blk.proposed is None:
+            toks = self._take_counters(toks, blk.k)   # superstep blocks
         dt_ms = (time.perf_counter() - blk.t0) * 1e3
         # request timelines: one "block" event per still-owned slot —
         # appended HERE, on the existing fetch boundary (toks is host
@@ -1378,6 +1392,18 @@ class GenerationServer:
                                  "delivered (mismatch or EOS/budget "
                                  "truncation)").inc(rejects)
         return live
+
+    def _take_counters(self, block, k):
+        """Split a fetched superstep block into its k rows of tokens and
+        the decoder's counters under them (host data already: no sync).
+        The device counts in wrapping int32 since its state was built;
+        `stats` gets what was added since the last block."""
+        seen = block[k:, 0].astype(np.int64)
+        for name, add in zip(self._counter_names,
+                             (seen - self._counts_seen) & 0xFFFFFFFF):
+            self.stats[name] += int(add)
+        self._counts_seen = seen
+        return block[:k]
 
     def _start_fetch(self, arr):
         """Start the NON-BLOCKING device→host copy of a sampled-token

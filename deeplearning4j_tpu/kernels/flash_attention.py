@@ -493,32 +493,44 @@ def _split_heads(cache, h):
 
 
 def _check_cache_operands(q, k_cache, v_cache):
-    """q is (B, H, Tq, D); the caches must match as (B, C, H·D)."""
+    """q is (B, Hq, Tq, D); the caches must match as (B, C, H·D), H the KV
+    heads, with Hq a multiple of H: query head i reads the lanes of KV
+    head i // (Hq / H). Returns H."""
     if k_cache.shape != v_cache.shape or k_cache.ndim != 3:
         raise ValueError(
             f"k_cache/v_cache must match as (B, C, H·D): "
             f"{k_cache.shape} vs {v_cache.shape}")
-    b, h, _, d = q.shape
-    if k_cache.shape[0] != b or k_cache.shape[2] != h * d:
+    b, hq, _, d = q.shape
+    h, rest = divmod(k_cache.shape[2], d)
+    if k_cache.shape[0] != b or rest or h < 1 or hq % h:
         raise ValueError(
-            f"k_cache/v_cache must be (B, C, H·D) = ({b}, C, {h * d}) "
-            f"for q {q.shape}, got {k_cache.shape}")
+            f"k_cache/v_cache must be (B, C, H·D) = ({b}, C, H·{d}) with "
+            f"H KV heads dividing the {hq} query heads of q {q.shape}, "
+            f"got {k_cache.shape}")
+    return h
 
 
 def _masked_attend(q, k_cache, v_cache, valid, k_scale=None, v_scale=None):
     """The einsum masked softmax every non-kernel decode path runs:
     softmax(q·Kᵀ/√d)·V over the VALID cache rows of each query.
 
-    - q (B, H, Tq, D); k_cache / v_cache (B, C, H·D)
+    - q (B, Hq, Tq, D); k_cache / v_cache (B, C, Hkv·D), Hq a multiple of
+      Hkv: the query heads of one KV head's group go through as more query
+      rows of that head (with Hq = Hkv nothing is moved)
     - valid (B, Tq, C) bool
-    - k_scale / v_scale: (B, C, H) float32 row scales of an int8 cache,
+    - k_scale / v_scale: (B, C, Hkv) float32 row scales of an int8 cache,
       folded INTO the contractions: the key scale multiplies the score
       logits (s·(k_row·ks) = (s·k_row)·ks), the value scale folds onto
       the softmax weights before the value pass — no dequantized copy of
       the cache materializes and the cache reads stay int8.
     Queries with no valid row come back zeroed (the kernel's
     empty-softmax convention)."""
-    h, d = q.shape[1], q.shape[3]
+    b, hq, tq, d = q.shape
+    h = k_cache.shape[2] // d
+    group = hq // h
+    if group > 1:
+        q = q.reshape(b, h, group * tq, d)
+        valid = jnp.tile(valid, (1, group, 1))
     scale = 1.0 / (d ** 0.5)
     s = jnp.einsum("bhqd,bchd->bhqc", q.astype(jnp.float32),
                    _split_heads(k_cache, h).astype(jnp.float32)) * scale
@@ -532,26 +544,35 @@ def _masked_attend(q, k_cache, v_cache, valid, k_scale=None, v_scale=None):
                      _split_heads(v_cache, h).astype(jnp.float32)
                      ).astype(q.dtype)
     any_valid = valid.any(axis=-1)                    # (B, Tq)
-    return jnp.where(any_valid[:, None, :, None], out, 0)
+    return jnp.where(any_valid[:, None, :, None], out, 0).reshape(
+        b, hq, tq, d)
 
 
 def _flash_decode_kernel(q_ref, k_ref, v_ref, km_ref, o_ref, acc_ref, l_ref,
-                         m_ref, *, scale, head_dim):
+                         m_ref, *, scale, head_dim, group):
     """Grid (B, k_tiles), k innermost: one slot's single query row against
-    a (block_k, H·D) tile of its cache rows, read IN PLACE — every head of
-    the slot in one grid step.
+    a (block_k, Hkv·D) tile of its cache rows, read IN PLACE — every head
+    of the slot in one grid step.
 
-    The query row becomes a block-diagonal (Hp, H·D) operand (row h holds
-    head h's D lanes, zeros elsewhere), so `_flash_fwd_kernel`'s own
-    arithmetic — q·Kᵀ, the masked online softmax with float32 o/l/m
-    scratch across the k tiles, p·V — runs once for all heads: scores
-    (Hp, block_k), accumulator (Hp, H·D). Head h's output is the h-th
-    diagonal (1, D) block of the accumulator; the last k step folds the
-    blocks back into one (1, H·D) row."""
+    The query becomes a block-diagonal (Hp, Hkv·D) operand (row i holds
+    query head i's D lanes at the lanes of ITS KV head, i // group, zeros
+    elsewhere), so `_flash_fwd_kernel`'s own arithmetic — q·Kᵀ, the masked
+    online softmax with float32 o/l/m scratch across the k tiles, p·V —
+    runs once for all heads: scores (Hp, block_k), accumulator (Hp,
+    Hkv·D). Head i's output is the (1, D) block of accumulator row i at
+    its KV head's lanes.
+
+    With `group` 1 (as many cache heads as query heads) the query comes in
+    as one (1, H·D) row, broadcast over the rows, and the last k step
+    folds the diagonal blocks back into one (1, H·D) row. With a larger
+    group the query heads come in as the rows of a (Hp, D) block, repeated
+    along the lanes once a KV head, and go out the same way."""
     kj = pl.program_id(1)
     hp, hd = acc_ref.shape
     row = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 0)
     lane = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 1)
+    if group > 1:
+        row = jax.lax.div(row, group)             # the row's KV head
     own = (lane >= row * head_dim) & (lane < (row + 1) * head_dim)
 
     @pl.when(kj == 0)
@@ -560,7 +581,10 @@ def _flash_decode_kernel(q_ref, k_ref, v_ref, km_ref, o_ref, acc_ref, l_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
 
-    q = jnp.where(own, q_ref[0].astype(jnp.float32) * scale, 0.0)
+    q = q_ref[0].astype(jnp.float32) * scale
+    if group > 1:
+        q = jnp.concatenate([q] * (hd // head_dim), axis=1)
+    q = jnp.where(own, q, 0.0)
     s = jax.lax.dot_general(
         q, k_ref[0].astype(jnp.float32),
         dimension_numbers=(((1,), (1,)), ((), ())),
@@ -575,36 +599,50 @@ def _flash_decode_kernel(q_ref, k_ref, v_ref, km_ref, o_ref, acc_ref, l_ref,
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
         p, v_ref[0].astype(jnp.float32),
         dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)           # (Hp, H·D)
+        preferred_element_type=jnp.float32)           # (Hp, Hkv·D)
 
     @pl.when(kj == pl.num_programs(1) - 1)
     def _finalize():
-        o = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = jnp.sum(jnp.where(own, o, 0.0), axis=0,
-                           keepdims=True).astype(o_ref.dtype)
+        o = jnp.where(own, acc_ref[...] / jnp.maximum(l_ref[...], 1e-30),
+                      0.0)
+        if group > 1:
+            out = o[:, :head_dim]
+            for h in range(1, hd // head_dim):
+                out = out + o[:, h * head_dim:(h + 1) * head_dim]
+            o_ref[0] = out.astype(o_ref.dtype)
+        else:
+            o_ref[0] = jnp.sum(o, axis=0, keepdims=True).astype(o_ref.dtype)
 
 
 def _flash_decode(q, k_cache, v_cache, cache_mask, block_k, interpret):
-    """q (B, H, 1, D) against (B, C, H·D) caches through the Pallas
-    kernel; returns (B, H, 1, D). The caches go in as they are: no pad,
+    """q (B, Hq, 1, D) against (B, C, Hkv·D) caches through the Pallas
+    kernel; returns (B, Hq, 1, D). The caches go in as they are: no pad,
     no reshape — a rung the k tile does not divide is one whole tile."""
     b, h, _, d = q.shape
     c, hd = k_cache.shape[1], k_cache.shape[2]
+    group = h // (hd // d)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if c % block_k:
         block_k = c
     hp = -(-h // 8) * 8            # float32 sublane tile of the scores
     cache_spec = pl.BlockSpec((1, block_k, hd), lambda i, j: (i, j, 0))
-    row_spec = pl.BlockSpec((1, 1, hd), lambda i, j: (i, 0, 0))
+    if group > 1:                  # the query heads as rows, padded to Hp
+        q_rows = jnp.pad(q[:, :, 0, :], ((0, 0), (0, hp - h), (0, 0)))
+        row_spec = pl.BlockSpec((1, hp, d), lambda i, j: (i, 0, 0))
+        out_shape = (b, hp, d)
+    else:
+        q_rows = q.reshape(b, 1, hd)
+        row_spec = pl.BlockSpec((1, 1, hd), lambda i, j: (i, 0, 0))
+        out_shape = (b, 1, hd)
     out = pl.pallas_call(
         functools.partial(_flash_decode_kernel, scale=1.0 / (d ** 0.5),
-                          head_dim=d),
+                          head_dim=d, group=group),
         grid=(b, c // block_k),
         in_specs=[row_spec, cache_spec, cache_spec,
                   pl.BlockSpec((1, 1, block_k), lambda i, j: (i, 0, j))],
         out_specs=row_spec,
-        out_shape=jax.ShapeDtypeStruct((b, 1, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
         scratch_shapes=[
             pltpu.VMEM((hp, hd), jnp.float32),
             pltpu.VMEM((hp, 1), jnp.float32),
@@ -614,8 +652,9 @@ def _flash_decode(q, k_cache, v_cache, cache_mask, block_k, interpret):
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="flash_fwd",
-    )(q.reshape(b, 1, hd), k_cache, v_cache,
-      cache_mask.astype(jnp.int32)[:, None, :])
+    )(q_rows, k_cache, v_cache, cache_mask.astype(jnp.int32)[:, None, :])
+    if group > 1:
+        out = out[:, :h]
     # a slot with NO valid cache row has no defined softmax: zeros
     any_valid = cache_mask.astype(bool).any(axis=1)
     return jnp.where(any_valid[:, None, None], out, 0).reshape(b, h, 1, d)
@@ -678,16 +717,18 @@ def flash_attention_decode(q1, k_cache, v_cache, cache_mask, impl="auto",
     positions), and the query is the current token only. O(C·D) HBM
     per step instead of the O(T²) full-sequence re-forward.
 
-    - q1: (B, H, D) or (B, H, 1, D) — current-token query
-    - k_cache / v_cache: (B, C, H·D) — rolling caches, rows major and
+    - q1: (B, Hq, D) or (B, Hq, 1, D) — current-token query
+    - k_cache / v_cache: (B, C, Hkv·D) — rolling caches, rows major and
       the hidden width minor (C = cache rung): one decode-cache leaf as
-      `BertDecoder.init_cache` lays it out, read in place
+      a decoder's `init_cache` lays it out, read in place. Hq is a
+      multiple of Hkv (grouped-query attention): query head i reads the
+      lanes of KV head i // (Hq / Hkv); Hq = Hkv is plain multi-head
     - cache_mask: (B, C) truthy — valid cache rows (ragged lengths)
     - impl: 'auto' (Pallas kernel on TPU, einsum elsewhere), 'pallas'
       (force kernel; interpret-mode off-TPU), or 'dense'
     - block_k: cache rows a kernel grid step reads (a rung it does not
       divide is read as one tile)
-    - k_scale / v_scale: (B, C, H) float32 per-head row scales of an
+    - k_scale / v_scale: (B, C, Hkv) float32 per-head row scales of an
       int8-quantized cache (quantize/kvcache.py). When given, the
       dequant happens INSIDE the attention contractions — the single-
       query decode pass is a bandwidth-bound GEMV, so reading the
@@ -704,7 +745,7 @@ def flash_attention_decode(q1, k_cache, v_cache, cache_mask, impl="auto",
     if q.ndim != 4 or q.shape[2] != 1:
         raise ValueError(
             f"q1 must be (B, H, D) or (B, H, 1, D), got {q1.shape}")
-    _check_cache_operands(q, k_cache, v_cache)
+    hkv = _check_cache_operands(q, k_cache, v_cache)
     if cache_mask.shape != (q.shape[0], k_cache.shape[1]):
         raise ValueError(
             f"cache_mask must be (B, C) = "
@@ -722,7 +763,7 @@ def flash_attention_decode(q1, k_cache, v_cache, cache_mask, impl="auto",
                 "streaming-softmax kernel has no slot for per-row "
                 "scales yet) — use 'auto' or 'dense' with a "
                 "quantized cache")
-        expect = (q.shape[0], k_cache.shape[1], q.shape[1])
+        expect = (q.shape[0], k_cache.shape[1], hkv)
         if tuple(k_scale.shape) != expect \
                 or tuple(v_scale.shape) != expect:
             raise ValueError(

@@ -508,9 +508,18 @@ def test_server_steady_state_never_compiles(server, monkeypatch):
     assert len(r2.result(timeout=60)) == 4
     assert server._store.trace_calls == traces
     # sync accounting: exactly one fetch per decode step plus one per
-    # admission (the prefill's first token) — nothing else materializes
-    assert (server.token_fetches - fetches0
-            == (server.stats["steps"] - steps0) + 2)
+    # admission (the prefill's first token) — nothing else materializes.
+    # The loop counts a block's fetch before it delivers the block's
+    # tokens and its step after, and drains one more block once every
+    # slot has retired: the two counters agree when it has come to rest
+    def settled():
+        return (server.token_fetches - fetches0
+                == (server.stats["steps"] - steps0) + 2)
+
+    deadline = time.monotonic() + 5.0
+    while not settled() and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert settled()
 
 
 def test_server_eos_and_length_retirement(server, net):

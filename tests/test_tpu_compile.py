@@ -94,6 +94,11 @@ def _decode_paged(q, kp, vp, ptab, m):
                                         interpret=False)
 
 
+def _routed_experts(x, scores, bias, w1, w2):
+    from deeplearning4j_tpu.parallel.moe import routed_experts
+    return routed_experts(x, scores, bias, w1, w2, (0, 128), 22, 5.0)
+
+
 def _layernorm(x, g, b):
     from deeplearning4j_tpu.kernels import fused_layernorm
     return fused_layernorm(x, g, b, 1e-12, 128, False)
@@ -151,6 +156,16 @@ CASES = [
     ("decode_pallas_c512_f32", _decode,
      [((8, 12, 64), f32)] + [((8, 512, 768), f32)] * 2
      + [((8, 512), i32)], True),
+    # grouped-query decode at the widths of nemotron3_super_serve_decode:
+    # 32 query heads over 2 KV heads of 128, 128 slots at a 1024-row rung
+    ("decode_pallas_gqa_32q_2kv_c1024_bf16", _decode,
+     [((128, 32, 128), bf16)] + [((128, 1024, 256), bf16)] * 2
+     + [((128, 1024), i32)], True),
+    # its expert layer: 128 slots x 22 choices over the 128 experts held
+    # (XLA's own grouped-matmul kernel, from lax.ragged_dot)
+    ("routed_experts_128x22_of_128_held", _routed_experts,
+     [((128, 1024), bf16), ((128, 512), f32), ((512,), f32),
+      ((128, 1024, 2688), bf16), ((128, 2688, 1024), bf16)], True),
     ("decode_paged_pool257_ps16", _decode_paged,
      [((8, 12, 64), bf16), _POOL, _POOL, ((8, 32), i32), ((8, 512), i32)],
      True),
