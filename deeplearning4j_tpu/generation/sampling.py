@@ -16,8 +16,10 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
-__all__ = ["GREEDY", "SAMPLE", "method_id", "sample_step", "split_keys"]
+__all__ = ["GREEDY", "SAMPLE", "kth_largest", "method_id", "sample_step",
+           "split_keys"]
 
 #: per-slot sampling method ids (device i32)
 GREEDY = 0
@@ -45,10 +47,38 @@ def split_keys(keys):
     return s[:, 0], s[:, 1]
 
 
+@jax.named_scope("select")
+def kth_largest(x, k):
+    """The k-th largest value of each row, exactly, without sorting
+    (scope `sample/select` in a profiler trace).
+
+    - x: (S, V) float32
+    - k: (S,) int32 in 1..V, a different one in every row
+
+    Returns (S,) float32: what an ascending sort of row s holds at
+    index V - k[s]. The row is mapped once to an unsigned image that
+    orders as the floats do (-inf lowest); the threshold is then built
+    bit by bit from the top: a bit stays set where at least k elements
+    lie at or above the candidate. 32 fused compare-and-count passes
+    over the row, no sorted copy (the sort was a third of BERT-base's
+    decode step on a v5e: `PERF.md`, PR 29)."""
+    b = lax.bitcast_convert_type(x, jnp.uint32)
+    top = jnp.uint32(1 << 31)
+    u = jnp.where(b >= top, ~b, b | top)
+
+    def grow(i, prefix):
+        cand = prefix | (top >> i.astype(jnp.uint32))
+        cnt = jnp.sum(u >= cand[:, None], axis=-1, dtype=jnp.int32)
+        return jnp.where(cnt >= k, cand, prefix)
+
+    prefix = lax.fori_loop(0, 32, grow, jnp.zeros(x.shape[:1], jnp.uint32))
+    return lax.bitcast_convert_type(
+        jnp.where(prefix >= top, prefix ^ top, ~prefix), jnp.float32)
+
+
 @jax.named_scope("sample")
 def sample_step(logits, keys, method, temperature, top_k):
-    """One batched sampling step (scope `sample` in a profiler trace:
-    the full-vocabulary `sort` below is its cost).
+    """One batched sampling step (scope `sample` in a profiler trace).
 
     - logits: (S, V) float32
     - keys: (S, 2) uint32 per-slot rng keys
@@ -62,13 +92,10 @@ def sample_step(logits, keys, method, temperature, top_k):
     greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     t = jnp.where(temperature > 0, temperature, 1.0)[:, None]
     scaled = logits / t
-    # top-k threshold: kth-largest value per row (ascending sort, index
-    # V-k); ties at the threshold stay in — a superset of k never
-    # excludes the true top-k
+    # top-k threshold: the kth-largest value per row; ties at the
+    # threshold stay in — a superset of k never excludes the true top-k
     k_eff = jnp.clip(top_k, 0, v)
-    srt = jnp.sort(scaled, axis=-1)
-    kth = jnp.take_along_axis(
-        srt, jnp.maximum(v - k_eff, 0)[:, None], axis=-1)
+    kth = kth_largest(scaled, jnp.maximum(k_eff, 1))[:, None]
     use_k = ((k_eff > 0) & (k_eff < v))[:, None]
     filtered = jnp.where(use_k & (scaled < kth), _NEG, scaled)
     new_keys, subkeys = split_keys(keys)

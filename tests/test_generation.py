@@ -24,8 +24,10 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.generation import (BertDecoder, GenerationServer,
                                            RecurrentDecoder)
 from deeplearning4j_tpu.generation.sampling import (GREEDY, SAMPLE,
+                                                    kth_largest,
                                                     method_id,
-                                                    sample_step)
+                                                    sample_step,
+                                                    split_keys)
 from deeplearning4j_tpu.kernels.flash_attention import (
     flash_attention_decode, flash_attention_decode_mq)
 from deeplearning4j_tpu.models.bert import (bert_encode, bert_mlm_logits,
@@ -442,6 +444,121 @@ def test_sampling_top_k_restricts_support():
     mixed_k = jnp.asarray([3, 0], jnp.int32)
     toks, _ = sample_step(logits, keys, m, ones, mixed_k)
     assert int(toks[0]) in top3
+
+
+def _sample_step_by_sort(logits, keys, method, temperature, top_k):
+    """The oracle: `sample_step` as it was while it sorted the whole
+    vocabulary for its threshold (until PR 29)."""
+    v = logits.shape[-1]
+    greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    t = jnp.where(temperature > 0, temperature, 1.0)[:, None]
+    scaled = logits / t
+    k_eff = jnp.clip(top_k, 0, v)
+    srt = jnp.sort(scaled, axis=-1)
+    kth = jnp.take_along_axis(
+        srt, jnp.maximum(v - k_eff, 0)[:, None], axis=-1)
+    use_k = ((k_eff > 0) & (k_eff < v))[:, None]
+    filtered = jnp.where(use_k & (scaled < kth), -1e30, scaled)
+    new_keys, subkeys = split_keys(keys)
+    sampled = jax.vmap(jax.random.categorical)(subkeys, filtered)
+    tokens = jnp.where(method == GREEDY, greedy_tok,
+                       sampled.astype(jnp.int32))
+    return tokens, new_keys
+
+
+_WIDE = 30522     # BERT's vocabulary: the width the serving cell selects in
+
+
+def _normal_rows(rows, v, seed=0, scale=4.0):
+    return np.random.default_rng(seed).standard_normal(
+        (rows, v)).astype(np.float32) * scale
+
+
+def _tied_row():
+    """5000 elements share the value that the 40th largest has."""
+    x = _normal_rows(1, _WIDE, seed=1)
+    x[0, 17:5017] = np.sort(x[0])[-40]
+    return x
+
+
+def _masked_row():
+    """Half the vocabulary masked out with -inf."""
+    x = _normal_rows(1, _WIDE, seed=2)
+    x[0, ::2] = -np.inf
+    return x
+
+
+def _zeros_row():
+    """+0.0 and -0.0 among small values of both signs: the zeros of
+    either sign are one value to the sort and to `<`."""
+    x = _normal_rows(1, 50, seed=3, scale=1.0).round(0)
+    x[0, 5:9] = 0.0
+    x[0, 20:24] = -0.0
+    return x
+
+
+_SELECT_CASES = {
+    # id: (rows (R, V), the ks asked for: one for every row, or one a row)
+    "k_1": (_normal_rows(2, _WIDE), [1]),
+    "k_2": (_normal_rows(2, _WIDE), [2]),
+    "k_40": (_normal_rows(2, _WIDE), [40]),
+    "k_v_minus_1": (_normal_rows(2, _WIDE), [_WIDE - 1]),
+    "k_v": (_normal_rows(2, _WIDE), [_WIDE]),
+    "toy_vocabulary_every_k": (_normal_rows(1, 50), range(1, 51)),
+    "ties_at_the_threshold": (_tied_row(), [1, 39, 40, 41, 5039, 5040]),
+    "minus_inf": (_masked_row(), [1, 40, _WIDE // 2, _WIDE // 2 + 1,
+                                  _WIDE]),
+    "signed_zeros": (_zeros_row(), range(1, 51)),
+    # rounded to a tenth: ties at every rank
+    "rounded": (_normal_rows(1, _WIDE, seed=4).round(1),
+                [1, 40, 1000, _WIDE]),
+    "negative_only": (-np.abs(_normal_rows(1, _WIDE)) - 1.0,
+                      [1, 40, _WIDE]),
+    "positive_only": (np.abs(_normal_rows(1, _WIDE)) + 1.0,
+                      [1, 40, _WIDE]),
+    # 0 is "filter off": sample_step asks for the largest there, and
+    # ignores it
+    "mixed_k_in_one_batch": (_normal_rows(6, _WIDE, seed=9),
+                             [[0, 1, 40, 7, _WIDE, _WIDE - 1]]),
+}
+
+
+def _tokens_over_32_steps():
+    rng = np.random.default_rng(7)
+    s, v = 8, 1000
+    logits = jnp.asarray(_normal_rows(s, v, seed=8).round(1))
+    method = jnp.asarray([GREEDY, SAMPLE] * 4, jnp.int32)
+    temp = jnp.asarray([1.0, 0.8, 0.0, 0.5, 1.0, 1.3, 0.8, 0.8],
+                       jnp.float32)
+    top_k = jnp.asarray([0, 40, 3, 1, v, v - 1, 2000, 0], jnp.int32)
+    keys = new = jnp.asarray(rng.integers(0, 2 ** 32, (s, 2)), jnp.uint32)
+    by_sort, by_selection = jax.jit(_sample_step_by_sort), \
+        jax.jit(sample_step)
+    for step in range(32):
+        want, keys = by_sort(logits, keys, method, temp, top_k)
+        got, new = by_selection(logits, new, method, temp, top_k)
+        assert jnp.array_equal(got, want), step
+        assert jnp.array_equal(new, keys), step
+        logits = jnp.roll(logits, 1, axis=1) * 1.01
+
+
+@pytest.mark.parametrize("case",
+                         list(_SELECT_CASES) + ["tokens_over_32_steps"])
+def test_top_k_threshold_is_the_sorts(case):
+    """`kth_largest` (32 counting passes) gives the float32 that the
+    full sort has at index V - k, for a traced k that differs from row
+    to row; and `sample_step` draws the tokens it drew while it sorted."""
+    if case == "tokens_over_32_steps":
+        return _tokens_over_32_steps()
+    rows, ks = _SELECT_CASES[case]
+    r, v = rows.shape
+    x = jnp.asarray(rows)
+    srt = np.asarray(jnp.sort(x, axis=-1))
+    select = jax.jit(kth_largest)
+    for k in ks:
+        k = np.maximum(np.broadcast_to(np.asarray(k, np.int32), (r,)), 1)
+        got = np.asarray(select(x, jnp.asarray(k)))
+        assert np.array_equal(got, srt[np.arange(r), v - k]), k
 
 
 def test_method_id_validates():

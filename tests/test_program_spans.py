@@ -280,9 +280,9 @@ def test_lowered_decode_programs_hold_scopes_and_kernel_name(bert):
                                (admit, "layer1/attn/flash_fwd")):
         for scope in ("embed/", "layer0/qkv/", "layer0/kv_write/",
                       "layer0/attn/", "layer0/proj/", "layer0/ffn/",
-                      "layer1/qkv/", "logits/", "sample/", kernel_scope):
+                      "layer1/qkv/", "logits/", "sample/",
+                      "sample/select/", kernel_scope):
             assert scope in text, scope
-    assert "sample/jit(sort)" in step or "sample/sort" in step
 
 
 def test_stores_name_their_programs(tmp_path):

@@ -13,7 +13,9 @@ Only one process may hold libtpu, so the topology is described inside a
 fixture of THIS file (never at import, in a skipif or in conftest.py) and
 the compile runs in the test's own process.
 """
+import functools
 import os
+import re
 
 import pytest
 
@@ -235,8 +237,7 @@ def _superstep_for_v5e(one_chip, dtype):
         def body(carry, _):
             cache, tokens, pos, rng = carry
             logits, cache = dec.step((params,), cache, tokens, pos)
-            with jax.named_scope("sample"):
-                tok, rng = sample_step(logits, rng, method, temp, topk)
+            tok, rng = sample_step(logits, rng, method, temp, topk)
             return (cache, tok, pos + 1, rng), tok
         return lax.scan(body, (cache, tokens, pos, rng), None, length=1)
 
@@ -264,16 +265,22 @@ def _superstep_for_v5e(one_chip, dtype):
     return compiled, _SLOTS * _RUNG * cfg.hidden_size
 
 
+@pytest.fixture(scope="module")
+def superstep_for_v5e(compile_for_chip, one_chip):
+    """dtype -> `_superstep_for_v5e`'s result, compiled once a dtype.
+    `compile_for_chip` is asked for only to keep jax's persistent cache
+    off around the compiles."""
+    return functools.cache(lambda dtype: _superstep_for_v5e(one_chip, dtype))
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_decode_superstep_keeps_the_cache_in_place_for_v5e(
-        compile_for_chip, one_chip, dtype):
+        superstep_for_v5e, dtype):
     """The cell's decode program moves no cache: every `(S, C, H·Dh)` leaf
     has one layout at entry, in the row write, in the kernel and at exit
     (a cache-sized copy or slice costs 0.7 ms a leaf a step on the chip:
-    `PERF.md`, PR 27). `compile_for_chip` is
-    asked for only to keep jax's persistent cache off around the compile."""
-    import re
-    compiled, leaf = _superstep_for_v5e(one_chip, dtype)
+    `PERF.md`, PR 27)."""
+    compiled, leaf = superstep_for_v5e(dtype)
     text = compiled.as_text()
     moved = []
     for line in text.splitlines():
@@ -295,3 +302,20 @@ def test_decode_superstep_keeps_the_cache_in_place_for_v5e(
                    and c.lstrip().lstrip("%").startswith("flash_fwd")
                    for c in calls) == 1, li
         assert f"layer{li}/kv_write" in text
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_decode_superstep_sorts_no_vocabulary_for_v5e(superstep_for_v5e,
+                                                      dtype):
+    """Top-k's threshold comes from a selection (`sampling.kth_largest`):
+    the decode program holds no `sort` as wide as the vocabulary (the full
+    sort of 64 x 30522 logits was 2.07 ms of a 5.99 ms step on the chip:
+    `PERF.md`, PR 29)."""
+    from deeplearning4j_tpu.models.bert import BertConfig
+    vocab = BertConfig().vocab_size
+    text = superstep_for_v5e(dtype)[0].as_text()
+    assert "sample/select" in text
+    wide = [line.strip()[:160] for line in text.splitlines()
+            if re.search(r"\bsort\(", line)
+            and re.search(rf"\[(\d+,)*{vocab}[,\]]", line)]
+    assert not wide, wide
