@@ -8,19 +8,26 @@ here:
   (sharding_rules), each chip holds |E|/|ep| experts, and the experts run as
   a one-hot dispatch einsum that computes every expert for every token.
 - `routed_experts`: top-k routing over ALL of a layer's experts for a chip
-  that HOLDS a contiguous share of them (`held = (first, count)`): the
-  (token, held expert) pairs are sorted by expert and each projection is ONE
-  grouped matrix product over the held experts' stacked weights
-  (`lax.ragged_dot`; on the TPU, XLA's own grouped-matmul kernel), so an
-  expert's weights are read once a call and only for the tokens routed to
-  it. What the experts held elsewhere would add is left out: on one chip the
-  layer runs without its exchange, and nothing here stands in for it.
+  that HOLDS a contiguous share of them (`held = (first, count)`): every
+  (token, held expert) pair is computed, `activation(x @ w_in[e]) @
+  w_out[e]`, as ONE grouped product over the held experts' stacked weights,
+  so an expert's weights are read once a call and only if a token was
+  routed to it. On a TPU, at widths that are multiples of 128, that is the
+  Pallas kernel of `kernels/grouped_matmul.py` (both projections fused, an
+  expert's weight blocks whole in VMEM, row tiles of 16 to 128 rows sized
+  from the rows an expert sees on average); elsewhere (the CPU, the tests'
+  toy widths) two `lax.ragged_dot`s over the pairs sorted by expert. The
+  choice reads the backend and the operands' shapes, nothing else. What
+  the experts held elsewhere would add is left out: on one chip the layer
+  runs without its exchange, and nothing here stands in for it.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from deeplearning4j_tpu.kernels import grouped_matmul
 
 #: what `routed_experts` counts a call, in this order
 ROUTED_COUNTS = ("pairs", "expert_reads", "pairs_max")
@@ -42,8 +49,21 @@ def switch_router(x, router_w, num_experts):
     return onehot, gate, aux
 
 
+def _row_tile(rows_per_expert):
+    """The kernel's row tile for experts that see `rows_per_expert` rows a
+    call on average: the first of 16, 32, 64, 128 to hold twice the mean
+    (the fullest expert sees 2.6 times it at 128 tokens over 512 experts),
+    so that most experts are one tile, one pass of their weights through
+    the MXU, and the tiles are not mostly padding to gather and write."""
+    tile = 16
+    while tile < 128 and tile < 2 * rows_per_expert:
+        tile *= 2
+    return tile
+
+
 def routed_experts(x, scores, select_bias, w_in, w_out, held, top_k,
-                   scale=1.0, activation=jax.nn.relu):
+                   scale=1.0, activation=jax.nn.relu, impl="auto",
+                   interpret=None):
     """This chip's part of a top-k expert layer.
 
     - x (T, D): the tokens, in the experts' input width
@@ -61,27 +81,54 @@ def routed_experts(x, scores, select_bias, w_in, w_out, held, top_k,
     the (token, held expert) pairs computed, the held experts with at
     least one pair, and the fullest expert's pairs).
 
-    The pair buffer has T·top_k rows, what every chosen expert being held
-    here would fill; rows past the held pairs belong to no group, and the
-    grouped product skips them."""
+    There are T·top_k pairs, what every chosen expert being held here
+    would fill; those of experts held elsewhere belong to no group, the
+    grouped product skips them and what it leaves in their rows is
+    selected away.
+
+    impl: 'auto' (the Pallas kernel on a TPU at widths it takes,
+    `lax.ragged_dot` elsewhere), 'pallas' (force the kernel; interpreted
+    off the TPU unless `interpret` says otherwise) or 'ragged'."""
     first, n = held
     t, k = x.shape[0], int(top_k)
     _, idx = lax.top_k(scores + select_bias, k)               # (T, k)
-    chosen = jnp.take_along_axis(scores, idx, axis=-1)
+    # (a selection over the experts' axis, not `take_along_axis`: a scalar
+    # gather of T·k entries costs the TPU 8 ns each)
+    chosen = jnp.where(idx[..., None] == jnp.arange(scores.shape[-1]),
+                       scores[:, None, :], 0.0).sum(-1)
     weights = scale * chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
     local = idx - first
     is_held = (local >= 0) & (local < n)
-    # pairs sorted by held expert; those of experts held elsewhere last
+    # a pair's group: its held expert, or n for one held elsewhere
     key = jnp.where(is_held, local, n).reshape(-1)            # (T·k,)
-    order = jnp.argsort(key)
-    group_sizes = jnp.bincount(key, length=n + 1)[:n].astype(jnp.int32)
-    rows = jnp.take(x, order // k, axis=0)                    # (T·k, D)
-    h = lax.ragged_dot(rows, w_in.astype(x.dtype), group_sizes,
-                       preferred_element_type=jnp.float32)
-    y = lax.ragged_dot(activation(h).astype(x.dtype), w_out.astype(x.dtype),
-                       group_sizes, preferred_element_type=jnp.float32)
-    # back to (token, choice) order; a pair not held adds nothing
-    y = jnp.take(y, jnp.argsort(order), axis=0).reshape(t, k, -1)
+    group_sizes = (key[:, None] == jnp.arange(n)[None, :]).sum(
+        0, dtype=jnp.int32)
+    if impl == "auto":
+        impl = "pallas" if jax.default_backend() == "tpu" \
+            and grouped_matmul.supported(*w_in.shape[1:], w_out.shape[2]) \
+            else "ragged"
+    if impl not in ("pallas", "ragged"):
+        raise ValueError(f"unknown routed_experts impl {impl!r}; expected "
+                         f"'auto', 'pallas' or 'ragged'")
+    if impl == "pallas":
+        y = grouped_matmul.grouped_mlp(
+            x, w_in, w_out, key, activation,
+            rows=jnp.arange(t * k, dtype=jnp.int32) // k,
+            row_tile=_row_tile(t * k / scores.shape[-1]),
+            interpret=interpret)
+    else:
+        # pairs sorted by held expert, those of experts held elsewhere
+        # last: rows past the groups, which the grouped product skips
+        order = jnp.argsort(key)
+        h = lax.ragged_dot(jnp.take(x, order // k, axis=0),
+                           w_in.astype(x.dtype), group_sizes,
+                           preferred_element_type=jnp.float32)
+        y = lax.ragged_dot(activation(h).astype(x.dtype),
+                           w_out.astype(x.dtype), group_sizes,
+                           preferred_element_type=jnp.float32)
+        y = jnp.take(y, jnp.argsort(order), axis=0)
+    # a pair not held adds nothing
+    y = y.reshape(t, k, -1)
     out = jnp.where(is_held[..., None], weights[..., None] * y, 0.0).sum(1)
     counts = jnp.stack([group_sizes.sum(), (group_sizes > 0).sum(),
                         group_sizes.max()]).astype(jnp.int32)

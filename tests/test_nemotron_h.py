@@ -278,6 +278,84 @@ def test_four_shares_add_up_to_the_uncut_layer(toy):
     np.testing.assert_allclose(routed_up + shared, uncut, atol=1e-5)
 
 
+# -- the expert layer through its Pallas kernel (interpret mode) ------------
+def _looped_experts(x, scores, bias, w_in, w_out, first, count, k, scale):
+    """The reference's way: every held expert over every token, masked."""
+    _, idx = jax.lax.top_k(scores + bias, k)
+    chosen = jnp.take_along_axis(scores, idx, -1)
+    wts = scale * chosen / chosen.sum(-1, keepdims=True)
+    out, sizes = jnp.zeros((x.shape[0], w_out.shape[2])), []
+    for j in range(count):
+        w_tok = jnp.where(idx == first + j, wts, 0.0).sum(-1)
+        out = out + w_tok[:, None] * (nh.relu2(x @ w_in[j]) @ w_out[j])
+        sizes.append(int((idx == first + j).sum()))
+    return out, [sum(sizes), sum(s > 0 for s in sizes), max(sizes)]
+
+
+@pytest.mark.parametrize("t,first,count", [
+    (24, 0, 16), (24, 4, 4), (24, 12, 4), (1, 0, 4), (1, 8, 8), (80, 4, 8)],
+    ids=["all_held", "a_quarter", "the_last_quarter", "one_token",
+         "one_token_half", "groups_over_a_tile"])
+def test_kernel_path_equals_ragged_path_and_looped_experts(t, first, count):
+    """`routed_experts` at widths the kernel takes (latent 128, expert
+    width 256; 16 experts, 4 a token): the Pallas path, interpreted, the
+    `lax.ragged_dot` path and the loop over experts agree, counts and
+    all. With a quarter held, three quarters of the pair buffer belong to
+    no group."""
+    rng = np.random.default_rng(t + first)
+    x = jnp.asarray(rng.normal(size=(t, 128)), jnp.float32)
+    scores = jax.nn.sigmoid(jnp.asarray(rng.normal(size=(t, 16)),
+                                        jnp.float32))
+    bias = jnp.asarray(rng.normal(size=(16,)) * 0.01, jnp.float32)
+    w_in = jnp.asarray(rng.normal(size=(count, 128, 256)) * 0.05,
+                       jnp.float32)
+    w_out = jnp.asarray(rng.normal(size=(count, 256, 128)) * 0.05,
+                        jnp.float32)
+    args = (x, scores, bias, w_in, w_out, (first, count), 4, 5.0, nh.relu2)
+    got, got_counts = routed_experts(*args, impl="pallas", interpret=True)
+    ragged, ragged_counts = routed_experts(*args, impl="ragged")
+    auto, _ = routed_experts(*args)          # the CPU takes the plain path
+    want, want_counts = _looped_experts(x, scores, bias, w_in, w_out, first,
+                                        count, 4, 5.0)
+    np.testing.assert_array_equal(auto, ragged)
+    np.testing.assert_allclose(got, ragged, atol=2e-5, rtol=0)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
+    assert list(got_counts) == list(ragged_counts) == want_counts
+
+
+def test_rows_the_kernel_never_visits_do_not_reach_the_output(monkeypatch):
+    """The kernel leaves the tiles past the last group as they were (on the
+    chip: whatever the buffer held). Poisoned here, they change nothing."""
+    from deeplearning4j_tpu.kernels import grouped_matmul as gm
+    real = gm._tiled_mlp
+
+    def poisoned(xt, w_in, w_out, tile_group, tiles, activation, tm, *rest):
+        yt = real(xt, w_in, w_out, tile_group, tiles, activation, tm, *rest)
+        visited = jnp.arange(yt.shape[0]) < jnp.maximum(tiles, 1) * tm
+        return jnp.where(visited[:, None], yt, jnp.nan)
+
+    rng = np.random.default_rng(5)
+    x = jnp.asarray(rng.normal(size=(24, 128)), jnp.float32)
+    scores = jax.nn.sigmoid(jnp.asarray(rng.normal(size=(24, 16)),
+                                        jnp.float32))
+    w_in = jnp.asarray(rng.normal(size=(4, 128, 256)) * 0.05, jnp.float32)
+    w_out = jnp.asarray(rng.normal(size=(4, 256, 128)) * 0.05, jnp.float32)
+    args = (x, scores, jnp.zeros(16), w_in, w_out, (4, 4), 4, 5.0, nh.relu2)
+    clean, _ = routed_experts(*args, impl="pallas", interpret=True)
+    monkeypatch.setattr(gm, "_tiled_mlp", poisoned)
+    got, counts = routed_experts(*args, impl="pallas", interpret=True)
+    assert int(counts[0]) < 24 * 4 // 2        # most pairs are held elsewhere
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(got, clean)
+
+
+def test_unknown_expert_impl_is_named():
+    z = jnp.zeros
+    with pytest.raises(ValueError, match="unknown routed_experts impl"):
+        routed_experts(z((2, 8)), z((2, 4)), z(4), z((4, 8, 8)),
+                       z((4, 8, 8)), (0, 4), 2, impl="megablox")
+
+
 # -- the grouped-query decode kernel (interpret mode) -----------------------
 @pytest.mark.parametrize("hq,hkv,d,c,block_k", [
     (4, 2, 16, 24, 8),        # the toy model's heads, three k tiles

@@ -96,9 +96,17 @@ def _decode_paged(q, kp, vp, ptab, m):
                                         interpret=False)
 
 
-def _routed_experts(x, scores, bias, w1, w2):
+def _routed_experts(x, scores, bias, w1, w2, impl="pallas",
+                    interpret=False):
+    from deeplearning4j_tpu.models.nemotron_h import relu2
     from deeplearning4j_tpu.parallel.moe import routed_experts
-    return routed_experts(x, scores, bias, w1, w2, (0, 128), 22, 5.0)
+    return routed_experts(x, scores, bias, w1, w2, (0, w1.shape[0]), 22,
+                          5.0, relu2, impl=impl, interpret=interpret)
+
+
+def _experts_specs(rows, latent=1024, width=2688, held=128):
+    return [((rows, latent), bf16), ((rows, 512), f32), ((512,), f32),
+            ((held, latent, width), bf16), ((held, width, latent), bf16)]
 
 
 def _layernorm(x, g, b):
@@ -163,11 +171,15 @@ CASES = [
     ("decode_pallas_gqa_32q_2kv_c1024_bf16", _decode,
      [((128, 32, 128), bf16)] + [((128, 1024, 256), bf16)] * 2
      + [((128, 1024), i32)], True),
-    # its expert layer: 128 slots x 22 choices over the 128 experts held
-    # (XLA's own grouped-matmul kernel, from lax.ragged_dot)
+    # its expert layer: 22 choices a row over the 128 experts held, both
+    # products in kernels/grouped_matmul.py's `grouped_mlp` with an
+    # expert's two 5.5 MB blocks whole in VMEM. 128 rows are the decode
+    # step's slots and the short prompt bucket's admit (16-row tiles), 512
+    # the long bucket's admit (64-row tiles)
     ("routed_experts_128x22_of_128_held", _routed_experts,
-     [((128, 1024), bf16), ((128, 512), f32), ((512,), f32),
-      ((128, 1024, 2688), bf16), ((128, 2688, 1024), bf16)], True),
+     _experts_specs(128), True),
+    ("routed_experts_512x22_of_128_held", _routed_experts,
+     _experts_specs(512), True),
     ("decode_paged_pool257_ps16", _decode_paged,
      [((8, 12, 64), bf16), _POOL, _POOL, ((8, 32), i32), ((8, 512), i32)],
      True),
@@ -196,6 +208,28 @@ CASES = [
 def test_kernel_compiles_for_v5e(compile_for_chip, fn, specs, has_kernel):
     text = compile_for_chip(fn, *specs)
     assert ("tpu_custom_call" in text) == has_kernel
+    # XLA's own grouped product is a custom call too: it is in none of these
+    assert "ragged-dot" not in text
+
+
+@pytest.mark.parametrize("rows", [128, 512])
+def test_expert_layer_takes_its_kernel_on_a_tpu_for_v5e(
+        compile_for_chip, monkeypatch, rows):
+    """No silent fallback at the published widths: left to itself on a TPU
+    backend, `routed_experts` compiles the Pallas kernel, named for the
+    trace, and no `lax.ragged_dot`; at widths Mosaic cannot take it
+    compiles `lax.ragged_dot`."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    left_alone = functools.partial(_routed_experts, impl="auto",
+                                   interpret=None)
+    text = compile_for_chip(left_alone, *_experts_specs(rows))
+    calls = [l for l in text.splitlines()
+             if "tpu_custom_call" in l and " custom-call(" in l]
+    assert sum(c.lstrip().lstrip("%").startswith("grouped_mlp")
+               for c in calls) == 1
+    assert "ragged-dot" not in text
+    toy = compile_for_chip(left_alone, *_experts_specs(rows, 32, 84, 16))
+    assert "ragged-dot" in toy and "grouped_mlp" not in toy
 
 
 def test_decode_kernel_carries_its_names_for_v5e(compile_for_chip):
