@@ -1,7 +1,7 @@
 """Decode-mode forwards: incremental single-token model evaluation over
 donated device state.
 
-Three adapters expose one contract to the GenerationServer:
+Four adapters expose one contract to the GenerationServer:
 
 - **BertDecoder** — transformer stacks built on `models/bert.py` params:
   one K and one V cache leaf A LAYER, `(S, C, H·Dh)` (S slots, C =
@@ -34,6 +34,20 @@ Three adapters expose one contract to the GenerationServer:
   `(S, heads, head_dim, state)` and a convolution-tail leaf `(S, K-1,
   lanes)` that no rung touches. Its expert layers count what they compute
   on the device (`counter_names`).
+
+- **KeyeDecoder** — stacks built on `models/keye_vl.py` params, whose
+  cache holds THREE leaves a layer: K and V rows `(S, C, Hkv·Dh)` and the
+  sparse-attention indexer's key rows, PACKED two positions a row, `(S, C
+  / 2, 2·Di)`, written, grown and grafted together. (Di = 64 is half a
+  lane tile, what the first paragraph warns of: the chip gave a `(S, C,
+  64)` leaf a layout of its own and the compiled step copied every one
+  whole, in and out. Packed, its minor dimension is one lane tile:
+  `kernels/indexer.py`.) `step` scores every row in use against the index
+  keys, finds the `topk` best exactly (`kernels/selection.py`), GATHERS
+  those rows of K and V and runs the decode kernel over the gathered rung
+  alone; `prefill` does the same selection for every prompt position. Its
+  expert layers count as NemotronHDecoder's do, and the indexer counts the
+  rows it scored and kept.
 
 The contract (all pure functions, traced into AOT executables by the
 server — nothing here may touch the host):
@@ -92,12 +106,17 @@ from jax import lax
 from deeplearning4j_tpu.kernels.flash_attention import (
     flash_attention, flash_attention_decode, flash_attention_decode_mq,
     flash_attention_decode_mq_paged, flash_attention_decode_paged)
-from deeplearning4j_tpu.models import nemotron_h
+from deeplearning4j_tpu.kernels.indexer import (index_scores_decode,
+                                                pack_rows, write_packed_row)
+from deeplearning4j_tpu.kernels.selection import (compact_indices,
+                                                  top_k_mask)
+from deeplearning4j_tpu.models import keye_vl, nemotron_h
 from deeplearning4j_tpu.models.bert import (_ffn, _layer_norm,
                                             bert_mlm_logits)
 from deeplearning4j_tpu.parallel.ring_attention import dense_attention
 
-__all__ = ["BertDecoder", "NemotronHDecoder", "RecurrentDecoder"]
+__all__ = ["BertDecoder", "KeyeDecoder", "NemotronHDecoder",
+           "RecurrentDecoder"]
 
 
 def _write_kv(cache, li, wi, wj, k, v):
@@ -693,6 +712,167 @@ class NemotronHDecoder:
                         graft("conv", i, state[1])
         h_last = jnp.take(x[0], plen - 1, axis=0)               # (H,)
         return cache, nemotron_h.logits(cfg, params, h_last)
+
+
+class KeyeDecoder:
+    """Decode over a `models/keye_vl.py` parameter tree: grouped-query
+    attention over the cache rows a learned indexer selects.
+
+    The cache pytree: `{"k": [...], "v": [...], "ki": [...]}`, one `(S, C,
+    Hkv·Dh)` K leaf, one V leaf and one `(S, C / 2, 2·Di)` index-key leaf
+    (positions 2r and 2r + 1 side by side in row r: `indexer.pack_rows`)
+    A LAYER, in the compute dtype, rows major; `"counts"`, the running
+    counts (`counter_names`). All three kinds of leaf know the rung, which
+    is an even number of rows.
+
+    The full-sequence reference this must match is `keye_vl.forward` over
+    the same prompt+generated prefix (and, outside the package, the plain
+    reference under `benchmarks/families/`)."""
+
+    uses_cache_rungs = True
+    n_model_args = 1
+    supports_draft = False
+    max_cache_len = None        # rotary positions: no table bounds them
+    counter_names = ("moe_pairs", "moe_expert_reads", "moe_pairs_max",
+                     "dsa_rows_scored", "dsa_rows_selected")
+    _LEAVES = ("k", "v", "ki")
+
+    def __init__(self, cfg, params, attn_impl="auto"):
+        if attn_impl not in ("auto", "dense", "pallas"):
+            raise ValueError(
+                f"attn_impl must be 'auto', 'dense' or 'pallas', "
+                f"got {attn_impl!r}")
+        self.cfg = cfg
+        self.params = params
+        self.attn_impl = attn_impl
+        self.vocab_size = int(cfg.vocab_size)
+
+    def fingerprint(self):
+        parts = ("keye-decode", repr(self.cfg), self.attn_impl,
+                 _shape_tree_repr(self.params),
+                 _shape_tree_repr(
+                     jax.eval_shape(lambda: self.init_cache(1, 2))))
+        return hashlib.sha256(repr(parts).encode()).hexdigest()[:16]
+
+    def model_args(self):
+        return (self.params,)
+
+    def init_cache(self, slots, cache_len):
+        cfg = self.cfg
+        if cache_len % 2:
+            raise ValueError(f"the index-key leaf packs two positions a "
+                             f"row: cache rung {cache_len} must be even")
+        shapes = {"k": (slots, cache_len, cfg.kv_width),
+                  "v": (slots, cache_len, cfg.kv_width),
+                  "ki": (slots, cache_len // 2, 2 * cfg.indexer_head_dim)}
+        cache = {name: [jnp.zeros(shapes[name], cfg.compute_dtype)
+                        for _ in range(cfg.num_hidden_layers)]
+                 for name in self._LEAVES}
+        cache["counts"] = jnp.zeros((len(self.counter_names),), jnp.int32)
+        return cache
+
+    def grow(self, cache, new_len):
+        """All three kinds of leaf padded to the longer rung, together;
+        the counts as they are."""
+        def pad(t, rows):
+            return jnp.pad(t, ((0, 0), (0, rows - t.shape[1]), (0, 0)))
+        new_len = int(new_len)
+        return {**cache, **{
+            name: [pad(t, new_len // 2 if name == "ki" else new_len)
+                   for t in cache[name]] for name in self._LEAVES}}
+
+    def counters(self, cache):
+        return cache["counts"]
+
+    def step(self, margs, cache, tokens, pos):
+        """One decode step for the whole batch. A layer writes its K, V
+        and index-key rows at `pos`, scores every row in use against the
+        slot's index query, keeps the `min(topk, pos + 1)` best, gathers
+        THOSE rows of K and V into a `(S, topk, Hkv·Dh)` rung and attends
+        over it; then its expert layer routes the S tokens. Returns
+        next-token logits (S, V). Text positions: all three rotary streams
+        are the token's index."""
+        (params,) = margs
+        cfg = self.cfg
+        s = tokens.shape[0]
+        with jax.named_scope("embed"):
+            x = jnp.take(params["embed"], tokens, axis=0)       # (S, H)
+        cache = {name: list(v) if isinstance(v, (list, tuple)) else v
+                 for name, v in cache.items()}
+        wi = _slot_index(pos)
+        c = cache["k"][0].shape[1]
+        width = min(cfg.indexer_topk, c)
+        # rows 0..pos are in use (the current write included); a lane
+        # frozen at the end of its rung (pos == C) keeps all of them
+        in_use = jnp.minimum(pos + 1, c)                        # (S,)
+        keep = jnp.minimum(in_use, width)
+        kept = jnp.arange(width)[None, :] < keep[:, None]       # (S, topk)
+        with jax.named_scope("rope"):
+            tables = keye_vl.rope_tables(
+                cfg, jnp.broadcast_to(pos[None], (3, s)))
+        counted = jnp.zeros((3,), jnp.int32)
+        for li, layer in enumerate(params["layers"]):
+            with jax.named_scope(f"layer{li}"):
+                u = keye_vl.rms_norm(x, layer["norm1"], cfg.rms_norm_eps)
+                with jax.named_scope("attn"):
+                    q, k, v, qi, ki, w = keye_vl.attention_inputs(
+                        cfg, layer, u, tables)
+                    with jax.named_scope("kv_write"):
+                        _write_kv(cache, li, wi, pos, k, v)
+                        cache["ki"][li] = write_packed_row(
+                            cache["ki"][li], pos, ki)
+                    with jax.named_scope("indexer"):
+                        with jax.named_scope("score"):
+                            scores = index_scores_decode(
+                                qi, cache["ki"][li], w, pos,
+                                cfg.index_scale, impl=self.attn_impl)
+                        with jax.named_scope("select"):
+                            rows = compact_indices(
+                                top_k_mask(scores, keep), width)
+                    with jax.named_scope("gather"):
+                        kg, vg = (jnp.take_along_axis(
+                            cache[name][li], rows[..., None], axis=1)
+                            for name in ("k", "v"))
+                    ctx = flash_attention_decode(
+                        q.reshape(s, cfg.num_attention_heads, cfg.head_dim),
+                        kg, vg, kept, impl=self.attn_impl)
+                    with jax.named_scope("proj"):
+                        x = x + ctx.reshape(s, -1).astype(x.dtype) \
+                            @ layer["o"].astype(x.dtype)
+                with jax.named_scope("moe"):
+                    out, counts = keye_vl.moe(cfg, layer, keye_vl.rms_norm(
+                        x, layer["norm2"], cfg.rms_norm_eps))
+                    counted = counted + counts
+                x = x + out.astype(x.dtype)
+        layers = cfg.num_hidden_layers
+        cache["counts"] = cache["counts"] + jnp.concatenate([
+            counted, layers * jnp.stack([in_use.sum(), keep.sum()]).astype(
+                jnp.int32)])
+        return keye_vl.logits(cfg, params, x), cache
+
+    def prefill(self, margs, cache, slot, prompt, plen):
+        """The full forward over one length-bucketed prompt (P,) with the
+        indexer's selection at every position, then the graft of the
+        slot's three blocks a layer for rows 0..P-1 (rows beyond plen hold
+        padding garbage that the decode step never scores: it masks rows
+        past `pos`). Returns the logits at plen - 1."""
+        (params,) = margs
+        cfg = self.cfg
+        x, states = keye_vl.encode(cfg, params, prompt,
+                                   impl=self.attn_impl)
+        cache = {name: list(v) if isinstance(v, (list, tuple)) else v
+                 for name, v in cache.items()}
+        for li, state in enumerate(states):
+            with jax.named_scope(f"layer{li}"), jax.named_scope("attn"), \
+                    jax.named_scope("kv_write"):
+                k, v, ki = state
+                for name, part in (("k", k), ("v", v),
+                                   ("ki", pack_rows(ki))):
+                    leaf = cache[name][li]
+                    cache[name][li] = lax.dynamic_update_slice(
+                        leaf, part[None].astype(leaf.dtype), (slot, 0, 0))
+        h_last = jnp.take(x, plen - 1, axis=0)                  # (H,)
+        return cache, keye_vl.logits(cfg, params, h_last)
 
 
 class RecurrentDecoder:

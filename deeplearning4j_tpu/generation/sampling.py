@@ -16,7 +16,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+
+from deeplearning4j_tpu.kernels.selection import kth_largest
 
 __all__ = ["GREEDY", "SAMPLE", "kth_largest", "method_id", "sample_step",
            "split_keys"]
@@ -45,35 +46,6 @@ def split_keys(keys):
     neighbours."""
     s = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
     return s[:, 0], s[:, 1]
-
-
-@jax.named_scope("select")
-def kth_largest(x, k):
-    """The k-th largest value of each row, exactly, without sorting
-    (scope `sample/select` in a profiler trace).
-
-    - x: (S, V) float32
-    - k: (S,) int32 in 1..V, a different one in every row
-
-    Returns (S,) float32: what an ascending sort of row s holds at
-    index V - k[s]. The row is mapped once to an unsigned image that
-    orders as the floats do (-inf lowest); the threshold is then built
-    bit by bit from the top: a bit stays set where at least k elements
-    lie at or above the candidate. 32 fused compare-and-count passes
-    over the row, no sorted copy (the sort was a third of BERT-base's
-    decode step on a v5e: `PERF.md`, PR 29)."""
-    b = lax.bitcast_convert_type(x, jnp.uint32)
-    top = jnp.uint32(1 << 31)
-    u = jnp.where(b >= top, ~b, b | top)
-
-    def grow(i, prefix):
-        cand = prefix | (top >> i.astype(jnp.uint32))
-        cnt = jnp.sum(u >= cand[:, None], axis=-1, dtype=jnp.int32)
-        return jnp.where(cnt >= k, cand, prefix)
-
-    prefix = lax.fori_loop(0, 32, grow, jnp.zeros(x.shape[:1], jnp.uint32))
-    return lax.bitcast_convert_type(
-        jnp.where(prefix >= top, prefix ^ top, ~prefix), jnp.float32)
 
 
 @jax.named_scope("sample")

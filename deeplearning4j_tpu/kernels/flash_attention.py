@@ -478,6 +478,143 @@ def flash_attention(q, k, v, causal=False, block_q=128, block_k=128,
 
 
 # ---------------------------------------------------------------------------
+# prefill under a row-by-row selection (learned sparse attention)
+# ---------------------------------------------------------------------------
+def _selected_kernel(q_ref, k_ref, v_ref, s_ref, o_ref, acc_ref, l_ref, m_ref,
+                     *, scale, group, head_dim, q_offset):
+    """Grid (KV head, q_tiles, k_tiles), k innermost: the `group` query
+    heads of one KV head, stacked as rows (group·block_q, D), against one
+    (block_k, D) tile of that head's keys and values, under ONE (block_q,
+    block_k) tile of the selection that every head shares. The online
+    softmax of `_flash_fwd_kernel`; a k tile wholly above the diagonal is
+    skipped (query row t sits at position `q_offset + t`)."""
+    i, j = pl.program_id(1), pl.program_id(2)
+    bq, bk = s_ref.shape
+    d = head_dim
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+
+    @pl.when(j * bk <= q_offset + (i + 1) * bq - 1)
+    def _compute():
+        q = jnp.concatenate([q_ref[:, g * d:(g + 1) * d]
+                             for g in range(group)], axis=0)
+        s = jax.lax.dot_general(
+            q, k_ref[...], dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        sel = s_ref[...].astype(jnp.float32)
+        keep = jnp.concatenate([sel] * group, axis=0) > 0
+        s = jnp.where(keep, s, _NEG_INF)
+        m_prev, l_prev = m_ref[...], l_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # (a row with nothing kept in any tile so far has m = -1e30 and
+        # exp(0) = 1 for every entry: the select drops them)
+        p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        m_ref[...] = m_new
+        v = v_ref[...]
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v,
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finalize():
+        o = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        for g in range(group):
+            o_ref[:, g * d:(g + 1) * d] = \
+                o[g * bq:(g + 1) * bq].astype(o_ref.dtype)
+
+
+def _selected_dense(q, k, v, selected, hkv):
+    """The same attention as one masked softmax a head, in XLA."""
+    tq, tk = selected.shape
+    d = k.shape[1] // hkv
+    qh = q.reshape(tq, hkv, -1, d).astype(jnp.float32)
+    kh, vh = (a.reshape(tk, hkv, d).astype(jnp.float32) for a in (k, v))
+    s = jnp.einsum("qhgd,khd->hgqk", qh, kh) / (d ** 0.5)
+    p = jax.nn.softmax(jnp.where(selected > 0, s, -jnp.inf), axis=-1)
+    return jnp.einsum("hgqk,khd->qhgd", p, vh).reshape(q.shape).astype(
+        q.dtype)
+
+
+def flash_attention_selected(q, k, v, selected, num_kv_heads, q_offset=0,
+                             impl="auto", block_q=128, block_k=512,
+                             interpret=None):
+    """Grouped-query attention of a block of queries over the key rows a
+    selection marks for each of them, row by row (`flash_attention` takes
+    one mask a sequence): what a sparse-attention indexer leaves of a
+    causal prefill. Forward only.
+
+    - q (Tq, Hq·D): the queries, heads side by side along the lanes; query
+      t sits at position `q_offset + t` (static), and `selected` marks no
+      key past it
+    - k, v (Tk, Hkv·D): the sequence's key and value rows, as a decode
+      cache leaf holds them; query head i reads KV head i // (Hq / Hkv)
+    - selected (Tq, Tk) int8 (or bool): nonzero where query t attends key
+      s; every row marks at least one key
+    - impl: 'auto' (the kernel on a TPU, XLA elsewhere), 'pallas'
+      (interpreted off the TPU unless `interpret` says otherwise), 'dense'
+
+    Returns (Tq, Hq·D). Products in the operands' dtype with float32 sums
+    and a float32 softmax; the weights go to the values' dtype for the
+    second product."""
+    if impl == "auto":
+        impl = "pallas" if jax.default_backend() == "tpu" else "dense"
+    if impl == "dense":
+        return _selected_dense(q, k, v, selected, num_kv_heads)
+    if impl != "pallas":
+        raise ValueError(f"unknown impl {impl!r}; expected 'auto', "
+                         f"'pallas' or 'dense'")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    tq, tk = selected.shape
+    d = k.shape[1] // num_kv_heads
+    group = q.shape[1] // (num_kv_heads * d)
+    bq, bk = min(block_q, tq), min(block_k, tk)
+    selected = selected.astype(jnp.int8)
+    if tq % bq or tk % bk:
+        q = _pad_to(q, 0, bq)
+        k, v = _pad_to(k, 0, bk), _pad_to(v, 0, bk)
+        selected = _pad_to(_pad_to(selected, 0, bq), 1, bk)
+
+    def kv_tile(h, i, j):
+        # a tile above the diagonal is not computed: name the last one
+        # that was, so that nothing is fetched for it
+        return jnp.minimum(j, (q_offset + (i + 1) * bq - 1) // bk), h
+
+    out = pl.pallas_call(
+        functools.partial(_selected_kernel, scale=1.0 / (d ** 0.5),
+                          group=group, head_dim=d, q_offset=int(q_offset)),
+        grid=(num_kv_heads, q.shape[0] // bq, k.shape[0] // bk),
+        in_specs=[
+            pl.BlockSpec((bq, group * d), lambda h, i, j: (i, h)),
+            pl.BlockSpec((bk, d), kv_tile),
+            pl.BlockSpec((bk, d), kv_tile),
+            pl.BlockSpec((bq, bk),
+                         lambda h, i, j: (i, kv_tile(h, i, j)[0])),
+        ],
+        out_specs=pl.BlockSpec((bq, group * d), lambda h, i, j: (i, h)),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((group * bq, d), jnp.float32),
+            pltpu.VMEM((group * bq, 1), jnp.float32),
+            pltpu.VMEM((group * bq, 1), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=64 << 20),
+        interpret=interpret,
+        name="flash_selected",
+    )(q, k, v, selected)
+    return out[:tq]
+
+
+# ---------------------------------------------------------------------------
 # decode: one query token (or a draft block) against a cached K/V
 #
 # Cache operands are ROWS MAJOR, HIDDEN MINOR: (B, C, H·D) — the layout

@@ -1,6 +1,9 @@
 """Pallas TPU grouped matmul for an expert layer: `activation(x @ w_in[g])
 @ w_out[g]` for the rows of each group `g`, the two products fused so that
-the intermediate never leaves VMEM.
+the intermediate never leaves VMEM. With a third stack `w_gate` the expert
+is GATED, `(activation(x @ w_gate[g]) * (x @ w_in[g])) @ w_out[g]` (SwiGLU
+with `activation = silu`): one more weight block a grid step, the same
+grid, rows and layout.
 
 What it is for (`parallel/moe.py` `routed_experts`): a chip that holds 128
 experts of (1024, 2688) + (2688, 1024) bfloat16 and gives each 5 to 22 rows
@@ -49,8 +52,9 @@ def supported(k, f, d):
 
 
 def _block_f(k, f, d, itemsize):
-    """The largest multiple of 128 that divides `f` whose two weight
-    blocks fit `_WEIGHT_VMEM` double-buffered."""
+    """The largest multiple of 128 that divides `f` whose weight blocks
+    fit `_WEIGHT_VMEM` double-buffered; `k` counts every stack that reads
+    the rows (twice the contraction for a gated expert)."""
     fits = [b for b in range(128, f + 1, 128)
             if f % b == 0 and 2 * b * (k + d) * itemsize <= _WEIGHT_VMEM]
     if not fits:
@@ -98,17 +102,22 @@ def _layout(groups, rows, n, tm):
     return tile_group, tile_end[-1], tile_rows, tiled
 
 
-def _kernel(tile_group_ref, x_ref, w_in_ref, w_out_ref, o_ref, *,
-            activation):
+def _kernel(tile_group_ref, x_ref, *refs, activation):
     """One row tile against one `block_f` of its expert: grid (tile, f),
     f innermost; the output tile stays in VMEM over f and sums the
-    blocks' contributions in float32."""
+    blocks' contributions in float32. `refs` are the weight blocks (in,
+    out, or gate, in, out) and the output."""
     del tile_group_ref                     # read by the index maps
+    *gate_ref, w_in_ref, w_out_ref, o_ref = refs
     x = x_ref[...]
     h = jnp.dot(x, w_in_ref[...].astype(x.dtype),
                 preferred_element_type=jnp.float32)
-    y = jnp.dot(activation(h).astype(x.dtype),
-                w_out_ref[...].astype(x.dtype),
+    if gate_ref:
+        h = activation(jnp.dot(x, gate_ref[0][...].astype(x.dtype),
+                               preferred_element_type=jnp.float32)) * h
+    else:
+        h = activation(h)
+    y = jnp.dot(h.astype(x.dtype), w_out_ref[...].astype(x.dtype),
                 preferred_element_type=jnp.float32)
     f = pl.program_id(1)
 
@@ -122,13 +131,16 @@ def _kernel(tile_group_ref, x_ref, w_in_ref, w_out_ref, o_ref, *,
 
 
 def _tiled_mlp(xt, w_in, w_out, tile_group, tiles, activation, tm, block_f,
-               interpret):
+               interpret, w_gate=None):
     """The kernel over rows already in tiles: xt (V·tm, K) -> (V·tm, D)
     float32, tiles `tiles` and up left as they were."""
     k, f, d = w_in.shape[1], w_in.shape[2], w_out.shape[2]
     itemsize = jnp.dtype(w_in.dtype).itemsize
+    reads = (w_in,) if w_gate is None else (w_gate, w_in)
     if block_f is None:
-        block_f = _block_f(k, f, d, itemsize)
+        block_f = _block_f(k * len(reads), f, d, itemsize)
+    read_spec = pl.BlockSpec((None, k, block_f),
+                             lambda v, j, grp: (grp[v], 0, j))
     return pl.pallas_call(
         functools.partial(_kernel, activation=activation),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -138,8 +150,7 @@ def _tiled_mlp(xt, w_in, w_out, tile_group, tiles, activation, tm, block_f,
             grid=(jnp.maximum(tiles, 1), f // block_f),
             in_specs=[
                 pl.BlockSpec((tm, k), lambda v, j, grp: (v, 0)),
-                pl.BlockSpec((None, k, block_f),
-                             lambda v, j, grp: (grp[v], 0, j)),
+                *[read_spec] * len(reads),
                 pl.BlockSpec((None, block_f, d),
                              lambda v, j, grp: (grp[v], j, 0)),
             ],
@@ -148,16 +159,20 @@ def _tiled_mlp(xt, w_in, w_out, tile_group, tiles, activation, tm, block_f,
         out_shape=jax.ShapeDtypeStruct((xt.shape[0], d), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=2 * block_f * (k + d) * itemsize + (16 << 20)),
+            vmem_limit_bytes=2 * block_f * (k * len(reads) + d) * itemsize
+            + (16 << 20)),
         interpret=interpret,
         name="grouped_mlp",
-    )(tile_group, xt, w_in, w_out)
+    )(tile_group, xt, *reads, w_out)
 
 
 def grouped_mlp(x, w_in, w_out, groups, activation=jax.nn.relu, *,
-                rows=None, row_tile=16, block_f=None, interpret=None):
+                rows=None, row_tile=16, block_f=None, interpret=None,
+                w_gate=None):
     """`activation(x[rows[i]] @ w_in[g]) @ w_out[g]` for every pair i of a
-    row and its group `g = groups[i]`.
+    row and its group `g = groups[i]`; with `w_gate` (n, K, F),
+    `(activation(x[rows[i]] @ w_gate[g]) * (x[rows[i]] @ w_in[g])) @
+    w_out[g]`.
 
     - x (R, K); rows (m,) int: the row pair i reads (default: x has m
       rows, one a pair)
@@ -183,5 +198,5 @@ def grouped_mlp(x, w_in, w_out, groups, activation=jax.nn.relu, *,
         groups, rows, w_in.shape[0], tm)
     xt = x.at[tile_rows].get(mode="promise_in_bounds")
     yt = _tiled_mlp(xt, w_in, w_out, tile_group, tiles, activation, tm,
-                    block_f, interpret)
+                    block_f, interpret, w_gate)
     return yt.at[tiled].get(mode="promise_in_bounds")

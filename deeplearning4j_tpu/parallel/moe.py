@@ -10,7 +10,9 @@ here:
 - `routed_experts`: top-k routing over ALL of a layer's experts for a chip
   that HOLDS a contiguous share of them (`held = (first, count)`): every
   (token, held expert) pair is computed, `activation(x @ w_in[e]) @
-  w_out[e]`, as ONE grouped product over the held experts' stacked weights,
+  w_out[e]` (or, with a gate stack, `(activation(x @ w_gate[e]) * (x @
+  w_in[e])) @ w_out[e]`), as ONE grouped product over the held experts'
+  stacked weights,
   so an expert's weights are read once a call and only if a token was
   routed to it. On a TPU, at widths that are multiples of 128, that is the
   Pallas kernel of `kernels/grouped_matmul.py` (both projections fused, an
@@ -63,19 +65,23 @@ def _row_tile(rows_per_expert):
 
 def routed_experts(x, scores, select_bias, w_in, w_out, held, top_k,
                    scale=1.0, activation=jax.nn.relu, impl="auto",
-                   interpret=None):
+                   interpret=None, w_gate=None):
     """This chip's part of a top-k expert layer.
 
     - x (T, D): the tokens, in the experts' input width
     - scores (T, E) float32: the router's scores over ALL E experts
-    - select_bias (E,): added to the scores for the CHOICE of the top_k only
-      (a load-balancing correction); the combine weights are the chosen
-      scores themselves, normalised over all top_k chosen — held here or
-      not — and times `scale`
+    - select_bias (E,) or None: added to the scores for the CHOICE of the
+      top_k only (a load-balancing correction); the combine weights are
+      the chosen scores themselves, normalised over all top_k chosen —
+      held here or not — and times `scale`
     - w_in (n, D, F), w_out (n, F, D): the held experts' weights, expert
       `first + j` at index j; `held = (first, n)`
     - activation: between the two projections (expert e computes
       `activation(x @ w_in[e]) @ w_out[e]`)
+    - w_gate (n, D, F) or None: a GATED expert's third stack; expert e
+      then computes `(activation(x @ w_gate[e]) * (x @ w_in[e])) @
+      w_out[e]` (SwiGLU with `activation = silu`). Without it the
+      programs are what they were
 
     Returns (out (T, D) float32, counts (3,) int32 as `ROUTED_COUNTS`:
     the (token, held expert) pairs computed, the held experts with at
@@ -91,7 +97,8 @@ def routed_experts(x, scores, select_bias, w_in, w_out, held, top_k,
     off the TPU unless `interpret` says otherwise) or 'ragged'."""
     first, n = held
     t, k = x.shape[0], int(top_k)
-    _, idx = lax.top_k(scores + select_bias, k)               # (T, k)
+    _, idx = lax.top_k(scores if select_bias is None
+                       else scores + select_bias, k)          # (T, k)
     # (a selection over the experts' axis, not `take_along_axis`: a scalar
     # gather of T·k entries costs the TPU 8 ns each)
     chosen = jnp.where(idx[..., None] == jnp.arange(scores.shape[-1]),
@@ -115,15 +122,21 @@ def routed_experts(x, scores, select_bias, w_in, w_out, held, top_k,
             x, w_in, w_out, key, activation,
             rows=jnp.arange(t * k, dtype=jnp.int32) // k,
             row_tile=_row_tile(t * k / scores.shape[-1]),
-            interpret=interpret)
+            interpret=interpret, w_gate=w_gate)
     else:
         # pairs sorted by held expert, those of experts held elsewhere
         # last: rows past the groups, which the grouped product skips
         order = jnp.argsort(key)
-        h = lax.ragged_dot(jnp.take(x, order // k, axis=0),
-                           w_in.astype(x.dtype), group_sizes,
+        xs = jnp.take(x, order // k, axis=0)
+        h = lax.ragged_dot(xs, w_in.astype(x.dtype), group_sizes,
                            preferred_element_type=jnp.float32)
-        y = lax.ragged_dot(activation(h).astype(x.dtype),
+        if w_gate is None:
+            h = activation(h)
+        else:
+            h = activation(lax.ragged_dot(
+                xs, w_gate.astype(x.dtype), group_sizes,
+                preferred_element_type=jnp.float32)) * h
+        y = lax.ragged_dot(h.astype(x.dtype),
                            w_out.astype(x.dtype), group_sizes,
                            preferred_element_type=jnp.float32)
         y = jnp.take(y, jnp.argsort(order), axis=0)

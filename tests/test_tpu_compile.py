@@ -353,3 +353,75 @@ def test_decode_superstep_sorts_no_vocabulary_for_v5e(superstep_for_v5e,
             if re.search(r"\bsort\(", line)
             and re.search(rf"\[(\d+,)*{vocab}[,\]]", line)]
     assert not wide, wide
+
+
+# Keye-VL-2.0 serving (PR 35): 32 query heads over 4 KV heads of 128, an
+# indexer of 16 heads of 64 over one key head, 16 held SwiGLU experts of
+# 2048 -> 768 -> 2048; 32 slots at an 18432-row rung, prompts of 16384 in
+# query blocks of 4096.
+def _keye_index_scores(q, k, w):
+    from deeplearning4j_tpu.kernels.indexer import index_scores
+    return index_scores(q, k, w, 1 / 32., q_offset=12288, impl="pallas",
+                        interpret=False)
+
+
+def _keye_index_scores_decode(q, packed, w, pos):
+    from deeplearning4j_tpu.kernels.indexer import index_scores_decode
+    return index_scores_decode(q, packed, w, pos, 1 / 32., impl="pallas",
+                               interpret=False)
+
+
+def _keye_selected_attention(q, k, v, sel):
+    from deeplearning4j_tpu.kernels.flash_attention import \
+        flash_attention_selected
+    return flash_attention_selected(q, k, v, sel, 4, q_offset=12288,
+                                    impl="pallas", interpret=False)
+
+
+def _keye_gathered_decode(q, k, v, m):
+    from deeplearning4j_tpu.kernels import flash_attention_decode
+    return flash_attention_decode(q, k, v, m, impl="pallas",
+                                  interpret=False)
+
+
+def _keye_gated_experts(x, w_gate, w_up, w_down, groups):
+    from deeplearning4j_tpu.kernels.grouped_matmul import grouped_mlp
+    return grouped_mlp(x, w_up, w_down, groups, jax.nn.silu,
+                       rows=jnp.arange(groups.shape[0]) // 8,
+                       w_gate=w_gate, interpret=False)
+
+
+_KEYE_EXPERTS = (((16, 2048, 768), bf16), ((16, 2048, 768), bf16),
+                 ((16, 768, 2048), bf16))
+
+
+@pytest.mark.parametrize("fn,specs,name", [
+    (_keye_index_scores, (((16, 4096, 64), bf16), ((16384, 64), bf16),
+                          ((4096, 16), f32)), "index_scores"),
+    (_keye_index_scores_decode, (((32, 16, 64), bf16),
+                                 ((32, 9216, 128), bf16), ((32, 16), f32),
+                                 ((32,), i32)), "index_scores_decode"),
+    (_keye_selected_attention, (((4096, 4096), bf16), ((16384, 512), bf16),
+                                ((16384, 512), bf16), ((4096, 16384), i8)),
+     "flash_selected"),
+    (_keye_gathered_decode, (((32, 32, 128), bf16), ((32, 2048, 512), bf16),
+                             ((32, 2048, 512), bf16),
+                             ((32, 2048), jnp.bool_)), "flash_fwd"),
+    (_keye_gated_experts, (((32, 2048), bf16), *_KEYE_EXPERTS,
+                           ((256,), i32)), "grouped_mlp"),
+    (_keye_gated_experts, (((2048, 2048), bf16), *_KEYE_EXPERTS,
+                           ((16384,), i32)), "grouped_mlp"),
+], ids=["index_scores", "index_scores_decode", "selected_attention",
+        "decode_over_the_gathered_rung", "gated_experts_a_step",
+        "gated_experts_a_prefill_run"])
+def test_sparse_attention_kernels_compile_for_v5e(compile_for_chip, fn,
+                                                  specs, name):
+    """The kernels `KeyeDecoder` adds, at the published widths: the
+    indexer's scores over a 4096-row query block of a 16384 prompt and over
+    a packed decode leaf, attention under a row-by-row selection, the
+    grouped-query decode kernel over a GATHERED rung of 2048 rows, and the
+    gated expert (three weight blocks of 3.1 MB, double-buffered)."""
+    text = compile_for_chip(fn, *specs)
+    calls = [l for l in text.splitlines()
+             if "tpu_custom_call" in l and " custom-call(" in l]
+    assert len(calls) == 1 and name in calls[0]
