@@ -43,11 +43,14 @@ Four adapters expose one contract to the GenerationServer:
   64)` leaf a layout of its own and the compiled step copied every one
   whole, in and out. Packed, its minor dimension is one lane tile:
   `kernels/indexer.py`.) `step` scores every row in use against the index
-  keys, finds the `topk` best exactly (`kernels/selection.py`), GATHERS
-  those rows of K and V and runs the decode kernel over the gathered rung
-  alone; `prefill` does the same selection for every prompt position. Its
+  keys, finds the `topk` best exactly (`kernels/selection.py`) and runs
+  the decode kernel over THOSE rows: the K and V leaves read in place
+  under the selection as a mask, the tiles past a slot's position
+  skipped, on a rung of up to `_IN_PLACE_RUNGS` x `topk` rows; on a longer
+  one the kept rows gathered into a `(S, topk, Hkv·Dh)` rung first;
+  `prefill` does the same selection for every prompt position. Its
   expert layers count as NemotronHDecoder's do, and the indexer counts the
-  rows it scored and kept.
+  rows it scored and kept and the rows attention read for them.
 
 The contract (all pure functions, traced into AOT executables by the
 server — nothing here may touch the host):
@@ -104,8 +107,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from deeplearning4j_tpu.kernels.flash_attention import (
-    flash_attention, flash_attention_decode, flash_attention_decode_mq,
-    flash_attention_decode_mq_paged, flash_attention_decode_paged)
+    decode_tile_rows, flash_attention, flash_attention_decode,
+    flash_attention_decode_mq, flash_attention_decode_mq_paged,
+    flash_attention_decode_paged)
 from deeplearning4j_tpu.kernels.indexer import (index_scores_decode,
                                                 pack_rows, write_packed_row)
 from deeplearning4j_tpu.kernels.selection import (compact_indices,
@@ -714,6 +718,27 @@ class NemotronHDecoder:
         return cache, nemotron_h.logits(cfg, params, h_last)
 
 
+#: `KeyeDecoder.step` attends its kept rows where they lie while the rung
+#: holds at most this many times `topk` rows, and gathers them past it.
+#: Two rates measured on a v5e (`PERF.md`, PR 36; K and V rows of 1 KB):
+#: gathered, a kept row costs 38.9 ns (XLA's row gather 2 x 15.2 ns,
+#: the index arithmetic around it 5.8 and the kernel over the gathered
+#: rung 2.7), whatever the context; read in place, a row in use costs 2.9
+#: ns. In place wins while a slot has fewer than 38.9 / 2.9 = 13.4 x
+#: `topk` rows in use, which the rung bounds: 12 leaves a tenth of room
+_IN_PLACE_RUNGS = 12
+
+
+def _attends_in_place(rung, topk):
+    """Whether a sparse decode step over a rung of `rung` rows reads K and
+    V in place under the selection mask (cost: the rows in use, which the
+    rung bounds) or gathers the `topk` kept rows first (cost: 2 x `topk`
+    row copies a slot, whatever the context). Both are one softmax over
+    the same rows; the rung and `topk` are static shapes of the program
+    being compiled, so the program holds one of them."""
+    return rung <= _IN_PLACE_RUNGS * topk
+
+
 class KeyeDecoder:
     """Decode over a `models/keye_vl.py` parameter tree: grouped-query
     attention over the cache rows a learned indexer selects.
@@ -734,7 +759,8 @@ class KeyeDecoder:
     supports_draft = False
     max_cache_len = None        # rotary positions: no table bounds them
     counter_names = ("moe_pairs", "moe_expert_reads", "moe_pairs_max",
-                     "dsa_rows_scored", "dsa_rows_selected")
+                     "dsa_rows_scored", "dsa_rows_selected",
+                     "dsa_rows_read")
     _LEAVES = ("k", "v", "ki")
 
     def __init__(self, cfg, params, attn_impl="auto"):
@@ -787,11 +813,15 @@ class KeyeDecoder:
     def step(self, margs, cache, tokens, pos):
         """One decode step for the whole batch. A layer writes its K, V
         and index-key rows at `pos`, scores every row in use against the
-        slot's index query, keeps the `min(topk, pos + 1)` best, gathers
-        THOSE rows of K and V into a `(S, topk, Hkv·Dh)` rung and attends
-        over it; then its expert layer routes the S tokens. Returns
-        next-token logits (S, V). Text positions: all three rotary streams
-        are the token's index."""
+        slot's index query, keeps the `min(topk, pos + 1)` best and attends
+        over THOSE rows, read in place under the selection mask or
+        gathered into a `(S, topk, Hkv·Dh)` rung (`_attends_in_place`);
+        then its expert layer routes the S tokens. Returns next-token
+        logits (S, V). Text positions: all three rotary streams are the
+        token's index. Counted a step, slots and layers summed: the rows
+        scored, the rows kept, and the rows of K the attention kernel
+        fetched for them (in place: a slot's rows in use rounded up to the
+        kernel's tile)."""
         (params,) = margs
         cfg = self.cfg
         s = tokens.shape[0]
@@ -806,7 +836,13 @@ class KeyeDecoder:
         # frozen at the end of its rung (pos == C) keeps all of them
         in_use = jnp.minimum(pos + 1, c)                        # (S,)
         keep = jnp.minimum(in_use, width)
-        kept = jnp.arange(width)[None, :] < keep[:, None]       # (S, topk)
+        in_place = _attends_in_place(c, cfg.indexer_topk)
+        if in_place:
+            tile = decode_tile_rows(c, cfg.kv_width, cache["k"][0].dtype)
+            read = (-(-in_use // tile) * tile).sum()
+        else:
+            kept = jnp.arange(width)[None, :] < keep[:, None]   # (S, topk)
+            read = keep.sum()
         with jax.named_scope("rope"):
             tables = keye_vl.rope_tables(
                 cfg, jnp.broadcast_to(pos[None], (3, s)))
@@ -827,15 +863,21 @@ class KeyeDecoder:
                                 qi, cache["ki"][li], w, pos,
                                 cfg.index_scale, impl=self.attn_impl)
                         with jax.named_scope("select"):
-                            rows = compact_indices(
-                                top_k_mask(scores, keep), width)
-                    with jax.named_scope("gather"):
-                        kg, vg = (jnp.take_along_axis(
-                            cache[name][li], rows[..., None], axis=1)
-                            for name in ("k", "v"))
-                    ctx = flash_attention_decode(
-                        q.reshape(s, cfg.num_attention_heads, cfg.head_dim),
-                        kg, vg, kept, impl=self.attn_impl)
+                            mask = top_k_mask(scores, keep)
+                            if not in_place:
+                                rows = compact_indices(mask, width)
+                    q = q.reshape(s, cfg.num_attention_heads, cfg.head_dim)
+                    if in_place:
+                        ctx = flash_attention_decode(
+                            q, cache["k"][li], cache["v"][li], mask,
+                            impl=self.attn_impl, lengths=in_use)
+                    else:
+                        with jax.named_scope("gather"):
+                            kg, vg = (jnp.take_along_axis(
+                                cache[name][li], rows[..., None], axis=1)
+                                for name in ("k", "v"))
+                        ctx = flash_attention_decode(q, kg, vg, kept,
+                                                     impl=self.attn_impl)
                     with jax.named_scope("proj"):
                         x = x + ctx.reshape(s, -1).astype(x.dtype) \
                             @ layer["o"].astype(x.dtype)
@@ -846,8 +888,8 @@ class KeyeDecoder:
                 x = x + out.astype(x.dtype)
         layers = cfg.num_hidden_layers
         cache["counts"] = cache["counts"] + jnp.concatenate([
-            counted, layers * jnp.stack([in_use.sum(), keep.sum()]).astype(
-                jnp.int32)])
+            counted, layers * jnp.stack(
+                [in_use.sum(), keep.sum(), read]).astype(jnp.int32)])
         return keye_vl.logits(cfg, params, x), cache
 
     def prefill(self, margs, cache, slot, prompt, plen):

@@ -685,8 +685,7 @@ def _masked_attend(q, k_cache, v_cache, valid, k_scale=None, v_scale=None):
         b, hq, tq, d)
 
 
-def _flash_decode_kernel(q_ref, k_ref, v_ref, km_ref, o_ref, acc_ref, l_ref,
-                         m_ref, *, scale, head_dim, group):
+def _flash_decode_kernel(*refs, scale, head_dim, group, ragged=False):
     """Grid (B, k_tiles), k innermost: one slot's single query row against
     a (block_k, Hkv·D) tile of its cache rows, read IN PLACE — every head
     of the slot in one grid step.
@@ -703,7 +702,17 @@ def _flash_decode_kernel(q_ref, k_ref, v_ref, km_ref, o_ref, acc_ref, l_ref,
     as one (1, H·D) row, broadcast over the rows, and the last k step
     folds the diagonal blocks back into one (1, H·D) row. With a larger
     group the query heads come in as the rows of a (Hp, D) block, repeated
-    along the lanes once a KV head, and go out the same way."""
+    along the lanes once a KV head, and go out the same way.
+
+    `ragged`: the slots' lengths come first, as a scalar-prefetch operand,
+    and a tile wholly past its slot's length is not computed (nor fetched:
+    `_ragged_tile` names another block for it). The mask hides every row
+    at or past the length already (`flash_attention_decode` sees to it),
+    so skipping changes the time and nothing else."""
+    if ragged:
+        len_ref, *refs = refs
+        length = len_ref[pl.program_id(0)]
+    q_ref, k_ref, v_ref, km_ref, o_ref, acc_ref, l_ref, m_ref = refs
     kj = pl.program_id(1)
     hp, hd = acc_ref.shape
     row = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 0)
@@ -718,25 +727,31 @@ def _flash_decode_kernel(q_ref, k_ref, v_ref, km_ref, o_ref, acc_ref, l_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
 
-    q = q_ref[0].astype(jnp.float32) * scale
-    if group > 1:
-        q = jnp.concatenate([q] * (hd // head_dim), axis=1)
-    q = jnp.where(own, q, 0.0)
-    s = jax.lax.dot_general(
-        q, k_ref[0].astype(jnp.float32),
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)           # (Hp, block_k)
-    s = jnp.where(km_ref[0] > 0, s, _NEG_INF)         # (1, block_k) mask
-    m_prev, l_prev = m_ref[...], l_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    m_ref[...] = m_new
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v_ref[0].astype(jnp.float32),
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)           # (Hp, Hkv·D)
+    def _tile():
+        q = q_ref[0].astype(jnp.float32) * scale
+        if group > 1:
+            q = jnp.concatenate([q] * (hd // head_dim), axis=1)
+        q = jnp.where(own, q, 0.0)
+        s = jax.lax.dot_general(
+            q, k_ref[0].astype(jnp.float32),
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)       # (Hp, block_k)
+        s = jnp.where(km_ref[0] > 0, s, _NEG_INF)     # (1, block_k) mask
+        m_prev, l_prev = m_ref[...], l_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        m_ref[...] = m_new
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p, v_ref[0].astype(jnp.float32),
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)       # (Hp, Hkv·D)
+
+    if ragged:
+        pl.when(kj * k_ref.shape[1] < length)(_tile)
+    else:
+        _tile()
 
     @pl.when(kj == pl.num_programs(1) - 1)
     def _finalize():
@@ -751,10 +766,49 @@ def _flash_decode_kernel(q_ref, k_ref, v_ref, km_ref, o_ref, acc_ref, l_ref,
             o_ref[0] = jnp.sum(o, axis=0, keepdims=True).astype(o_ref.dtype)
 
 
-def _flash_decode(q, k_cache, v_cache, cache_mask, block_k, interpret):
+#: tiles a decode call with `lengths` may read its rung in, largest first,
+#: and the bytes of K one may hold. Measured on a v5e at the sparse cell's
+#: shapes (32 slots of a `(18432, 512)` bfloat16 leaf, 9.6k rows in use a
+#: slot, `PERF.md` PR 36): 0.963, 0.940 and 0.961 ms a call at 512, 1024
+#: and 2048 rows. Small tiles pay a grid step for every tile skipped, large
+#: ones read more rows past the length; 1024 rows of 1 KB won
+_RAGGED_TILES = (2048, 1024, 512)
+_RAGGED_TILE_BYTES = 1 << 20
+
+
+def decode_tile_rows(rung, lanes, dtype):
+    """Cache rows a grid step of `flash_attention_decode(..., lengths=)`
+    reads, from the call's shapes alone: the largest of `_RAGGED_TILES`
+    that divides the rung and whose K tile holds at most
+    `_RAGGED_TILE_BYTES`; a rung none of them divides is one whole tile."""
+    row = lanes * jnp.dtype(dtype).itemsize
+    fits = [t for t in _RAGGED_TILES
+            if rung % t == 0 and t * row <= _RAGGED_TILE_BYTES]
+    return fits[0] if fits else rung
+
+
+def _ragged_tile(i, j, lengths, block_k):
+    """(slot, tile) of the K, V and mask block that grid step (i, j)
+    names: tile j up to the slot's last tile in use, `(max(lengths[i], 1) -
+    1) // block_k`; past it, the NEXT slot's first tile. A block index that
+    does not change is not copied again, so the tiles wholly past a slot's
+    rows are never fetched, and the next slot's first tile arrives under
+    this slot's last compute instead of after its skipped steps (0.966 ->
+    0.940 ms a call against naming the last tile in use again)."""
+    past = j > (jnp.maximum(lengths[i], 1) - 1) // block_k
+    last_slot = lengths.shape[0] - 1
+    return (jnp.where(past, jnp.minimum(i + 1, last_slot), i),
+            jnp.where(past, 0, j))
+
+
+def _flash_decode(q, k_cache, v_cache, cache_mask, block_k, interpret,
+                  lengths=None):
     """q (B, Hq, 1, D) against (B, C, Hkv·D) caches through the Pallas
     kernel; returns (B, Hq, 1, D). The caches go in as they are: no pad,
-    no reshape — a rung the k tile does not divide is one whole tile."""
+    no reshape — a rung the k tile does not divide is one whole tile.
+    With `lengths` (B,) int32, past which the mask holds nothing, the
+    grid is the same and the lengths go in first, as a scalar-prefetch
+    operand (`_flash_decode_kernel`)."""
     b, h, _, d = q.shape
     c, hd = k_cache.shape[1], k_cache.shape[2]
     group = h // (hd // d)
@@ -763,33 +817,59 @@ def _flash_decode(q, k_cache, v_cache, cache_mask, block_k, interpret):
     if c % block_k:
         block_k = c
     hp = -(-h // 8) * 8            # float32 sublane tile of the scores
-    cache_spec = pl.BlockSpec((1, block_k, hd), lambda i, j: (i, j, 0))
+    ragged = lengths is not None
+
+    def tile_at(i, j, *n):         # *n: the lengths, where prefetched
+        return _ragged_tile(i, j, n[0], block_k) if n else (i, j)
+
+    def row_at(i, j, *n):
+        return i, 0, 0
+
+    def mask_at(i, j, *n):
+        slot, tile = tile_at(i, j, *n)
+        return slot, 0, tile
+
+    cache_spec = pl.BlockSpec((1, block_k, hd),
+                              lambda i, j, *n: (*tile_at(i, j, *n), 0))
     if group > 1:                  # the query heads as rows, padded to Hp
         q_rows = jnp.pad(q[:, :, 0, :], ((0, 0), (0, hp - h), (0, 0)))
-        row_spec = pl.BlockSpec((1, hp, d), lambda i, j: (i, 0, 0))
+        row_spec = pl.BlockSpec((1, hp, d), row_at)
         out_shape = (b, hp, d)
     else:
         q_rows = q.reshape(b, 1, hd)
-        row_spec = pl.BlockSpec((1, 1, hd), lambda i, j: (i, 0, 0))
+        row_spec = pl.BlockSpec((1, 1, hd), row_at)
         out_shape = (b, 1, hd)
-    out = pl.pallas_call(
-        functools.partial(_flash_decode_kernel, scale=1.0 / (d ** 0.5),
-                          head_dim=d, group=group),
+    spec = dict(
         grid=(b, c // block_k),
         in_specs=[row_spec, cache_spec, cache_spec,
-                  pl.BlockSpec((1, 1, block_k), lambda i, j: (i, 0, j))],
+                  pl.BlockSpec((1, 1, block_k), mask_at)],
         out_specs=row_spec,
-        out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
         scratch_shapes=[
             pltpu.VMEM((hp, hd), jnp.float32),
             pltpu.VMEM((hp, 1), jnp.float32),
             pltpu.VMEM((hp, 1), jnp.float32),
-        ],
+        ])
+    operands = (q_rows, k_cache, v_cache,
+                cache_mask.astype(jnp.int32)[:, None, :])
+    limits = {}
+    if ragged:
+        operands = (lengths.astype(jnp.int32),) + operands
+        spec = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, **spec))
+        # the K and the V tile twice (the pipeline's two buffers) and their
+        # float32 images in the body; twice that for the rest and for room
+        vmem = 2 * block_k * hd * (4 * k_cache.dtype.itemsize + 8)
+        limits = dict(vmem_limit_bytes=min(max(vmem, 32 << 20), 100 << 20))
+    out = pl.pallas_call(
+        functools.partial(_flash_decode_kernel, scale=1.0 / (d ** 0.5),
+                          head_dim=d, group=group, ragged=ragged),
+        **spec,
+        out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"), **limits),
         interpret=interpret,
         name="flash_fwd",
-    )(q_rows, k_cache, v_cache, cache_mask.astype(jnp.int32)[:, None, :])
+    )(*operands)
     if group > 1:
         out = out[:, :h]
     # a slot with NO valid cache row has no defined softmax: zeros
@@ -842,8 +922,8 @@ def flash_attention_decode_mq(q, k_cache, v_cache, q_mask, impl="auto"):
 
 @jax.named_scope("flash_decode")
 def flash_attention_decode(q1, k_cache, v_cache, cache_mask, impl="auto",
-                           block_k=512, interpret=None, k_scale=None,
-                           v_scale=None):
+                           block_k=None, interpret=None, k_scale=None,
+                           v_scale=None, lengths=None):
     """Incremental-decode attention: a SINGLE query block per sequence
     attends over that sequence's cached K/V under a cache-validity mask.
 
@@ -864,7 +944,8 @@ def flash_attention_decode(q1, k_cache, v_cache, cache_mask, impl="auto",
     - impl: 'auto' (Pallas kernel on TPU, einsum elsewhere), 'pallas'
       (force kernel; interpret-mode off-TPU), or 'dense'
     - block_k: cache rows a kernel grid step reads (a rung it does not
-      divide is read as one tile)
+      divide is read as one tile); by default 512, and with `lengths`
+      what `decode_tile_rows` gives for the call's shapes
     - k_scale / v_scale: (B, C, Hkv) float32 per-head row scales of an
       int8-quantized cache (quantize/kvcache.py). When given, the
       dequant happens INSIDE the attention contractions — the single-
@@ -874,6 +955,13 @@ def flash_attention_decode(q1, k_cache, v_cache, cache_mask, impl="auto",
       based on every backend: the scales fold onto logits/softmax
       weights, which the Pallas fp kernel's streaming-softmax layout
       has no slot for yet.)
+    - lengths: (B,) int32, optional: rows 0..lengths[b] - 1 of slot b are
+      in use. A row at or past its slot's length is masked whatever
+      `cache_mask` says, and the kernel neither fetches nor computes a
+      tile wholly past it: a selection mask over a long rung (the rows a
+      sparse-attention indexer keeps) costs the rows in use, rounded up
+      to a tile, not the rung. `lengths[b] == 0` gives zeros. Without it
+      the call is what it was (not given with an int8 cache)
     Forward-only (decode never backprops). Rows whose mask has NO valid
     cache entry return zeros. Returns the same rank as q1.
     """
@@ -893,6 +981,11 @@ def flash_attention_decode(q1, k_cache, v_cache, cache_mask, impl="auto",
             "or 'dense'")
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be given together")
+    if lengths is not None and (lengths.shape != q.shape[:1]
+                                or k_scale is not None):
+        raise ValueError(
+            f"lengths must be (B,) = {q.shape[:1]}, over a cache without "
+            f"row scales, got {lengths.shape}")
     if k_scale is not None:
         if impl == "pallas":
             raise ValueError(
@@ -909,13 +1002,19 @@ def flash_attention_decode(q1, k_cache, v_cache, cache_mask, impl="auto",
         impl = "dense"
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "dense"
-    if impl == "pallas":
-        out = _flash_decode(q, k_cache, v_cache, cache_mask, block_k,
-                            interpret)
-    else:
+    if lengths is not None:       # one contract for both impls
+        cache_mask = cache_mask.astype(bool) & (
+            jnp.arange(k_cache.shape[1])[None, :] < lengths[:, None])
+    if impl == "dense":
         out = _masked_attend(q, k_cache, v_cache,
                              cache_mask.astype(bool)[:, None, :],
                              k_scale, v_scale)
+    else:
+        if block_k is None:
+            block_k = 512 if lengths is None else decode_tile_rows(
+                *k_cache.shape[1:], k_cache.dtype)
+        out = _flash_decode(q, k_cache, v_cache, cache_mask, block_k,
+                            interpret, lengths)
     return out[:, :, 0, :] if squeeze else out
 
 

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.families import keye_vl_serve as family
+from deeplearning4j_tpu.generation import decode
 from deeplearning4j_tpu.generation.decode import KeyeDecoder
 from deeplearning4j_tpu.generation.server import GenerationServer
 from deeplearning4j_tpu.kernels import indexer
@@ -234,22 +235,122 @@ def test_selected_attention_kernel_matches_masked_softmax(tq, tk, offset,
                                p @ v[:, kvh * d:(kvh + 1) * d], atol=2e-6)
 
 
+def _ragged_case(dtype, hq, hkv, d, c, seed):
+    """Four slots of a rung: lengths 1, the whole rung, one that no tile
+    divides, and 0; a random selection of the rows in use as the mask; and
+    large finite garbage in every row at or past a slot's length, set in
+    the mask too, so that only `lengths` hides it."""
+    rng = np.random.default_rng(seed)
+    lengths = np.array([1, c, c // 2 + 77, 0], np.int32)
+    in_use = np.arange(c)[None, :] < lengths[:, None]
+    q = jnp.asarray(rng.normal(size=(4, hq, d)), dtype)
+    k, v = (jnp.asarray(np.where(in_use[..., None],
+                                 rng.normal(size=(4, c, hkv * d)), 3e4), dtype)
+            for _ in range(2))
+    selected = in_use & (rng.random((4, c)) < 0.3)
+    selected[[0, 1, 2], [0, c - 1, c // 2]] = True     # none is empty
+    return q, k, v, selected, jnp.asarray(lengths)
+
+
+@pytest.mark.parametrize("dtype,hq,hkv,d,c,tol", [
+    (jnp.bfloat16, 32, 4, 128, 2048, 2e-2),
+    (jnp.float32, 4, 4, 16, 1536, 2e-6)],
+    ids=["grouped_query_bfloat16", "multi_head_float32"])
+def test_decode_kernel_under_lengths_matches_masked_softmax(dtype, hq, hkv,
+                                                            d, c, tol):
+    """The selection mask over a rung read in place: rows past a slot's
+    length are masked whatever the mask says, a tile wholly past it leaves
+    no trace of the garbage it holds, and a slot of length 0 gives zeros;
+    the dense path means the same."""
+    q, k, v, selected, lengths = _ragged_case(dtype, hq, hkv, d, c, c)
+    tile = fa.decode_tile_rows(c, hkv * d, dtype)
+    assert c % tile == 0 and tile < c and (c // 2 + 77) % tile
+    selected = jnp.asarray(selected)
+    want = fa._masked_attend(q[:, :, None], k, v,
+                             selected[:, None, :])[:, :, 0]
+    past = jnp.arange(c)[None, :] >= lengths[:, None]   # the garbage "valid"
+    for mask in (selected, selected | past):
+        for impl in ("pallas", "dense"):
+            got = fa.flash_attention_decode(q, k, v, mask, impl=impl,
+                                            interpret=True, lengths=lengths)
+            assert np.isfinite(np.asarray(got, np.float32)).all()
+            np.testing.assert_allclose(np.asarray(got, np.float32),
+                                       np.asarray(want, np.float32),
+                                       atol=tol, rtol=0)
+            assert not np.asarray(got[3], np.float32).any()
+    # a tile the caller names, smaller than the rule's
+    got = fa.flash_attention_decode(q, k, v, selected, impl="pallas",
+                                    interpret=True, lengths=lengths,
+                                    block_k=128)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=tol,
+                               rtol=0)
+    with pytest.raises(ValueError, match="lengths must be"):
+        fa.flash_attention_decode(q, k, v, selected, lengths=lengths[:3])
+
+
+@pytest.mark.parametrize("dtype,hq,hkv,d,c", [
+    (jnp.bfloat16, 32, 4, 128, 1024), (jnp.float32, 4, 4, 16, 512)],
+    ids=["grouped_query_bfloat16", "multi_head_float32"])
+def test_decode_kernel_without_lengths_is_the_call_it_was(dtype, hq, hkv, d,
+                                                          c, monkeypatch):
+    """`lengths=None` builds today's `pallas_call`: a plain grid, no
+    scalar-prefetch operand, no VMEM limit, a tile of 512 rows; and gives
+    bit for bit what the kernel gives with every length at the rung."""
+    q, k, v, selected, _ = _ragged_case(dtype, hq, hkv, d, c, 3)
+    selected = jnp.asarray(selected)
+    calls = []
+    real = fa.pl.pallas_call
+    monkeypatch.setattr(fa.pl, "pallas_call", lambda kernel, **kw: (
+        calls.append(kw), real(kernel, **kw))[1])
+    plain = fa.flash_attention_decode(q, k, v, selected, impl="pallas",
+                                      interpret=True)
+    ragged = fa.flash_attention_decode(
+        q, k, v, selected, impl="pallas", interpret=True, block_k=512,
+        lengths=jnp.full((4,), c, jnp.int32))
+    was, now = calls
+    assert was["grid"] == (4, c // 512) and "grid_spec" not in was
+    assert len(was["in_specs"]) == 4
+    assert was["compiler_params"].vmem_limit_bytes is None
+    assert now["grid_spec"].num_scalar_prefetch == 1 and "grid" not in now
+    np.testing.assert_array_equal(np.asarray(plain, np.float32),
+                                  np.asarray(ragged, np.float32))
+
+
+def test_the_rule_reads_in_place_up_to_twelve_times_topk():
+    """The benchmark configuration's rung, 18432 = 9 x `topk` 2048, takes
+    the in-place read; a rung past the rule keeps the gather. A function
+    of the two static shapes alone."""
+    assert decode._attends_in_place(18432, 2048)
+    assert decode._attends_in_place(12 * 2048, 2048)
+    assert not decode._attends_in_place(12 * 2048 + 2, 2048)
+    assert not decode._attends_in_place(32768, 2048)
+    assert decode._attends_in_place(64, 16) \
+        and not decode._attends_in_place(256, 16)
+    # and the tile the kernel reads that rung in comes from its shapes
+    assert fa.decode_tile_rows(18432, 512, jnp.bfloat16) in (512, 1024, 2048)
+    assert fa.decode_tile_rows(64, 32, jnp.float32) == 64
+
+
 # -- the decoder: prefill, then decode through the three leaves ---------------
+@pytest.mark.parametrize("rung", [64, 256], ids=["in_place", "gathered"])
 @pytest.mark.parametrize("attn_impl", ["dense", "pallas"])
-def test_prefill_then_ten_steps_match_the_full_forward(toy, attn_impl):
+def test_prefill_then_ten_steps_match_the_full_forward(toy, attn_impl, rung):
     """Slots 2 and 0 of a 3-slot cache take prompts of 21 and 35 (buckets
     of 40: both past `topk` 16) and decode 10 greedy tokens at DIFFERENT
     positions while slot 1 idles: every step's logits are the reference's
-    full forward at that position, so each step gathered the rows the
-    reference's sort selects."""
+    full forward at that position, so each step attended the rows the
+    reference's sort selects. A rung of 64 = 4 x `topk` reads them where
+    they lie, one of 256 = 16 x `topk` gathers them first."""
     cfg, params = toy
+    assert decode._attends_in_place(rung, cfg.indexer_topk) == (rung == 64)
     dec = KeyeDecoder(cfg, params, attn_impl=attn_impl)
     margs = dec.model_args()
     prefill, step = jax.jit(dec.prefill), jax.jit(dec.step)
     slots, prompts = (2, 0), (_ids(1, 21), _ids(2, 35))
-    cache = dec.init_cache(3, 64)
+    cache = dec.init_cache(3, rung)
     assert [l.shape for l in (cache["k"][0], cache["ki"][1])] \
-        == [(3, 64, 32), (3, 32, 16)]
+        == [(3, rung, 32), (3, rung // 2, 16)]
     seqs, tokens, got = {}, np.zeros(3, np.int32), {s: [] for s in slots}
     pos = np.zeros(3, np.int32)
     for slot, prompt in zip(slots, prompts):
@@ -279,6 +380,33 @@ def test_prefill_then_ten_steps_match_the_full_forward(toy, attn_impl):
     # rows in use: 22..31, 36..45 and the idle slot's 1; kept: 16, 16, 1
     assert counts["dsa_rows_scored"] == 2 * (265 + 405 + 10)
     assert counts["dsa_rows_selected"] == 2 * 10 * (16 + 16 + 1)
+    # in place a slot reads its whole one-tile rung; gathered, what it kept
+    assert counts["dsa_rows_read"] == (2 * 10 * 3 * 64 if rung == 64
+                                       else counts["dsa_rows_selected"])
+    # the `(S, topk, Hkv·Dh)` rung of gathered rows exists on one side only
+    hlo = step.lower(margs, cache, tokens, pos).as_text()
+    assert ("tensor<3x16x32xf32>" in hlo) == (rung == 256)
+
+
+def test_rows_read_are_the_rows_in_use_rounded_up_to_the_tile(toy):
+    """`dsa_rows_read` on a rung the kernel reads in several tiles (4096
+    rows of 32 float32 lanes): `ceil(in_use / tile) * tile` a slot a layer,
+    so one tile for a slot at position 21 or 0, and what holds row `tile +
+    40` for the third. `topk` 512 keeps that rung under the rule."""
+    _, params = toy
+    cfg = kv.KeyeVLConfig.from_dict(
+        {**TOY, "sa_config": {**TOY["sa_config"], "topk": 512}})
+    dec = KeyeDecoder(cfg, params, attn_impl="dense")
+    tile = fa.decode_tile_rows(4096, cfg.kv_width, jnp.float32)
+    assert tile in (512, 1024, 2048)
+    cache = dec.init_cache(3, 4096)
+    pos = np.array([21, 0, tile + 40], np.int32)
+    _, cache = jax.jit(dec.step)(dec.model_args(), cache,
+                                 np.ones(3, np.int32), pos)
+    counts = dict(zip(dec.counter_names, np.asarray(cache["counts"])))
+    assert counts["dsa_rows_scored"] == 2 * (22 + 1 + tile + 41)
+    assert counts["dsa_rows_selected"] == 2 * (22 + 1 + 512)
+    assert counts["dsa_rows_read"] == 2 * (tile + tile + 2 * tile)
 
 
 def test_grow_pads_all_three_kinds_of_leaf_together(toy):
@@ -301,11 +429,18 @@ def test_grow_pads_all_three_kinds_of_leaf_together(toy):
 
 
 # -- through the server ---------------------------------------------------------
-def test_server_streams_equal_the_decoders_own_and_never_compile(toy):
+@pytest.mark.parametrize("topk", [16, 2], ids=["in_place", "gathered"])
+def test_server_streams_equal_the_decoders_own_and_never_compile(toy, topk):
     """Greedy streams through `GenerationServer` (two requests at once, a
     rung grown mid-service) are what the decoder's own prefill and steps
-    give, token for token; past warm-up nothing traces or compiles."""
-    cfg, params = toy
+    give, token for token; past warm-up nothing traces or compiles. With
+    `topk` 16 both rungs (32, 64) are read in place, with `topk` 2 (the
+    same weights: no shape depends on it) both are past the rule."""
+    _, params = toy
+    cfg = kv.KeyeVLConfig.from_dict(
+        {**TOY, "sa_config": {**TOY["sa_config"], "topk": topk}})
+    assert [decode._attends_in_place(r, topk) for r in (32, 64)] \
+        == [topk == 16] * 2
     dec = KeyeDecoder(cfg, params)
     srv = GenerationServer(dec, slots=2, cache_lengths=[32, 64],
                            prompt_buckets=[24, 40], method="greedy",
@@ -340,8 +475,15 @@ def test_server_streams_equal_the_decoders_own_and_never_compile(toy):
         assert list(stream) == own
     assert st["decoder"] == "KeyeDecoder" and st["state"] == "serving"
     assert st["moe_pairs"] == 2 * 2 * 4 * st["steps"] > 0
-    assert 0 < st["dsa_rows_selected"] <= 2 * 2 * 16 * st["steps"]
+    assert 0 < st["dsa_rows_selected"] <= 2 * 2 * topk * st["steps"]
     assert st["dsa_rows_selected"] < st["dsa_rows_scored"]
+    # `status()` shows the sixth counter: in place each slot's one-tile rung
+    # a layer a step, gathered what was kept
+    if topk == 16:
+        assert 2 * 2 * 32 * st["steps"] <= st["dsa_rows_read"] \
+            <= 2 * 2 * 64 * st["steps"]
+    else:
+        assert st["dsa_rows_read"] == st["dsa_rows_selected"]
 
 
 # -- the gated expert layer ---------------------------------------------------

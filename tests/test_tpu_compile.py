@@ -289,7 +289,8 @@ def _superstep_for_v5e(one_chip, dtype):
     fa = importlib.import_module(
         "deeplearning4j_tpu.kernels.flash_attention")
     real = fa._flash_decode
-    fa._flash_decode = lambda q, k, v, m, bk, _: real(q, k, v, m, bk, False)
+    fa._flash_decode = lambda q, k, v, m, bk, _, *n: real(q, k, v, m, bk,
+                                                          False, *n)
     try:
         with jax.default_matmul_precision("default"):
             compiled = jax.jit(superstep, donate_argnums=(1, 2, 3, 4)) \
@@ -384,6 +385,12 @@ def _keye_gathered_decode(q, k, v, m):
                                   interpret=False)
 
 
+def _keye_decode_in_place(q, k, v, m, n):
+    from deeplearning4j_tpu.kernels import flash_attention_decode
+    return flash_attention_decode(q, k, v, m, impl="pallas",
+                                  interpret=False, lengths=n)
+
+
 def _keye_gated_experts(x, w_gate, w_up, w_down, groups):
     from deeplearning4j_tpu.kernels.grouped_matmul import grouped_mlp
     return grouped_mlp(x, w_up, w_down, groups, jax.nn.silu,
@@ -407,21 +414,100 @@ _KEYE_EXPERTS = (((16, 2048, 768), bf16), ((16, 2048, 768), bf16),
     (_keye_gathered_decode, (((32, 32, 128), bf16), ((32, 2048, 512), bf16),
                              ((32, 2048, 512), bf16),
                              ((32, 2048), jnp.bool_)), "flash_fwd"),
+    (_keye_decode_in_place, (((32, 32, 128), bf16),
+                             ((32, 18432, 512), bf16),
+                             ((32, 18432, 512), bf16),
+                             ((32, 18432), jnp.bool_), ((32,), i32)),
+     "flash_fwd"),
     (_keye_gated_experts, (((32, 2048), bf16), *_KEYE_EXPERTS,
                            ((256,), i32)), "grouped_mlp"),
     (_keye_gated_experts, (((2048, 2048), bf16), *_KEYE_EXPERTS,
                            ((16384,), i32)), "grouped_mlp"),
 ], ids=["index_scores", "index_scores_decode", "selected_attention",
-        "decode_over_the_gathered_rung", "gated_experts_a_step",
+        "decode_over_the_gathered_rung", "decode_in_place_under_a_selection",
+        "gated_experts_a_step",
         "gated_experts_a_prefill_run"])
 def test_sparse_attention_kernels_compile_for_v5e(compile_for_chip, fn,
                                                   specs, name):
     """The kernels `KeyeDecoder` adds, at the published widths: the
     indexer's scores over a 4096-row query block of a 16384 prompt and over
     a packed decode leaf, attention under a row-by-row selection, the
-    grouped-query decode kernel over a GATHERED rung of 2048 rows, and the
-    gated expert (three weight blocks of 3.1 MB, double-buffered)."""
+    grouped-query decode kernel over a GATHERED rung of 2048 rows and over
+    the whole 18432-row leaf in place under a selection mask and the slots'
+    lengths (PR 36), and the gated expert (three weight blocks of 3.1 MB,
+    double-buffered)."""
     text = compile_for_chip(fn, *specs)
     calls = [l for l in text.splitlines()
              if "tpu_custom_call" in l and " custom-call(" in l]
     assert len(calls) == 1 and name in calls[0]
+
+
+def test_sparse_decode_superstep_gathers_no_rows_for_v5e(compile_for_chip,
+                                                         one_chip):
+    """`KeyeDecoder`'s superstep at the published widths (32 slots, a rung
+    of 18432 = 9 x `topk`, 2 layers) attends its kept rows where they lie:
+    the program holds no `(32, 2048, 512)` rung of gathered rows (XLA's row
+    gather was 19.3 of a 25.0 ms step on the chip: `PERF.md`, PR 36), no
+    temporary the size of a K leaf, and one `flash_fwd` a layer under the
+    scope the benchmark reads."""
+    import json
+
+    from jax import lax
+
+    from benchmarks.families.keye_vl_serve import model_config
+    from deeplearning4j_tpu.generation.decode import KeyeDecoder
+    from deeplearning4j_tpu.generation.sampling import sample_step
+    from deeplearning4j_tpu.models import keye_vl
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "..", "benchmarks", "configs",
+                           "keye_vl2_30b_a3b_ep8.json")) as f:
+        cfg = model_config({**json.load(f), "num_hidden_layers": 2},
+                           "bfloat16")
+    slots, rung = 32, 18432
+    params = jax.eval_shape(lambda k: keye_vl.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    dec = KeyeDecoder(cfg, params, attn_impl="pallas")
+    cache = jax.eval_shape(lambda: dec.init_cache(slots, rung))
+
+    def superstep(params, cache, tokens, pos, rng, method, temp, topk):
+        def body(carry, _):
+            cache, tokens, pos, rng = carry
+            logits, cache = dec.step((params,), cache, tokens, pos)
+            tok, rng = sample_step(logits, rng, method, temp, topk)
+            return (cache, tok, pos + 1, rng), tok
+        return lax.scan(body, (cache, tokens, pos, rng), None, length=1)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype,
+                                           sharding=one_chip), tree)
+
+    slot = jax.ShapeDtypeStruct((slots,), i32)
+    args = on_chip((params, cache, slot, slot,
+                    jax.ShapeDtypeStruct((slots, 2), jnp.uint32), slot,
+                    jax.ShapeDtypeStruct((slots,), f32), slot))
+    # the kernels and the expert layer ask `jax.default_backend()`: steer
+    # it from here, not through an option of the program
+    real = jax.default_backend
+    jax.default_backend = lambda: "tpu"
+    try:
+        with jax.default_matmul_precision("default"):
+            compiled = jax.jit(superstep, donate_argnums=(1, 2, 3, 4)) \
+                .lower(*args).compile()
+    finally:
+        jax.default_backend = real
+    text = compiled.as_text()
+    gathered = [line.strip()[:160] for line in text.splitlines()
+                if re.search(r"bf16\[(32,2048,512|65536,512)\]", line)]
+    assert not gathered, gathered
+    assert "attn/gather" not in text
+    # 4.6 MB here; the two gathered rungs a layer made it 275 MB, and a
+    # copy of a K leaf would be 604 MB
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+    calls = [l for l in text.splitlines()
+             if "tpu_custom_call" in l and " custom-call(" in l]
+    for li in range(2):
+        assert sum(f"layer{li}/attn/flash_decode/flash_fwd" in c
+                   and c.lstrip().lstrip("%").startswith("flash_fwd")
+                   for c in calls) == 1, li
