@@ -1,7 +1,7 @@
 """Decode-mode forwards: incremental single-token model evaluation over
 donated device state.
 
-Four adapters expose one contract to the GenerationServer:
+Five adapters expose one contract to the GenerationServer:
 
 - **BertDecoder** — transformer stacks built on `models/bert.py` params:
   one K and one V cache leaf A LAYER, `(S, C, H·Dh)` (S slots, C =
@@ -51,6 +51,18 @@ Four adapters expose one contract to the GenerationServer:
   `prefill` does the same selection for every prompt position. Its
   expert layers count as NemotronHDecoder's do, and the indexer counts the
   rows it scored and kept and the rows attention read for them.
+
+- **MLADecoder** — stacks built on `models/deepseek_v3.py` params
+  (multi-head latent attention), whose cache holds ONE leaf a layer and in
+  it ONE row a position for all heads' keys AND values: the normed latent
+  and the rotated key lanes, two positions a row, `(S, C / 2, 2·(L + R))`
+  (`kernels/mla_attention.py` says why). `prefill` attends in the EXPANDED
+  form (keys and values decompressed for the prompt) and grafts the latent
+  rows; `step` attends in the ABSORBED form (the key up-projection folded
+  into the query, the value up-projection applied after the softmax) and
+  reads each row in use once. Two algebraically equal paths that meet in
+  the cache. Its expert layers count as NemotronHDecoder's do, and the
+  attention counts the rows it needed and the rows its kernel fetched.
 
 The contract (all pure functions, traced into AOT executables by the
 server — nothing here may touch the host):
@@ -112,14 +124,16 @@ from deeplearning4j_tpu.kernels.flash_attention import (
     flash_attention_decode_paged)
 from deeplearning4j_tpu.kernels.indexer import (index_scores_decode,
                                                 pack_rows, write_packed_row)
+from deeplearning4j_tpu.kernels.mla_attention import (latent_tile_positions,
+                                                      pack_latent)
 from deeplearning4j_tpu.kernels.selection import (compact_indices,
                                                   top_k_mask)
-from deeplearning4j_tpu.models import keye_vl, nemotron_h
+from deeplearning4j_tpu.models import deepseek_v3, keye_vl, nemotron_h
 from deeplearning4j_tpu.models.bert import (_ffn, _layer_norm,
                                             bert_mlm_logits)
 from deeplearning4j_tpu.parallel.ring_attention import dense_attention
 
-__all__ = ["BertDecoder", "KeyeDecoder", "NemotronHDecoder",
+__all__ = ["BertDecoder", "KeyeDecoder", "MLADecoder", "NemotronHDecoder",
            "RecurrentDecoder"]
 
 
@@ -915,6 +929,127 @@ class KeyeDecoder:
                         leaf, part[None].astype(leaf.dtype), (slot, 0, 0))
         h_last = jnp.take(x, plen - 1, axis=0)                  # (H,)
         return cache, keye_vl.logits(cfg, params, h_last)
+
+
+class MLADecoder:
+    """Decode over a `models/deepseek_v3.py` parameter tree: multi-head
+    latent attention over one cached row a position.
+
+    The cache pytree: `{"kv": [...]}`, one `(S, C / 2, 2·(L + R))` leaf A
+    LAYER in the compute dtype, rows major, row r holding positions 2r and
+    2r + 1 as `[c, c', kr, kr']` (`mla_attention.pack_latent`); `"counts"`,
+    the running counts (`counter_names`). The rung is an even number of
+    rows.
+
+    The full-sequence reference this must match is `deeplearning4j_tpu.
+    models.deepseek_v3.forward` over the same prompt+generated prefix
+    (and, outside the package, the plain reference under
+    `benchmarks/families/`)."""
+
+    uses_cache_rungs = True
+    n_model_args = 1
+    supports_draft = False      # the model's prediction module is not run
+    max_cache_len = None        # rotary positions: no table bounds them
+    counter_names = ("moe_pairs", "moe_expert_reads", "moe_pairs_max",
+                     "mla_rows_attended", "mla_rows_read")
+
+    def __init__(self, cfg, params, attn_impl="auto"):
+        if attn_impl not in ("auto", "dense", "pallas"):
+            raise ValueError(
+                f"attn_impl must be 'auto', 'dense' or 'pallas', "
+                f"got {attn_impl!r}")
+        self.cfg = cfg
+        self.params = params
+        self.attn_impl = attn_impl
+        self.vocab_size = int(cfg.vocab_size)
+
+    def fingerprint(self):
+        parts = ("mla-decode", repr(self.cfg), self.attn_impl,
+                 _shape_tree_repr(self.params),
+                 _shape_tree_repr(
+                     jax.eval_shape(lambda: self.init_cache(1, 2))))
+        return hashlib.sha256(repr(parts).encode()).hexdigest()[:16]
+
+    def model_args(self):
+        return (self.params,)
+
+    def init_cache(self, slots, cache_len):
+        cfg = self.cfg
+        if cache_len % 2:
+            raise ValueError(f"the latent leaf packs two positions a row: "
+                             f"cache rung {cache_len} must be even")
+        return {"kv": [jnp.zeros((slots, cache_len // 2,
+                                  2 * cfg.latent_width), cfg.compute_dtype)
+                       for _ in range(cfg.num_hidden_layers)],
+                "counts": jnp.zeros((len(self.counter_names),), jnp.int32)}
+
+    def grow(self, cache, new_len):
+        """Every latent leaf padded to the longer rung; the counts as they
+        are."""
+        def pad(t):
+            return jnp.pad(t, ((0, 0), (0, int(new_len) // 2 - t.shape[1]),
+                               (0, 0)))
+        return {**cache, "kv": [pad(t) for t in cache["kv"]]}
+
+    def counters(self, cache):
+        return cache["counts"]
+
+    def step(self, margs, cache, tokens, pos):
+        """One decode step for the whole batch, in the absorbed form: a
+        layer writes its latent row at `pos` and attends rows 0..pos of its
+        leaf, each read once as key and as value; then its feed-forward,
+        dense or experts. Returns next-token logits (S, V). Counted a step,
+        slots and layers summed: the rows attended (what the mathematics
+        needs) and the rows the kernel fetched for them (a slot's rows in
+        use rounded up to its tile)."""
+        (params,) = margs
+        cfg = self.cfg
+        with jax.named_scope("embed"):
+            x = jnp.take(params["embed"], tokens, axis=0)       # (S, H)
+        leaves = list(cache["kv"])
+        c = 2 * leaves[0].shape[1]
+        # rows 0..pos are in use (the current write included); a lane
+        # frozen at the end of its rung (pos == C) keeps all of them
+        in_use = jnp.minimum(pos + 1, c)                        # (S,)
+        tile = latent_tile_positions(c, cfg.kv_lora_rank, leaves[0].dtype)
+        read = (-(-in_use // tile) * tile).sum()
+        with jax.named_scope("rope"):
+            tables = deepseek_v3.rope_tables(cfg, pos)
+        counted = jnp.zeros((3,), jnp.int32)
+        for li, layer in enumerate(params["layers"]):
+            with jax.named_scope(f"layer{li}"):
+                x, leaves[li], counts = deepseek_v3.decode_layer(
+                    cfg, li, layer, x, tables, leaves[li], pos, in_use,
+                    self.attn_impl)
+                if counts is not None:
+                    counted = counted + counts
+        counts = cache["counts"] + jnp.concatenate([
+            counted, cfg.num_hidden_layers * jnp.stack(
+                [in_use.sum(), read]).astype(jnp.int32)])
+        return deepseek_v3.logits(cfg, params, x), \
+            {"kv": leaves, "counts": counts}
+
+    def prefill(self, margs, cache, slot, prompt, plen):
+        """The full forward over one length-bucketed prompt (P,) in the
+        expanded form, then the graft of the slot's latent rows 0..P-1 a
+        layer (rows beyond plen hold padding garbage that the decode step
+        never reads: it attends rows up to `pos`). Returns the logits at
+        plen - 1."""
+        (params,) = margs
+        cfg = self.cfg
+        x, states = deepseek_v3.encode(cfg, params, prompt,
+                                       impl=self.attn_impl)
+        leaves = list(cache["kv"])
+        for li, (c, kr) in enumerate(states):
+            with jax.named_scope(f"layer{li}"), jax.named_scope("attn"), \
+                    jax.named_scope("kv_write"):
+                leaves[li] = lax.dynamic_update_slice(
+                    leaves[li],
+                    pack_latent(c, kr)[None].astype(leaves[li].dtype),
+                    (slot, 0, 0))
+        h_last = jnp.take(x, plen - 1, axis=0)                  # (H,)
+        return {**cache, "kv": leaves}, \
+            deepseek_v3.logits(cfg, params, h_last)
 
 
 class RecurrentDecoder:

@@ -34,12 +34,18 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_INF = -1e30
 
 
-def _flash_fwd_kernel(*refs, block_k, causal, scale, tk_actual, has_mask):
+def _flash_fwd_kernel(*refs, block_k, causal, scale, tk_actual, has_mask,
+                      native=False):
     """Grid (BH, q_tiles, k_tiles), k innermost: only one (block_k, d) K/V
     tile is VMEM-resident per step; o/l/m accumulate in VMEM scratch across
     the k dimension and the output tile is written on the last k step.
     The q and k tilings are independent, so Tq ≠ Tk (cross-attention)
-    falls out of the same kernel.
+    falls out of the same kernel; so is V's width, which only the
+    accumulator and the output see.
+
+    `native`: both products take their operands in the operands' own dtype
+    (bfloat16 on the MXU in one pass) with float32 sums, the scale applied
+    to the float32 scores; without it every operand is float32 first.
 
     With has_mask, an extra (1, block_k) int32 KEY-validity tile (from the
     per-example (B, Tk) padding mask) masks scores; invalid QUERY rows are
@@ -61,13 +67,19 @@ def _flash_fwd_kernel(*refs, block_k, causal, scale, tk_actual, has_mask):
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
 
     def _compute():
-        q = q_ref[0].astype(jnp.float32) * scale  # (block_q, d)
-        k = k_ref[0]                              # (block_k, d)
-        v = v_ref[0]
-        s = jax.lax.dot_general(
-            q, k.astype(jnp.float32),
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)   # (block_q, block_k)
+        if native:
+            k, v = k_ref[0], v_ref[0]
+            s = jax.lax.dot_general(
+                q_ref[0], k, dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+        else:
+            q = q_ref[0].astype(jnp.float32) * scale  # (block_q, d)
+            k = k_ref[0]                              # (block_k, d)
+            v = v_ref[0]
+            s = jax.lax.dot_general(
+                q, k.astype(jnp.float32),
+                dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)   # (block_q, block_k)
         q_pos = qi * block_q + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 0)
         k_pos = kj * block_k + jax.lax.broadcasted_iota(
@@ -85,7 +97,8 @@ def _flash_fwd_kernel(*refs, block_k, causal, scale, tk_actual, has_mask):
         l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
         m_ref[...] = m_new
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v.astype(jnp.float32),
+            *((p.astype(v.dtype), v) if native
+              else (p, v.astype(jnp.float32))),
             dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
@@ -125,30 +138,32 @@ def _prep_mask(mask, block_k):
 
 
 def _flash_forward(q, k, v, q_mask, kv_mask, causal, block_q, block_k,
-                   interpret):
-    """Returns (out (B,H,Tq,D), lse (B*H, Tq_padded)). `kv_mask` is an
+                   interpret, native=False):
+    """Returns (out (B,H,Tq,Dv), lse (B*H, Tq_padded)). `kv_mask` is an
     optional (B, Tk) KEY-validity mask; `q_mask` an optional (B, Tq)
     QUERY-validity mask — invalid q rows come back zeroed with
     lse = +1e30 so the backward kernels recompute p == 0 for them.
-    Self-attention passes the same (B, T) mask for both."""
+    Self-attention passes the same (B, T) mask for both. V's width Dv may
+    differ from Q's and K's D (the scale is D's)."""
     b, h, tq_a, d = q.shape
     tk_a = k.shape[2]
+    dv = v.shape[3]
     scale = 1.0 / (d ** 0.5)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     block_q, block_k = _block_sizes(tq_a, tk_a, block_q, block_k)
     qp = _pad_to(q.reshape(b * h, tq_a, d), 1, block_q)
     kp = _pad_to(k.reshape(b * h, tk_a, d), 1, block_k)
-    vp = _pad_to(v.reshape(b * h, tk_a, d), 1, block_k)
+    vp = _pad_to(v.reshape(b * h, tk_a, dv), 1, block_k)
     tq = qp.shape[1]
     grid = (b * h, tq // block_q, kp.shape[1] // block_k)
     kernel = functools.partial(_flash_fwd_kernel, block_k=block_k,
                                causal=causal, scale=scale, tk_actual=tk_a,
-                               has_mask=kv_mask is not None)
+                               has_mask=kv_mask is not None, native=native)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
         pl.BlockSpec((1, block_k, d), lambda bh, i, j: (bh, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda bh, i, j: (bh, j, 0)),
+        pl.BlockSpec((1, block_k, dv), lambda bh, i, j: (bh, j, 0)),
     ]
     operands = [qp, kp, vp]
     if kv_mask is not None:
@@ -160,18 +175,18 @@ def _flash_forward(q, k, v, q_mask, kv_mask, causal, block_q, block_k,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda bh, i, j: (bh, i, 0)),
             # row vectors ride as (N, 1, T) with (1, 1, block) tiles:
             # a 2-D (1, block) tile violates the Mosaic (8, 128) minimum
             # unless the block covers the full array dim
             pl.BlockSpec((1, 1, block_q), lambda bh, i, j: (bh, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, tq, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, 1, tq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
@@ -181,7 +196,7 @@ def _flash_forward(q, k, v, q_mask, kv_mask, causal, block_q, block_k,
         name="flash_fwd",
     )(*operands)
     lse = lse[:, 0]
-    out = out[:, :tq_a, :].reshape(b, h, tq_a, d)
+    out = out[:, :tq_a, :].reshape(b, h, tq_a, dv)
     if q_mask is not None or kv_mask is not None:
         qvalid = (jnp.ones((b, tq_a), bool) if q_mask is None
                   else q_mask.astype(bool))             # (B, Tq)
@@ -433,8 +448,28 @@ def _flash_bwd_rule(causal, block_q, block_k, interpret, res, g):
 _flash_attention_vjp.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _flash_forward_only(q, k, v, q_mask, kv_mask, causal, block_q, block_k,
+                        interpret, native):
+    return _flash_forward(q, k, v, q_mask, kv_mask, causal, block_q,
+                          block_k, interpret, native)[0]
+
+
+def _forward_only_fwd_rule(q, k, v, q_mask, kv_mask, causal, block_q,
+                           block_k, interpret, native):
+    raise NotImplementedError(
+        f"flash_attention is forward only with native=True or with values "
+        f"of another width than the keys (native={native}, keys of "
+        f"{k.shape[3]}, values of {v.shape[3]}): the backward kernels take "
+        f"float32 products and equal widths. Differentiate through "
+        f"native=False at equal widths, or through a dense attention")
+
+
+_flash_forward_only.defvjp(_forward_only_fwd_rule, lambda *_: None)
+
+
 def flash_attention(q, k, v, causal=False, block_q=128, block_k=128,
-                    interpret=None, mask=None, kv_mask=None):
+                    interpret=None, mask=None, kv_mask=None, native=False):
     """Fused attention: softmax(QKᵀ/√d)·V without materialising (Tq,Tk).
 
     Pallas on TPU (interpret-mode elsewhere); differentiable — backward is
@@ -451,6 +486,15 @@ def flash_attention(q, k, v, causal=False, block_q=128, block_k=128,
     - cross-attention: pass `kv_mask` (B, Tk) for key/value padding and
       optionally `mask` (B, Tq) for query-row padding.
     Gradients flow to q/k/v only at valid positions.
+
+    FORWARD ONLY in two cases, which serving's prefill uses: V narrower or
+    wider than Q and K (latent attention's expanded form: keys of 192,
+    values of 128; the scale is the keys'), and `native=True`, where both
+    products take their operands as they come (bfloat16 through the MXU in
+    one pass, float32 sums) instead of as float32. A gradient taken through
+    either raises. `native` is a TEMPORARY fork (PERF.md section 7, ROADMAP
+    S10): it exists so that the other callers' programs stay as they were
+    measured, not because two numerics are wanted.
     """
     tq, tk = q.shape[2], k.shape[2]
     if causal and tq != tk:
@@ -473,6 +517,9 @@ def flash_attention(q, k, v, causal=False, block_q=128, block_k=128,
     if kv_mask is not None and kv_mask.shape[1] != tk:
         raise ValueError(
             f"kv_mask length {kv_mask.shape[1]} != Tk {tk}")
+    if native or v.shape[3] != q.shape[3]:
+        return _flash_forward_only(q, k, v, mask, kv_mask, causal, block_q,
+                                   block_k, interpret, native)
     return _flash_attention_vjp(q, k, v, mask, kv_mask, causal, block_q,
                                 block_k, interpret)
 

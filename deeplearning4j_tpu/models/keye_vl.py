@@ -40,6 +40,8 @@ from deeplearning4j_tpu.kernels.flash_attention import \
     flash_attention_selected
 from deeplearning4j_tpu.kernels.indexer import index_scores
 from deeplearning4j_tpu.kernels.selection import top_k_mask
+from deeplearning4j_tpu.models.decoder_common import (  # noqa: F401
+    MOE_CHUNK, logits, rms_norm)
 from deeplearning4j_tpu.parallel.moe import routed_experts
 
 #: query rows of a prefill whose index scores and selection exist at once
@@ -48,9 +50,6 @@ Q_BLOCK = 4096
 #: rows whose threshold one `top_k_mask` call finds (their scores' unsigned
 #: image is 8 MB at 16384 columns, which 32 counting passes can keep near)
 SELECT_ROWS = 128
-#: tokens one call of the expert layer takes: its pairs' tiled rows and
-#: results are (tokens x top_k) x hidden, a gigabyte at 16384 tokens
-MOE_CHUNK = 2048
 
 
 @dataclass(frozen=True)
@@ -187,12 +186,6 @@ def init_params(cfg, key):
 
 
 # -- pieces -----------------------------------------------------------------
-def rms_norm(x, weight, eps):
-    x32 = x.astype(jnp.float32)
-    y = x32 * lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (y * weight).astype(x.dtype)
-
-
 def layer_norm(x, weight, bias, eps):
     x32 = x.astype(jnp.float32)
     c = x32 - jnp.mean(x32, axis=-1, keepdims=True)
@@ -369,14 +362,6 @@ def encode(cfg, params, ids, positions=None, impl="auto", q_block=Q_BLOCK):
             x, state = apply_layer(cfg, layer, x, tables, impl, q_block)
         states.append(state)
     return x, states
-
-
-def logits(cfg, params, x):
-    """The final norm and the head over hidden rows `x` (..., H), float32."""
-    with jax.named_scope("logits"):
-        u = rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
-        return jnp.dot(u, params["head"].astype(u.dtype),
-                       preferred_element_type=jnp.float32)
 
 
 def forward(cfg, params, ids, positions=None, impl="auto", q_block=Q_BLOCK):

@@ -442,33 +442,15 @@ def test_sparse_attention_kernels_compile_for_v5e(compile_for_chip, fn,
     assert len(calls) == 1 and name in calls[0]
 
 
-def test_sparse_decode_superstep_gathers_no_rows_for_v5e(compile_for_chip,
-                                                         one_chip):
-    """`KeyeDecoder`'s superstep at the published widths (32 slots, a rung
-    of 18432 = 9 x `topk`, 2 layers) attends its kept rows where they lie:
-    the program holds no `(32, 2048, 512)` rung of gathered rows (XLA's row
-    gather was 19.3 of a 25.0 ms step on the chip: `PERF.md`, PR 36), no
-    temporary the size of a K leaf, and one `flash_fwd` a layer under the
-    scope the benchmark reads."""
-    import json
-
+def _compile_one_step_superstep(dec, params, cache, slots, one_chip):
+    """One decode step and its sampling as the server's superstep runs
+    them (`lax.scan`, the cache donated), compiled for the described chip
+    over shapes alone. The kernels and the expert layer ask
+    `jax.default_backend()`: steered from here, not through an option of
+    the program."""
     from jax import lax
 
-    from benchmarks.families.keye_vl_serve import model_config
-    from deeplearning4j_tpu.generation.decode import KeyeDecoder
     from deeplearning4j_tpu.generation.sampling import sample_step
-    from deeplearning4j_tpu.models import keye_vl
-
-    here = os.path.dirname(os.path.abspath(__file__))
-    with open(os.path.join(here, "..", "benchmarks", "configs",
-                           "keye_vl2_30b_a3b_ep8.json")) as f:
-        cfg = model_config({**json.load(f), "num_hidden_layers": 2},
-                           "bfloat16")
-    slots, rung = 32, 18432
-    params = jax.eval_shape(lambda k: keye_vl.init_params(cfg, k),
-                            jax.random.PRNGKey(0))
-    dec = KeyeDecoder(cfg, params, attn_impl="pallas")
-    cache = jax.eval_shape(lambda: dec.init_cache(slots, rung))
 
     def superstep(params, cache, tokens, pos, rng, method, temp, topk):
         def body(carry, _):
@@ -487,16 +469,43 @@ def test_sparse_decode_superstep_gathers_no_rows_for_v5e(compile_for_chip,
     args = on_chip((params, cache, slot, slot,
                     jax.ShapeDtypeStruct((slots, 2), jnp.uint32), slot,
                     jax.ShapeDtypeStruct((slots,), f32), slot))
-    # the kernels and the expert layer ask `jax.default_backend()`: steer
-    # it from here, not through an option of the program
     real = jax.default_backend
     jax.default_backend = lambda: "tpu"
     try:
         with jax.default_matmul_precision("default"):
-            compiled = jax.jit(superstep, donate_argnums=(1, 2, 3, 4)) \
+            return jax.jit(superstep, donate_argnums=(1, 2, 3, 4)) \
                 .lower(*args).compile()
     finally:
         jax.default_backend = real
+
+
+def test_sparse_decode_superstep_gathers_no_rows_for_v5e(compile_for_chip,
+                                                         one_chip):
+    """`KeyeDecoder`'s superstep at the published widths (32 slots, a rung
+    of 18432 = 9 x `topk`, 2 layers) attends its kept rows where they lie:
+    the program holds no `(32, 2048, 512)` rung of gathered rows (XLA's row
+    gather was 19.3 of a 25.0 ms step on the chip: `PERF.md`, PR 36), no
+    temporary the size of a K leaf, and one `flash_fwd` a layer under the
+    scope the benchmark reads."""
+    import json
+
+    from benchmarks.families.keye_vl_serve import model_config
+    from deeplearning4j_tpu.generation.decode import KeyeDecoder
+    from deeplearning4j_tpu.models import keye_vl
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "..", "benchmarks", "configs",
+                           "keye_vl2_30b_a3b_ep8.json")) as f:
+        cfg = model_config({**json.load(f), "num_hidden_layers": 2},
+                           "bfloat16")
+    slots, rung = 32, 18432
+    params = jax.eval_shape(lambda k: keye_vl.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    dec = KeyeDecoder(cfg, params, attn_impl="pallas")
+    cache = jax.eval_shape(lambda: dec.init_cache(slots, rung))
+
+    compiled = _compile_one_step_superstep(dec, params, cache, slots,
+                                           one_chip)
     text = compiled.as_text()
     gathered = [line.strip()[:160] for line in text.splitlines()
                 if re.search(r"bf16\[(32,2048,512|65536,512)\]", line)]
@@ -511,3 +520,79 @@ def test_sparse_decode_superstep_gathers_no_rows_for_v5e(compile_for_chip,
         assert sum(f"layer{li}/attn/flash_decode/flash_fwd" in c
                    and c.lstrip().lstrip("%").startswith("flash_fwd")
                    for c in calls) == 1, li
+
+
+# kanana-2-30b-a3b behind MLADecoder (PR 37): 32 heads over a latent of 512
+# and 64 rotary lanes, 48 slots at an 18432-row rung packed two positions a
+# row, prompts of 16384 expanded to keys of 192 and values of 128.
+def _mla_decode(q_lat, q_rope, leaf, n):
+    from deeplearning4j_tpu.kernels.mla_attention import \
+        mla_attention_decode
+    return mla_attention_decode(q_lat, q_rope, leaf, n, 192 ** -0.5,
+                                impl="pallas", interpret=False)
+
+
+def _mla_prefill(q, k, v):
+    from deeplearning4j_tpu.kernels import flash_attention
+    return flash_attention(q, k, v, causal=True, block_q=1024, block_k=1024,
+                           native=True, interpret=False)
+
+
+@pytest.mark.parametrize("fn,specs,name", [
+    (_mla_decode, (((48, 32, 512), bf16), ((48, 32, 64), bf16),
+                   ((48, 9216, 1152), bf16), ((48,), i32)), "mla_decode"),
+    (_mla_prefill, (((1, 32, 16384, 192), bf16), ((1, 32, 16384, 192), bf16),
+                    ((1, 32, 16384, 128), bf16)), "flash_fwd"),
+], ids=["absorbed_decode_over_the_packed_leaf",
+        "expanded_prefill_keys_192_values_128"])
+def test_latent_attention_kernels_compile_for_v5e(compile_for_chip, fn,
+                                                  specs, name):
+    """The kernels `MLADecoder` adds, at the published widths: the absorbed
+    decode over a `(48, 9216, 1152)` leaf in tiles of 512 packed rows under
+    the slots' lengths, and `flash_attention` with values narrower than
+    keys, bfloat16 operands as they come, in tiles of 1024."""
+    text = compile_for_chip(fn, *specs)
+    calls = [l for l in text.splitlines()
+             if "tpu_custom_call" in l and " custom-call(" in l]
+    assert len(calls) == 1 and name in calls[0]
+
+
+def test_latent_decode_superstep_reads_the_leaf_in_place_for_v5e(
+        compile_for_chip, one_chip):
+    """`MLADecoder`'s superstep at the published widths (48 slots, a rung
+    of 18432, the dense layer and one expert layer): one `mla_decode` a
+    layer under the scope the benchmark reads, the expert layer's
+    `grouped_mlp`, and no temporary the size of a latent leaf (1.02 GB):
+    the row write is in place and the kernel takes the leaf as it lies."""
+    import json
+
+    from benchmarks.families.deepseek_v3_serve import model_config
+    from deeplearning4j_tpu.generation.decode import MLADecoder
+    from deeplearning4j_tpu.models import deepseek_v3
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "..", "benchmarks", "configs",
+                           "kanana2_30b_a3b_ep8.json")) as f:
+        cfg = model_config({**json.load(f), "num_hidden_layers": 2},
+                           "bfloat16")
+    slots, rung = 48, 18432
+    params = jax.eval_shape(lambda k: deepseek_v3.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    dec = MLADecoder(cfg, params, attn_impl="pallas")
+    cache = jax.eval_shape(lambda: dec.init_cache(slots, rung))
+    assert [l.shape for l in cache["kv"]] == [(48, 9216, 1152)] * 2
+
+    compiled = _compile_one_step_superstep(dec, params, cache, slots,
+                                           one_chip)
+    text = compiled.as_text()
+    # 20 MB at 8 layers; a copy of one leaf would be 1019 MB
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+    assert not [l for l in text.splitlines()
+                if "bf16[48,9216,1152]" in l and " copy(" in l]
+    calls = [l for l in text.splitlines()
+             if "tpu_custom_call" in l and " custom-call(" in l]
+    for li in range(2):
+        assert sum(f"layer{li}/attn/flash_decode/mla_decode" in c
+                   for c in calls) == 1, li
+    assert sum("layer1/moe/experts/grouped_mlp" in c for c in calls) == 1
+    assert not any("layer0/moe" in c for c in calls)
