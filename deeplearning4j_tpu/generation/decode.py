@@ -15,7 +15,11 @@ Five adapters expose one contract to the GenerationServer:
   its slot position, writes its K/V row (S whole rows of H·Dh lanes),
   and attends the single query against the cached keys via
   `flash_attention_decode` (Pallas kernel on TPU, einsum elsewhere) —
-  O(C) work per token instead of the O(T²) full-sequence re-forward.
+  O(rows in use) work per token instead of the O(T²) full-sequence
+  re-forward: over the dense float cache `step` hands the kernel the
+  slots' rows in use (`lengths = min(pos + 1, C)`), and its grid holds
+  the tiles of each leaf that those rows reach, not the rung (the paged
+  and the int8 cache read as they did).
   `prefill` runs the causal full forward over a length-bucketed prompt
   and writes the whole `(P, H·Dh)` K/V block into the slot's cache rows
   in one shot.
@@ -295,9 +299,13 @@ class BertDecoder:
                            emb["ln_scale"], emb["ln_bias"],
                            self.cfg.layer_norm_eps)
 
-    def _decode_attn(self, q, kc, vc, cmask, ptab=None, ks=None, vs=None):
+    def _decode_attn(self, q, kc, vc, cmask, ptab=None, ks=None, vs=None,
+                     lengths=None):
         """One layer's decode attention over its cache leaves — through
-        the page index when `ptab` is given."""
+        the page index when `ptab` is given. `lengths` (S,): what `cmask`
+        says as a count, rows 0..lengths - 1 of a slot in use; handed to
+        the kernel over a dense float cache, which then reads a slot's
+        rows in use and not its rung."""
         impl = self.attn_impl
         if impl == "auto":
             # int8 cache: the quantized decode GEMV reads the cache at
@@ -310,8 +318,9 @@ class BertDecoder:
             return flash_attention_decode_paged(
                 q, kc, vc, ptab, cmask, impl=impl, k_scale_pool=ks,
                 v_scale_pool=vs)
-        return flash_attention_decode(q, kc, vc, cmask, impl=impl,
-                                      k_scale=ks, v_scale=vs)
+        return flash_attention_decode(
+            q, kc, vc, cmask, impl=impl, k_scale=ks, v_scale=vs,
+            lengths=lengths if ks is None else None)
 
     def _prefill_attn(self, q, k, v):
         if self.attn_impl == "pallas" or (
@@ -379,6 +388,7 @@ class BertDecoder:
         wi, wj, c = self._write_index(cache, pos, ptab)
         # rows 0..pos are valid (the current write included)
         cmask = jnp.arange(c)[None, :] <= pos[:, None]  # (S, C)
+        in_use = jnp.minimum(pos + 1, c)                # (S,)
         dt = x.dtype
         # the stages of a layer are named scopes (compile-time metadata:
         # a profiler trace attributes the step's device time to them)
@@ -395,7 +405,7 @@ class BertDecoder:
                         q.reshape(s, nh, hd), cache["k"][li],
                         cache["v"][li], cmask, ptab,
                         cache["ks"][li] if int8_kv else None,
-                        cache["vs"][li] if int8_kv else None)
+                        cache["vs"][li] if int8_kv else None, in_use)
                     ctx = ctx.astype(dt)
                 with jax.named_scope("proj"):
                     a = ctx.reshape(s, cfg.hidden_size) \

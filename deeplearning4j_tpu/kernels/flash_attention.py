@@ -751,16 +751,21 @@ def _flash_decode_kernel(*refs, scale, head_dim, group, ragged=False):
     group the query heads come in as the rows of a (Hp, D) block, repeated
     along the lanes once a KV head, and go out the same way.
 
-    `ragged`: the slots' lengths come first, as a scalar-prefetch operand,
-    and a tile wholly past its slot's length is not computed (nor fetched:
-    `_ragged_tile` names another block for it). The mask hides every row
-    at or past the length already (`flash_attention_decode` sees to it),
-    so skipping changes the time and nothing else."""
+    `ragged`: the grid is ONE axis over the tiles in use, slot by slot
+    (`_tiles_in_use`), whose three vectors come first as scalar-prefetch
+    operands: a grid step's slot, its tile of that slot, and a slot's
+    tiles in use. A tile wholly past its slot's length has no grid step,
+    so it is neither fetched nor computed nor paid a step for. The mask
+    hides every row at or past the length already
+    (`flash_attention_decode` sees to it), so the grid changes the time
+    and nothing else."""
     if ragged:
-        len_ref, *refs = refs
-        length = len_ref[pl.program_id(0)]
+        slot_ref, tile_ref, used_ref, *refs = refs
+        step = pl.program_id(0)
+        kj = tile_ref[step]
+    else:
+        kj = pl.program_id(1)
     q_ref, k_ref, v_ref, km_ref, o_ref, acc_ref, l_ref, m_ref = refs
-    kj = pl.program_id(1)
     hp, hd = acc_ref.shape
     row = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 0)
     lane = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 1)
@@ -774,33 +779,28 @@ def _flash_decode_kernel(*refs, scale, head_dim, group, ragged=False):
         l_ref[...] = jnp.zeros_like(l_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
 
-    def _tile():
-        q = q_ref[0].astype(jnp.float32) * scale
-        if group > 1:
-            q = jnp.concatenate([q] * (hd // head_dim), axis=1)
-        q = jnp.where(own, q, 0.0)
-        s = jax.lax.dot_general(
-            q, k_ref[0].astype(jnp.float32),
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)       # (Hp, block_k)
-        s = jnp.where(km_ref[0] > 0, s, _NEG_INF)     # (1, block_k) mask
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        m_ref[...] = m_new
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v_ref[0].astype(jnp.float32),
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # (Hp, Hkv·D)
+    q = q_ref[0].astype(jnp.float32) * scale
+    if group > 1:
+        q = jnp.concatenate([q] * (hd // head_dim), axis=1)
+    q = jnp.where(own, q, 0.0)
+    s = jax.lax.dot_general(
+        q, k_ref[0].astype(jnp.float32),
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)       # (Hp, block_k)
+    s = jnp.where(km_ref[0] > 0, s, _NEG_INF)     # (1, block_k) mask
+    m_prev, l_prev = m_ref[...], l_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    m_ref[...] = m_new
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p, v_ref[0].astype(jnp.float32),
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)       # (Hp, Hkv·D)
+    last = (used_ref[slot_ref[step]] if ragged else pl.num_programs(1)) - 1
 
-    if ragged:
-        pl.when(kj * k_ref.shape[1] < length)(_tile)
-    else:
-        _tile()
-
-    @pl.when(kj == pl.num_programs(1) - 1)
+    @pl.when(kj == last)
     def _finalize():
         o = jnp.where(own, acc_ref[...] / jnp.maximum(l_ref[...], 1e-30),
                       0.0)
@@ -814,38 +814,51 @@ def _flash_decode_kernel(*refs, scale, head_dim, group, ragged=False):
 
 
 #: tiles a decode call with `lengths` may read its rung in, largest first,
-#: and the bytes of K one may hold. Measured on a v5e at the sparse cell's
-#: shapes (32 slots of a `(18432, 512)` bfloat16 leaf, 9.6k rows in use a
-#: slot, `PERF.md` PR 36): 0.963, 0.940 and 0.961 ms a call at 512, 1024
-#: and 2048 rows. Small tiles pay a grid step for every tile skipped, large
-#: ones read more rows past the length; 1024 rows of 1 KB won
-_RAGGED_TILES = (2048, 1024, 512)
+#: the bytes of K one may hold, and the tiles a rung has at least. A slot
+#: reads its rows in use rounded UP to a tile, so a large tile reads rows
+#: past the length and a small one pays a grid step's fixed part more
+#: often. Measured on a v5e, device ms a call (`PERF.md` PR 38,
+#: `scripts/sweep_decode_tiles.py`): 32 slots of a `(18432, 512)` bfloat16
+#: leaf, 8.9k rows in use a slot: 0.800, 0.822, 0.862 at 512, 1024, 2048
+#: rows (1.597 the whole rung); 1024 stays, the tile `mla_decode` shares
+#: through `latent_tile_positions` and measured best for itself. 64 slots
+#: of a `(512, 768)` float32 leaf, 121 rows in use a slot (BERT-base's
+#: decode): 0.106, 0.139, 0.268 at 128, 256, 512 rows, and in bfloat16
+#: 0.076, 0.075, 0.135: on a short rung half a tile of rounding is most of
+#: what a slot reads, hence the share of the rung. 64 rows cannot be: the
+#: mask's block would be half a lane tile
+_RAGGED_TILES = (2048, 1024, 512, 256, 128)
 _RAGGED_TILE_BYTES = 1 << 20
+_RAGGED_RUNG_TILES = 4
 
 
 def decode_tile_rows(rung, lanes, dtype):
     """Cache rows a grid step of `flash_attention_decode(..., lengths=)`
     reads, from the call's shapes alone: the largest of `_RAGGED_TILES`
-    that divides the rung and whose K tile holds at most
-    `_RAGGED_TILE_BYTES`; a rung none of them divides is one whole tile."""
+    that divides the rung, whose K tile holds at most `_RAGGED_TILE_BYTES`
+    and of which the rung holds `_RAGGED_RUNG_TILES` or more (a slot reads
+    its rows in use rounded UP to a tile, so the tile is held to a share of
+    what a slot may hold); a rung none of them fits is one whole tile."""
     row = lanes * jnp.dtype(dtype).itemsize
     fits = [t for t in _RAGGED_TILES
-            if rung % t == 0 and t * row <= _RAGGED_TILE_BYTES]
+            if rung % t == 0 and t * row <= _RAGGED_TILE_BYTES
+            and t * _RAGGED_RUNG_TILES <= rung]
     return fits[0] if fits else rung
 
 
-def _ragged_tile(i, j, lengths, block_k):
-    """(slot, tile) of the K, V and mask block that grid step (i, j)
-    names: tile j up to the slot's last tile in use, `(max(lengths[i], 1) -
-    1) // block_k`; past it, the NEXT slot's first tile. A block index that
-    does not change is not copied again, so the tiles wholly past a slot's
-    rows are never fetched, and the next slot's first tile arrives under
-    this slot's last compute instead of after its skipped steps (0.966 ->
-    0.940 ms a call against naming the last tile in use again)."""
-    past = j > (jnp.maximum(lengths[i], 1) - 1) // block_k
-    last_slot = lengths.shape[0] - 1
-    return (jnp.where(past, jnp.minimum(i + 1, last_slot), i),
-            jnp.where(past, 0, j))
+def _tiles_in_use(lengths, block_k, tiles):
+    """The ragged decode grid, from the slots' `lengths` (B,) over a rung of
+    `tiles` tiles of `block_k` rows: a slot takes the tiles that hold its
+    rows in use, and at least one (its output block is written on its last
+    step). -> for every grid step there may be, `B x tiles` of them, its
+    slot and its tile of that slot; a slot's tiles in use (B,); and the
+    grid's extent, their sum. Steps at or past the extent are never run."""
+    used = jnp.clip(-(-lengths // block_k), 1, tiles).astype(jnp.int32)
+    ends = jnp.cumsum(used)
+    step = jnp.arange(lengths.shape[0] * tiles, dtype=jnp.int32)
+    slot = jnp.minimum((step[:, None] >= ends[None, :]).sum(
+        axis=1, dtype=jnp.int32), lengths.shape[0] - 1)
+    return slot, step - (ends - used)[slot], used, ends[-1]
 
 
 def _flash_decode(q, k_cache, v_cache, cache_mask, block_k, interpret,
@@ -853,9 +866,11 @@ def _flash_decode(q, k_cache, v_cache, cache_mask, block_k, interpret,
     """q (B, Hq, 1, D) against (B, C, Hkv·D) caches through the Pallas
     kernel; returns (B, Hq, 1, D). The caches go in as they are: no pad,
     no reshape — a rung the k tile does not divide is one whole tile.
-    With `lengths` (B,) int32, past which the mask holds nothing, the
-    grid is the same and the lengths go in first, as a scalar-prefetch
-    operand (`_flash_decode_kernel`)."""
+    Without `lengths` the grid is (slot, k tile) over the whole rung. With
+    `lengths` (B,) int32, past which the mask holds nothing, it is one
+    axis over the tiles in use (`_tiles_in_use`), its extent read from the
+    lengths when the call runs, and the step's slot and tile go in first,
+    as scalar-prefetch operands (`_flash_decode_kernel`)."""
     b, h, _, d = q.shape
     c, hd = k_cache.shape[1], k_cache.shape[2]
     group = h // (hd // d)
@@ -865,19 +880,30 @@ def _flash_decode(q, k_cache, v_cache, cache_mask, block_k, interpret,
         block_k = c
     hp = -(-h // 8) * 8            # float32 sublane tile of the scores
     ragged = lengths is not None
+    if ragged:
+        slot, tile, used, extent = _tiles_in_use(lengths, block_k,
+                                                 c // block_k)
+        grid, semantics = (extent,), ("arbitrary",)
+        prefetch = (slot, tile, used)
 
-    def tile_at(i, j, *n):         # *n: the lengths, where prefetched
-        return _ragged_tile(i, j, n[0], block_k) if n else (i, j)
+        def tile_at(g, slot_ref, tile_ref, _):
+            return slot_ref[g], tile_ref[g]
+    else:
+        grid, semantics = (b, c // block_k), ("parallel", "arbitrary")
+        prefetch = ()
 
-    def row_at(i, j, *n):
-        return i, 0, 0
+        def tile_at(i, j):
+            return i, j
 
-    def mask_at(i, j, *n):
-        slot, tile = tile_at(i, j, *n)
-        return slot, 0, tile
+    def row_at(*at):
+        return tile_at(*at)[0], 0, 0
+
+    def mask_at(*at):
+        i, j = tile_at(*at)
+        return i, 0, j
 
     cache_spec = pl.BlockSpec((1, block_k, hd),
-                              lambda i, j, *n: (*tile_at(i, j, *n), 0))
+                              lambda *at: (*tile_at(*at), 0))
     if group > 1:                  # the query heads as rows, padded to Hp
         q_rows = jnp.pad(q[:, :, 0, :], ((0, 0), (0, hp - h), (0, 0)))
         row_spec = pl.BlockSpec((1, hp, d), row_at)
@@ -887,7 +913,7 @@ def _flash_decode(q, k_cache, v_cache, cache_mask, block_k, interpret,
         row_spec = pl.BlockSpec((1, 1, hd), row_at)
         out_shape = (b, 1, hd)
     spec = dict(
-        grid=(b, c // block_k),
+        grid=grid,
         in_specs=[row_spec, cache_spec, cache_spec,
                   pl.BlockSpec((1, 1, block_k), mask_at)],
         out_specs=row_spec,
@@ -896,13 +922,10 @@ def _flash_decode(q, k_cache, v_cache, cache_mask, block_k, interpret,
             pltpu.VMEM((hp, 1), jnp.float32),
             pltpu.VMEM((hp, 1), jnp.float32),
         ])
-    operands = (q_rows, k_cache, v_cache,
-                cache_mask.astype(jnp.int32)[:, None, :])
     limits = {}
     if ragged:
-        operands = (lengths.astype(jnp.int32),) + operands
         spec = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, **spec))
+            num_scalar_prefetch=len(prefetch), **spec))
         # the K and the V tile twice (the pipeline's two buffers) and their
         # float32 images in the body; twice that for the rest and for room
         vmem = 2 * block_k * hd * (4 * k_cache.dtype.itemsize + 8)
@@ -913,10 +936,11 @@ def _flash_decode(q, k_cache, v_cache, cache_mask, block_k, interpret,
         **spec,
         out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"), **limits),
+            dimension_semantics=semantics, **limits),
         interpret=interpret,
         name="flash_fwd",
-    )(*operands)
+    )(*prefetch, q_rows, k_cache, v_cache,
+      cache_mask.astype(jnp.int32)[:, None, :])
     if group > 1:
         out = out[:, :h]
     # a slot with NO valid cache row has no defined softmax: zeros
@@ -992,7 +1016,8 @@ def flash_attention_decode(q1, k_cache, v_cache, cache_mask, impl="auto",
       (force kernel; interpret-mode off-TPU), or 'dense'
     - block_k: cache rows a kernel grid step reads (a rung it does not
       divide is read as one tile); by default 512, and with `lengths`
-      what `decode_tile_rows` gives for the call's shapes
+      what `decode_tile_rows` gives for the call's shapes: 1024 rows of
+      a long bfloat16 rung, a quarter of a short one
     - k_scale / v_scale: (B, C, Hkv) float32 per-head row scales of an
       int8-quantized cache (quantize/kvcache.py). When given, the
       dequant happens INSIDE the attention contractions — the single-
@@ -1004,11 +1029,13 @@ def flash_attention_decode(q1, k_cache, v_cache, cache_mask, impl="auto",
       has no slot for yet.)
     - lengths: (B,) int32, optional: rows 0..lengths[b] - 1 of slot b are
       in use. A row at or past its slot's length is masked whatever
-      `cache_mask` says, and the kernel neither fetches nor computes a
-      tile wholly past it: a selection mask over a long rung (the rows a
-      sparse-attention indexer keeps) costs the rows in use, rounded up
-      to a tile, not the rung. `lengths[b] == 0` gives zeros. Without it
-      the call is what it was (not given with an int8 cache)
+      `cache_mask` says, and the kernel's grid holds the tiles in use
+      and no other (`_tiles_in_use`): a slot costs its rows in use,
+      rounded up to a tile, not its rung — a decoder's slots part-way
+      up a rung, or a selection mask over a long one (the rows a
+      sparse-attention indexer keeps). `lengths[b] == 0` gives zeros.
+      Without it the kernel reads every slot's whole rung, the call it
+      was (not given with an int8 cache)
     Forward-only (decode never backprops). Rows whose mask has NO valid
     cache entry return zeros. Returns the same rank as q1.
     """

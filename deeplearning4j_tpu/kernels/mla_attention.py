@@ -29,7 +29,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deeplearning4j_tpu.kernels.flash_attention import (_NEG_INF,
-                                                        _ragged_tile,
                                                         decode_tile_rows)
 
 
@@ -75,6 +74,22 @@ def latent_tile_positions(rung, lat, dtype):
     `PERF.md`, PR 37): 1.632, 1.156, 0.913 and 0.924 ms a call at 256, 512,
     1024 and 2048 positions."""
     return decode_tile_rows(rung, lat, dtype)
+
+
+def _ragged_tile(i, j, lengths, block_k):
+    """(slot, tile) of the K, V and mask block that grid step (i, j)
+    names: tile j up to the slot's last tile in use, `(max(lengths[i], 1) -
+    1) // block_k`; past it, the NEXT slot's first tile. A block index that
+    does not change is not copied again, so the tiles wholly past a slot's
+    rows are never fetched, and the next slot's first tile arrives under
+    this slot's last compute instead of after its skipped steps (0.966 ->
+    0.940 ms a call of `flash_fwd` against naming the last tile in use
+    again, PR 36; since PR 38 that kernel's grid holds no skipped step at
+    all, `flash_attention._tiles_in_use`, a form this kernel could take)."""
+    past = j > (jnp.maximum(lengths[i], 1) - 1) // block_k
+    last_slot = lengths.shape[0] - 1
+    return (jnp.where(past, jnp.minimum(i + 1, last_slot), i),
+            jnp.where(past, 0, j))
 
 
 def _mla_decode_kernel(len_ref, ql_ref, qr_ref, c_ref, o_ref, acc_ref, l_ref,

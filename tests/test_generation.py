@@ -283,6 +283,49 @@ def test_bert_kv_decode_first_step_matches_full_forward(bert):
                                atol=1e-5, rtol=1e-5)
 
 
+def test_bert_step_under_the_kernel_reads_the_rows_in_use():
+    """`BertDecoder.step` hands the slots' rows in use to the decode
+    kernel (`attn_impl="pallas"`, interpret mode here), which reads a
+    rung in the tiles `decode_tile_rows` gives: ten steps, token for
+    token and logit for logit against the dense implementation, with
+    slots at unequal positions (one crosses a tile's edge, one the old
+    rung's end after a `grow`, one holds nothing) over a cache whose rows
+    past each position hold large garbage."""
+    from deeplearning4j_tpu.kernels.flash_attention import decode_tile_rows
+    cfg = bert_tiny(max_position_embeddings=1024)
+    params = init_bert_params(cfg, jax.random.PRNGKey(2))
+    kernel, dense = (BertDecoder(cfg, params, attn_impl=impl)
+                     for impl in ("pallas", "dense"))
+    tile = decode_tile_rows(512, cfg.hidden_size, cfg.compute_dtype)
+    assert tile == 128 and decode_tile_rows(
+        1024, cfg.hidden_size, cfg.compute_dtype) == 256
+    pos = np.array([tile - 4, 3, 0, 505], np.int32)
+    rng = np.random.default_rng(5)
+    held = np.arange(512)[None, :, None] < pos[:, None, None]
+    cache = jax.tree_util.tree_map(
+        lambda l: jnp.asarray(np.where(held, rng.normal(size=l.shape), 3e4),
+                              l.dtype), dense.init_cache(4, 512))
+    caches = {kernel: cache, dense: cache}
+    steps = {dec: jax.jit(dec.step) for dec in caches}
+    tokens = np.array([5, 9, 0, 17], np.int32)
+    for i in range(10):
+        if i == 5:      # slot 3 stands at 510 of 512
+            caches = {dec: dec.grow(c, 1024) for dec, c in caches.items()}
+        logits = {}
+        for dec in caches:
+            logits[dec], caches[dec] = steps[dec](
+                dec.model_args(), caches[dec], tokens, pos + i)
+        np.testing.assert_allclose(np.asarray(logits[kernel]),
+                                   np.asarray(logits[dense]), atol=2e-5,
+                                   rtol=0, err_msg=f"step {i}")
+        chosen = {dec: np.asarray(jnp.argmax(lg, axis=-1))
+                  for dec, lg in logits.items()}
+        np.testing.assert_array_equal(chosen[kernel], chosen[dense])
+        tokens = chosen[dense].astype(np.int32)
+    # slot 0 crossed the first rung's tile edge, slot 3 the second's
+    assert (pos + 10).tolist() == [tile + 6, 13, 10, 2 * 256 + 3]
+
+
 @pytest.mark.slow   # suite diet (ISSUE 18): ~17 s — four growing-length
 # encode recompiles; prefill + first-step exactness stays tier-1 via
 # test_bert_kv_decode_first_step_matches_full_forward

@@ -235,20 +235,22 @@ def test_selected_attention_kernel_matches_masked_softmax(tq, tk, offset,
                                p @ v[:, kvh * d:(kvh + 1) * d], atol=2e-6)
 
 
-def _ragged_case(dtype, hq, hkv, d, c, seed):
-    """Four slots of a rung: lengths 1, the whole rung, one that no tile
-    divides, and 0; a random selection of the rows in use as the mask; and
-    large finite garbage in every row at or past a slot's length, set in
-    the mask too, so that only `lengths` hides it."""
+def _ragged_case(dtype, hq, hkv, d, c, seed, lengths=None):
+    """Slots of a rung, by default four: lengths 1, the whole rung, one
+    that no tile divides, and 0; a random selection of the rows in use as
+    the mask; and large finite garbage in every row at or past a slot's
+    length, set in the mask too, so that only `lengths` hides it."""
     rng = np.random.default_rng(seed)
-    lengths = np.array([1, c, c // 2 + 77, 0], np.int32)
+    lengths = np.array([1, c, c // 2 + 77, 0] if lengths is None
+                       else lengths, np.int32)
+    slots = len(lengths)
     in_use = np.arange(c)[None, :] < lengths[:, None]
-    q = jnp.asarray(rng.normal(size=(4, hq, d)), dtype)
-    k, v = (jnp.asarray(np.where(in_use[..., None],
-                                 rng.normal(size=(4, c, hkv * d)), 3e4), dtype)
-            for _ in range(2))
-    selected = in_use & (rng.random((4, c)) < 0.3)
-    selected[[0, 1, 2], [0, c - 1, c // 2]] = True     # none is empty
+    q = jnp.asarray(rng.normal(size=(slots, hq, d)), dtype)
+    k, v = (jnp.asarray(np.where(in_use[..., None], rng.normal(
+        size=(slots, c, hkv * d)), 3e4), dtype) for _ in range(2))
+    selected = in_use & (rng.random((slots, c)) < 0.3)
+    live = np.flatnonzero(lengths)
+    selected[live, lengths[live] - 1] = True           # none is empty
     return q, k, v, selected, jnp.asarray(lengths)
 
 
@@ -289,6 +291,55 @@ def test_decode_kernel_under_lengths_matches_masked_softmax(dtype, hq, hkv,
         fa.flash_attention_decode(q, k, v, selected, lengths=lengths[:3])
 
 
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["float32", "bfloat16"])
+def test_decode_kernel_reads_a_short_rung_in_the_tiles_in_use(dtype, tol,
+                                                              monkeypatch):
+    """Plain multi-head attention over BERT's kind of rung (512 rows, as
+    many cache heads as query heads, every row in use up to the length
+    attended): slots that hold nothing, one row, a row short of a tile, a
+    tile, a row more, and the rung. The grid is the tiles in use and no
+    more; what the garbage past a length holds leaves no trace."""
+    hq = hkv = 12
+    d, c = 16, 512
+    tile = fa.decode_tile_rows(c, hkv * d, dtype)
+    assert 128 <= tile < c and c % tile == 0
+    lens = [0, 1, tile - 1, tile, tile + 1, c]
+    q, k, v, _, lengths = _ragged_case(dtype, hq, hkv, d, c, 38, lens)
+    in_use = jnp.arange(c)[None, :] < lengths[:, None]
+    want = fa._masked_attend(q[:, :, None], k, v, in_use[:, None, :])[:, :, 0]
+    grids = []
+    real = fa.pl.pallas_call
+    monkeypatch.setattr(fa.pl, "pallas_call", lambda kernel, **kw: (
+        grids.append(kw["grid_spec"].grid), real(kernel, **kw))[1])
+    everything = jnp.ones((len(lens), c), bool)        # the garbage "valid"
+    for mask in (in_use, everything):
+        for impl in ("pallas", "dense"):
+            got = fa.flash_attention_decode(q, k, v, mask, impl=impl,
+                                            interpret=True, lengths=lengths)
+            np.testing.assert_allclose(np.asarray(got, np.float32),
+                                       np.asarray(want, np.float32),
+                                       atol=tol, rtol=0)
+            assert not np.asarray(got[0], np.float32).any()
+    # 1 + 1 + 1 + 1 + 2 tiles and the rung's: a slot that holds nothing
+    # still takes one step, which writes its zeros
+    assert [int(g[0]) for g in grids] == [6 + c // tile] * 2
+
+
+def test_tiles_in_use_lays_the_slots_end_to_end():
+    """`_tiles_in_use`: a step's slot and tile, slot by slot, each slot its
+    rows in use rounded up to a tile and at least one; the steps past the
+    extent (never run) name the last slot."""
+    slot, tile, used, extent = fa._tiles_in_use(
+        jnp.asarray([0, 300, 128, 512, 129], jnp.int32), 128, 4)
+    assert int(extent) == 1 + 3 + 1 + 4 + 2 and slot.shape == (20,)
+    assert used.tolist() == [1, 3, 1, 4, 2]
+    assert slot[:11].tolist() == [0, 1, 1, 1, 2, 3, 3, 3, 3, 4, 4]
+    assert tile[:11].tolist() == [0, 0, 1, 2, 0, 0, 1, 2, 3, 0, 1]
+    assert set(slot[11:].tolist()) == {4}
+
+
 @pytest.mark.parametrize("dtype,hq,hkv,d,c", [
     (jnp.bfloat16, 32, 4, 128, 1024), (jnp.float32, 4, 4, 16, 512)],
     ids=["grouped_query_bfloat16", "multi_head_float32"])
@@ -312,7 +363,10 @@ def test_decode_kernel_without_lengths_is_the_call_it_was(dtype, hq, hkv, d,
     assert was["grid"] == (4, c // 512) and "grid_spec" not in was
     assert len(was["in_specs"]) == 4
     assert was["compiler_params"].vmem_limit_bytes is None
-    assert now["grid_spec"].num_scalar_prefetch == 1 and "grid" not in now
+    # with lengths: one axis over the tiles in use, the step's slot and
+    # tile and a slot's tiles scalar-prefetched
+    assert now["grid_spec"].num_scalar_prefetch == 3 and "grid" not in now
+    assert int(now["grid_spec"].grid[0]) == 4 * (c // 512)
     np.testing.assert_array_equal(np.asarray(plain, np.float32),
                                   np.asarray(ragged, np.float32))
 
@@ -328,8 +382,33 @@ def test_the_rule_reads_in_place_up_to_twelve_times_topk():
     assert decode._attends_in_place(64, 16) \
         and not decode._attends_in_place(256, 16)
     # and the tile the kernel reads that rung in comes from its shapes
-    assert fa.decode_tile_rows(18432, 512, jnp.bfloat16) in (512, 1024, 2048)
+    assert fa.decode_tile_rows(18432, 512, jnp.bfloat16) == 1024
     assert fa.decode_tile_rows(64, 32, jnp.float32) == 64
+
+
+@pytest.mark.parametrize("rung,lanes,dtype,tile", [
+    (18432, 512, jnp.bfloat16, 1024),      # Keye's leaves: 1 MiB of K
+    (512, 768, jnp.float32, None),         # BERT-base's: under the rung
+    (512, 768, jnp.bfloat16, None),
+    (48, 768, jnp.float32, 48),            # rungs no tile divides: whole
+    (32, 768, jnp.float32, 32),
+    (70, 32, jnp.float32, 70),
+    (128, 768, jnp.float32, 128)],         # and one that IS the least tile
+    ids=["keye", "bert_float32", "bert_bfloat16", "rung_48", "rung_32",
+         "rung_70", "rung_128"])
+def test_the_tile_comes_from_the_rung_the_lanes_and_the_dtype(rung, lanes,
+                                                              dtype, tile):
+    """`decode_tile_rows`: one rule over the call's static shapes. A long
+    rung takes the largest tile under 1 MiB of K; a short one a share of
+    itself (a slot reads its rows in use rounded UP to a tile: at 127 rows
+    in use of 512, a tile of 512 is the whole loss); a rung that no tile
+    divides, or that holds too few, is one tile."""
+    got = fa.decode_tile_rows(rung, lanes, dtype)
+    assert rung % got == 0
+    if tile is None:
+        assert 128 <= got < rung and got % 128 == 0
+    else:
+        assert got == tile
 
 
 # -- the decoder: prefill, then decode through the three leaves ---------------

@@ -314,7 +314,10 @@ def test_decode_superstep_keeps_the_cache_in_place_for_v5e(
     """The cell's decode program moves no cache: every `(S, C, H·Dh)` leaf
     has one layout at entry, in the row write, in the kernel and at exit
     (a cache-sized copy or slice costs 0.7 ms a leaf a step on the chip:
-    `PERF.md`, PR 27)."""
+    `PERF.md`, PR 27). And each layer's kernel reads the rows in use, not
+    the rung (PR 38): it takes the slots' lengths as a grid over the tiles
+    in use, of `decode_tile_rows(512, 768, dtype)` rows each."""
+    from deeplearning4j_tpu.kernels.flash_attention import decode_tile_rows
     compiled, leaf = superstep_for_v5e(dtype)
     text = compiled.as_text()
     moved = []
@@ -337,6 +340,76 @@ def test_decode_superstep_keeps_the_cache_in_place_for_v5e(
                    and c.lstrip().lstrip("%").startswith("flash_fwd")
                    for c in calls) == 1, li
         assert f"layer{li}/kv_write" in text
+    # the call's operands, as the compiled program lays them out: the
+    # grid's extent, a step's slot and its tile for every step there may
+    # be (slots x rung / tile of them), a slot's tiles in use, then q, the
+    # K and V leaves whole and the mask
+    tile = decode_tile_rows(_RUNG, 768, jnp.dtype(dtype))
+    assert 128 <= tile < _RUNG
+    steps = _SLOTS * _RUNG // tile
+    short = {"float32": "f32", "bfloat16": "bf16"}[dtype]
+    want = [r"s32\[\]", rf"s32\[{steps}\]", rf"s32\[{steps}\]",
+            rf"s32\[{_SLOTS}\]", rf"{short}\[{_SLOTS},1,768\]",
+            rf"{short}\[{_SLOTS},{_RUNG},768\]",
+            rf"{short}\[{_SLOTS},{_RUNG},768\]",
+            rf"s32\[{_SLOTS},1,{_RUNG}\]"]
+    for call in calls:
+        operands = re.search(r"operand_layout_constraints=\{(.*?)\}, \w+=",
+                             call).group(1)
+        got = re.split(r", (?=\w+\[)", operands)
+        assert len(got) == len(want) and all(
+            re.match(w, g) for w, g in zip(want, got)), operands
+
+
+def test_hybrid_superstep_is_the_program_it_was_for_v5e(compile_for_chip,
+                                                        one_chip):
+    """`NemotronHDecoder.step` calls the decode kernel WITHOUT lengths, and
+    what PR 38 did to the kernel under lengths left that call alone: a
+    toy-size superstep lowered for the described chip is, outside its one
+    kernel, the text it was, and the kernel's Mosaic module, printed
+    without source locations, the module it was (both as sha256 of the
+    parent commit's; a PR that changes the hybrid's step or the kernel's
+    plain form on purpose prints the new ones from here)."""
+    import base64
+    import hashlib
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    from deeplearning4j_tpu.generation.decode import NemotronHDecoder
+    from deeplearning4j_tpu.models import nemotron_h as nh
+
+    cfg = nh.NemotronHConfig.from_dict(dict(
+        vocab_size=96, hidden_size=64, hybrid_override_pattern="*EMEM",
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        mamba_num_heads=8, mamba_head_dim=8, n_groups=2, ssm_state_size=16,
+        conv_kernel=4, chunk_size=8, n_routed_experts=16,
+        num_experts_per_tok=4, moe_latent_size=32, moe_intermediate_size=84,
+        moe_shared_expert_intermediate_size=84, routed_scaling_factor=5,
+        norm_eps=1e-5, time_step_min=0.001, time_step_max=0.1,
+        time_step_floor=1e-4, num_hidden_layers=5,
+        held={"pattern": "*EMEM", "experts": [0, 16]}))
+    slots, rung = 4, 1024
+    params = jax.eval_shape(lambda k: nh.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    dec = NemotronHDecoder(cfg, params, attn_impl="pallas")
+    cache = jax.eval_shape(lambda: dec.init_cache(slots, rung))
+
+    text = _lower_one_step_superstep(dec, params, cache, slots,
+                                     one_chip).as_text()
+    body = r'\\22body\\22: \\22([^\\]*)\\22'
+    kernel, = re.findall(body, text)
+    context = jax_mlir.make_ir_context()
+    context.allow_unregistered_dialects = True
+    with context:
+        module = ir.Module.parse(base64.b64decode(kernel)) \
+            .operation.get_asm(enable_debug_info=False)
+
+    def digest(s):
+        return hashlib.sha256(s.encode()).hexdigest()[:16]
+
+    got = (digest(re.sub(body, "", text)), digest(module))
+    assert got == ("fc77e0b076917756", "9c4683b0a83dbc25"), got
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -442,9 +515,9 @@ def test_sparse_attention_kernels_compile_for_v5e(compile_for_chip, fn,
     assert len(calls) == 1 and name in calls[0]
 
 
-def _compile_one_step_superstep(dec, params, cache, slots, one_chip):
+def _lower_one_step_superstep(dec, params, cache, slots, one_chip):
     """One decode step and its sampling as the server's superstep runs
-    them (`lax.scan`, the cache donated), compiled for the described chip
+    them (`lax.scan`, the cache donated), lowered for the described chip
     over shapes alone. The kernels and the expert layer ask
     `jax.default_backend()`: steered from here, not through an option of
     the program."""
@@ -474,9 +547,13 @@ def _compile_one_step_superstep(dec, params, cache, slots, one_chip):
     try:
         with jax.default_matmul_precision("default"):
             return jax.jit(superstep, donate_argnums=(1, 2, 3, 4)) \
-                .lower(*args).compile()
+                .lower(*args)
     finally:
         jax.default_backend = real
+
+
+def _compile_one_step_superstep(*args):
+    return _lower_one_step_superstep(*args).compile()
 
 
 def test_sparse_decode_superstep_gathers_no_rows_for_v5e(compile_for_chip,
