@@ -62,11 +62,20 @@ serialized — a restarted replica warms from disk):
 - ``("page_copy",)`` — copy one physical KV page pool→pool (paged
   servers only: the copy-on-write primitive behind prefix sharing).
 
+Host operands: superstep, verify and admit each take their per-call
+host values as ONE int32 numpy array after the model arguments and the
+donated state (`pack_admit`'s header + prompt + write row; the block's
+eos / budget / draft / page-table columns), unpacked in the program by
+static slices and bitcasts. jax's call path costs about the same for
+every numpy operand whatever its size (0.13-0.2 ms each on a v5e host),
+so one array instead of seven takes about a millisecond off an admit.
+
 Paged KV mode (decoder built with ``page_size``/``pool_pages``): the
 cache is a fixed pool of physical pages instead of S contiguous rung
 rows, and every executable additionally threads a host-built page
 index — superstep/verify take the ``(S, rung // page_size)`` int32 page
-table, admit takes the per-logical-page write-redirect row. The pool is
+table, admit takes the per-logical-page write-redirect row, each as
+columns of the program's one host operand (below). The pool is
 RUNG-INDEPENDENT, so ``grow`` degenerates to a host-side rung relabel
 (no dispatch, no per-rung-pair executables); the rung only sets the
 page-table width the dispatch reads through. Between dispatches the
@@ -156,6 +165,48 @@ _SERVERS = weakref.WeakSet()
 
 #: decode-state tuple layout (everything donated through each step)
 _CACHE, _POS, _ACTIVE, _TOKENS, _RNG, _METHOD, _TEMP, _TOPK = range(8)
+
+#: the admit's ONE host operand (`pack_admit`), int32: a header of eight
+#: words (slot, plen, the key's two words, method, the temperature's
+#: bits, top-k, a pad word), the prompt padded to its bucket, then in
+#: paged mode the prefill's write-redirect row
+_A_SLOT, _A_PLEN, _A_KEY, _A_METHOD, _A_TEMP, _A_TOPK = 0, 1, 2, 4, 5, 6
+ADMIT_HEADER = 8
+#: columns of the superstep's and verify's ONE host operand, int32
+#: (S, w): eos, budget, then (verify) dlen and the draft, then (paged)
+#: the page table from `_page_col()` on
+_C_EOS, _C_BUDGET, _C_DLEN, _C_DRAFT = 0, 1, 2, 3
+
+
+def pack_admit(slot, plen, key, method, temperature, top_k, prompt,
+               pbucket, wrow=None):
+    """The admit's values as ONE int32 array (layout at `ADMIT_HEADER`).
+    The key's uint32 words and the temperature's float32 bits are laid
+    in as they are, so `unpack_admit` hands the program the very bits
+    it had as separate operands. `key` is a host uint32 (2,) array."""
+    width = ADMIT_HEADER + pbucket + (0 if wrow is None else len(wrow))
+    buf = np.zeros((width,), np.int32)
+    buf[_A_SLOT] = slot
+    buf[_A_PLEN] = plen
+    buf[_A_KEY:_A_KEY + 2] = key.view(np.int32)
+    buf[_A_METHOD] = method
+    buf[_A_TEMP] = np.float32(temperature).view(np.int32)
+    buf[_A_TOPK] = top_k
+    buf[ADMIT_HEADER:ADMIT_HEADER + len(prompt)] = prompt
+    if wrow is not None:
+        buf[ADMIT_HEADER + pbucket:] = wrow
+    return buf
+
+
+def unpack_admit(packed, pbucket):
+    """Traced inverse of `pack_admit` by static slices: (slot, prompt,
+    plen, key, method, temperature, top_k, wrow or None)."""
+    h = ADMIT_HEADER
+    key = lax.bitcast_convert_type(packed[_A_KEY:_A_KEY + 2], jnp.uint32)
+    temp = lax.bitcast_convert_type(packed[_A_TEMP], jnp.float32)
+    wrow = packed[h + pbucket:] if packed.shape[0] > h + pbucket else None
+    return (packed[_A_SLOT], packed[h:h + pbucket], packed[_A_PLEN], key,
+            packed[_A_METHOD], temp, packed[_A_TOPK], wrow)
 
 
 class GenerationRequest:
@@ -537,40 +588,23 @@ class GenerationServer:
         self._margs = tuple(self.decoder.model_args())
         sds = jax.ShapeDtypeStruct
         scalar_i = sds((), jnp.int32)
-        scalar_f = sds((), jnp.float32)
-        slot_i = sds((self.slots,), jnp.int32)
         for ci, rung in enumerate(self.cache_lengths):
             spec = self._state_spec(rung)
             margs_spec = jax.tree_util.tree_map(
                 lambda l: sds(jnp.shape(l), jnp.result_type(l)),
                 self._margs)
-            # paged mode threads the page table through every decode
-            # dispatch; its width is the rung's page count
-            ptab = ((sds((self.slots,
-                          rung // self.decoder.page_size), jnp.int32),)
-                    if self.paged else ())
-            if self.draft:
-                key = ("verify", rung, self.draft)
-                e = store.load_or_compile(
-                    key, (*margs_spec, *spec, slot_i, slot_i,
-                          sds((self.slots, self.draft), jnp.int32),
-                          slot_i, *ptab))
-            else:
-                key = ("superstep", rung, self.superstep)
-                e = store.load_or_compile(
-                    key, (*margs_spec, *spec, slot_i, slot_i, *ptab))
+            host = sds((self.slots, self._step_width(rung)), jnp.int32)
+            key = (("verify", rung, self.draft) if self.draft
+                   else ("superstep", rung, self.superstep))
+            e = store.load_or_compile(key, (*margs_spec, *spec, host))
             self._exes[key] = e.call
             for p in self.prompt_buckets:
                 if p > rung:
                     continue
-                wrow = ((sds((-(-p // self.decoder.page_size),),
-                             jnp.int32),) if self.paged else ())
                 key = ("admit", rung, p)
                 e = store.load_or_compile(
-                    key, (*margs_spec, *spec, scalar_i,
-                          sds((p,), jnp.int32), scalar_i,
-                          sds((2,), jnp.uint32), scalar_i, scalar_f,
-                          scalar_i, *wrow))
+                    key, (*margs_spec, *spec,
+                          sds((self._admit_width(p),), jnp.int32)))
                 self._exes[key] = e.call
             if self.paged:
                 # the pool is rung-independent: growth is a host-side
@@ -620,6 +654,32 @@ class GenerationServer:
         n = len(tuple(self.decoder.model_args()))
         return tuple(range(n, n + 8))
 
+    def _page_col(self):
+        """First page-table column of a decode block's host operand."""
+        return _C_DRAFT + self.draft if self.draft else _C_DLEN
+
+    def _step_width(self, rung):
+        """Columns of a decode block's host operand at `rung`."""
+        return self._page_col() + (rung // self.decoder.page_size
+                                   if self.paged else 0)
+
+    def _admit_width(self, pbucket):
+        """Length of an admit's host operand at prompt bucket `pbucket`."""
+        w = ADMIT_HEADER + pbucket
+        return w + (-(-pbucket // self.decoder.page_size) if self.paged
+                    else 0)
+
+    def _host_columns(self, host):
+        """A decode block's host operand, sliced back into (eos, budget,
+        dlen, draft, ptab); dlen and draft are None unless drafting,
+        ptab unless paged."""
+        dlen = draft = None
+        if self.draft:
+            dlen = host[:, _C_DLEN]
+            draft = host[:, _C_DRAFT:_C_DRAFT + self.draft]
+        ptab = host[:, self._page_col():] if self.paged else None
+        return host[:, _C_EOS], host[:, _C_BUDGET], dlen, draft, ptab
+
     def _state_spec(self, rung):
         sds = jax.ShapeDtypeStruct
         s = self.slots
@@ -653,18 +713,16 @@ class GenerationServer:
         after delivery, up to k steps late. `eos` is -1 for slots with
         no EOS (sampled ids are always >= 0, so it never matches);
         `budget` is the per-slot count of tokens the block may still
-        emit (see _superstep_args for the replay accounting)."""
+        emit (see _superstep_args for the replay accounting). Both come
+        in the block's one host operand, with the page table in paged
+        mode (`_host_columns`)."""
 
         def superstep(*args):
             n = self.decoder.n_model_args
             margs = args[:n]
-            if self.paged:
-                (cache, pos, active, tokens, rng, method, temp, topk,
-                 eos, budget, ptab) = args[n:]
-            else:
-                (cache, pos, active, tokens, rng, method, temp, topk,
-                 eos, budget) = args[n:]
-                ptab = None
+            (cache, pos, active, tokens, rng, method, temp, topk,
+             host) = args[n:]
+            eos, budget, _, _, ptab = self._host_columns(host)
 
             def body(carry, _):
                 cache, pos, active, tokens, rng, budget = carry
@@ -715,16 +773,15 @@ class GenerationServer:
         def verify(*args):
             n = self.decoder.n_model_args
             margs = args[:n]
-            if self.paged:
-                (cache, pos, active, tokens, rng, method, temp, topk,
-                 eos, budget, draft, dlen, ptab) = args[n:]
-                logits, cache = self.decoder.verify(
-                    margs, cache, tokens, pos, draft, ptab)  # (S, d, V)
-            else:
-                (cache, pos, active, tokens, rng, method, temp, topk,
-                 eos, budget, draft, dlen) = args[n:]
+            (cache, pos, active, tokens, rng, method, temp, topk,
+             host) = args[n:]
+            eos, budget, dlen, draft, ptab = self._host_columns(host)
+            if ptab is None:
                 logits, cache = self.decoder.verify(
                     margs, cache, tokens, pos, draft)        # (S, d, V)
+            else:
+                logits, cache = self.decoder.verify(
+                    margs, cache, tokens, pos, draft, ptab)  # (S, d, V)
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             # position 0 samples with the slot's own config (ONE split
             # per round — greedy slots ignore the key, sampled slots
@@ -760,18 +817,23 @@ class GenerationServer:
         return verify
 
     def _traced_admit(self, *args):
+        """Prefill one prompt into a slot and sample its first token;
+        every per-call value rides the one host operand (`pack_admit`),
+        whose length gives the prompt bucket."""
         n = self.decoder.n_model_args
         margs = args[:n]
-        if self.paged:
-            (cache, pos, active, tokens, rng, method, temp, topk,
-             slot, prompt, plen, key, m, t, k, wrow) = args[n:]
-            cache, logits = self.decoder.prefill(margs, cache, slot,
-                                                 prompt, plen, wrow)
-        else:
-            (cache, pos, active, tokens, rng, method, temp, topk,
-             slot, prompt, plen, key, m, t, k) = args[n:]
+        (cache, pos, active, tokens, rng, method, temp, topk,
+         packed) = args[n:]
+        pbucket = next(p for p in self.prompt_buckets
+                       if self._admit_width(p) == packed.shape[0])
+        slot, prompt, plen, key, m, t, k, wrow = unpack_admit(packed,
+                                                              pbucket)
+        if wrow is None:
             cache, logits = self.decoder.prefill(margs, cache, slot,
                                                  prompt, plen)
+        else:
+            cache, logits = self.decoder.prefill(margs, cache, slot,
+                                                 prompt, plen, wrow)
         first, key2 = sample_step(logits[None], key[None], m[None],
                                   t[None], k[None])
         pos = pos.at[slot].set(plen)
@@ -1112,24 +1174,20 @@ class GenerationServer:
                 self._rung = rung
             else:
                 call = self._exes[(f"grow_to_{rung}", self._rung)]
-                with _mon.span("serve.call", program="grow"):
+                with _mon.span("serve.call", program="grow", host_args=0):
                     cache = call(self._state[_CACHE])
                 self._state = (cache,) + self._state[1:]
                 self._rung = rung
         if req.trace is not None:
             req.trace.event("admit", slot=slot, rung=rung,
                             bucket=pbucket, admit_id=rec.admit_id)
-        padded = np.zeros((pbucket,), np.int32)
-        padded[:plen] = prompt
+        packed = pack_admit(slot, plen, key, req.method, req.temperature,
+                            req.top_k, prompt, pbucket, wrow)
         if _faults.ACTIVE is not None:
             _faults.ACTIVE.fire(_faults.GENERATION_ADMIT)
         call = self._exes[("admit", rung, pbucket)]
-        extra = () if wrow is None else (wrow,)
-        with _mon.span("serve.call", program="admit"):
-            out = call(*self._margs, *self._state, np.int32(slot), padded,
-                       np.int32(plen), key, np.int32(req.method),
-                       np.float32(req.temperature), np.int32(req.top_k),
-                       *extra)
+        with _mon.span("serve.call", program="admit", host_args=1):
+            out = call(*self._margs, *self._state, packed)
         self._state = tuple(out[:8])
         if self._pages is not None:
             self._emit_page_metrics()
@@ -1149,37 +1207,43 @@ class GenerationServer:
         return next(c for c in self.cache_lengths
                     if c >= needed and c >= pbucket)
 
-    def _superstep_args(self):
-        """Per-dispatch EOS/budget columns: pure functions of the host
-        journal at dispatch time. A replay-suppressed slot's budget
+    def _superstep_args(self, k):
+        """The decode block's ONE host operand, (S, `_step_width`) int32,
+        filled in place: the EOS and budget columns (pure functions of
+        the host journal at dispatch time), then the drafts (verify)
+        and the page table (paged). A replay-suppressed slot's budget
         includes its undelivered journaled prefix (the device must
         regenerate it before the live continuation). With a block
         already in flight, its undelivered tokens are not yet counted,
         so the budget may over-allow by up to one block — delivery
         clamps exactly at max_new/EOS, so overshoot is
         computed-but-dropped, never delivered."""
-        eos = np.full((self.slots,), -1, np.int32)
-        budget = np.zeros((self.slots,), np.int32)
+        host = np.zeros((self.slots, self._step_width(self._rung)),
+                        np.int32)
+        host[:, _C_EOS] = -1
         for slot, rec in self._slot_req.items():
             req = rec.req
             if req.eos_id is not None:
-                eos[slot] = req.eos_id
+                host[slot, _C_EOS] = req.eos_id
             left = req.max_new_tokens - len(req.tokens)
             if rec.expect is not None:
                 left += len(rec.expect) - rec.replay_idx
-            budget[slot] = max(left, 0)
-        return eos, budget
+            host[slot, _C_BUDGET] = max(left, 0)
+        if self.draft:
+            self._propose_drafts(host)
+        if self._pages is not None:
+            host[:, self._page_col():] = self._page_args(k)
+        return host
 
-    def _propose_drafts(self):
-        """Host-side draft proposal (pure numpy over the request
-        journal — no device work, no syncs): a replaying slot proposes
-        its journaled prefix (a guaranteed-exact draft); a live GREEDY
-        slot proposes the prompt-lookup n-gram continuation of its own
-        history; non-greedy slots propose nothing (their sampled
-        streams must consume exactly one rng split per token)."""
+    def _propose_drafts(self, host):
+        """Host-side draft proposal into the block's host operand (pure
+        numpy over the request journal — no device work, no syncs): a
+        replaying slot proposes its journaled prefix (a guaranteed-exact
+        draft); a live GREEDY slot proposes the prompt-lookup n-gram
+        continuation of its own history; non-greedy slots propose
+        nothing (their sampled streams must consume exactly one rng
+        split per token)."""
         nd = self.draft
-        draft = np.zeros((self.slots, nd), np.int32)
-        dlen = np.zeros((self.slots,), np.int32)
         for slot, rec in self._slot_req.items():
             req = rec.req
             if req.method != GREEDY:
@@ -1192,9 +1256,8 @@ class GenerationServer:
                                     np.array(req.tokens, np.int32)]),
                     nd)
             if len(tail):
-                draft[slot, :len(tail)] = tail
-                dlen[slot] = len(tail)
-        return draft, dlen
+                host[slot, _C_DRAFT:_C_DRAFT + len(tail)] = tail
+                host[slot, _C_DLEN] = len(tail)
 
     def _page_args(self, k):
         """Paged-mode page prep for one decode block (host work on the
@@ -1217,7 +1280,8 @@ class GenerationServer:
                 continue
             for src, dst in self._pages.ensure_range(slot, rec.disp_pos,
                                                      hi - 1):
-                with _mon.span("serve.call", program="page_copy"):
+                with _mon.span("serve.call", program="page_copy",
+                               host_args=2):
                     cache = copy(self._state[_CACHE], np.int32(src),
                                  np.int32(dst))
                 self._state = (cache,) + self._state[1:]
@@ -1292,20 +1356,16 @@ class GenerationServer:
                                 if self.superstep > 1 or self.draft
                                 else _faults.GENERATION_STEP)
         with _mon.span("serve.args"):
-            eos, budget = self._superstep_args()
-            ptab = (() if self._pages is None else (self._page_args(k),))
-            draft, dlen = (self._propose_drafts() if self.draft
-                           else (None, None))
+            host = self._superstep_args(k)
         if self.draft:
+            program, dlen = "verify", host[:, _C_DLEN]
             call = self._exes[("verify", self._rung, self.draft)]
-            with _mon.span("serve.call", program="verify"):
-                out = call(*self._margs, *self._state, eos, budget, draft,
-                           dlen, *ptab)
         else:
+            program, dlen = "superstep", None
             call = self._exes[("superstep", self._rung,
                                self.superstep)]
-            with _mon.span("serve.call", program="superstep"):
-                out = call(*self._margs, *self._state, eos, budget, *ptab)
+        with _mon.span("serve.call", program=program, host_args=1):
+            out = call(*self._margs, *self._state, host)
         self._state = tuple(out[:8])
         block = self._start_fetch(out[8])
         prev, self._inflight = self._inflight, _Block(
@@ -1481,7 +1541,7 @@ class GenerationServer:
         """Per-sequence retirement: clear the slot's device columns
         (one tiny pre-compiled dispatch) and free it for admission."""
         call = self._exes[("retire",)]
-        with _mon.span("serve.call", program="retire"):
+        with _mon.span("serve.call", program="retire", host_args=1):
             pos, active, tokens = call(self._state[_POS],
                                        self._state[_ACTIVE],
                                        self._state[_TOKENS],
@@ -1633,7 +1693,10 @@ class GenerationServer:
         if use_prefix:
             prefix = np.concatenate(
                 [req.prompt, np.asarray(req.tokens, np.int32)])
-            key = self._advance_key(self._admit_key(rec.admit_id), g)
+            # the advanced key lives on the device: one fetch a replayed
+            # request (recovery only) so the admit packs it on the host
+            key = np.asarray(self._advance_key(self._admit_key(rec.admit_id),
+                                               g))
             rec.expect = None
             rec.replay_idx = 0
             self._admit_rec(rec, prefix, key)
@@ -1684,7 +1747,7 @@ class GenerationServer:
         pre-compiled `("advance_key_n",)` executable (n is a traced
         scalar), so replay performs zero live compiles and O(1)
         dispatches however long the delivered prefix."""
-        with _mon.span("serve.call", program="advance_key_n"):
+        with _mon.span("serve.call", program="advance_key_n", host_args=2):
             return self._exes[("advance_key_n",)](key, np.int32(n))
 
     def _supervised_restart(self, exc):

@@ -348,8 +348,12 @@ class _AotStoreBase:
         return e
 
     # -- miss path (boundary: the lint stops descending here) -------------
-    def _entry_path(self, key):
-        h = hashlib.sha256(repr(key).encode()).hexdigest()[:24]
+    def _entry_path(self, key, sig=None):
+        """The on-disk entry of `key`; `sig`, where given, is the
+        lowered argument signature, hashed with it, so that a program
+        whose arguments changed under the same key is a clean miss."""
+        ident = key if sig is None else (key, sig)
+        h = hashlib.sha256(repr(ident).encode()).hexdigest()[:24]
         return os.path.join(self.directory, LAYOUT_VERSION, self.flavour,
                             self.fingerprint, h + ".exe")
 
@@ -379,11 +383,12 @@ class _AotStoreBase:
                            "dispatch of this cached executable") \
                .set(e.cost["bytes_accessed"])
 
-    def _resolve(self, key, lower_fn):
+    def _resolve(self, key, lower_fn, sig=None):
         """Memory → disk (deserialize, no XLA compile) → live compile
         (persisted back), under the store lock. Corrupt or mismatched
         disk entries count `deserialize_failures` and fall through to
-        the live compile — never crash, never go stale."""
+        the live compile — never crash, never go stale. `sig` names the
+        disk entry together with `key` (`_entry_path`)."""
         with self._lock:
             e = self._mem.get(key)
             if e is not None:
@@ -394,7 +399,7 @@ class _AotStoreBase:
             # signature) — never the in-memory steady state above
             if _faults.ACTIVE is not None:
                 _faults.ACTIVE.fire(_faults.EXECUTABLES_LOAD)
-            path = (self._entry_path(key) if self.directory else None)
+            path = (self._entry_path(key, sig) if self.directory else None)
             if path is not None and os.path.exists(path):
                 with self._miss("load", key):
                     e = self._load_disk(key, path)
@@ -720,12 +725,16 @@ class FunctionStore(_AotStoreBase):
     def load_or_compile(self, key, example_args):
         """key: (name, *static identity); example_args: concrete or
         ShapeDtypeStruct positional args the executable will be called
-        with. Resolves through memory → disk → live compile."""
+        with. Resolves through memory → disk → live compile. The disk
+        entry is named from the key AND the args' shapes and dtypes: a
+        store directory kept across an upgrade that changed a program's
+        arguments hands the new server no stale program."""
         name = key[0]
         if name not in self._fns:
             raise KeyError(f"no function registered under {name!r}")
-        return self._resolve(
-            key, lambda: self._lower_named(name, tuple(example_args)))
+        args = tuple(example_args)
+        return self._resolve(key, lambda: self._lower_named(name, args),
+                             _shape_dtype_tree(args))
 
 
 def status():

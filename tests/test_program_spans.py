@@ -277,6 +277,9 @@ def test_server_under_a_session_holds_spans_stats_and_names(tmp_path, bert):
         assert c.thread == loop
         calls[c.stats["program"]].append(c)
     assert set(calls) == {"superstep", "admit", "retire"}
+    # each is handed ONE numpy operand beside the model and the state
+    assert {c.stats["host_args"] for cs in calls.values() for c in cs} \
+        == {1}
     assert steps_of(calls["superstep"], dispatches) == sorted(dispatches)
     assert sorted(around(admits, c).stats["req"] for c in calls["admit"]) \
         == sorted(admits)
@@ -284,9 +287,13 @@ def test_server_under_a_session_holds_spans_stats_and_names(tmp_path, bert):
     assert len(steps_of(calls["retire"], delivers)) == len(handles)
     # a superstep opens five spans: dispatch, args, call, and a step later
     # deliver and fetch; an admission brings four (submit, admit, its call
-    # and fetch) and a retirement one, a collection its own
-    per_step = (len(s.events) - 4 * len(admits) - len(calls["retire"])
-                - len(s.named("serve.idle")) - len(s.named("gc")))
+    # and fetch) and a retirement one, a collection its own. Counted on
+    # this server's threads alone: a thread that another test of the
+    # same worker process left running may write spans into the session
+    ours = {loop} | {e.thread for e in submits.values()}
+    events = [e for e in s.events if e.thread in ours]
+    per_step = (len(events) - 4 * len(admits) - len(calls["retire"])
+                - sum(e.name in ("serve.idle", "gc") for e in events))
     assert len(dispatches) * 3 + len(delivers) * 2 == per_step
     assert per_step <= 5 * len(dispatches)
 
@@ -308,6 +315,25 @@ def test_idle_server_waits_in_a_span(tmp_path, bert):
         srv.shutdown()
     idle = s.named("serve.idle")
     assert idle and all(e.end - e.start <= 0.2e9 for e in idle)
+
+
+def test_a_drafting_round_hands_its_program_one_host_operand(tmp_path,
+                                                              bert):
+    cfg, params = bert
+    srv = GenerationServer(BertDecoder(cfg, params), slots=2,
+                           cache_lengths=[32], prompt_buckets=[8], seed=1,
+                           draft=2)
+    srv.warmup()
+    try:
+        with _Session(tmp_path) as s:
+            srv.generate([1, 2, 1, 2], max_new_tokens=4, eos_id=None,
+                         timeout=120)
+    finally:
+        srv.shutdown()
+    calls = collections.defaultdict(set)
+    for c in s.named("serve.call"):
+        calls[c.stats["program"]].add(c.stats["host_args"])
+    assert calls == {"verify": {1}, "admit": {1}, "retire": {1}}
 
 
 # -- the interpreter's collections ------------------------------------------
@@ -351,13 +377,12 @@ def test_lowered_decode_programs_hold_scopes_and_kernel_name(bert):
     sds = jax.ShapeDtypeStruct
     margs = tuple(dec.model_args())
     spec = srv._state_spec(32)
-    slot_i, scalar_i = sds((4,), jnp.int32), sds((), jnp.int32)
     step = jax.jit(srv._traced_superstep(1)).lower(
-        *margs, *spec, slot_i, slot_i).as_text(debug_info=True)
+        *margs, *spec, sds((4, srv._step_width(32)), jnp.int32)
+    ).as_text(debug_info=True)
     admit = jax.jit(srv._traced_admit).lower(
-        *margs, *spec, scalar_i, sds((8,), jnp.int32), scalar_i,
-        sds((2,), jnp.uint32), scalar_i, sds((), jnp.float32),
-        scalar_i).as_text(debug_info=True)
+        *margs, *spec, sds((srv._admit_width(8),), jnp.int32)
+    ).as_text(debug_info=True)
     for text, kernel_scope in ((step, "layer1/attn/flash_decode/flash_fwd"),
                                (admit, "layer1/attn/flash_fwd")):
         for scope in ("embed/", "layer0/qkv/", "layer0/kv_write/",
@@ -392,9 +417,10 @@ def test_old_layout_entry_is_a_miss_and_load_time_is_summed(tmp_path,
 
     # an entry as the store before this layout wrote it
     monkeypatch.setattr(executables, "LAYOUT_VERSION", "v2")
+    sig = executables._shape_dtype_tree((x,))
     old = store()
     old.load_or_compile(key, (x,))
-    old_path = old._entry_path(key)
+    old_path = old._entry_path(key, sig)
     assert os.path.exists(old_path) and os.sep + "v2" + os.sep in old_path
     monkeypatch.undo()
 
@@ -404,7 +430,7 @@ def test_old_layout_entry_is_a_miss_and_load_time_is_summed(tmp_path,
     assert fresh.stats["compile_seconds"] > 0
     assert fresh.stats["load_seconds"] == 0
     # even copied to where this layout looks, its meta gives it away
-    new_path = fresh._entry_path(key)
+    new_path = fresh._entry_path(key, sig)
     assert os.path.exists(new_path)
     shutil.copy(old_path, new_path)
     moved = store()

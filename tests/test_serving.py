@@ -272,7 +272,8 @@ def test_disk_entry_reloads_onto_its_own_devices_and_runs(tmp_path):
     out = e.call(x)                         # the call is the test
     np.testing.assert_array_equal(np.asarray(out), np.asarray(x) * 2 + 1)
     assert out.devices() == {jax.local_devices()[0]}
-    with open(store2._entry_path(("affine", 4)), "rb") as f:
+    path = store2._entry_path(("affine", 4), exe._shape_dtype_tree((x,)))
+    with open(path, "rb") as f:
         assert pickle.load(f)["devices"] == [jax.local_devices()[0].id]
 
 
@@ -288,7 +289,7 @@ def test_disk_entry_for_devices_this_host_lacks_is_a_miss(tmp_path):
 
     store1 = build()
     store1.load_or_compile(("inc",), (x,))
-    path = store1._entry_path(("inc",))
+    path = store1._entry_path(("inc",), exe._shape_dtype_tree((x,)))
     with open(path, "rb") as f:
         rec = pickle.load(f)
     rec["devices"] = [10 ** 6]
