@@ -1,7 +1,7 @@
 """Decode-mode forwards: incremental single-token model evaluation over
 donated device state.
 
-Five adapters expose one contract to the GenerationServer:
+Six adapters expose one contract to the GenerationServer:
 
 - **BertDecoder** — transformer stacks built on `models/bert.py` params:
   one K and one V cache leaf A LAYER, `(S, C, H·Dh)` (S slots, C =
@@ -68,6 +68,19 @@ Five adapters expose one contract to the GenerationServer:
   the cache. Its expert layers count as NemotronHDecoder's do, and the
   attention counts the rows it needed and the rows its kernel fetched.
 
+- **AfmoeDecoder** — stacks built on `models/afmoe.py` params, whose
+  layers are of two kinds and whose cache holds leaves of two LENGTHS: a
+  sliding-window layer's K and V rows in a RING of `min(window, C)` rows
+  (position p at row p mod window), which `grow` leaves alone once it has
+  the window's rows, beside a full-attention layer's rows `(S, C,
+  Hkv·Dh)`, which grow with the rung. `step` attends both through
+  `flash_attention_decode(..., lengths=)` (a full ring is attended whole:
+  a softmax does not care in which order rows come); `prefill` runs a
+  sliding layer's prompt through the BANDED prefill kernel and grafts the
+  prompt's last `window` real positions into their ring rows. Its expert
+  layers count as NemotronHDecoder's do, and the attention counts the
+  rows in use of each kind of layer.
+
 The contract (all pure functions, traced into AOT executables by the
 server — nothing here may touch the host):
 
@@ -132,13 +145,13 @@ from deeplearning4j_tpu.kernels.mla_attention import (latent_tile_positions,
                                                       pack_latent)
 from deeplearning4j_tpu.kernels.selection import (compact_indices,
                                                   top_k_mask)
-from deeplearning4j_tpu.models import deepseek_v3, keye_vl, nemotron_h
+from deeplearning4j_tpu.models import afmoe, deepseek_v3, keye_vl, nemotron_h
 from deeplearning4j_tpu.models.bert import (_ffn, _layer_norm,
                                             bert_mlm_logits)
 from deeplearning4j_tpu.parallel.ring_attention import dense_attention
 
-__all__ = ["BertDecoder", "KeyeDecoder", "MLADecoder", "NemotronHDecoder",
-           "RecurrentDecoder"]
+__all__ = ["AfmoeDecoder", "BertDecoder", "KeyeDecoder", "MLADecoder",
+           "NemotronHDecoder", "RecurrentDecoder"]
 
 
 def _write_kv(cache, li, wi, wj, k, v):
@@ -1060,6 +1073,143 @@ class MLADecoder:
         h_last = jnp.take(x, plen - 1, axis=0)                  # (H,)
         return {**cache, "kv": leaves}, \
             deepseek_v3.logits(cfg, params, h_last)
+
+
+class AfmoeDecoder:
+    """Decode over a `models/afmoe.py` parameter tree: sliding-window and
+    full grouped-query attention layers, each with the cache its kind
+    needs.
+
+    The cache pytree: `{"k": [...], "v": [...]}`, one K and one V leaf A
+    LAYER in the compute dtype, rows major: a sliding layer's `(S, min(W,
+    C), Hkv·Dh)` ring (W the window; position p at row p mod W), a full
+    layer's `(S, C, Hkv·Dh)`; `"counts"`, the running counts
+    (`counter_names`). Only the full layers' leaves, and a ring shorter
+    than W, know the rung.
+
+    The full-sequence reference this must match is `deeplearning4j_tpu.
+    models.afmoe.forward` over the same prompt+generated prefix (and,
+    outside the package, the plain reference under `benchmarks/families/`)."""
+
+    uses_cache_rungs = True
+    n_model_args = 1
+    supports_draft = False
+    max_cache_len = None        # rotary positions: no table bounds them
+    counter_names = ("moe_pairs", "moe_expert_reads", "moe_pairs_max",
+                     "window_rows_attended", "global_rows_attended")
+
+    def __init__(self, cfg, params, attn_impl="auto"):
+        if attn_impl not in ("auto", "dense", "pallas"):
+            raise ValueError(
+                f"attn_impl must be 'auto', 'dense' or 'pallas', "
+                f"got {attn_impl!r}")
+        self.cfg = cfg
+        self.params = params
+        self.attn_impl = attn_impl
+        self.vocab_size = int(cfg.vocab_size)
+
+    def fingerprint(self):
+        parts = ("afmoe-decode", repr(self.cfg), self.attn_impl,
+                 _shape_tree_repr(self.params),
+                 _shape_tree_repr(
+                     jax.eval_shape(lambda: self.init_cache(1, 1))))
+        return hashlib.sha256(repr(parts).encode()).hexdigest()[:16]
+
+    def model_args(self):
+        return (self.params,)
+
+    def _rows(self, li, cache_len):
+        """Rows of layer `li`'s leaves at rung `cache_len`."""
+        if self.cfg.is_sliding(li):
+            return min(self.cfg.sliding_window, int(cache_len))
+        return int(cache_len)
+
+    def init_cache(self, slots, cache_len):
+        cfg = self.cfg
+        cache = {name: [jnp.zeros((slots, self._rows(li, cache_len),
+                                   cfg.kv_width), cfg.compute_dtype)
+                        for li in range(cfg.num_hidden_layers)]
+                 for name in ("k", "v")}
+        cache["counts"] = jnp.zeros((len(self.counter_names),), jnp.int32)
+        return cache
+
+    def grow(self, cache, new_len):
+        """A full layer's leaves padded to the longer rung, a ring up to
+        the window's rows while it is shorter (it held positions 0..C - 1
+        at their own rows, none wrapped); the counts as they are."""
+        def pad(li, t):
+            return jnp.pad(t, ((0, 0), (0, self._rows(li, new_len)
+                                        - t.shape[1]), (0, 0)))
+        return {**cache, **{name: [pad(li, t)
+                                   for li, t in enumerate(cache[name])]
+                            for name in ("k", "v")}}
+
+    def counters(self, cache):
+        return cache["counts"]
+
+    def step(self, margs, cache, tokens, pos):
+        """One decode step for the whole batch: a layer writes its K and V
+        rows at `pos` (a full layer) or `pos mod W` (a sliding layer's
+        ring) and attends the rows in use, `min(pos + 1, rows)`: on a full
+        ring that is every row, the last W positions; then its
+        feed-forward, dense or experts. Returns next-token logits (S, V).
+        Counted a step, slots and layers summed: the rows in use of the
+        sliding layers and of the full layers."""
+        (params,) = margs
+        cfg = self.cfg
+        with jax.named_scope("embed"):
+            x = afmoe.embed(cfg, params, tokens)                # (S, H)
+        with jax.named_scope("rope"):
+            tables = afmoe.rope_tables(cfg, pos)
+        leaves = {name: list(cache[name]) for name in ("k", "v")}
+        counted = jnp.zeros((3,), jnp.int32)
+        rows = jnp.zeros((2,), jnp.int32)       # (sliding, full) in use
+        for li, layer in enumerate(params["layers"]):
+            sliding = cfg.is_sliding(li)
+            r = leaves["k"][li].shape[1]
+            # rows 0..pos are in use (the current write included); a lane
+            # frozen at the end of its rung (pos == C) keeps all of them
+            in_use = jnp.minimum(pos + 1, r)                    # (S,)
+            write = pos % cfg.sliding_window if sliding else pos
+            with jax.named_scope(f"layer{li}"):
+                x, (leaves["k"][li], leaves["v"][li]), counts = \
+                    afmoe.decode_layer(
+                        cfg, li, layer, x, tables,
+                        (leaves["k"][li], leaves["v"][li]), write, in_use,
+                        self.attn_impl)
+            rows = rows.at[0 if sliding else 1].add(in_use.sum())
+            if counts is not None:
+                counted = counted + counts
+        counts = cache["counts"] + jnp.concatenate([counted, rows])
+        return afmoe.logits(cfg, params, x), {**leaves, "counts": counts}
+
+    def prefill(self, margs, cache, slot, prompt, plen):
+        """The full forward over one length-bucketed prompt (P,), sliding
+        layers through the banded kernel, then the graft of the slot's
+        rows a layer: a full layer's rows 0..P-1 (rows beyond plen hold
+        padding garbage that the decode step never reads: it attends rows
+        up to `pos`), a sliding layer's ring row r the prompt's position
+        `plen - 1 - ((plen - 1 - r) mod W)`, the last W REAL positions (a
+        row whose position would be negative is written before it is
+        read). Returns the logits at plen - 1."""
+        (params,) = margs
+        cfg = self.cfg
+        x, states = afmoe.encode(cfg, params, prompt, impl=self.attn_impl)
+        leaves = {name: list(cache[name]) for name in ("k", "v")}
+        for li, state in enumerate(states):
+            with jax.named_scope(f"layer{li}"), jax.named_scope("attn"), \
+                    jax.named_scope("kv_write"):
+                if cfg.is_sliding(li):
+                    r = jnp.arange(leaves["k"][li].shape[1])
+                    at = plen - 1 - (plen - 1 - r) % cfg.sliding_window
+                    state = tuple(jnp.take(part, jnp.maximum(at, 0), axis=0)
+                                  for part in state)
+                for name, part in zip(("k", "v"), state):
+                    leaf = leaves[name][li]
+                    leaves[name][li] = lax.dynamic_update_slice(
+                        leaf, part[None].astype(leaf.dtype), (slot, 0, 0))
+        h_last = jnp.take(x, plen - 1, axis=0)                  # (H,)
+        return {**cache, **leaves}, afmoe.logits(cfg, params, h_last)
 
 
 class RecurrentDecoder:

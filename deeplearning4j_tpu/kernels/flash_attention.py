@@ -35,7 +35,7 @@ _NEG_INF = -1e30
 
 
 def _flash_fwd_kernel(*refs, block_k, causal, scale, tk_actual, has_mask,
-                      native=False):
+                      native=False, window=None, k_tiles=None):
     """Grid (BH, q_tiles, k_tiles), k innermost: only one (block_k, d) K/V
     tile is VMEM-resident per step; o/l/m accumulate in VMEM scratch across
     the k dimension and the output tile is written on the last k step.
@@ -50,7 +50,13 @@ def _flash_fwd_kernel(*refs, block_k, causal, scale, tk_actual, has_mask,
     With has_mask, an extra (1, block_k) int32 KEY-validity tile (from the
     per-example (B, Tk) padding mask) masks scores; invalid QUERY rows are
     handled outside the kernel (outputs zeroed, lse forced to +inf so the
-    backward recompute sees p == 0)."""
+    backward recompute sees p == 0).
+
+    `window` (causal only): query t sees keys `t - window < s <= t`, and
+    the k axis of the grid runs over the BAND: step j of q tile i is k tile
+    `_band_span(i)[0] + j` (`_band_span`), and a step past the band's last
+    tile computes nothing and names that tile again, so nothing is
+    fetched for it. Tiles wholly below the band have no grid step."""
     if has_mask:
         q_ref, k_ref, v_ref, km_ref, o_ref, lse_ref, acc_ref, l_ref, m_ref = refs
     else:
@@ -59,8 +65,11 @@ def _flash_fwd_kernel(*refs, block_k, causal, scale, tk_actual, has_mask,
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     block_q = q_ref.shape[1]
+    if window is not None:
+        first, last = _band_span(qi, block_q, block_k, window, k_tiles)
+        band_step, kj = kj, first + kj
 
-    @pl.when(kj == 0)
+    @pl.when((kj if window is None else band_step) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -87,6 +96,8 @@ def _flash_fwd_kernel(*refs, block_k, causal, scale, tk_actual, has_mask,
         mask = k_pos < tk_actual
         if causal:
             mask &= q_pos >= k_pos
+        if window is not None:
+            mask &= k_pos > q_pos - window
         if has_mask:
             mask &= km_ref[0] > 0            # (1, block_k) broadcasts
         s = jnp.where(mask, s, _NEG_INF)
@@ -102,19 +113,46 @@ def _flash_fwd_kernel(*refs, block_k, causal, scale, tk_actual, has_mask,
             dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    if causal:
+    if window is not None:
+        # the band's steps past its last tile repeat that tile's block
+        pl.when(kj <= last)(_compute)
+    elif causal:
         # skip k-tiles entirely above the diagonal: both MXU matmuls would
         # only produce fully-masked (p == 0) contributions
         pl.when(kj * block_k <= qi * block_q + block_q - 1)(_compute)
     else:
         _compute()
 
-    @pl.when(kj == pl.num_programs(2) - 1)
+    @pl.when((kj if window is None else band_step)
+             == pl.num_programs(2) - 1)
     def _finalize():
         o_ref[0] = (acc_ref[...] /
                     jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
         lse_ref[0, 0] = (m_ref[...] +
                          jnp.log(jnp.maximum(l_ref[...], 1e-30)))[:, 0]
+
+
+def _band_span(qi, block_q, block_k, window, k_tiles):
+    """The k tiles (first, last) that q tile `qi` of a sliding-window causal
+    attention needs: its rows `t` see keys `t - window < s <= t`. Works on
+    Python ints and on traced int32 alike."""
+    lo = qi * block_q - window + 1
+    if isinstance(lo, int):
+        first = max(lo, 0) // block_k
+        last = min((qi * block_q + block_q - 1) // block_k, k_tiles - 1)
+    else:
+        first = jnp.maximum(lo, 0) // block_k
+        last = jnp.minimum((qi * block_q + block_q - 1) // block_k,
+                           k_tiles - 1)
+    return first, last
+
+
+def band_tiles(q_tiles, block_q, block_k, window, k_tiles):
+    """The extent of a banded grid's k axis: the most k tiles any q tile's
+    band touches."""
+    return max(last - first + 1 for first, last in (
+        _band_span(i, block_q, block_k, window, k_tiles)
+        for i in range(q_tiles)))
 
 
 def _pad_to(x, axis, mult):
@@ -138,7 +176,7 @@ def _prep_mask(mask, block_k):
 
 
 def _flash_forward(q, k, v, q_mask, kv_mask, causal, block_q, block_k,
-                   interpret, native=False):
+                   interpret, native=False, window=None):
     """Returns (out (B,H,Tq,Dv), lse (B*H, Tq_padded)). `kv_mask` is an
     optional (B, Tk) KEY-validity mask; `q_mask` an optional (B, Tq)
     QUERY-validity mask — invalid q rows come back zeroed with
@@ -165,6 +203,21 @@ def _flash_forward(q, k, v, q_mask, kv_mask, causal, block_q, block_k,
         pl.BlockSpec((1, block_k, d), lambda bh, i, j: (bh, j, 0)),
         pl.BlockSpec((1, block_k, dv), lambda bh, i, j: (bh, j, 0)),
     ]
+    if window is not None:
+        # the k axis over the band's tiles only (`_flash_fwd_kernel`)
+        k_tiles = grid[2]
+        grid = grid[:2] + (band_tiles(grid[1], block_q, block_k, window,
+                                      k_tiles),)
+        kernel = functools.partial(kernel, window=window, k_tiles=k_tiles)
+
+        def band(i, j):
+            first, last = _band_span(i, block_q, block_k, window, k_tiles)
+            return jnp.minimum(first + j, last)
+
+        in_specs[1:] = [
+            pl.BlockSpec((1, block_k, w), lambda bh, i, j: (bh, band(i, j),
+                                                            0))
+            for w in (d, dv)]
     operands = [qp, kp, vp]
     if kv_mask is not None:
         in_specs.append(
@@ -448,28 +501,30 @@ def _flash_bwd_rule(causal, block_q, block_k, interpret, res, g):
 _flash_attention_vjp.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def _flash_forward_only(q, k, v, q_mask, kv_mask, causal, block_q, block_k,
-                        interpret, native):
+                        interpret, native, window):
     return _flash_forward(q, k, v, q_mask, kv_mask, causal, block_q,
-                          block_k, interpret, native)[0]
+                          block_k, interpret, native, window)[0]
 
 
 def _forward_only_fwd_rule(q, k, v, q_mask, kv_mask, causal, block_q,
-                           block_k, interpret, native):
+                           block_k, interpret, native, window):
     raise NotImplementedError(
-        f"flash_attention is forward only with native=True or with values "
-        f"of another width than the keys (native={native}, keys of "
-        f"{k.shape[3]}, values of {v.shape[3]}): the backward kernels take "
-        f"float32 products and equal widths. Differentiate through "
-        f"native=False at equal widths, or through a dense attention")
+        f"flash_attention is forward only with native=True, with a sliding "
+        f"window or with values of another width than the keys (native="
+        f"{native}, window={window}, keys of {k.shape[3]}, values of "
+        f"{v.shape[3]}): the backward kernels take float32 products, equal "
+        f"widths and no band. Differentiate through native=False at equal "
+        f"widths without a window, or through a dense attention")
 
 
 _flash_forward_only.defvjp(_forward_only_fwd_rule, lambda *_: None)
 
 
 def flash_attention(q, k, v, causal=False, block_q=128, block_k=128,
-                    interpret=None, mask=None, kv_mask=None, native=False):
+                    interpret=None, mask=None, kv_mask=None, native=False,
+                    window=None):
     """Fused attention: softmax(QKᵀ/√d)·V without materialising (Tq,Tk).
 
     Pallas on TPU (interpret-mode elsewhere); differentiable — backward is
@@ -495,8 +550,22 @@ def flash_attention(q, k, v, causal=False, block_q=128, block_k=128,
     either raises. `native` is a TEMPORARY fork (PERF.md section 7, ROADMAP
     S10): it exists so that the other callers' programs stay as they were
     measured, not because two numerics are wanted.
+
+    `window` (an int, with `causal`): sliding-window attention, query t
+    over keys `t - window < s <= t`. The grid's k axis runs over the band's
+    tiles only: a K/V tile wholly below the band is neither fetched nor
+    computed, so a prompt of T costs about `T x (window + block)` pairs and
+    not `T^2 / 2`. Forward only, without masks. Without it the call is the
+    one it was.
     """
     tq, tk = q.shape[2], k.shape[2]
+    if window is not None:
+        if not causal or tq != tk or int(window) < 1 or mask is not None \
+                or kv_mask is not None:
+            raise ValueError(f"window={window!r} needs causal=True over "
+                             f"Tq == Tk, a positive width and no masks")
+        return _flash_forward_only(q, k, v, None, None, True, block_q,
+                                   block_k, interpret, native, int(window))
     if causal and tq != tk:
         raise ValueError(
             f"causal flash attention requires Tq == Tk, got {tq} != {tk}")
@@ -519,7 +588,7 @@ def flash_attention(q, k, v, causal=False, block_q=128, block_k=128,
             f"kv_mask length {kv_mask.shape[1]} != Tk {tk}")
     if native or v.shape[3] != q.shape[3]:
         return _flash_forward_only(q, k, v, mask, kv_mask, causal, block_q,
-                                   block_k, interpret, native)
+                                   block_k, interpret, native, None)
     return _flash_attention_vjp(q, k, v, mask, kv_mask, causal, block_q,
                                 block_k, interpret)
 

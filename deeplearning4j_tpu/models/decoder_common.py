@@ -1,11 +1,15 @@
 """What the served language models of this package share outside their
-layers: the norm, the head, and the run of tokens an expert layer takes at
-once. One place, so that no model's file imports another's."""
+attention: the norm, the head, the run of tokens an expert layer takes at
+once, and the sigmoid-routed expert layer with its shared SwiGLU that
+`deepseek_v3.py` and `afmoe.py` both compute. One place, so that no model's
+file imports another's."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from deeplearning4j_tpu.parallel.moe import routed_experts
 
 #: tokens one call of an expert layer takes: its pairs' tiled rows and
 #: results are (tokens x top_k) x hidden, a gigabyte at 16384 tokens
@@ -24,3 +28,47 @@ def logits(cfg, params, x):
         u = rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
         return jnp.dot(u, params["head"].astype(u.dtype),
                        preferred_element_type=jnp.float32)
+
+
+def swiglu(x, gate, up, down):
+    """`(silu(x W_g) * (x W_u)) W_d`, the products in x's dtype."""
+    dt = x.dtype
+    return (jax.nn.silu(x @ gate.astype(dt)) * (x @ up.astype(dt))) \
+        @ down.astype(dt)
+
+
+def router_scores(layer, g):
+    """The router's sigmoid over ALL experts for tokens `g` (T, H),
+    float32."""
+    return jax.nn.sigmoid(jnp.dot(g, layer["router"].astype(g.dtype),
+                                  preferred_element_type=jnp.float32))
+
+
+def moe(cfg, layer, g):
+    """The expert layer for tokens `g` (T, H): this chip's part of the
+    routed experts (those of `cfg.experts_held`, `cfg.num_experts_per_tok`
+    of them a token by `s + router_bias`; the weights the chosen `s`
+    normalised over all the chosen, times `cfg.routed_scaling_factor`) and
+    the shared experts whole. Returns (out (T, H) float32, the
+    `routed_experts` counts). Prefill and decode run the same function; a
+    long sequence goes through in runs of `MOE_CHUNK` tokens."""
+    def run(tokens):
+        with jax.named_scope("router"):
+            scores = router_scores(layer, tokens)
+        with jax.named_scope("experts"):
+            out, counts = routed_experts(
+                tokens, scores, layer["router_bias"], layer["w_up"],
+                layer["w_down"], cfg.experts_held, cfg.num_experts_per_tok,
+                cfg.routed_scaling_factor, jax.nn.silu,
+                w_gate=layer["w_gate"])
+        with jax.named_scope("shared"):
+            out = out + swiglu(tokens, layer["s_gate"], layer["s_up"],
+                               layer["s_down"]).astype(jnp.float32)
+        return out, counts
+
+    t = g.shape[0]
+    if t <= MOE_CHUNK or t % MOE_CHUNK:
+        return run(g)
+    out, counts = lax.map(run, g.reshape(t // MOE_CHUNK, MOE_CHUNK, -1))
+    return out.reshape(t, -1), jnp.concatenate(
+        [counts[:, :2].sum(0), counts[:, 2:].max(0)])

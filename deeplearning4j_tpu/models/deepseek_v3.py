@@ -43,16 +43,14 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from deeplearning4j_tpu.kernels.flash_attention import flash_attention
 from deeplearning4j_tpu.kernels.mla_attention import (mla_attention_decode,
                                                       write_latent_row)
-# (RMSNorm, the final norm and head, and the expert layer's run of tokens
-# are every served decoder's)
+# (RMSNorm, the final norm and head, and the sigmoid-routed expert layer
+# with its shared SwiGLU are every served decoder's)
 from deeplearning4j_tpu.models.decoder_common import (  # noqa: F401
-    MOE_CHUNK, logits, rms_norm)
-from deeplearning4j_tpu.parallel.moe import routed_experts
+    MOE_CHUNK, logits, moe, rms_norm, router_scores, swiglu)
 
 #: rows of queries and of keys a grid step of a prompt's attention takes.
 #: On a v5e at 32 heads, keys of 192 and values of 128, bfloat16 (`PERF.md`,
@@ -307,48 +305,6 @@ def absorbed_attention(cfg, layer, q_nope, q_rope, leaf, lengths,
                           layer["v_up"].astype(dt).reshape(lat, nh, -1),
                           preferred_element_type=jnp.float32
                           ).astype(dt).reshape(s, -1)
-
-
-def swiglu(x, gate, up, down):
-    """`(silu(x W_g) * (x W_u)) W_d`, the products in x's dtype."""
-    dt = x.dtype
-    return (jax.nn.silu(x @ gate.astype(dt)) * (x @ up.astype(dt))) \
-        @ down.astype(dt)
-
-
-def router_scores(layer, g):
-    """The router's sigmoid over ALL experts for tokens `g` (T, H),
-    float32."""
-    return jax.nn.sigmoid(jnp.dot(g, layer["router"].astype(g.dtype),
-                                  preferred_element_type=jnp.float32))
-
-
-def moe(cfg, layer, g):
-    """The expert layer for tokens `g` (T, H): this chip's part of the
-    routed experts (those of `cfg.experts_held`; the weights normalised
-    over all the chosen) and the shared experts whole. Returns (out (T, H)
-    float32, the `routed_experts` counts). Prefill and decode run the same
-    function; a long sequence goes through in runs of `MOE_CHUNK` tokens."""
-    def run(tokens):
-        with jax.named_scope("router"):
-            scores = router_scores(layer, tokens)
-        with jax.named_scope("experts"):
-            out, counts = routed_experts(
-                tokens, scores, layer["router_bias"], layer["w_up"],
-                layer["w_down"], cfg.experts_held, cfg.num_experts_per_tok,
-                cfg.routed_scaling_factor, jax.nn.silu,
-                w_gate=layer["w_gate"])
-        with jax.named_scope("shared"):
-            out = out + swiglu(tokens, layer["s_gate"], layer["s_up"],
-                               layer["s_down"]).astype(jnp.float32)
-        return out, counts
-
-    t = g.shape[0]
-    if t <= MOE_CHUNK or t % MOE_CHUNK:
-        return run(g)
-    out, counts = lax.map(run, g.reshape(t // MOE_CHUNK, MOE_CHUNK, -1))
-    return out.reshape(t, -1), jnp.concatenate(
-        [counts[:, :2].sum(0), counts[:, 2:].max(0)])
 
 
 def feed_forward(cfg, li, layer, x):

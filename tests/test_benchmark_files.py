@@ -10,8 +10,9 @@ from benchmarks.tests.test_manifest import *  # noqa: F401,F403
 from benchmarks.tests.test_spreads import *  # noqa: F401,F403
 from benchmarks.tests import test_spreads as _spreads
 
-# `keye_vl2_serve_longdoc` (PR 35) and `kanana2_serve_longdoc` (PR 37) are
-# serving cells whose sets of six are not in `benchmarks/spreads.json`: a `model_config` PR may add files to the
+# `keye_vl2_serve_longdoc`, `kanana2_serve_longdoc` and
+# `trinity_mini_serve_longdoc` are serving cells whose sets of six
+# are not in `benchmarks/spreads.json`: a `model_config` PR may add files to the
 # benchmark and edit none, and that file is the rule's input for the three
 # serving bounds. The cells' runs lie beside it, ready for `spreads.py
 # collect` (`benchmarks/spreads_pending/`); the `benchmark` PR that collects

@@ -29,6 +29,7 @@ from benchmarks.families import deepseek_v3_serve as family
 from deeplearning4j_tpu.generation.decode import MLADecoder
 from deeplearning4j_tpu.generation.server import GenerationServer
 from deeplearning4j_tpu.kernels import mla_attention as mla
+from deeplearning4j_tpu.models import decoder_common
 from deeplearning4j_tpu.models import deepseek_v3 as ds
 from deeplearning4j_tpu.parallel.moe import routed_experts
 
@@ -501,7 +502,8 @@ def test_long_sequences_go_through_the_experts_in_runs(toy, monkeypatch):
     layer = params["layers"][2]
     g = jax.random.normal(jax.random.PRNGKey(5), (64, cfg.hidden_size))
     whole, counts = ds.moe(cfg, layer, g)
-    monkeypatch.setattr(ds, "MOE_CHUNK", 16)
+    # the expert layer is `decoder_common`'s, and reads its run length there
+    monkeypatch.setattr(decoder_common, "MOE_CHUNK", 16)
     runs, run_counts = ds.moe(cfg, layer, g)
     np.testing.assert_allclose(runs, whole, atol=1e-5, rtol=0)
     assert run_counts[0] == counts[0] == 64 * 4
@@ -554,3 +556,35 @@ def test_eight_shares_of_sixteen_add_up_to_the_uncut_reference():
     np.testing.assert_allclose(
         got, _reference(cut, ids, family.reference_sizes(one))[0],
         atol=LOGIT_TOL, rtol=0)
+
+
+def test_programs_lower_as_before_the_expert_layer_moved():
+    """`router_scores`, `swiglu` and `moe` live in `models/decoder_common.py`
+    (where `afmoe.py` takes them too) and still resolve here; the server's
+    superstep and admit over this model, lowered at toy size (StableHLO
+    without source locations), are the programs they were before the move:
+    sha256 of the text as the commit before it lowered them."""
+    import hashlib
+
+    from deeplearning4j_tpu.models import decoder_common as common
+    assert (ds.moe, ds.swiglu, ds.router_scores) == (
+        common.moe, common.swiglu, common.router_scores)
+    cfg = ds.DeepseekV3Config.from_dict(TOY)
+    params = jax.eval_shape(lambda k: ds.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    srv = GenerationServer(MLADecoder(cfg, params), slots=2,
+                           cache_lengths=[64], prompt_buckets=[32],
+                           max_new_tokens=8, seed=0)
+    sds = jax.ShapeDtypeStruct
+    try:
+        state = srv._state_spec(64)
+        with jax.default_matmul_precision("default"):
+            step = jax.jit(srv._traced_superstep(srv.superstep)).lower(
+                params, *state, sds((2, srv._step_width(64)), jnp.int32))
+            admit = jax.jit(srv._traced_admit).lower(
+                params, *state, sds((srv._admit_width(32),), jnp.int32))
+    finally:
+        srv.shutdown()
+    got = [hashlib.sha256(lowered.as_text().encode()).hexdigest()[:16]
+           for lowered in (step, admit)]
+    assert got == ["01486d796fb57ad7", "edacb233d54c051b"], got

@@ -20,7 +20,8 @@ NEW_METRICS = (
     "serve.idle_elsewhere_ms", "serve.loop_cpu_share", "serve.args_ms",
     "serve.call_ms", "serve.enqueue_ms", "serve.deliver_ms")
 SERVING_CELLS = ["bert_serve_decode", "nemotron3_super_serve_decode",
-                 "keye_vl2_serve_longdoc", "kanana2_serve_longdoc"]
+                 "keye_vl2_serve_longdoc", "kanana2_serve_longdoc",
+                 "trinity_mini_serve_longdoc"]
 
 
 def _op(s, e):

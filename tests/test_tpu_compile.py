@@ -673,3 +673,113 @@ def test_latent_decode_superstep_reads_the_leaf_in_place_for_v5e(
                    for c in calls) == 1, li
     assert sum("layer1/moe/experts/grouped_mlp" in c for c in calls) == 1
     assert not any("layer0/moe" in c for c in calls)
+
+
+# Trinity-Mini behind AfmoeDecoder: 32 query heads over 4 KV heads
+# of 128, sliding layers on a ring of 2048 rows beside full layers on the
+# 18432-row rung, prompts of 16384 through the banded prefill kernel.
+def _prefill_kernel_module(text):
+    """The Mosaic module of the one kernel in a lowered program, printed
+    without source locations."""
+    import base64
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    kernel, = re.findall(r'\\22body\\22: \\22([^\\]*)\\22', text)
+    context = jax_mlir.make_ir_context()
+    context.allow_unregistered_dialects = True
+    with context:
+        return ir.Module.parse(base64.b64decode(kernel)) \
+            .operation.get_asm(enable_debug_info=False)
+
+
+def test_prefill_kernel_without_a_window_is_the_kernel_it_was_for_v5e(
+        one_chip):
+    """`flash_attention(..., window=None)`, as BERT's and kanana-2's
+    prefills call it, builds the Mosaic module it built before the band
+    came in (sha256 of the text the commit before it printed): the causal
+    kernel at BERT's serving shape, and the native one at kanana-2's keys
+    of 192 and values of 128 in tiles of 1024."""
+    import hashlib
+
+    from deeplearning4j_tpu.kernels import flash_attention
+
+    def lowered(fn, *shapes):
+        args = [jax.ShapeDtypeStruct(s, bf16, sharding=one_chip)
+                for s in shapes]
+        with jax.default_matmul_precision("default"):
+            return jax.jit(fn).lower(*args).as_text()
+
+    bert = lowered(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, interpret=False), *[(8, 12, 512, 64)] * 3)
+    kanana = lowered(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=1024, block_k=1024, native=True,
+        interpret=False), (1, 32, 16384, 192), (1, 32, 16384, 192),
+        (1, 32, 16384, 128))
+    got = [hashlib.sha256(_prefill_kernel_module(t).encode()).hexdigest()[
+        :16] for t in (bert, kanana)]
+    assert got == ["aa8fea3ae37bf56a", "5482702a70bc08fb"], got
+
+
+def _band_prefill(q, k, v):
+    from deeplearning4j_tpu.kernels import flash_attention
+    return flash_attention(q, k, v, causal=True, block_q=1024, block_k=1024,
+                           native=True, window=2048, interpret=False)
+
+
+def test_banded_prefill_compiles_for_v5e(compile_for_chip):
+    """The band at the cell's largest prompt: 32 heads of 128, 16384
+    tokens, a window of 2048 in tiles of 1024 (three k tiles a q tile in
+    the grid, where the causal call has sixteen)."""
+    text = compile_for_chip(_band_prefill, *[((1, 32, 16384, 128), bf16)] * 3)
+    calls = [l for l in text.splitlines()
+             if "tpu_custom_call" in l and " custom-call(" in l]
+    assert len(calls) == 1 and "flash_fwd" in calls[0]
+
+
+def test_window_decode_superstep_keeps_both_leaves_in_place_for_v5e(
+        compile_for_chip, one_chip):
+    """`AfmoeDecoder`'s superstep at the published widths (48 slots, a rung
+    of 18432, the first 4 layers: two dense sliding layers, an expert
+    sliding layer and an expert full layer): the rings `(48, 2048, 512)`
+    and the full leaves `(48, 18432, 512)` are written and read in place,
+    with no copy of either and no temporary the size of a leaf; one
+    `flash_fwd` a layer under the scope of its kind (`window` or
+    `global`), and the expert layers' `grouped_mlp`."""
+    import json
+
+    from benchmarks.families.afmoe_serve import model_config
+    from deeplearning4j_tpu.generation.decode import AfmoeDecoder
+    from deeplearning4j_tpu.models import afmoe
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "..", "benchmarks", "configs",
+                           "trinity_mini_ep8.json")) as f:
+        cfg = model_config({**json.load(f), "num_hidden_layers": 4},
+                           "bfloat16")
+    slots, rung = 48, 18432
+    params = jax.eval_shape(lambda k: afmoe.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    dec = AfmoeDecoder(cfg, params, attn_impl="pallas")
+    cache = jax.eval_shape(lambda: dec.init_cache(slots, rung))
+    assert [l.shape for l in cache["k"]] \
+        == [(48, 2048, 512)] * 3 + [(48, 18432, 512)]
+
+    compiled = _compile_one_step_superstep(dec, params, cache, slots,
+                                           one_chip)
+    text = compiled.as_text()
+    # a copy of one full leaf would be 906 MB, of a ring 101 MB
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+    assert not [l for l in text.splitlines()
+                if re.search(r"bf16\[48,(2048|18432),512\]", l)
+                and " copy(" in l]
+    calls = [l for l in text.splitlines()
+             if "tpu_custom_call" in l and " custom-call(" in l]
+    for li, kind in enumerate(("window", "window", "window", "global")):
+        assert sum(f"layer{li}/attn/{kind}/flash_decode/flash_fwd" in c
+                   for c in calls) == 1, li
+    for li in (2, 3):
+        assert sum(f"layer{li}/moe/experts/grouped_mlp" in c
+                   for c in calls) == 1, li
+    assert not any("layer0/moe" in c or "layer1/moe" in c for c in calls)
