@@ -11,8 +11,10 @@ from jax import lax
 
 from deeplearning4j_tpu.parallel.moe import routed_experts
 
-#: tokens one call of an expert layer takes: its pairs' tiled rows and
-#: results are (tokens x top_k) x hidden, a gigabyte at 16384 tokens
+#: tokens one call of an expert layer takes: the ragged path's pairs' rows
+#: and results are (tokens x top_k) x hidden, a gigabyte at 16384 tokens,
+#: and the kernel holds a call's rows and sums in VMEM
+#: (`grouped_matmul.rows_a_call`)
 MOE_CHUNK = 2048
 
 
@@ -51,20 +53,21 @@ def moe(cfg, layer, g):
     normalised over all the chosen, times `cfg.routed_scaling_factor`) and
     the shared experts whole. Returns (out (T, H) float32, the
     `routed_experts` counts). Prefill and decode run the same function; a
-    long sequence goes through in runs of `MOE_CHUNK` tokens."""
+    long sequence goes through in runs of `MOE_CHUNK` tokens. The shared
+    product comes first and is the routed experts' starting value: the
+    expert kernel adds into it, so no sum of the two follows."""
     def run(tokens):
         with jax.named_scope("router"):
             scores = router_scores(layer, tokens)
+        with jax.named_scope("shared"):
+            shared = swiglu(tokens, layer["s_gate"], layer["s_up"],
+                            layer["s_down"]).astype(jnp.float32)
         with jax.named_scope("experts"):
-            out, counts = routed_experts(
+            return routed_experts(
                 tokens, scores, layer["router_bias"], layer["w_up"],
                 layer["w_down"], cfg.experts_held, cfg.num_experts_per_tok,
                 cfg.routed_scaling_factor, jax.nn.silu,
-                w_gate=layer["w_gate"])
-        with jax.named_scope("shared"):
-            out = out + swiglu(tokens, layer["s_gate"], layer["s_up"],
-                               layer["s_down"]).astype(jnp.float32)
-        return out, counts
+                w_gate=layer["w_gate"], init=shared)
 
     t = g.shape[0]
     if t <= MOE_CHUNK or t % MOE_CHUNK:

@@ -17,11 +17,15 @@ here:
   routed to it. On a TPU, at widths that are multiples of 128, that is the
   Pallas kernel of `kernels/grouped_matmul.py` (both projections fused, an
   expert's weight blocks whole in VMEM, row tiles of 16 to 128 rows sized
-  from the rows an expert sees on average); elsewhere (the CPU, the tests'
-  toy widths) two `lax.ragged_dot`s over the pairs sorted by expert. The
-  choice reads the backend and the operands' shapes, nothing else. What
-  the experts held elsewhere would add is left out: on one chip the layer
-  runs without its exchange, and nothing here stands in for it.
+  from the rows an expert sees on average), which picks each held pair's
+  token row itself and adds the pair's weighted result into the token's
+  output row itself: no row is moved outside it for a pair, held here or
+  not; elsewhere (the CPU, the tests' toy widths) two `lax.ragged_dot`s
+  over the pairs sorted by expert, their results gathered back and summed
+  with their weights. The choice reads the backend and the operands'
+  shapes, nothing else. What the experts held elsewhere would add is left
+  out: on one chip the layer runs without its exchange, and nothing here
+  stands in for it.
 """
 from __future__ import annotations
 
@@ -63,9 +67,45 @@ def _row_tile(rows_per_expert):
     return tile
 
 
+def _kernel_runs(x, key, weights, w_in, w_out, n, k, tm, activation,
+                 interpret, w_gate, init):
+    """The kernel over the tokens x (T, D), their pairs' groups `key` (T·k,)
+    and weights (T, k), each token's sum started from its row of `init`
+    (T, D) where given: in ONE call where the tokens fit what a call may
+    hold (`grouped_matmul.rows_a_call`), else one call a run of equal
+    length, the last padded with tokens whose pairs have no group. Each run
+    reads the weights of the experts it uses."""
+    t = x.shape[0]
+    per_call = grouped_matmul.rows_a_call(
+        t, k, x.shape[1], w_out.shape[2], x.dtype.itemsize, n, tm)
+
+    def call(x, key, weights, init):
+        return grouped_matmul.grouped_mlp(
+            x, w_in, w_out, key, activation, weights=weights,
+            rows=jnp.arange(key.shape[0], dtype=jnp.int32) // k,
+            row_tile=tm, interpret=interpret, w_gate=w_gate, init=init)
+
+    if per_call >= t:
+        return call(x, key, weights.reshape(-1), init)
+    runs = -(-t // per_call)
+    run = (-(-t // runs) + 15) // 16 * 16
+    pad = ((0, runs * run - t), (0, 0))
+    x, key, weights = (jnp.pad(x, pad), jnp.pad(
+        key.reshape(t, k), pad, constant_values=n), jnp.pad(weights, pad))
+    if init is not None:
+        init = jnp.pad(init, pad)
+    # unrolled: under `lax.map` XLA would place the stacked results in
+    # VMEM beside the kernel's own
+    return jnp.concatenate([
+        call(x[i:i + run], key[i:i + run].reshape(-1),
+             weights[i:i + run].reshape(-1),
+             None if init is None else init[i:i + run])
+        for i in range(0, runs * run, run)])[:t]
+
+
 def routed_experts(x, scores, select_bias, w_in, w_out, held, top_k,
                    scale=1.0, activation=jax.nn.relu, impl="auto",
-                   interpret=None, w_gate=None):
+                   interpret=None, w_gate=None, init=None):
     """This chip's part of a top-k expert layer.
 
     - x (T, D): the tokens, in the experts' input width
@@ -82,15 +122,19 @@ def routed_experts(x, scores, select_bias, w_in, w_out, held, top_k,
       then computes `(activation(x @ w_gate[e]) * (x @ w_in[e])) @
       w_out[e]` (SwiGLU with `activation = silu`). Without it the
       programs are what they were
+    - init (T, D) float32 or None: where each token's sum starts (a shared
+      expert's output that the layer adds anyway). The kernel starts its
+      sum from it and writes the result over it, so a caller computes it
+      BEFORE the routed experts and adds nothing after them
 
-    Returns (out (T, D) float32, counts (3,) int32 as `ROUTED_COUNTS`:
-    the (token, held expert) pairs computed, the held experts with at
-    least one pair, and the fullest expert's pairs).
+    Returns (out (T, D) float32, the routed sum after `init`; counts (3,)
+    int32 as `ROUTED_COUNTS`: the (token, held expert) pairs computed, the
+    held experts with at least one pair, and the fullest expert's pairs).
 
     There are T·top_k pairs, what every chosen expert being held here
-    would fill; those of experts held elsewhere belong to no group, the
-    grouped product skips them and what it leaves in their rows is
-    selected away.
+    would fill; those of experts held elsewhere belong to no group and add
+    nothing: the kernel neither computes nor adds them, the ragged path
+    selects their rows away.
 
     impl: 'auto' (the Pallas kernel on a TPU at widths it takes,
     `lax.ragged_dot` elsewhere), 'pallas' (force the kernel; interpreted
@@ -118,11 +162,9 @@ def routed_experts(x, scores, select_bias, w_in, w_out, held, top_k,
         raise ValueError(f"unknown routed_experts impl {impl!r}; expected "
                          f"'auto', 'pallas' or 'ragged'")
     if impl == "pallas":
-        y = grouped_matmul.grouped_mlp(
-            x, w_in, w_out, key, activation,
-            rows=jnp.arange(t * k, dtype=jnp.int32) // k,
-            row_tile=_row_tile(t * k / scores.shape[-1]),
-            interpret=interpret, w_gate=w_gate)
+        out = _kernel_runs(x, key, weights, w_in, w_out, n, k,
+                           _row_tile(t * k / scores.shape[-1]), activation,
+                           interpret, w_gate, init)
     else:
         # pairs sorted by held expert, those of experts held elsewhere
         # last: rows past the groups, which the grouped product skips
@@ -139,10 +181,12 @@ def routed_experts(x, scores, select_bias, w_in, w_out, held, top_k,
         y = lax.ragged_dot(h.astype(x.dtype),
                            w_out.astype(x.dtype), group_sizes,
                            preferred_element_type=jnp.float32)
-        y = jnp.take(y, jnp.argsort(order), axis=0)
-    # a pair not held adds nothing
-    y = y.reshape(t, k, -1)
-    out = jnp.where(is_held[..., None], weights[..., None] * y, 0.0).sum(1)
+        y = jnp.take(y, jnp.argsort(order), axis=0).reshape(t, k, -1)
+        # a pair not held adds nothing
+        out = jnp.where(is_held[..., None], weights[..., None] * y,
+                        0.0).sum(1)
+        if init is not None:
+            out = init + out
     counts = jnp.stack([group_sizes.sum(), (group_sizes > 0).sum(),
                         group_sizes.max()]).astype(jnp.int32)
     return out, counts

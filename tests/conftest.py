@@ -2,11 +2,14 @@
 
 Must set env vars BEFORE jax initializes its backend, hence module level in
 conftest. Multi-chip sharding paths (parallel/) run against these virtual
-devices; the real TPU is only used by chip_smoke.py and bench.py.
+devices; the real TPU is only used by chip_smoke.py and bench.py, and by
+the few tests that run on a chip only (each skips elsewhere), under
+`DL4J_TEST_ON_CHIP=1`, which leaves the platform alone.
 """
 import os
 
-os.environ["JAX_PLATFORMS"] = "cpu"
+if os.environ.get("DL4J_TEST_ON_CHIP") != "1":
+    os.environ["JAX_PLATFORMS"] = "cpu"
 xla_flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in xla_flags:
     os.environ["XLA_FLAGS"] = (

@@ -563,7 +563,9 @@ def test_programs_lower_as_before_the_expert_layer_moved():
     (where `afmoe.py` takes them too) and still resolve here; the server's
     superstep and admit over this model, lowered at toy size (StableHLO
     without source locations), are the programs they were before the move:
-    sha256 of the text as the commit before it lowered them."""
+    sha256 of the text as the commit before it lowered them, since taken
+    again for one change of the expert layer's, its shared product
+    computed first as the routed experts' starting value."""
     import hashlib
 
     from deeplearning4j_tpu.models import decoder_common as common
@@ -587,4 +589,4 @@ def test_programs_lower_as_before_the_expert_layer_moved():
         srv.shutdown()
     got = [hashlib.sha256(lowered.as_text().encode()).hexdigest()[:16]
            for lowered in (step, admit)]
-    assert got == ["01486d796fb57ad7", "edacb233d54c051b"], got
+    assert got == ["6d091e4e66b0c801", "956ca80a74c5b88c"], got

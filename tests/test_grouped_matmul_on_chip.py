@@ -1,0 +1,143 @@
+"""The expert layer on a chip, against the same layer of another tree: wall
+clock a call at the shapes the serving cells run, and the two results held
+to each other. Skipped off the TPU. On a machine with one:
+
+    env DL4J_TEST_ON_CHIP=1 DL4J_PARENT_TREE=<checkout> \\
+        python -m pytest -s tests/test_grouped_matmul_on_chip.py
+
+`DL4J_PARENT_TREE` is a checkout of the commit to compare with (for
+instance `git archive <commit>` unpacked into a git-ignored directory of
+this one); its `parallel/moe.py`, `kernels/grouped_matmul.py` and
+`models/decoder_common.py` are loaded beside this tree's. Where a model has
+a shared expert beside its routed ones (kanana-2, Trinity:
+`decoder_common.moe`), the layer timed is that function, router and shared
+expert included; elsewhere (Keye's gated experts, the hybrid's latent
+ones) it is `routed_experts` alone. Each shape prints one JSON line:
+milliseconds a call (median of `CALLS`, the two trees' calls alternating),
+the pairs held.
+"""
+import importlib.util
+import json
+import os
+import time
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deeplearning4j_tpu.models import decoder_common
+from deeplearning4j_tpu.models.nemotron_h import relu2
+from deeplearning4j_tpu.parallel import moe
+
+CALLS = 30
+
+#: id -> tokens, experts, top_k, held, width, expert width, gated, scale,
+#: shared width (0: no shared expert in this layer): the long-context
+#: cells' prefill runs of 2048 tokens (16 of 128 experts held) and
+#: kanana-2's decode step of 48 slots, the hybrid's decode step and
+#: long-bucket admit (128 of 512), and its one prompt of 4096 tokens, past
+#: what one call holds (two runs)
+SHAPES = {
+    "keye_prefill_run": (2048, 128, 8, 16, 2048, 768, True, 1.0, 0),
+    "trinity_prefill_run": (2048, 128, 8, 16, 2048, 1024, True, 2.826, 1024),
+    "kanana2_prefill_run": (2048, 128, 6, 16, 2048, 768, True, 2.448, 1536),
+    "kanana2_decode_step": (48, 128, 6, 16, 2048, 768, True, 2.448, 1536),
+    "hybrid_decode_step": (128, 512, 22, 128, 1024, 2688, False, 5.0, 0),
+    "hybrid_long_admit": (512, 512, 22, 128, 1024, 2688, False, 5.0, 0),
+    "hybrid_prompt_4096": (4096, 512, 22, 128, 1024, 2688, False, 5.0, 0),
+}
+
+
+def _load(name, path):
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+@pytest.fixture(scope="module")
+def other_tree():
+    """The other tree's (routed_experts, decoder_common.moe)."""
+    if jax.default_backend() != "tpu":
+        pytest.skip("times the expert layer on a TPU only")
+    tree = os.environ.get("DL4J_PARENT_TREE")
+    if not tree:
+        pytest.skip("DL4J_PARENT_TREE names no tree to compare with")
+    pkg = os.path.join(tree, "deeplearning4j_tpu")
+    other = _load("other_moe", os.path.join(pkg, "parallel", "moe.py"))
+    other.grouped_matmul = _load(
+        "other_grouped_matmul",
+        os.path.join(pkg, "kernels", "grouped_matmul.py"))
+    common = _load("other_decoder_common",
+                   os.path.join(pkg, "models", "decoder_common.py"))
+    common.routed_experts = other.routed_experts
+    return other.routed_experts, common.moe
+
+
+def _operands(t, e, held, d, f, shared, seed):
+    keys = iter(jax.random.split(jax.random.key(seed), 9))
+
+    def mat(*shape):
+        return 0.02 * jax.random.normal(next(keys), shape, jnp.bfloat16)
+    x = jax.random.normal(next(keys), (t, d), jnp.bfloat16)
+    layer = dict(router=mat(d, e),
+                 router_bias=0.01 * jax.random.normal(next(keys), (e,)),
+                 w_gate=mat(held, d, f), w_up=mat(held, d, f),
+                 w_down=mat(held, f, d))
+    if shared:
+        layer.update(s_gate=mat(d, shared), s_up=mat(d, shared),
+                     s_down=mat(shared, d))
+    return x, layer
+
+
+def _ms_a_call(fns, args):
+    """Median wall clock of each of `fns` over CALLS calls, interleaved."""
+    times = [[] for _ in fns]
+    for _ in range(CALLS):
+        for fn, ts in zip(fns, times):
+            start = time.perf_counter()
+            jax.block_until_ready(fn(*args))
+            ts.append(time.perf_counter() - start)
+    return [1e3 * float(np.median(ts)) for ts in times]
+
+
+@pytest.mark.parametrize("shape", list(SHAPES))
+def test_expert_layer_time_against_another_tree_on_the_chip(other_tree,
+                                                            shape):
+    t, e, k, held, d, f, gated, scale, shared = SHAPES[shape]
+    x, layer = _operands(t, e, held, d, f, shared, seed=t + k)
+    cfg = types.SimpleNamespace(experts_held=(0, held),
+                                num_experts_per_tok=k,
+                                routed_scaling_factor=scale)
+    other_routed, other_moe = other_tree
+
+    def jitted(routed, common_moe):
+        if shared:
+            return jax.jit(lambda x, layer: common_moe(cfg, layer, x))
+
+        def routed_layer(x, layer):
+            scores = jax.nn.sigmoid(x.astype(jnp.float32)
+                                    @ layer["router"].astype(jnp.float32))
+            return routed(
+                x, scores, layer["router_bias"], layer["w_up"],
+                layer["w_down"], (0, held), k, scale,
+                jax.nn.silu if gated else relu2, impl="pallas",
+                w_gate=layer["w_gate"] if gated else None)
+        return jax.jit(routed_layer)
+
+    this = jitted(moe.routed_experts, decoder_common.moe)
+    other = jitted(other_routed, other_moe)
+    args = (x, layer)
+    # the numerics the programs run with, not the CPU oracles' "highest"
+    with jax.default_matmul_precision("default"):
+        (got, got_counts), (want, want_counts) = this(*args), other(*args)
+        this_ms, other_ms = _ms_a_call([this, other], args)
+    print(json.dumps({"shape": shape, "this_ms": round(this_ms, 4),
+                      "other_ms": round(other_ms, 4),
+                      "pairs_held": int(got_counts[0]), "pairs": t * k}))
+    assert list(np.asarray(got_counts)) == list(np.asarray(want_counts))
+    # the same products; only the order of each row's float32 sum differs
+    np.testing.assert_allclose(
+        got, want, rtol=0, atol=1e-5 * float(jnp.abs(want).max()))

@@ -324,15 +324,24 @@ def test_kernel_path_equals_ragged_path_and_looped_experts(t, first, count):
 
 
 def test_rows_the_kernel_never_visits_do_not_reach_the_output(monkeypatch):
-    """The kernel leaves the tiles past the last group as they were (on the
-    chip: whatever the buffer held). Poisoned here, they change nothing."""
-    from deeplearning4j_tpu.kernels import grouped_matmul as gm
-    real = gm._tiled_mlp
+    """The kernel neither picks nor adds a tiled row that holds no pair (a
+    group's last tile past its last pair, the tiles past the last group):
+    on the chip such a row holds whatever the buffer held. Here the scratch
+    starts as NaN, and every such row names a pair of weight NaN; they
+    change nothing."""
+    from jax.experimental.pallas import tpu as pltpu
 
-    def poisoned(xt, w_in, w_out, tile_group, tiles, activation, tm, *rest):
-        yt = real(xt, w_in, w_out, tile_group, tiles, activation, tm, *rest)
-        visited = jnp.arange(yt.shape[0]) < jnp.maximum(tiles, 1) * tm
-        return jnp.where(visited[:, None], yt, jnp.nan)
+    from deeplearning4j_tpu.kernels import grouped_matmul as gm
+    real = gm._fused_mlp
+
+    def poisoned(x, w_in, w_out, tile_group, tile_count, tiles, tile_pair,
+                 rows, weights, activation, tm, *rest):
+        row = jnp.arange(tile_pair.shape[0])
+        used = row % tm < tile_count[row // tm]
+        m = rows.shape[0]
+        return real(x, w_in, w_out, tile_group, tile_count, tiles,
+                    jnp.where(used, tile_pair, m), jnp.append(rows, 0),
+                    jnp.append(weights, jnp.nan), activation, tm, *rest)
 
     rng = np.random.default_rng(5)
     x = jnp.asarray(rng.normal(size=(24, 128)), jnp.float32)
@@ -342,8 +351,9 @@ def test_rows_the_kernel_never_visits_do_not_reach_the_output(monkeypatch):
     w_out = jnp.asarray(rng.normal(size=(4, 256, 128)) * 0.05, jnp.float32)
     args = (x, scores, jnp.zeros(16), w_in, w_out, (4, 4), 4, 5.0, nh.relu2)
     clean, _ = routed_experts(*args, impl="pallas", interpret=True)
-    monkeypatch.setattr(gm, "_tiled_mlp", poisoned)
-    got, counts = routed_experts(*args, impl="pallas", interpret=True)
+    monkeypatch.setattr(gm, "_fused_mlp", poisoned)
+    got, counts = routed_experts(*args, impl="pallas",
+                                 interpret=pltpu.InterpretParams())
     assert int(counts[0]) < 24 * 4 // 2        # most pairs are held elsewhere
     assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_array_equal(got, clean)

@@ -464,11 +464,11 @@ def _keye_decode_in_place(q, k, v, m, n):
                                   interpret=False, lengths=n)
 
 
-def _keye_gated_experts(x, w_gate, w_up, w_down, groups):
+def _keye_gated_experts(x, w_gate, w_up, w_down, groups, weights):
     from deeplearning4j_tpu.kernels.grouped_matmul import grouped_mlp
     return grouped_mlp(x, w_up, w_down, groups, jax.nn.silu,
                        rows=jnp.arange(groups.shape[0]) // 8,
-                       w_gate=w_gate, interpret=False)
+                       weights=weights, w_gate=w_gate, interpret=False)
 
 
 _KEYE_EXPERTS = (((16, 2048, 768), bf16), ((16, 2048, 768), bf16),
@@ -493,9 +493,9 @@ _KEYE_EXPERTS = (((16, 2048, 768), bf16), ((16, 2048, 768), bf16),
                              ((32, 18432), jnp.bool_), ((32,), i32)),
      "flash_fwd"),
     (_keye_gated_experts, (((32, 2048), bf16), *_KEYE_EXPERTS,
-                           ((256,), i32)), "grouped_mlp"),
+                           ((256,), i32), ((256,), f32)), "grouped_mlp"),
     (_keye_gated_experts, (((2048, 2048), bf16), *_KEYE_EXPERTS,
-                           ((16384,), i32)), "grouped_mlp"),
+                           ((16384,), i32), ((16384,), f32)), "grouped_mlp"),
 ], ids=["index_scores", "index_scores_decode", "selected_attention",
         "decode_over_the_gathered_rung", "decode_in_place_under_a_selection",
         "gated_experts_a_step",
@@ -508,11 +508,91 @@ def test_sparse_attention_kernels_compile_for_v5e(compile_for_chip, fn,
     grouped-query decode kernel over a GATHERED rung of 2048 rows and over
     the whole 18432-row leaf in place under a selection mask and the slots'
     lengths (PR 36), and the gated expert (three weight blocks of 3.1 MB,
-    double-buffered)."""
+    double-buffered) whose rows go in and whose weighted results come out
+    inside the kernel: no gather is left, and no array of the tiled rows
+    (V·tm, K) or of the pairs' results (m, D), both longer than x, is
+    anywhere in the program."""
     text = compile_for_chip(fn, *specs)
     calls = [l for l in text.splitlines()
              if "tpu_custom_call" in l and " custom-call(" in l]
     assert len(calls) == 1 and name in calls[0]
+    if fn is _keye_gated_experts:
+        tokens = specs[0][0][0]
+        assert " gather(" not in text
+        assert max(int(r) for r in re.findall(r"\[(\d+),2048\]", text)) \
+            == tokens
+
+
+def _expert_layer(top_k, n, gated):
+    def layer(x, scores, *weights):
+        from deeplearning4j_tpu.parallel.moe import routed_experts
+        *gate, w_in, w_out = weights
+        return routed_experts(
+            x, scores, None, w_in, w_out, (0, n), top_k, 1.0,
+            jax.nn.silu if gated else jax.nn.relu, impl="pallas",
+            interpret=False, w_gate=gate[0] if gate else None)
+    return layer
+
+
+@pytest.mark.parametrize("tokens,experts,top_k,n,width,ff,gated,calls", [
+    (4096, 512, 22, 128, 1024, 2688, False, 2),
+    (2048, 128, 8, 16, 4096, 1024, True, 2),
+    (77, 128, 8, 16, 2048, 1024, True, 1),
+], ids=["hybrid_bucket_4096", "prefill_run_at_width_4096",
+        "decode_step_of_77_slots"])
+def test_the_expert_layer_compiles_at_its_largest_calls(
+        compile_for_chip, tokens, experts, top_k, n, width, ff, gated,
+        calls):
+    """What one call of the expert kernel holds grows with its tokens (x
+    and their sums in VMEM) and its pairs (the layout's tables in SMEM,
+    1 MiB on a v5e), so `routed_experts` takes tokens past what a call may
+    hold in runs: the hybrid's one prompt of 4096 tokens (90 112 pairs,
+    `nemotron_h.moe_mixer` runs it whole) and a 2048-token run at a width
+    of 4096 each in two calls; a decode step of a slot count that is no
+    multiple of 8 in one."""
+    specs = [((tokens, width), bf16), ((tokens, experts), f32),
+             *[((n, width, ff), bf16)] * (2 if gated else 1),
+             ((n, ff, width), bf16)]
+    text = compile_for_chip(_expert_layer(top_k, n, gated), *specs)
+    kernels = [l for l in text.splitlines()
+               if "tpu_custom_call" in l and " custom-call(" in l]
+    assert len(kernels) == calls
+    assert all("grouped_mlp" in l for l in kernels)
+    assert " gather(" not in text
+
+
+def _expert_layer_from_a_start(top_k, n):
+    def layer(x, scores, start, w_gate, w_in, w_out):
+        from deeplearning4j_tpu.parallel.moe import routed_experts
+        return routed_experts(
+            x, scores, None, w_in, w_out, (0, n), top_k, 2.448, jax.nn.silu,
+            impl="pallas", interpret=False, w_gate=w_gate, init=start)
+    return layer
+
+
+@pytest.mark.parametrize("tokens,top_k,width,ff,calls", [
+    (2048, 6, 2048, 768, 1),
+    (2048, 8, 4096, 1024, 2),
+    (77, 6, 2048, 768, 1),
+], ids=["kanana2_prefill_run", "prefill_run_at_width_4096",
+        "decode_step_of_77_slots"])
+def test_the_expert_layer_sums_into_its_start_for_v5e(
+        compile_for_chip, tokens, top_k, width, ff, calls):
+    """Handed a starting value (a shared expert's output, float32), each
+    call of the kernel starts its sum from that run's rows and writes its
+    result over them (the output aliases the operand), so nothing adds a
+    `(tokens, width)` float32 array after the kernel: the layer's whole sum
+    is the kernel's."""
+    specs = [((tokens, width), bf16), ((tokens, 128), f32),
+             ((tokens, width), f32), *[((16, width, ff), bf16)] * 2,
+             ((16, ff, width), bf16)]
+    text = compile_for_chip(_expert_layer_from_a_start(top_k, 16), *specs)
+    kernels = [l for l in text.splitlines()
+               if "tpu_custom_call" in l and " custom-call(" in l]
+    assert len(kernels) == calls
+    assert all("grouped_mlp" in l and "output_to_operand_aliasing" in l
+               for l in kernels)
+    assert not re.findall(rf"f32\[{tokens},{width}\]\S* add\(", text)
 
 
 def _lower_one_step_superstep(dec, params, cache, slots, one_chip):
