@@ -1,7 +1,7 @@
 """Decode-mode forwards: incremental single-token model evaluation over
 donated device state.
 
-Six adapters expose one contract to the GenerationServer:
+Seven adapters expose one contract to the GenerationServer:
 
 - **BertDecoder** — transformer stacks built on `models/bert.py` params:
   one K and one V cache leaf A LAYER, `(S, C, H·Dh)` (S slots, C =
@@ -81,6 +81,17 @@ Six adapters expose one contract to the GenerationServer:
   layers count as NemotronHDecoder's do, and the attention counts the
   rows in use of each kind of layer.
 
+- **Lfm2MoeDecoder** — stacks built on `models/lfm2_moe.py` params, whose
+  layers are gated short convolutions or grouped-query attention and whose
+  cache holds state of both kinds: a conv layer's TAIL `(S, K-1, H)`, the
+  last K-1 rows of `B * x` that its convolution reads, which no rung
+  touches, beside an attention layer's K and V rows `(S, C, Hkv·Dh)`,
+  which grow with the rung. `prefill` grafts a tail as the last REAL
+  positions of the padded prompt left it (`decoder_common.conv_tail`);
+  `step` shifts it by the token (`decoder_common.conv_shift`) and attends
+  through `flash_attention_decode(..., lengths=)`. Its expert layers count
+  as NemotronHDecoder's do, and the attention counts its rows in use.
+
 The contract (all pure functions, traced into AOT executables by the
 server — nothing here may touch the host):
 
@@ -145,13 +156,14 @@ from deeplearning4j_tpu.kernels.mla_attention import (latent_tile_positions,
                                                       pack_latent)
 from deeplearning4j_tpu.kernels.selection import (compact_indices,
                                                   top_k_mask)
-from deeplearning4j_tpu.models import afmoe, deepseek_v3, keye_vl, nemotron_h
+from deeplearning4j_tpu.models import (afmoe, deepseek_v3, keye_vl,
+                                       lfm2_moe, nemotron_h)
 from deeplearning4j_tpu.models.bert import (_ffn, _layer_norm,
                                             bert_mlm_logits)
 from deeplearning4j_tpu.parallel.ring_attention import dense_attention
 
-__all__ = ["AfmoeDecoder", "BertDecoder", "KeyeDecoder", "MLADecoder",
-           "NemotronHDecoder", "RecurrentDecoder"]
+__all__ = ["AfmoeDecoder", "BertDecoder", "KeyeDecoder", "Lfm2MoeDecoder",
+           "MLADecoder", "NemotronHDecoder", "RecurrentDecoder"]
 
 
 def _write_kv(cache, li, wi, wj, k, v):
@@ -1210,6 +1222,153 @@ class AfmoeDecoder:
                         leaf, part[None].astype(leaf.dtype), (slot, 0, 0))
         h_last = jnp.take(x, plen - 1, axis=0)                  # (H,)
         return {**cache, **leaves}, afmoe.logits(cfg, params, h_last)
+
+
+class Lfm2MoeDecoder:
+    """Decode over a `models/lfm2_moe.py` parameter tree: gated short
+    convolutions and grouped-query attention layers, each with the state
+    its kind needs.
+
+    The cache pytree: `{"k": [...], "v": [...]}`, one `(S, C, Hkv·Dh)` K
+    and one V leaf an ATTENTION layer in the compute dtype, rows major;
+    `"conv"`, one `(S, K-1, H)` tail a CONV layer in the compute dtype;
+    `"counts"`, the running counts (`counter_names`). Only the K/V leaves
+    know the rung.
+
+    The full-sequence reference this must match is `deeplearning4j_tpu.
+    models.lfm2_moe.forward` over the same prompt+generated prefix (and,
+    outside the package, the plain reference under `benchmarks/families/`)."""
+
+    uses_cache_rungs = True
+    n_model_args = 1
+    supports_draft = False
+    max_cache_len = None        # rotary positions: no table bounds them
+    counter_names = ("moe_pairs", "moe_expert_reads", "moe_pairs_max",
+                     "global_rows_attended")
+
+    def __init__(self, cfg, params, attn_impl="auto"):
+        if attn_impl not in ("auto", "dense", "pallas"):
+            raise ValueError(
+                f"attn_impl must be 'auto', 'dense' or 'pallas', "
+                f"got {attn_impl!r}")
+        self.cfg = cfg
+        self.params = params
+        self.attn_impl = attn_impl
+        self.vocab_size = int(cfg.vocab_size)
+        # layer index -> index among the layers of its kind (its leaves)
+        self._leaf = [sum(cfg.is_attention(j) == cfg.is_attention(li)
+                          for j in range(li))
+                      for li in range(cfg.num_hidden_layers)]
+
+    def fingerprint(self):
+        parts = ("lfm2-moe-decode", repr(self.cfg), self.attn_impl,
+                 _shape_tree_repr(self.params),
+                 _shape_tree_repr(
+                     jax.eval_shape(lambda: self.init_cache(1, 1))))
+        return hashlib.sha256(repr(parts).encode()).hexdigest()[:16]
+
+    def model_args(self):
+        return (self.params,)
+
+    def init_cache(self, slots, cache_len):
+        cfg = self.cfg
+        dt = cfg.compute_dtype
+        na = sum(map(cfg.is_attention, range(cfg.num_hidden_layers)))
+        nc = cfg.num_hidden_layers - na
+
+        def leaves(n, shape):
+            return [jnp.zeros((slots,) + shape, dt) for _ in range(n)]
+
+        return {"k": leaves(na, (cache_len, cfg.kv_width)),
+                "v": leaves(na, (cache_len, cfg.kv_width)),
+                "conv": leaves(nc, (cfg.conv_L_cache - 1, cfg.hidden_size)),
+                "counts": jnp.zeros((len(self.counter_names),), jnp.int32)}
+
+    def grow(self, cache, new_len):
+        """The K/V leaves padded to the longer rung; the tails and the
+        counts as they are."""
+        def pad(t):
+            return jnp.pad(t, ((0, 0), (0, int(new_len) - t.shape[1]),
+                               (0, 0)))
+        return {**cache, "k": [pad(t) for t in cache["k"]],
+                "v": [pad(t) for t in cache["v"]]}
+
+    def counters(self, cache):
+        return cache["counts"]
+
+    def step(self, margs, cache, tokens, pos):
+        """One decode step for the whole batch: a conv layer shifts its
+        slots' tails by the token; an attention layer writes its K and V
+        rows at `pos` and attends the rows in use, `min(pos + 1, C)`; then
+        the feed-forward, dense or experts. Returns next-token logits (S,
+        V). Counted a step, slots and layers summed: the attention layers'
+        rows in use."""
+        (params,) = margs
+        cfg = self.cfg
+        with jax.named_scope("embed"):
+            x = lfm2_moe.embed(params, tokens)                  # (S, H)
+        with jax.named_scope("rope"):
+            tables = lfm2_moe.rope_tables(cfg, pos)
+        leaves = {name: list(cache[name]) for name in ("k", "v", "conv")}
+        counted = jnp.zeros((3,), jnp.int32)
+        rows = jnp.zeros((), jnp.int32)
+        for li, layer in enumerate(params["layers"]):
+            i = self._leaf[li]
+            with jax.named_scope(f"layer{li}"):
+                if cfg.is_attention(li):
+                    # rows 0..pos are in use (the current write included);
+                    # a lane frozen at the end of its rung (pos == C)
+                    # keeps all of them
+                    in_use = jnp.minimum(pos + 1, leaves["k"][i].shape[1])
+                    x, (leaves["k"][i], leaves["v"][i]), counts = \
+                        lfm2_moe.decode_layer(
+                            cfg, li, layer, x, tables,
+                            (leaves["k"][i], leaves["v"][i]), pos, in_use,
+                            self.attn_impl)
+                    rows = rows + in_use.sum()
+                else:
+                    x, leaves["conv"][i], counts = lfm2_moe.decode_layer(
+                        cfg, li, layer, x, tables, leaves["conv"][i], pos,
+                        None, self.attn_impl)
+            if counts is not None:
+                counted = counted + counts
+        counts = cache["counts"] + jnp.concatenate([counted, rows[None]])
+        return lfm2_moe.logits(cfg, params, x), {**leaves, "counts": counts}
+
+    def prefill(self, margs, cache, slot, prompt, plen):
+        """The full forward over one length-bucketed prompt (P,), attention
+        through the causal prefill kernel, then the graft of the slot's
+        state a layer: an attention layer's K/V rows 0..P-1 (rows beyond
+        plen hold padding garbage that the decode step never reads: it
+        attends rows up to `pos`), a conv layer's tail as the last REAL
+        positions left it (z of positions plen - K + 1 .. plen - 1, zeros
+        before 0; the bucket's last rows would be padding). Returns the
+        logits at plen - 1."""
+        (params,) = margs
+        cfg = self.cfg
+        x, states = lfm2_moe.encode(cfg, params, prompt, plen,
+                                    impl=self.attn_impl)
+        leaves = {name: list(cache[name]) for name in ("k", "v", "conv")}
+
+        def graft(name, i, part):
+            leaf = leaves[name][i]
+            leaves[name][i] = lax.dynamic_update_slice(
+                leaf, part[None].astype(leaf.dtype), (slot, 0, 0))
+
+        for li, state in enumerate(states):
+            i = self._leaf[li]
+            with jax.named_scope(f"layer{li}"):
+                if cfg.is_attention(li):
+                    with jax.named_scope("attn"), \
+                            jax.named_scope("kv_write"):
+                        graft("k", i, state[0])
+                        graft("v", i, state[1])
+                else:
+                    with jax.named_scope("shortconv"), \
+                            jax.named_scope("tail_write"):
+                        graft("conv", i, state)
+        h_last = jnp.take(x, plen - 1, axis=0)                  # (H,)
+        return {**cache, **leaves}, lfm2_moe.logits(cfg, params, h_last)
 
 
 class RecurrentDecoder:

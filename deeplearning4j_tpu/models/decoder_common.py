@@ -1,8 +1,10 @@
 """What the served language models of this package share outside their
 attention: the norm, the head, the run of tokens an expert layer takes at
-once, and the sigmoid-routed expert layer with its shared SwiGLU that
-`deepseek_v3.py` and `afmoe.py` both compute. One place, so that no model's
-file imports another's."""
+once, the sigmoid-routed expert layer (with or without a shared SwiGLU)
+that `deepseek_v3.py`, `afmoe.py` and `lfm2_moe.py` compute, and the two
+steps of a causal convolution's tail that `nemotron_h.py` and
+`lfm2_moe.py` keep. One place, so that no model's file imports
+another's."""
 from __future__ import annotations
 
 import jax
@@ -51,23 +53,30 @@ def moe(cfg, layer, g):
     routed experts (those of `cfg.experts_held`, `cfg.num_experts_per_tok`
     of them a token by `s + router_bias`; the weights the chosen `s`
     normalised over all the chosen, times `cfg.routed_scaling_factor`) and
-    the shared experts whole. Returns (out (T, H) float32, the
-    `routed_experts` counts). Prefill and decode run the same function; a
-    long sequence goes through in runs of `MOE_CHUNK` tokens. The shared
-    product comes first and is the routed experts' starting value: the
-    expert kernel adds into it, so no sum of the two follows."""
+    the shared experts whole, where the layer has them (`s_gate`). Returns
+    (out (T, H) float32, the `routed_experts` counts). Prefill and decode
+    run the same function; a long sequence goes through in runs of
+    `MOE_CHUNK` tokens. The shared product comes first and is the routed
+    experts' starting value: the expert kernel adds into it, so no sum of
+    the two follows; without one the sum starts from zero. The
+    normalisation adds `cfg.route_norm_eps` to the chosen scores' sum
+    where the family names one, 1e-20 elsewhere."""
+    eps = getattr(cfg, "route_norm_eps", 1e-20)
+
     def run(tokens):
         with jax.named_scope("router"):
             scores = router_scores(layer, tokens)
-        with jax.named_scope("shared"):
-            shared = swiglu(tokens, layer["s_gate"], layer["s_up"],
-                            layer["s_down"]).astype(jnp.float32)
+        shared = None
+        if "s_gate" in layer:
+            with jax.named_scope("shared"):
+                shared = swiglu(tokens, layer["s_gate"], layer["s_up"],
+                                layer["s_down"]).astype(jnp.float32)
         with jax.named_scope("experts"):
             return routed_experts(
                 tokens, scores, layer["router_bias"], layer["w_up"],
                 layer["w_down"], cfg.experts_held, cfg.num_experts_per_tok,
                 cfg.routed_scaling_factor, jax.nn.silu,
-                w_gate=layer["w_gate"], init=shared)
+                w_gate=layer["w_gate"], init=shared, norm_eps=eps)
 
     t = g.shape[0]
     if t <= MOE_CHUNK or t % MOE_CHUNK:
@@ -75,3 +84,23 @@ def moe(cfg, layer, g):
     out, counts = lax.map(run, g.reshape(t // MOE_CHUNK, MOE_CHUNK, -1))
     return out.reshape(t, -1), jnp.concatenate(
         [counts[:, :2].sum(0), counts[:, 2:].max(0)])
+
+
+# -- a causal convolution's tail ---------------------------------------------
+def conv_tail(padded, plen, rows):
+    """What a causal convolution of `rows + 1` taps carries past each
+    sequence's last REAL position: the `rows` rows before `plen` (B,) of
+    `padded` (B, rows + T, C), the sequences left-padded with `rows` zero
+    rows (so a prompt shorter than the tail keeps zeros before position 0)
+    and right-padded past `plen`. Returns (B, rows, C)."""
+    return jax.vmap(lambda seq, at: lax.dynamic_slice_in_dim(
+        seq, at, rows, axis=0))(padded, plen)
+
+
+def conv_shift(tail, row, conv):
+    """One decode step of a causal convolution of K taps: the slots' `tail`
+    (S, K-1, C) and the new row `row` (S, C) make the window of the K rows
+    ending at the new one (S, K, C); returns (`conv` of that window, the
+    tail after it: the window without its oldest row)."""
+    window = jnp.concatenate([tail, row[:, None].astype(tail.dtype)], axis=1)
+    return conv(window), window[:, 1:]

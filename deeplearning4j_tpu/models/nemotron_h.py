@@ -37,6 +37,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from deeplearning4j_tpu.kernels.flash_attention import flash_attention
+from deeplearning4j_tpu.models.decoder_common import conv_shift, conv_tail
 from deeplearning4j_tpu.parallel.moe import routed_experts
 from deeplearning4j_tpu.parallel.ring_attention import dense_attention
 
@@ -319,8 +320,7 @@ def mamba_mixer(cfg, layer, u, plen):
         padded = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0)))
         window = jnp.stack([padded[:, j:j + t] for j in range(k)], axis=2)
         x, b, c = _split_xbc(cfg, _conv_silu(window, layer))
-        tail = jax.vmap(lambda rows, at: lax.dynamic_slice_in_dim(
-            rows, at, k - 1, axis=0))(padded, plen)
+        tail = conv_tail(padded, plen, k - 1)
     with jax.named_scope("scan"):
         dt = jax.nn.softplus(dt.astype(jnp.float32) + layer["dt_bias"])
         dt = jnp.where(jnp.arange(t)[None, :, None] < plen[:, None, None],
@@ -343,10 +343,8 @@ def mamba_step(cfg, layer, u, state, tail):
     with jax.named_scope("in_proj"):
         z, xbc, dt = _split_in_proj(cfg, u @ layer["in_proj"].astype(dt_))
     with jax.named_scope("conv"):
-        window = jnp.concatenate([tail, xbc[:, None].astype(tail.dtype)],
-                                 axis=1)
-        x, b, c = _split_xbc(cfg, _conv_silu(window, layer))
-        tail = window[:, 1:]
+        (x, b, c), tail = conv_shift(
+            tail, xbc, lambda w: _split_xbc(cfg, _conv_silu(w, layer)))
     with jax.named_scope("scan"):
         dt = jax.nn.softplus(dt.astype(jnp.float32) + layer["dt_bias"])
         keep = jnp.exp(dt * -jnp.exp(layer["A_log"]))           # (S, heads)

@@ -105,7 +105,7 @@ def _kernel_runs(x, key, weights, w_in, w_out, n, k, tm, activation,
 
 def routed_experts(x, scores, select_bias, w_in, w_out, held, top_k,
                    scale=1.0, activation=jax.nn.relu, impl="auto",
-                   interpret=None, w_gate=None, init=None):
+                   interpret=None, w_gate=None, init=None, norm_eps=1e-20):
     """This chip's part of a top-k expert layer.
 
     - x (T, D): the tokens, in the experts' input width
@@ -126,6 +126,8 @@ def routed_experts(x, scores, select_bias, w_in, w_out, held, top_k,
       expert's output that the layer adds anyway). The kernel starts its
       sum from it and writes the result over it, so a caller computes it
       BEFORE the routed experts and adds nothing after them
+    - norm_eps: added to the chosen scores' sum before the weights are
+      divided by it (a family's own constant; 1e-20 guards a zero sum)
 
     Returns (out (T, D) float32, the routed sum after `init`; counts (3,)
     int32 as `ROUTED_COUNTS`: the (token, held expert) pairs computed, the
@@ -147,7 +149,7 @@ def routed_experts(x, scores, select_bias, w_in, w_out, held, top_k,
     # gather of T·k entries costs the TPU 8 ns each)
     chosen = jnp.where(idx[..., None] == jnp.arange(scores.shape[-1]),
                        scores[:, None, :], 0.0).sum(-1)
-    weights = scale * chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
+    weights = scale * chosen / (chosen.sum(-1, keepdims=True) + norm_eps)
     local = idx - first
     is_held = (local >= 0) & (local < n)
     # a pair's group: its held expert, or n for one held elsewhere

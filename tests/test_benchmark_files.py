@@ -10,13 +10,14 @@ from benchmarks.tests.test_manifest import *  # noqa: F401,F403
 from benchmarks.tests.test_spreads import *  # noqa: F401,F403
 from benchmarks.tests import test_spreads as _spreads
 
-# `keye_vl2_serve_longdoc`, `kanana2_serve_longdoc` and
-# `trinity_mini_serve_longdoc` are serving cells whose sets of six
-# are not in `benchmarks/spreads.json`: a `model_config` PR may add files to the
-# benchmark and edit none, and that file is the rule's input for the three
-# serving bounds. The cells' runs lie beside it, ready for `spreads.py
-# collect` (`benchmarks/spreads_pending/`); the `benchmark` PR that collects
-# them takes this mark away (strict: it fails the day the case passes).
+# `keye_vl2_serve_longdoc`, `kanana2_serve_longdoc`,
+# `trinity_mini_serve_longdoc` and `lfm2_24b_serve_longdoc` are serving
+# cells whose sets of six are not in `benchmarks/spreads.json`: a
+# `model_config` PR may add files to the benchmark and edit none, and that
+# file is the rule's input for the three serving bounds. The cells' runs lie
+# beside it, ready for `spreads.py collect` (`benchmarks/spreads_pending/`);
+# the `benchmark` PR that collects them takes this mark away (strict: it
+# fails the day the case passes).
 test_every_serving_cell_was_measured_at_the_window = pytest.mark.xfail(
     strict=True, reason="keye_vl2_serve_longdoc's and kanana2_serve_longdoc's "
     "sets are not in spreads.json until a benchmark PR collects them")(

@@ -21,7 +21,7 @@ NEW_METRICS = (
     "serve.call_ms", "serve.enqueue_ms", "serve.deliver_ms")
 SERVING_CELLS = ["bert_serve_decode", "nemotron3_super_serve_decode",
                  "keye_vl2_serve_longdoc", "kanana2_serve_longdoc",
-                 "trinity_mini_serve_longdoc"]
+                 "trinity_mini_serve_longdoc", "lfm2_24b_serve_longdoc"]
 
 
 def _op(s, e):

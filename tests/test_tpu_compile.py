@@ -863,3 +863,55 @@ def test_window_decode_superstep_keeps_both_leaves_in_place_for_v5e(
         assert sum(f"layer{li}/moe/experts/grouped_mlp" in c
                    for c in calls) == 1, li
     assert not any("layer0/moe" in c or "layer1/moe" in c for c in calls)
+
+
+def test_shortconv_decode_superstep_keeps_leaves_and_tails_in_place_for_v5e(
+        compile_for_chip, one_chip):
+    """`Lfm2MoeDecoder`'s superstep at the published widths (48 slots, a
+    rung of 18432, the first 4 layers: two dense conv layers, an expert
+    attention layer and an expert conv layer): the K/V leaves `(48, 18432,
+    512)` and the conv tails `(48, 2, 2048)` are written and read in place,
+    with no copy of either and no temporary the size of a leaf; the tied
+    head reads the embedding as it lies (no transposed copy of its `(65536,
+    2048)`); one `flash_fwd` under the scope the benchmark reads, and the
+    expert layers' `grouped_mlp` (all 64 experts held, none shared)."""
+    import json
+
+    from benchmarks.families.lfm2_moe_serve import model_config
+    from deeplearning4j_tpu.generation.decode import Lfm2MoeDecoder
+    from deeplearning4j_tpu.models import lfm2_moe
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "..", "benchmarks", "configs",
+                           "lfm2_24b_a2b_pp5.json")) as f:
+        cfg = model_config({**json.load(f), "num_hidden_layers": 4},
+                           "bfloat16")
+    slots, rung = 48, 18432
+    params = jax.eval_shape(lambda k: lfm2_moe.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    dec = Lfm2MoeDecoder(cfg, params, attn_impl="pallas")
+    cache = jax.eval_shape(lambda: dec.init_cache(slots, rung))
+    assert [l.shape for l in cache["k"]] == [(48, 18432, 512)]
+    assert [l.shape for l in cache["conv"]] == [(48, 2, 2048)] * 3
+
+    compiled = _compile_one_step_superstep(dec, params, cache, slots,
+                                           one_chip)
+    text = compiled.as_text()
+    # a copy of one K leaf would be 906 MB, of the embedding 268 MB; a
+    # copy into on-chip memory (`S(1)`, a 393 KB tail among the small
+    # operands the compiler prefetches) re-lays nothing in HBM
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+    assert not [l for l in text.splitlines()
+                if re.search(r"bf16\[(48,18432,512|48,2,2048|65536,2048|"
+                             r"2048,65536)\]", l)
+                and (" copy(" in l and "S(1)} copy(" not in l
+                     or " transpose(" in l)]
+    calls = [l for l in text.splitlines()
+             if "tpu_custom_call" in l and " custom-call(" in l]
+    assert sum("layer2/attn/flash_decode/flash_fwd" in c
+               for c in calls) == 1
+    for li in (2, 3):
+        assert sum(f"layer{li}/moe/experts/grouped_mlp" in c
+                   for c in calls) == 1, li
+    assert not any("layer0/moe" in c or "layer1/moe" in c
+                   or "moe/shared" in c for c in calls)
