@@ -156,8 +156,8 @@ from deeplearning4j_tpu.kernels.mla_attention import (latent_tile_positions,
                                                       pack_latent)
 from deeplearning4j_tpu.kernels.selection import (compact_indices,
                                                   top_k_mask)
-from deeplearning4j_tpu.models import (afmoe, deepseek_v3, keye_vl,
-                                       lfm2_moe, nemotron_h)
+from deeplearning4j_tpu.models import (afmoe, decoder_common, deepseek_v3,
+                                       keye_vl, lfm2_moe, nemotron_h)
 from deeplearning4j_tpu.models.bert import (_ffn, _layer_norm,
                                             bert_mlm_logits)
 from deeplearning4j_tpu.parallel.ring_attention import dense_attention
@@ -186,6 +186,11 @@ def _shape_tree_repr(tree):
     return repr((str(treedef),
                  tuple((tuple(l.shape), str(jnp.result_type(l)))
                        for l in leaves)))
+
+
+def _expert_layer(params):
+    """The first of a model's layers that routes to experts."""
+    return next(layer for layer in params["layers"] if "router" in layer)
 
 
 class BertDecoder:
@@ -648,6 +653,12 @@ class NemotronHDecoder:
                      jax.eval_shape(lambda: self.init_cache(1, 1))))
         return hashlib.sha256(repr(parts).encode()).hexdigest()[:16]
 
+    def expert_runs(self, bucket):
+        """How many times one expert layer of an admit of `bucket` tokens
+        reads the layer's experts (`nemotron_h.expert_runs`)."""
+        return nemotron_h.expert_runs(self.cfg, _expert_layer(self.params),
+                                      bucket)
+
     def model_args(self):
         return (self.params,)
 
@@ -829,6 +840,12 @@ class KeyeDecoder:
                      jax.eval_shape(lambda: self.init_cache(1, 2))))
         return hashlib.sha256(repr(parts).encode()).hexdigest()[:16]
 
+    def expert_runs(self, bucket):
+        """How many times one expert layer of an admit of `bucket` tokens
+        reads the layer's experts (`keye_vl.expert_runs`)."""
+        return keye_vl.expert_runs(self.cfg, _expert_layer(self.params),
+                                   bucket)
+
     def model_args(self):
         return (self.params,)
 
@@ -1005,6 +1022,12 @@ class MLADecoder:
                      jax.eval_shape(lambda: self.init_cache(1, 2))))
         return hashlib.sha256(repr(parts).encode()).hexdigest()[:16]
 
+    def expert_runs(self, bucket):
+        """How many times one expert layer of an admit of `bucket` tokens
+        reads the layer's experts (`decoder_common.expert_runs`)."""
+        return decoder_common.expert_runs(self.cfg,
+                                          _expert_layer(self.params), bucket)
+
     def model_args(self):
         return (self.params,)
 
@@ -1126,6 +1149,12 @@ class AfmoeDecoder:
                  _shape_tree_repr(
                      jax.eval_shape(lambda: self.init_cache(1, 1))))
         return hashlib.sha256(repr(parts).encode()).hexdigest()[:16]
+
+    def expert_runs(self, bucket):
+        """How many times one expert layer of an admit of `bucket` tokens
+        reads the layer's experts (`decoder_common.expert_runs`)."""
+        return decoder_common.expert_runs(self.cfg,
+                                          _expert_layer(self.params), bucket)
 
     def model_args(self):
         return (self.params,)
@@ -1266,6 +1295,12 @@ class Lfm2MoeDecoder:
                  _shape_tree_repr(
                      jax.eval_shape(lambda: self.init_cache(1, 1))))
         return hashlib.sha256(repr(parts).encode()).hexdigest()[:16]
+
+    def expert_runs(self, bucket):
+        """How many times one expert layer of an admit of `bucket` tokens
+        reads the layer's experts (`decoder_common.expert_runs`)."""
+        return decoder_common.expert_runs(self.cfg,
+                                          _expert_layer(self.params), bucket)
 
     def model_args(self):
         return (self.params,)

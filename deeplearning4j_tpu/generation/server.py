@@ -505,6 +505,11 @@ class GenerationServer:
         #: host and are cumulative in `stats`
         self._counter_names = tuple(getattr(decoder, "counter_names", ()))
         self.stats.update(dict.fromkeys(self._counter_names, 0))
+        #: a prompt bucket -> how many times one expert layer of its admit
+        #: reads the layer's experts (a fact of the shapes, reckoned once)
+        self._expert_runs = (
+            {p: decoder.expert_runs(p) for p in self.prompt_buckets}
+            if hasattr(decoder, "expert_runs") else None)
         self._counts_seen = None     # the device's counts, last fetched
         self._queue = queue.Queue(maxsize=int(queue_limit))
         self._store = None           # FunctionStore, built at warmup
@@ -2068,6 +2073,7 @@ class GenerationServer:
             **self._latency_percentiles(),
             **self.serving_state(),
             **self.stats,
+            "expert_runs": self._expert_runs,
             "store": (None if self._store is None
                       else self._store.status()),
         }

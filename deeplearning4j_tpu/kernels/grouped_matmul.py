@@ -40,7 +40,10 @@ kernel is built around that stream and not around the rows:
 - what a call holds grows with its rows (VMEM: x and the sums) and its
   pairs (SMEM: the layout's tables), so a call takes at most what
   `rows_a_call` allows, and a caller with more rows loops over runs of
-  them, each run reading the weights of the experts it uses once;
+  them, each run reading the weights of the experts it uses once; runs
+  whose sums have a starting value pass one buffer of all their rows on
+  (`at`: a run reads its start from its rows of it and writes its sums
+  over them), so no run's start is cut out and no results are joined;
 - the grid is `(tiles in use, F / block_f)`, its first extent read from
   the groups' sizes at run time: tiles past the last group are never
   visited.
@@ -61,13 +64,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 #: VMEM the two weight blocks of a grid step may take, both pipeline
-#: buffers counted (a v5e core has 128 MiB; Mosaic's default scoped limit
-#: of 16 MiB is raised to what the blocks need)
+#: buffers counted: `block_f` is the widest whose blocks fit
 _WEIGHT_VMEM = 48 << 20
 
-#: VMEM a call's rows may take beside the weight blocks: x as words, the
-#: rows' float32 sums and a tile's rows and result
-_ROWS_VMEM = 40 << 20
+#: VMEM a call may take for its weight blocks and its rows together (a v5e
+#: core has 128 MiB; Mosaic's default scoped limit of 16 MiB is raised to
+#: what the call holds, plus 16 MiB): the rows (x as words, their float32
+#: sums, a tile's rows and result) take what the call's own weight blocks
+#: leave, never less than 40 MiB. 88 MiB holds LFM2's 4096 tokens of 2048
+#: beside its gated blocks of 1536 (36 MiB), so a prompt of 16384 goes in
+#: 4 runs, each reading the experts once
+_CALL_VMEM = 88 << 20
 
 #: SMEM a call's scalar-prefetched tables may take, of a v5e's 1 MiB: a
 #: word for each tile's group and count, each tiled row's pair, and each
@@ -91,6 +98,15 @@ def _block_f(k, f, d, itemsize):
         raise ValueError(f"no block of the width {f} fits VMEM at "
                          f"contraction {k} and output {d}")
     return fits[-1]
+
+
+def weight_bytes(k, f, d, itemsize, block_f=None):
+    """VMEM a call's weight blocks take, both pipeline buffers: `k` counts
+    every stack that reads the rows, `block_f` (default `_block_f`'s) the
+    width of a block."""
+    if block_f is None:
+        block_f = _block_f(k, f, d, itemsize)
+    return 2 * block_f * (k + d) * itemsize
 
 
 def _layout(groups, n, tm):
@@ -167,14 +183,27 @@ def _held_bytes(r, m, k, d, itemsize, n, tm):
     return vmem, 4 * (v * (tm + 2) + 1 + 2 * m)
 
 
-def rows_a_call(r, top_k, k, d, itemsize, n, tm):
-    """How many of r rows of x, top_k pairs each, one call may take: all r
-    where they fit `_ROWS_VMEM` and their tables `_TABLE_SMEM`, else the
-    most that fit, a multiple of 16."""
+def vmem_limit(weights, vmem):
+    """The scoped VMEM a call asks Mosaic for: its weight blocks, what its
+    rows hold (`_held_bytes`) and 16 MiB for the kernel's own values"""
+    return weights + vmem + (16 << 20)
+
+
+def _rows_vmem(weights):
+    """VMEM a call's rows may take beside weight blocks of `weights`
+    bytes"""
+    return _CALL_VMEM - weights
+
+
+def rows_a_call(r, top_k, k, d, itemsize, n, tm, weights):
+    """How many of r rows of x, top_k pairs each, one call whose weight
+    blocks take `weights` bytes (`weight_bytes`) may take: all r where they
+    fit what the blocks leave of `_CALL_VMEM` and their tables
+    `_TABLE_SMEM`, else the most that fit, a multiple of 16."""
     vmem, smem = _held_bytes(r, r * top_k, k, d, itemsize, n, tm)
-    if vmem <= _ROWS_VMEM and smem <= _TABLE_SMEM:
+    if vmem <= _rows_vmem(weights) and smem <= _TABLE_SMEM:
         return r
-    by_vmem = (_ROWS_VMEM - _tile_bytes(k, d, tm)) \
+    by_vmem = (_rows_vmem(weights) - _tile_bytes(k, d, tm)) \
         // (k * itemsize + d * 4)
     # SMEM words, m pairs: at most (m / tm + n)(tm + 2) + 1 + 2m
     words = _TABLE_SMEM // 4 - 1 - n * (tm + 2)
@@ -201,7 +230,7 @@ def _words(x):
 
 def _kernel(tile_group_ref, tile_count_ref, tiles_ref, tile_pair_ref,
             rows_ref, weights_ref, x_hbm, *refs, activation, tm, nf, dtype,
-            seeded):
+            seeded, at):
     """One row tile against one `block_f` of its expert: grid (tile, f),
     f innermost. The first step brings x in and starts the sum (zeroed,
     or `seeded`: copied in from the starting value); at f == 0 a tile
@@ -211,11 +240,16 @@ def _kernel(tile_group_ref, tile_count_ref, tiles_ref, tile_pair_ref,
     out, or gate, in, out), the starting value in HBM where `seeded`, the
     output in HBM and the scratch: x as words (`_words`), the tile's rows as
     words, each row's shift into the high half, its result, the sum.
-    `dtype` is x's, into the products."""
+    `dtype` is x's, into the products. Where `at` is a row, the start and
+    the output are a buffer of several calls' rows and this call's are
+    those from `at` on."""
     del tile_group_ref                     # read by the index maps
     blocks = list(refs[:-7])
     o_hbm, x_ref, xt_ref, shift_ref, acc_ref, sum_ref, copied = refs[-7:]
     init_hbm = blocks.pop() if seeded else None
+
+    def mine(ref):
+        return ref if at is None else ref.at[pl.ds(at, sum_ref.shape[0])]
     *gate_ref, w_in_ref, w_out_ref = blocks
     packed = x_ref.dtype == jnp.uint32
     v, f = pl.program_id(0), pl.program_id(1)
@@ -236,7 +270,7 @@ def _kernel(tile_group_ref, tile_count_ref, tiles_ref, tile_pair_ref,
         if seeded:
             # on x's semaphore: nothing reads either buffer before both
             # waits have returned
-            start = pltpu.make_async_copy(init_hbm, sum_ref, copied)
+            start = pltpu.make_async_copy(mine(init_hbm), sum_ref, copied)
             start.start()
             start.wait()
         else:
@@ -296,29 +330,32 @@ def _kernel(tile_group_ref, tile_count_ref, tiles_ref, tile_pair_ref,
 
         @pl.when(v == jnp.maximum(tiles_ref[0], 1) - 1)
         def _store():
-            copy = pltpu.make_async_copy(sum_ref, o_hbm, copied)
+            copy = pltpu.make_async_copy(sum_ref, mine(o_hbm), copied)
             copy.start()
             copy.wait()
 
 
 def _fused_mlp(x, w_in, w_out, tile_group, tile_count, tiles, tile_pair,
                rows, weights, activation, tm, block_f, interpret,
-               w_gate=None, init=None):
+               w_gate=None, init=None, at=None):
     """The kernel over the layout: x (R, K) in HBM -> (R, D) float32, the
     weighted sum of each row's pairs' results, started from `init` (R, D)
-    float32 where it is given (the output is written over it)."""
+    float32 where it is given (the output is written over it); with `at`,
+    `init` is a float32 buffer of several calls' rows, x's from `at` on,
+    and the whole buffer is returned."""
     (r, k), f, d = x.shape, w_in.shape[2], w_out.shape[2]
     itemsize = jnp.dtype(w_in.dtype).itemsize
     reads = (w_in,) if w_gate is None else (w_gate, w_in)
     if block_f is None:
         block_f = _block_f(k * len(reads), f, d, itemsize)
+    blocks = weight_bytes(k * len(reads), f, d, itemsize, block_f)
     vmem, smem = _held_bytes(r, rows.shape[0], k, d, x.dtype.itemsize,
                              w_in.shape[0], tm)
-    if vmem > _ROWS_VMEM or smem > _TABLE_SMEM:
+    if vmem > _rows_vmem(blocks) or smem > _TABLE_SMEM:
         raise ValueError(
             f"{r} rows of width {k} and {rows.shape[0]} pairs hold {vmem} "
-            f"bytes of VMEM and {smem} of SMEM, over {_ROWS_VMEM} and "
-            f"{_TABLE_SMEM}: take at most `rows_a_call` rows a call")
+            f"bytes of VMEM and {smem} of SMEM, over {_rows_vmem(blocks)} "
+            f"and {_TABLE_SMEM}: take at most `rows_a_call` rows a call")
     words = _words(x)
     read_spec = pl.BlockSpec((None, k, block_f),
                              lambda v, j, grp, *_: (grp[v], 0, j))
@@ -330,12 +367,21 @@ def _fused_mlp(x, w_in, w_out, tile_group, tile_count, tiles, tile_pair,
                pltpu.SemaphoreType.DMA(())]
     seeded = init is not None
     starts = ()
-    if seeded:
+    if at is not None:
+        if init.dtype != jnp.float32 or r % 8 \
+                or not 0 <= at <= init.shape[0] - r:
+            raise ValueError(
+                f"rows {at}..{at + r} of a {init.dtype} buffer of "
+                f"{init.shape[0]}: `at` takes a float32 buffer that holds "
+                f"x's rows, a multiple of 8")
+        starts = (init,)
+    elif seeded:
         starts = (jnp.pad(init.astype(jnp.float32),
                           ((0, _sum_rows(r) - r), (0, 0))),)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_kernel, activation=activation, tm=tm,
-                          nf=f // block_f, dtype=x.dtype, seeded=seeded),
+                          nf=f // block_f, dtype=x.dtype, seeded=seeded,
+                          at=at),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=6,
             # no tile in use (no pair belongs to a group): one visit of
@@ -351,23 +397,24 @@ def _fused_mlp(x, w_in, w_out, tile_group, tile_count, tiles, tile_pair,
             out_specs=pl.BlockSpec(memory_space=pl.ANY),
             scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct((_sum_rows(r), d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(
+            starts[0].shape if seeded else (_sum_rows(r), d), jnp.float32),
         # the sum is written over its starting value: operands are the six
         # tables, x, the weight stacks, then the start
         input_output_aliases={7 + len(reads) + 1: 0} if seeded else {},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=2 * block_f * (k * len(reads) + d) * itemsize
-            + vmem + (16 << 20)),
+            vmem_limit_bytes=vmem_limit(blocks, vmem)),
         interpret=interpret,
         name="grouped_mlp",
     )(tile_group, tile_count, jnp.reshape(tiles, (1,)), tile_pair, rows,
-      weights, words, *reads, w_out, *starts)[:r]
+      weights, words, *reads, w_out, *starts)
+    return out if at is not None else out[:r]
 
 
 def grouped_mlp(x, w_in, w_out, groups, activation=jax.nn.relu, *,
                 weights, rows=None, row_tile=16, block_f=None,
-                interpret=None, w_gate=None, init=None):
+                interpret=None, w_gate=None, init=None, at=None):
     """`sum_i weights[i] * activation(x[r] @ w_in[g]) @ w_out[g]` for every
     row r of x, over the pairs i of a row `rows[i] = r` and a group `g =
     groups[i]`; with `w_gate` (n, K, F) each pair computes
@@ -377,6 +424,11 @@ def grouped_mlp(x, w_in, w_out, groups, activation=jax.nn.relu, *,
       and adds into (default: x has m rows, one a pair); weights (m,)
       float32: each pair's weight in its row's sum; init (R, D) or None:
       where each row's sum starts (0 without)
+    - at: a row of `init`, which is then a float32 buffer of several
+      calls' rows (T, D): x's R rows are those from `at` on (R a multiple
+      of 8), their sums start from them and are written over them, and the
+      whole buffer, the other rows as they were, is returned; a caller with
+      more rows than a call holds runs its calls through one buffer so
     - groups (m,) int: the pairs' groups, in any order; a pair whose group
       lies outside [0, n) is computed by nobody and adds nothing
     - w_in (n, K, F), w_out (n, F, D); K, F, D multiples of 128
@@ -403,4 +455,4 @@ def grouped_mlp(x, w_in, w_out, groups, activation=jax.nn.relu, *,
     return _fused_mlp(x, w_in, w_out, tile_group, tile_count, tiles,
                       tile_pair, rows.astype(jnp.int32),
                       weights.astype(jnp.float32), activation, tm, block_f,
-                      interpret, w_gate, init)
+                      interpret, w_gate, init, at)

@@ -11,12 +11,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from deeplearning4j_tpu.parallel.moe import routed_experts
+from deeplearning4j_tpu.parallel.moe import (routed_calls, routed_experts,
+                                             takes_kernel)
 
-#: tokens one call of an expert layer takes: the ragged path's pairs' rows
-#: and results are (tokens x top_k) x hidden, a gigabyte at 16384 tokens,
-#: and the kernel holds a call's rows and sums in VMEM
-#: (`grouped_matmul.rows_a_call`)
+#: tokens one run of an expert layer takes off the kernel: the ragged
+#: path's pairs' rows and results are (tokens x top_k) x hidden, a gigabyte
+#: at 16384 tokens. The kernel takes a whole prompt, and cuts it itself
+#: into as few runs as its VMEM allows (`grouped_matmul.rows_a_call`)
 MOE_CHUNK = 2048
 
 
@@ -55,12 +56,12 @@ def moe(cfg, layer, g):
     normalised over all the chosen, times `cfg.routed_scaling_factor`) and
     the shared experts whole, where the layer has them (`s_gate`). Returns
     (out (T, H) float32, the `routed_experts` counts). Prefill and decode
-    run the same function; a long sequence goes through in runs of
-    `MOE_CHUNK` tokens. The shared product comes first and is the routed
-    experts' starting value: the expert kernel adds into it, so no sum of
-    the two follows; without one the sum starts from zero. The
-    normalisation adds `cfg.route_norm_eps` to the chosen scores' sum
-    where the family names one, 1e-20 elsewhere."""
+    run the same function; a long sequence goes through in `_runs` runs.
+    The shared product comes first and is the routed experts' starting
+    value: the expert kernel adds into it, so no sum of the two follows;
+    without one the sum starts from zero. The normalisation adds
+    `cfg.route_norm_eps` to the chosen scores' sum where the family names
+    one, 1e-20 elsewhere."""
     eps = getattr(cfg, "route_norm_eps", 1e-20)
 
     def run(tokens):
@@ -79,11 +80,31 @@ def moe(cfg, layer, g):
                 w_gate=layer["w_gate"], init=shared, norm_eps=eps)
 
     t = g.shape[0]
-    if t <= MOE_CHUNK or t % MOE_CHUNK:
+    runs = _runs(layer, t)
+    if runs == 1:
         return run(g)
-    out, counts = lax.map(run, g.reshape(t // MOE_CHUNK, MOE_CHUNK, -1))
+    out, counts = lax.map(run, g.reshape(runs, t // runs, -1))
     return out.reshape(t, -1), jnp.concatenate(
         [counts[:, :2].sum(0), counts[:, 2:].max(0)])
+
+
+def _runs(layer, t):
+    """The runs `moe` cuts t tokens into: one where `routed_experts` takes
+    the kernel, which cuts what one of its calls cannot hold itself; else
+    runs of `MOE_CHUNK` where t is a longer multiple of it."""
+    if takes_kernel(layer["w_up"], layer["w_down"]) or t <= MOE_CHUNK \
+            or t % MOE_CHUNK:
+        return 1
+    return t // MOE_CHUNK
+
+
+def expert_runs(cfg, layer, t):
+    """How many times `moe` over t tokens reads the held experts' weights:
+    its runs, each as many times as `routed_experts` reads them."""
+    runs = _runs(layer, t)
+    return runs * routed_calls(
+        t // runs, cfg.num_experts_per_tok, layer["router"].shape[1],
+        cfg.compute_dtype, layer["w_up"], layer["w_down"], layer["w_gate"])
 
 
 # -- a causal convolution's tail ---------------------------------------------

@@ -42,7 +42,7 @@ from deeplearning4j_tpu.kernels.indexer import index_scores
 from deeplearning4j_tpu.kernels.selection import top_k_mask
 from deeplearning4j_tpu.models.decoder_common import (  # noqa: F401
     MOE_CHUNK, logits, rms_norm)
-from deeplearning4j_tpu.parallel.moe import routed_experts
+from deeplearning4j_tpu.parallel.moe import routed_calls, routed_experts
 
 #: query rows of a prefill whose index scores and selection exist at once
 #: ((Q_BLOCK, T) float32 scores and int8 selection: 268 + 67 MB at 16384)
@@ -321,6 +321,16 @@ def moe(cfg, layer, g):
     out, counts = lax.map(run, g.reshape(t // MOE_CHUNK, MOE_CHUNK, -1))
     return out.reshape(t, -1), jnp.concatenate(
         [counts[:, :2].sum(0), counts[:, 2:].max(0)])
+
+
+def expert_runs(cfg, layer, t):
+    """How many times `moe` over t tokens reads the held experts' weights:
+    its runs of `MOE_CHUNK`, each as many times as `routed_experts` reads
+    them."""
+    runs = t // MOE_CHUNK if t > MOE_CHUNK and not t % MOE_CHUNK else 1
+    return runs * routed_calls(
+        t // runs, cfg.num_experts_per_tok, layer["router"].shape[1],
+        cfg.compute_dtype, layer["w_up"], layer["w_down"], layer["w_gate"])
 
 
 # -- the whole model --------------------------------------------------------

@@ -38,7 +38,7 @@ from jax import lax
 
 from deeplearning4j_tpu.kernels.flash_attention import flash_attention
 from deeplearning4j_tpu.models.decoder_common import conv_shift, conv_tail
-from deeplearning4j_tpu.parallel.moe import routed_experts
+from deeplearning4j_tpu.parallel.moe import routed_calls, routed_experts
 from deeplearning4j_tpu.parallel.ring_attention import dense_attention
 
 _HIGHEST = lax.Precision.HIGHEST
@@ -412,6 +412,14 @@ def moe_mixer(cfg, layer, u):
         out = out + relu2(u @ layer["shared_w1"].astype(dt_)) \
             @ layer["shared_w2"].astype(dt_)
     return out, counts
+
+
+def expert_runs(cfg, layer, t):
+    """How many times `moe_mixer` over t tokens reads the held experts'
+    weights: as many times as `routed_experts` reads them."""
+    return routed_calls(t, cfg.num_experts_per_tok,
+                        layer["router"].shape[1], cfg.compute_dtype,
+                        layer["w1"], layer["w2"])
 
 
 # -- the whole model --------------------------------------------------------

@@ -67,40 +67,81 @@ def _row_tile(rows_per_expert):
     return tile
 
 
+def takes_kernel(w_in, w_out):
+    """Whether `routed_experts(impl="auto")` over these weight stacks takes
+    the Pallas kernel: on a TPU, at widths Mosaic takes."""
+    return jax.default_backend() == "tpu" \
+        and grouped_matmul.supported(*w_in.shape[1:], w_out.shape[2])
+
+
+def kernel_runs(t, top_k, tm, x_dtype, w_in, w_out, w_gate=None):
+    """(calls, tokens a call) of the kernel over t tokens of `x_dtype`,
+    top_k pairs each, at row tile tm: one call where the tokens fit what a
+    call may hold beside its weight blocks (`grouped_matmul.rows_a_call`),
+    else runs of equal length, a multiple of 16. Each call reads the
+    weights of the experts it uses."""
+    n, k, f = w_in.shape
+    d = w_out.shape[2]
+    stacks = 1 if w_gate is None else 2
+    per_call = grouped_matmul.rows_a_call(
+        t, top_k, k, d, jnp.dtype(x_dtype).itemsize, n, tm,
+        grouped_matmul.weight_bytes(stacks * k, f, d,
+                                    jnp.dtype(w_in.dtype).itemsize))
+    if per_call >= t:
+        return 1, t
+    runs = -(-t // per_call)
+    return runs, (-(-t // runs) + 15) // 16 * 16
+
+
+def routed_calls(t, top_k, experts, x_dtype, w_in, w_out, w_gate=None):
+    """How many times `routed_experts(impl="auto")` over t tokens, top_k of
+    `experts` each, reads the held experts' weights: its kernel's calls
+    (`kernel_runs`) where it takes the kernel, else once."""
+    if not takes_kernel(w_in, w_out):
+        return 1
+    return kernel_runs(t, top_k, _row_tile(t * top_k / experts), x_dtype,
+                       w_in, w_out, w_gate)[0]
+
+
 def _kernel_runs(x, key, weights, w_in, w_out, n, k, tm, activation,
                  interpret, w_gate, init):
     """The kernel over the tokens x (T, D), their pairs' groups `key` (T·k,)
     and weights (T, k), each token's sum started from its row of `init`
-    (T, D) where given: in ONE call where the tokens fit what a call may
-    hold (`grouped_matmul.rows_a_call`), else one call a run of equal
-    length, the last padded with tokens whose pairs have no group. Each run
-    reads the weights of the experts it uses."""
+    (T, D) where given: in as many calls as `kernel_runs` says, the last
+    run padded with tokens whose pairs have no group, the runs' sums
+    started from and written into one buffer where there is a start."""
     t = x.shape[0]
-    per_call = grouped_matmul.rows_a_call(
-        t, k, x.shape[1], w_out.shape[2], x.dtype.itemsize, n, tm)
+    runs, run = kernel_runs(t, k, tm, x.dtype, w_in, w_out, w_gate)
 
-    def call(x, key, weights, init):
+    def call(x, key, weights, init, at=None):
         return grouped_matmul.grouped_mlp(
             x, w_in, w_out, key, activation, weights=weights,
             rows=jnp.arange(key.shape[0], dtype=jnp.int32) // k,
-            row_tile=tm, interpret=interpret, w_gate=w_gate, init=init)
+            row_tile=tm, interpret=interpret, w_gate=w_gate, init=init,
+            at=at)
 
-    if per_call >= t:
+    if runs == 1:
         return call(x, key, weights.reshape(-1), init)
-    runs = -(-t // per_call)
-    run = (-(-t // runs) + 15) // 16 * 16
     pad = ((0, runs * run - t), (0, 0))
     x, key, weights = (jnp.pad(x, pad), jnp.pad(
         key.reshape(t, k), pad, constant_values=n), jnp.pad(weights, pad))
-    if init is not None:
-        init = jnp.pad(init, pad)
     # unrolled: under `lax.map` XLA would place the stacked results in
     # VMEM beside the kernel's own
-    return jnp.concatenate([
-        call(x[i:i + run], key[i:i + run].reshape(-1),
-             weights[i:i + run].reshape(-1),
-             None if init is None else init[i:i + run])
-        for i in range(0, runs * run, run)])[:t]
+    starts = range(0, runs * run, run)
+    # without a start the results are joined: one pass over them, as
+    # zeroing a buffer for them would be
+    if init is None:
+        return jnp.concatenate([
+            call(x[i:i + run], key[i:i + run].reshape(-1),
+                 weights[i:i + run].reshape(-1), None)
+            for i in starts])[:t]
+    # each run starts from its rows of ONE buffer and writes its sums over
+    # them: no run's start is sliced out and no results are joined
+    out = jnp.pad(init.astype(jnp.float32), pad)
+    for i in starts:
+        out = call(x[i:i + run], key[i:i + run].reshape(-1),
+                   weights[i:i + run].reshape(-1), out, at=i)
+    return out[:t]
 
 
 def routed_experts(x, scores, select_bias, w_in, w_out, held, top_k,
@@ -157,9 +198,7 @@ def routed_experts(x, scores, select_bias, w_in, w_out, held, top_k,
     group_sizes = (key[:, None] == jnp.arange(n)[None, :]).sum(
         0, dtype=jnp.int32)
     if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" \
-            and grouped_matmul.supported(*w_in.shape[1:], w_out.shape[2]) \
-            else "ragged"
+        impl = "pallas" if takes_kernel(w_in, w_out) else "ragged"
     if impl not in ("pallas", "ragged"):
         raise ValueError(f"unknown routed_experts impl {impl!r}; expected "
                          f"'auto', 'pallas' or 'ragged'")

@@ -502,7 +502,8 @@ def test_long_sequences_go_through_the_experts_in_runs(toy, monkeypatch):
     layer = params["layers"][2]
     g = jax.random.normal(jax.random.PRNGKey(5), (64, cfg.hidden_size))
     whole, counts = ds.moe(cfg, layer, g)
-    # the expert layer is `decoder_common`'s, and reads its run length there
+    # the expert layer is `decoder_common`'s, and reads the ragged path's
+    # run length there (the toy widths take that path on any backend)
     monkeypatch.setattr(decoder_common, "MOE_CHUNK", 16)
     runs, run_counts = ds.moe(cfg, layer, g)
     np.testing.assert_allclose(runs, whole, atol=1e-5, rtol=0)

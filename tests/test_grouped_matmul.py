@@ -313,33 +313,78 @@ def test_widths_mosaic_cannot_take_are_named():
     assert gm._block_f(8192, 2688, 8192, 2) == 384
 
 
-#: id -> tokens, top_k, K, D, held, row tile: the largest calls the models
-#: make (the hybrid's 4096-token bucket, a 2048-token run at widths of
-#: 4096 and at DeepSeek-V3's 7168) and a prefill run that fits whole
+@pytest.mark.parametrize("at", [0, 16, 32])
+def test_a_call_at_a_row_of_a_buffer_writes_its_rows_and_no_other(at):
+    """`at`: a call's 16 rows of a (48, D) float32 buffer start its sums
+    and take them, bit for bit what the call makes from those rows alone;
+    the buffer's other rows come back as they were; a buffer that does not
+    hold the rows, or is not float32, is refused."""
+    x, w_in, w_out, _, groups = _operands([5, 0, 9], 16, jnp.bfloat16,
+                                          seed=12)
+    buffer = _start(48, 13)
+    weights = jnp.asarray(np.random.default_rng(14).uniform(size=16),
+                          jnp.float32)
+
+    def call(init, at=None):
+        return gm.grouped_mlp(x, w_in, w_out, groups, jax.nn.relu,
+                              weights=weights, interpret=NAN_SCRATCH,
+                              init=init, at=at)
+    got = call(buffer, at)
+    assert got.shape == (48, D)
+    np.testing.assert_array_equal(got[at:at + 16], call(buffer[at:at + 16]))
+    mask = np.ones(48, bool)
+    mask[at:at + 16] = False
+    np.testing.assert_array_equal(np.asarray(got)[mask],
+                                  np.asarray(buffer)[mask])
+    with pytest.raises(ValueError, match="buffer"):
+        call(buffer, 40)
+    with pytest.raises(ValueError, match="buffer"):
+        call(buffer.astype(jnp.bfloat16), at)
+
+
+#: id -> tokens, top_k, K, D, held, row tile, expert width, gated: the
+#: largest calls the models make (the hybrid's 4096-token bucket, a
+#: 2048-token run at widths of 4096 and at DeepSeek-V3's 7168) and a
+#: prefill run that fits whole
 BUDGET_SHAPES = {
-    "hybrid_bucket_4096": (4096, 22, 1024, 1024, 128, 128),
-    "run_2048_at_4096": (2048, 8, 4096, 4096, 16, 128),
-    "run_2048_at_7168": (2048, 8, 7168, 7168, 32, 128),
-    "prefill_run_2048": (2048, 8, 2048, 2048, 16, 128),
+    "hybrid_bucket_4096": (4096, 22, 1024, 1024, 128, 128, 2688, False),
+    "run_2048_at_4096": (2048, 8, 4096, 4096, 16, 128, 1024, True),
+    "run_2048_at_7168": (2048, 8, 7168, 7168, 32, 128, 2048, True),
+    "prefill_run_2048": (2048, 8, 2048, 2048, 16, 128, 768, True),
 }
 
 
 @pytest.mark.parametrize("shape", list(BUDGET_SHAPES))
 def test_a_call_holds_at_most_what_vmem_and_smem_allow(shape):
     """`rows_a_call` gives all the rows where they fit, else the most, a
-    multiple of 16, whose x, sums and tables fit the budgets; 16 more would
-    not."""
-    t, k, width, d, n, tm = BUDGET_SHAPES[shape]
-    per_call = gm.rows_a_call(t, k, width, d, 2, n, tm)
+    multiple of 16, whose x, sums and tables fit the budgets (the rows'
+    VMEM what the call's weight blocks leave of `_CALL_VMEM`); 16 more
+    would not."""
+    t, k, width, d, n, tm, f, gated = BUDGET_SHAPES[shape]
+    blocks = gm.weight_bytes((2 if gated else 1) * width, f, d, 2)
+    per_call = gm.rows_a_call(t, k, width, d, 2, n, tm, blocks)
 
     def fits(c):
         vmem, smem = gm._held_bytes(c, c * k, width, d, 2, n, tm)
-        return vmem <= gm._ROWS_VMEM and smem <= gm._TABLE_SMEM
+        return vmem <= gm._CALL_VMEM - blocks and smem <= gm._TABLE_SMEM
     assert fits(per_call)
     if shape == "prefill_run_2048":
         assert per_call == t
     else:
         assert per_call < t and per_call % 16 == 0 and not fits(per_call + 16)
+
+
+def test_the_rows_take_what_the_weight_blocks_leave():
+    """Narrower weight blocks leave the rows more of one call's VMEM, and
+    the widest blocks (`_WEIGHT_VMEM`) still leave them 40 MiB: a call
+    that fit beside fixed budgets of 48 and 40 MiB fits as it was."""
+    assert gm._CALL_VMEM - gm._WEIGHT_VMEM == 40 << 20
+    lfm2 = gm.weight_bytes(2 * 2048, 1536, 2048, 2)      # 36 MiB
+    keye = gm.weight_bytes(2 * 2048, 768, 2048, 2)       # 18 MiB
+    assert lfm2 == 36 << 20 and keye == 18 << 20
+    calls = [gm.rows_a_call(16384, 4, 2048, 2048, 2, 64, 128, blocks)
+             for blocks in (gm._WEIGHT_VMEM, lfm2, keye)]
+    assert calls == sorted(calls) and calls[0] < 4096 <= calls[1]
 
 
 def test_a_call_over_the_budget_is_refused():
@@ -369,11 +414,12 @@ def test_tokens_past_one_call_go_in_runs(monkeypatch, start):
     args = (x, scores, None, w_in, w_out, (4, 8), k, 2.0, jax.nn.relu)
     init = _start(t, 12) if start else None
     want, want_counts = moe.routed_experts(*args, impl="ragged", init=init)
-    # room for 32 rows a call beside a tile of 64 rows (300 pairs over 16
-    # experts): three runs of 32
-    monkeypatch.setattr(gm, "_ROWS_VMEM",
-                        gm._tile_bytes(K, D, 64) + 32 * (K + D) * 4)
-    assert gm.rows_a_call(t, k, K, D, 4, 8, 64) == 32
+    # room for 32 rows a call beside the weight blocks and a tile of 64
+    # rows (300 pairs over 16 experts): three runs of 32
+    blocks = gm.weight_bytes(K, F, D, 4)
+    monkeypatch.setattr(gm, "_CALL_VMEM", blocks + gm._tile_bytes(K, D, 64)
+                        + 32 * (K + D) * 4)
+    assert gm.rows_a_call(t, k, K, D, 4, 8, 64, blocks) == 32
     calls = []
     real = gm._fused_mlp
     monkeypatch.setattr(gm, "_fused_mlp", lambda x, *a, **kw: (
@@ -385,6 +431,71 @@ def test_tokens_past_one_call_go_in_runs(monkeypatch, start):
     np.testing.assert_allclose(got, want, atol=5e-5, rtol=0)
 
 
+def _layer(shared, dtype=jnp.float32, seed=13):
+    """A sigmoid-routed expert layer at lane-aligned toy widths: 32
+    experts, 4 a token, 8 held here (16-23); with a shared SwiGLU of 256
+    (Trinity's and kanana-2's kind) or without one (LFM2's)."""
+    import types
+    rng = np.random.default_rng(seed)
+
+    def mat(*shape, std=0.1):
+        return jnp.asarray(rng.normal(size=shape) * std, dtype)
+    cfg = types.SimpleNamespace(experts_held=(16, 8), num_experts_per_tok=4,
+                                routed_scaling_factor=2.5,
+                                compute_dtype=dtype)
+    layer = dict(router=mat(K, 32), router_bias=jnp.asarray(
+        rng.normal(size=32) * 0.01, jnp.float32),
+        w_gate=mat(8, K, F), w_up=mat(8, K, F), w_down=mat(8, F, D))
+    if shared:
+        layer.update(s_gate=mat(K, 256), s_up=mat(K, 256),
+                     s_down=mat(256, D))
+    return cfg, layer
+
+
+def _on_the_kernel_path(monkeypatch, per_call=None, itemsize=4, tm=16):
+    """`decoder_common.moe` steered as a TPU steers it (the kernel,
+    interpreted here) with room for `per_call` tokens of `itemsize` bytes
+    a call of the kernel at row tile `tm` (a prompt that fits one call
+    where None), a multiple of 16; returns the starting values the kernel
+    is handed and the rows of them each call takes (`at`), in the order
+    of its calls."""
+    import functools
+
+    from deeplearning4j_tpu.models import decoder_common as dc
+    from deeplearning4j_tpu.parallel import moe
+    starts = []
+    real = gm.grouped_mlp
+
+    def spy(*a, init=None, at=None, **kw):
+        starts.append((init, at))
+        return real(*a, init=init, at=at, **kw)
+    monkeypatch.setattr(gm, "grouped_mlp", spy)
+    monkeypatch.setattr(dc, "takes_kernel", lambda w_in, w_out: True)
+    monkeypatch.setattr(dc, "routed_experts", functools.partial(
+        moe.routed_experts, impl="pallas", interpret=NAN_SCRATCH))
+    if per_call is not None:
+        # gated blocks of K x F
+        monkeypatch.setattr(gm, "_CALL_VMEM", gm.weight_bytes(
+            2 * K, F, D, itemsize) + gm._tile_bytes(K, D, tm)
+            + per_call * (K * itemsize + D * 4))
+    return starts
+
+
+def _ragged_layer(cfg, layer, g):
+    """The expert layer the ragged way, whole: (out, counts)."""
+    from deeplearning4j_tpu.models import decoder_common as dc
+    from deeplearning4j_tpu.parallel import moe
+    routed, counts = moe.routed_experts(
+        g, dc.router_scores(layer, g), layer["router_bias"], layer["w_up"],
+        layer["w_down"], cfg.experts_held, cfg.num_experts_per_tok,
+        cfg.routed_scaling_factor, jax.nn.silu, impl="ragged",
+        w_gate=layer["w_gate"])
+    if "s_gate" in layer:
+        routed = routed + dc.swiglu(g, layer["s_gate"], layer["s_up"],
+                                    layer["s_down"]).astype(jnp.float32)
+    return routed, counts
+
+
 @pytest.mark.parametrize("t,chunk", [(12, 2048), (48, 16)],
                          ids=["one_run", "three_runs_of_the_layer"])
 def test_the_shared_expert_is_the_kernels_start_through_decoder_common(
@@ -393,58 +504,146 @@ def test_the_shared_expert_is_the_kernels_start_through_decoder_common(
     its shared SwiGLU's output to `routed_experts` as the starting value:
     the kernel receives it and its result is the layer's, the shared
     product plus the routed sum as the ragged path computes them, to 1e-5
-    of the largest, with the counts exact; over several runs of the layer
-    (`MOE_CHUNK`) too."""
-    import functools
-    import types
-
+    of the largest, with the counts exact; over several calls of the
+    kernel too, where the prompt is longer than one call holds (`chunk`
+    tokens a call): the layer takes the whole prompt and each call of the
+    kernel starts from its run's rows of the one buffer the calls pass on,
+    and writes its sums over them."""
     from deeplearning4j_tpu.models import decoder_common as dc
-    from deeplearning4j_tpu.parallel import moe
-    rng = np.random.default_rng(13)
-    e, held, k = 32, (8, 8), 4
 
-    # float32: the layer's runs are jitted under `lax.map` and the oracle's
-    # are not, and bfloat16 roundings would fall differently
-    def mat(*shape, std=0.1):
-        return jnp.asarray(rng.normal(size=shape) * std, jnp.float32)
-    cfg = types.SimpleNamespace(experts_held=held, num_experts_per_tok=k,
-                                routed_scaling_factor=2.5)
-    layer = dict(router=mat(K, e), router_bias=jnp.asarray(
-        rng.normal(size=e) * 0.01, jnp.float32),
-        w_gate=mat(8, K, F), w_up=mat(8, K, F), w_down=mat(8, F, D),
-        s_gate=mat(K, 256), s_up=mat(K, 256), s_down=mat(256, D))
-    g = mat(t, K, std=1.0)
-
-    starts = []
-    real = gm.grouped_mlp
-
-    def spy(*a, init=None, **kw):
-        starts.append(init)
-        return real(*a, init=init, **kw)
-    monkeypatch.setattr(gm, "grouped_mlp", spy)
-    monkeypatch.setattr(dc, "MOE_CHUNK", chunk)
-    monkeypatch.setattr(dc, "routed_experts", functools.partial(
-        moe.routed_experts, impl="pallas", interpret=NAN_SCRATCH))
+    # float32: bfloat16 roundings would fall differently in the oracle
+    cfg, layer = _layer(shared=True)
+    g = jnp.asarray(np.random.default_rng(14).normal(size=(t, K)),
+                    jnp.float32)
+    starts = _on_the_kernel_path(monkeypatch,
+                                 per_call=chunk if chunk < t else None)
     got, counts = dc.moe(cfg, layer, g)
 
-    runs = max(t // chunk, 1)
-    # the runs of the layer are one traced function under `lax.map`
-    assert len(starts) == 1 and starts[0].shape == (t // runs, D)
-    want, want_counts = [], []
-    for tokens in g.reshape(runs, t // runs, K):
-        routed, c = moe.routed_experts(
-            tokens, dc.router_scores(layer, tokens), layer["router_bias"],
-            layer["w_up"], layer["w_down"], held, k, 2.5, jax.nn.silu,
-            impl="ragged", w_gate=layer["w_gate"])
-        want.append(routed + dc.swiglu(tokens, layer["s_gate"],
-                                       layer["s_up"], layer["s_down"])
-                    .astype(jnp.float32))
-        want_counts.append(c)
-    want = jnp.concatenate(want)
-    want_counts = jnp.stack(want_counts)
+    if chunk >= t:
+        assert [(s.shape, at) for s, at in starts] == [((t, D), None)]
+    else:
+        assert [(s.shape, at) for s, at in starts] \
+            == [((t, D), at) for at in range(0, t, chunk)]
+    want, want_counts = _ragged_layer(cfg, layer, g)
     assert got.shape == (t, D) and got.dtype == jnp.float32
     np.testing.assert_allclose(
         got, want, rtol=0, atol=1e-5 * float(jnp.abs(want).max()))
-    assert list(np.asarray(counts)) == [
-        int(want_counts[:, 0].sum()), int(want_counts[:, 1].sum()),
-        int(want_counts[:, 2].max())]
+    assert list(np.asarray(counts)) == list(np.asarray(want_counts))
+
+
+def test_a_layer_without_a_shared_expert_past_one_call_is_the_ragged_one(
+        monkeypatch):
+    """LFM2's kind of layer (no shared expert: each run's sum starts at
+    zero) over a prompt of three calls of the kernel: the ragged path's
+    outputs to 1e-5 of the largest and its counts exactly."""
+    from deeplearning4j_tpu.models import decoder_common as dc
+    cfg, layer = _layer(shared=False)
+    g = jnp.asarray(np.random.default_rng(15).normal(size=(48, K)),
+                    jnp.float32)
+    starts = _on_the_kernel_path(monkeypatch, per_call=16)
+    got, counts = dc.moe(cfg, layer, g)
+    assert starts == [(None, None)] * 3
+    want, want_counts = _ragged_layer(cfg, layer, g)
+    np.testing.assert_allclose(
+        got, want, rtol=0, atol=1e-5 * float(jnp.abs(want).max()))
+    assert list(np.asarray(counts)) == list(np.asarray(want_counts))
+
+
+@pytest.mark.parametrize("shared", [False, True],
+                         ids=["no_shared_expert", "shared_start"])
+def test_a_tokens_result_is_the_same_bits_in_runs_of_any_length(
+        monkeypatch, shared):
+    """The same bfloat16 layer over 96 tokens in one call of the kernel
+    and in runs of 16, 32 and 48 (6, 3 and 2 calls): a token's pairs all
+    fall in one call and are summed in the same order after the same
+    start, so its result is the same to the bit, and so are the counts."""
+    from deeplearning4j_tpu.models import decoder_common as dc
+    cfg, layer = _layer(shared, jnp.bfloat16)
+    g = jnp.asarray(np.random.default_rng(16).normal(size=(96, K)),
+                    jnp.bfloat16)
+    got = {}
+    for per_call in (None, 16, 32, 48):
+        with monkeypatch.context() as m:
+            # 12 pairs an expert on average: a row tile of 32
+            starts = _on_the_kernel_path(m, per_call, itemsize=2, tm=32)
+            out, counts = dc.moe(cfg, layer, g)
+        assert len(starts) == (1 if per_call is None else 96 // per_call)
+        got[per_call] = (np.asarray(out), list(np.asarray(counts)))
+    whole, whole_counts = got.pop(None)
+    for per_call, (out, counts) in got.items():
+        np.testing.assert_array_equal(out, whole, err_msg=str(per_call))
+        assert counts == whole_counts
+
+
+#: a serving cell's configuration -> (its family's module, its decoder,
+#: its slots, tokens a call of `routed_experts` takes in an admit of 8192
+#: and of 16384, and how many times one expert layer of such an admit
+#: reads its experts on a TPU and on the CPU). Keye cuts a prompt into
+#: runs of `MOE_CHUNK` itself (`keye_vl.moe`), on either path; the hybrid
+#: (`nemotron_h.moe_mixer`) hands `routed_experts` the whole prompt on
+#: either, and its tables in SMEM hold 2048-2352 tokens of 22 pairs a
+#: call; the rest hand the kernel the whole prompt, and take runs of
+#: `MOE_CHUNK` on the ragged path
+_WHOLE, _CHUNKS = {8192: 8192, 16384: 16384}, {8192: 4, 16384: 8}
+PUBLISHED = {
+    "lfm2_24b_a2b_pp5": ("lfm2_moe", "Lfm2MoeDecoder", 48, _WHOLE,
+                         {8192: 2, 16384: 4}, _CHUNKS),
+    "trinity_mini_ep8": ("afmoe", "AfmoeDecoder", 48, _WHOLE,
+                         {8192: 2, 16384: 4}, _CHUNKS),
+    "kanana2_30b_a3b_ep8": ("deepseek_v3", "MLADecoder", 48, _WHOLE,
+                            {8192: 2, 16384: 3}, _CHUNKS),
+    "keye_vl2_30b_a3b_ep8": ("keye_vl", "KeyeDecoder", 32,
+                             {8192: 2048, 16384: 2048}, _CHUNKS, _CHUNKS),
+    "nemotron3_super_120b_ep4": ("nemotron_h", "NemotronHDecoder", 128,
+                                 _WHOLE, {8192: 4, 16384: 7},
+                                 {8192: 1, 16384: 1}),
+}
+
+
+@pytest.mark.parametrize("config", list(PUBLISHED))
+def test_the_published_expert_layers_take_these_runs(monkeypatch, config):
+    """At the published widths, as a TPU runs them: how many times one
+    expert layer of an admit of 8192 and of 16384 tokens reads its experts
+    (the decoder's `expert_runs`, which `GenerationServer.status()` shows),
+    once a decode step of the cell's slots; the tokens of each call, and
+    every call's scoped VMEM 24 MiB or more under a v5e core's 128 MiB;
+    and how many on the CPU, where the ragged path takes the layer."""
+    import importlib
+    import json
+    import os
+
+    from deeplearning4j_tpu.generation import decode
+    from deeplearning4j_tpu.parallel import moe
+    module, decoder, slots, tokens, runs, on_cpu = PUBLISHED[config]
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "..", "benchmarks", "configs",
+                           config + ".json")) as f:
+        file = json.load(f)
+    family = importlib.import_module(
+        f"benchmarks.families.{file['family']}")
+    model = importlib.import_module(f"deeplearning4j_tpu.models.{module}")
+    cfg = family.model_config(file, "bfloat16")
+    params = jax.eval_shape(lambda k: model.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    dec = getattr(decode, decoder)(cfg, params)
+    assert {b: dec.expert_runs(b) for b in runs} == on_cpu
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert {b: dec.expert_runs(b) for b in runs} == runs
+    assert dec.expert_runs(slots) == 1
+    layer = decode._expert_layer(params)
+    w_in, w_out = (layer["w_up"], layer["w_down"]) if "w_up" in layer \
+        else (layer["w1"], layer["w2"])
+    w_gate = layer.get("w_gate")
+    n, width, f = w_in.shape
+    k = cfg.num_experts_per_tok
+    for b, t in [*tokens.items(), (slots, slots)]:
+        tm = moe._row_tile(t * k / layer["router"].shape[1])
+        calls, run = moe.kernel_runs(t, k, tm, jnp.bfloat16, w_in, w_out,
+                                     w_gate)
+        assert calls * (b // t) == dec.expert_runs(b)
+        assert run % 16 == 0 or calls == 1
+        vmem, _ = gm._held_bytes(run, run * k, width, width, 2, n, tm)
+        blocks = gm.weight_bytes((1 if w_gate is None else 2) * width, f,
+                                 width, 2)
+        assert gm.vmem_limit(blocks, vmem) <= 104 << 20, (b, run)

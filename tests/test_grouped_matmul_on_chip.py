@@ -15,6 +15,10 @@ expert included; elsewhere (Keye's gated experts, the hybrid's latent
 ones) it is `routed_experts` alone. Each shape prints one JSON line:
 milliseconds a call (median of `CALLS`, the two trees' calls alternating),
 the pairs held.
+
+`test_an_admits_expert_layer_in_runs_of_each_length_on_the_chip` needs no
+other tree: LFM2's expert layer over a prompt of 16384 tokens in runs of
+three lengths, timed and held to each other to the bit.
 """
 import importlib.util
 import json
@@ -27,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deeplearning4j_tpu.kernels import grouped_matmul
 from deeplearning4j_tpu.models import decoder_common
 from deeplearning4j_tpu.models.nemotron_h import relu2
 from deeplearning4j_tpu.parallel import moe
@@ -141,3 +146,88 @@ def test_expert_layer_time_against_another_tree_on_the_chip(other_tree,
     # the same products; only the order of each row's float32 sum differs
     np.testing.assert_allclose(
         got, want, rtol=0, atol=1e-5 * float(jnp.abs(want).max()))
+
+
+#: LFM2's expert layer in an admit of its largest bucket: tokens, experts
+#: (all held), top_k, width, expert width (gated)
+LFM2_ADMIT = (16384, 64, 4, 2048, 1536)
+
+#: run length -> how the layer is cut: the runs of `MOE_CHUNK` it took
+#: under `lax.map` before its kernel took whole prompts (router and experts
+#: a run, each run reading every expert it uses), the kernel's own runs
+#: where a call's rows may take 40 MiB whatever its weight blocks leave (6
+#: of 2736), and the runs it takes now (4 of 4096)
+LFM2_RUNS = {2048: "lax_map", 2736: 40 << 20, 4096: None}
+
+
+def test_an_admits_expert_layer_in_runs_of_each_length_on_the_chip(
+        monkeypatch):
+    """LFM2's expert layer (`decoder_common.moe`, no shared expert) in an
+    admit of 16384 tokens: 64 experts of 2048 x 1536 gated, 4 a token, all
+    held. Each run length prints one JSON line: milliseconds a call
+    (median of `CALLS`, the lengths' calls alternating), the calls, the
+    row tiles they visit and the experts they read. A token's router row,
+    its pairs and their sum do not depend on the tokens beside it, so the
+    layer's output added to its input is the same bits whatever the
+    length, and every pair is
+    counted (`routed_experts` counts the experts read over its call, the
+    runs under `lax.map` theirs each)."""
+    import types
+    if jax.default_backend() != "tpu":
+        pytest.skip("times the expert layer on a TPU only")
+    t, e, k, d, f = LFM2_ADMIT
+    x, layer = _operands(t, e, e, d, f, 0, seed=t + k)
+    cfg = types.SimpleNamespace(experts_held=(0, e), num_experts_per_tok=k,
+                                routed_scaling_factor=1.0,
+                                route_norm_eps=1e-6)
+
+    # each as the model adds the layer to its input (`lfm2_moe.
+    # feed_forward`): the stacked float32 results of `lax.map` returned as
+    # they are make XLA fuse their update into the kernel's output, which
+    # then needs more than the 16 MiB of VMEM such a fusion is given
+    def whole(x):
+        out, counts = decoder_common.moe(cfg, layer, x)
+        return x + out.astype(x.dtype), counts
+
+    def in_runs(run):
+        def layer_in_runs(x):
+            out, counts = jax.lax.map(
+                lambda a: decoder_common.moe(cfg, layer, a),
+                x.reshape(t // run, run, d))
+            return x + out.reshape(t, d).astype(x.dtype), jnp.concatenate(
+                [counts[:, :2].sum(0), counts[:, 2:].max(0)])
+        return layer_in_runs
+
+    fns, results, calls = {}, {}, {}
+    tm = moe._row_tile(t * k / e)
+    # the numerics the programs run with, not the CPU oracles' "highest"
+    with jax.default_matmul_precision("default"):
+        for run, how in LFM2_RUNS.items():
+            fns[run] = jax.jit(in_runs(run) if how == "lax_map" else whole)
+            with monkeypatch.context() as m:
+                if how != "lax_map" and how is not None:
+                    m.setattr(grouped_matmul, "_CALL_VMEM",
+                              how + grouped_matmul.weight_bytes(
+                                  2 * d, f, d, 2))
+                calls[run] = (t // run, run) if how == "lax_map" else \
+                    moe.kernel_runs(t, k, tm, jnp.bfloat16, layer["w_up"],
+                                    layer["w_down"], layer["w_gate"])
+                results[run] = fns[run](x)                # traced here
+            assert calls[run][1] == run, calls[run]
+        times = _ms_a_call(list(fns.values()), (x,))
+        scores = decoder_common.router_scores(layer, x)
+    want, want_counts = results[max(LFM2_RUNS)]
+    _, idx = jax.lax.top_k(scores + layer["router_bias"], k)
+    idx = np.asarray(idx)
+    for run, ms in zip(fns, times):
+        got, counts = results[run]
+        sizes = [np.bincount(idx[i:i + run].reshape(-1), minlength=e)
+                 for i in range(0, t, run)]
+        print(json.dumps({
+            "shape": "lfm2_admit_16384", "run": run, "calls": calls[run][0],
+            "ms": round(ms, 4),
+            "tiles": int(sum((-(-s // tm)).sum() for s in sizes)),
+            "expert_reads": int(sum((s > 0).sum() for s in sizes))}))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
+                                      err_msg=str(run))
+        assert int(counts[0]) == int(want_counts[0]) == t * k

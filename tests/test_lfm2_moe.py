@@ -437,8 +437,10 @@ def test_the_expert_layer_without_a_shared_expert_is_the_looped_sum(
         toy, monkeypatch):
     """`decoder_common.moe` with no `s_gate` starts the routed sum from
     zero: every token's output is the sum of its chosen experts' SwiGLUs,
-    weighted, looped here expert by expert; in runs of `MOE_CHUNK` tokens
-    too. Every pair is computed on this chip (all 8 held)."""
+    weighted, looped here expert by expert; in the ragged path's runs of
+    `MOE_CHUNK` tokens too (the toy widths take that path on any backend;
+    the kernel's own runs are `tests/test_grouped_matmul.py`'s). Every pair
+    is computed on this chip (all 8 held)."""
     cfg, params = toy
     layer = params["layers"][4]
     g = jax.random.normal(jax.random.PRNGKey(8), (48, cfg.hidden_size))
@@ -508,3 +510,34 @@ def test_programs_lower_as_before_the_moves(model):
                                 jax.random.PRNGKey(0))
         got = _lowered_hashes(AfmoeDecoder(cfg, params), params)
         assert got == ["d1790977b3ffe598", "a5ce5771db458e92"], got
+
+
+def test_status_says_how_often_an_admits_expert_layer_reads_its_experts(
+        monkeypatch):
+    """`GenerationServer.status()["expert_runs"]`, at the cell's widths
+    and buckets: a TPU's kernel takes 4096 tokens of 2048 a call beside
+    the 64 gated experts' blocks, so an expert layer reads its experts
+    twice in an admit of 8192 and 4 times in one of 16384 (4 and 8 times
+    in runs of `MOE_CHUNK`, the ragged path's, which the CPU takes)."""
+    import json
+    import os
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "..", "benchmarks", "configs",
+                           "lfm2_24b_a2b_pp5.json")) as f:
+        cfg = family.model_config({**json.load(f), "num_hidden_layers": 3},
+                                  "bfloat16")
+    params = jax.eval_shape(lambda k: lfm2_moe.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    dec = Lfm2MoeDecoder(cfg, params)
+
+    def expert_runs():
+        srv = GenerationServer(dec, slots=48, cache_lengths=[18432],
+                               prompt_buckets=[8192, 16384])
+        try:
+            return srv.status()["expert_runs"]
+        finally:
+            srv.shutdown()
+    assert expert_runs() == {8192: 4, 16384: 8}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert expert_runs() == {8192: 2, 16384: 4}

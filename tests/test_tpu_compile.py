@@ -915,3 +915,133 @@ def test_shortconv_decode_superstep_keeps_leaves_and_tails_in_place_for_v5e(
                    for c in calls) == 1, li
     assert not any("layer0/moe" in c or "layer1/moe" in c
                    or "moe/shared" in c for c in calls)
+
+
+def _published(config, **overrides):
+    """(the family's decoder over shapes, the parameters' shapes) of a
+    serving cell's configuration file, with `overrides` of its keys."""
+    import importlib
+    import json
+
+    from deeplearning4j_tpu.generation import decode
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "..", "benchmarks", "configs",
+                           config + ".json")) as f:
+        file = {**json.load(f), **overrides}
+    family = importlib.import_module(f"benchmarks.families.{file['family']}")
+    module, decoder = {
+        "lfm2_moe_serve": ("lfm2_moe", "Lfm2MoeDecoder"),
+        "afmoe_serve": ("afmoe", "AfmoeDecoder"),
+        "deepseek_v3_serve": ("deepseek_v3", "MLADecoder"),
+        "keye_vl_serve": ("keye_vl", "KeyeDecoder"),
+        "nemotron_h_serve": ("nemotron_h", "NemotronHDecoder"),
+    }[file["family"]]
+    model = importlib.import_module(f"deeplearning4j_tpu.models.{module}")
+    cfg = family.model_config(file, "bfloat16")
+    params = jax.eval_shape(lambda k: model.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    return getattr(decode, decoder)(cfg, params, attn_impl="pallas"), params
+
+
+def _lower_admit(dec, params, bucket, rung, slots, one_chip):
+    """`dec.prefill` over a prompt bucket, the cache donated, lowered for
+    the described chip with `jax.default_backend()` steered to "tpu"."""
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype,
+                                           sharding=one_chip), tree)
+    cache = jax.eval_shape(lambda: dec.init_cache(slots, rung))
+    scalar = jax.ShapeDtypeStruct((), i32, sharding=one_chip)
+    args = (on_chip(params), on_chip(cache), scalar,
+            jax.ShapeDtypeStruct((bucket,), i32, sharding=one_chip), scalar)
+    real = jax.default_backend
+    jax.default_backend = lambda: "tpu"
+    try:
+        with jax.default_matmul_precision("default"):
+            return jax.jit(lambda p, c, s, prompt, plen: dec.prefill(
+                (p,), c, s, prompt, plen), donate_argnums=(1,)).lower(*args)
+    finally:
+        jax.default_backend = real
+
+
+def test_an_lfm2_admit_of_16384_reads_its_experts_four_times_for_v5e(
+        compile_for_chip, one_chip):
+    """`Lfm2MoeDecoder.prefill` at the cell's largest bucket (16384
+    tokens, the first 3 layers: two dense conv layers and an expert
+    attention layer) compiles for the described chip with the expert
+    layer in 4 calls of the kernel, 4096 tokens each beside the 64 gated
+    experts' blocks: 102 MiB of scoped VMEM a call, which Mosaic takes."""
+    dec, params = _published("lfm2_24b_a2b_pp5", num_hidden_layers=3)
+    compiled = _lower_admit(dec, params, 16384, 18432, 48,
+                            one_chip).compile()
+    calls = [l for l in compiled.as_text().splitlines()
+             if "tpu_custom_call" in l and " custom-call(" in l]
+    assert sum("layer2/moe/experts/grouped_mlp" in c for c in calls) == 4
+    assert sum("grouped_mlp" in c for c in calls) == 4
+    # 0.92 GB at 4 layers (1.04 in runs of 2048 under `lax.map`)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+def _program_digest(text):
+    """sha256 of a lowered program: its StableHLO text outside the
+    kernels' bodies, then each kernel's Mosaic module printed without
+    source locations."""
+    import base64
+    import hashlib
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    body = r'\\22body\\22: \\22([^\\]*)\\22'
+    context = jax_mlir.make_ir_context()
+    context.allow_unregistered_dialects = True
+    parts = [re.sub(body, "", text)]
+    with context:
+        parts += [ir.Module.parse(base64.b64decode(kernel))
+                  .operation.get_asm(enable_debug_info=False)
+                  for kernel in re.findall(body, text)]
+    return hashlib.sha256("\n".join(parts).encode()).hexdigest()[:16]
+
+
+#: program -> (configuration, its keys overridden, slots, rung, bucket of
+#: an admit or None for the superstep, the `_program_digest` that the
+#: commit before a call's rows took what its weight blocks leave printed):
+#: every expert family's decode step at the published widths, and Keye's
+#: admit, whose own runs of 2048 tokens each fit one call of the kernel
+AS_BEFORE = {
+    "lfm2_superstep": ("lfm2_24b_a2b_pp5", dict(num_hidden_layers=4), 48,
+                       18432, None, "6a179c89ca4a65db"),
+    "trinity_superstep": ("trinity_mini_ep8", dict(num_hidden_layers=4),
+                          48, 18432, None, "fa2860f17dbeca2b"),
+    "kanana2_superstep": ("kanana2_30b_a3b_ep8", dict(num_hidden_layers=2),
+                          48, 18432, None, "7954d3105e756b98"),
+    "keye_superstep": ("keye_vl2_30b_a3b_ep8", dict(num_hidden_layers=2),
+                       32, 18432, None, "a9e9048f8071560a"),
+    "hybrid_superstep": ("nemotron3_super_120b_ep4", dict(
+        num_hidden_layers=3, held={"pattern": "*EM", "experts": [0, 128]}),
+        128, 1024, None, "b16c28b9579ce943"),
+    "keye_admit": ("keye_vl2_30b_a3b_ep8", dict(num_hidden_layers=1), 32,
+                   18432, 8192, "ad731055150ed821"),
+}
+
+
+@pytest.mark.parametrize("program", list(AS_BEFORE))
+def test_what_fit_one_call_lowers_as_it_did_for_v5e(one_chip, program):
+    """A call of the expert kernel whose tokens fit what a call held
+    before its rows' VMEM became what its weight blocks leave is the call
+    it was: every expert family's superstep and Keye's admit lower, for
+    the described chip, to the program the commit before printed
+    (`_program_digest`; a PR that changes one of them on purpose prints
+    the new digest from here)."""
+    config, overrides, slots, rung, bucket, want = AS_BEFORE[program]
+    dec, params = _published(config, **overrides)
+    if bucket is None:
+        cache = jax.eval_shape(lambda: dec.init_cache(slots, rung))
+        lowered = _lower_one_step_superstep(dec, params, cache, slots,
+                                            one_chip)
+    else:
+        lowered = _lower_admit(dec, params, bucket, rung, slots, one_chip)
+    text = lowered.as_text()
+    assert "grouped_mlp" in text
+    got = _program_digest(text)
+    assert got == want, got
