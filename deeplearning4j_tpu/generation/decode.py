@@ -1028,6 +1028,15 @@ class MLADecoder:
         return decoder_common.expert_runs(self.cfg,
                                           _expert_layer(self.params), bucket)
 
+    def prefill_attn_pairs(self, plen, bucket):
+        """(run, static) grid steps of one head of the prefill kernel over
+        an admit of `plen` tokens in `bucket`, every layer's causal call
+        summed (`decoder_common.prefill_attn_pairs`); None off the
+        kernel."""
+        return decoder_common.prefill_attn_pairs(
+            self.attn_impl, plen, bucket, deepseek_v3.PREFILL_BLOCK,
+            [None] * self.cfg.num_hidden_layers)
+
     def model_args(self):
         return (self.params,)
 
@@ -1089,14 +1098,16 @@ class MLADecoder:
 
     def prefill(self, margs, cache, slot, prompt, plen):
         """The full forward over one length-bucketed prompt (P,) in the
-        expanded form, then the graft of the slot's latent rows 0..P-1 a
+        expanded form, its attention over the tile pairs up to plen alone
+        (`deepseek_v3.causal_attention`), then the graft of the slot's
+        latent rows 0..P-1 a
         layer (rows beyond plen hold padding garbage that the decode step
         never reads: it attends rows up to `pos`). Returns the logits at
         plen - 1."""
         (params,) = margs
         cfg = self.cfg
         x, states = deepseek_v3.encode(cfg, params, prompt,
-                                       impl=self.attn_impl)
+                                       impl=self.attn_impl, plen=plen)
         leaves = list(cache["kv"])
         for li, (c, kr) in enumerate(states):
             with jax.named_scope(f"layer{li}"), jax.named_scope("attn"), \
@@ -1155,6 +1166,17 @@ class AfmoeDecoder:
         reads the layer's experts (`decoder_common.expert_runs`)."""
         return decoder_common.expert_runs(self.cfg,
                                           _expert_layer(self.params), bucket)
+
+    def prefill_attn_pairs(self, plen, bucket):
+        """(run, static) grid steps of one head of the prefill kernels over
+        an admit of `plen` tokens in `bucket`, the sliding layers' bands
+        and the full layers' triangles summed
+        (`decoder_common.prefill_attn_pairs`); None off the kernel."""
+        cfg = self.cfg
+        return decoder_common.prefill_attn_pairs(
+            self.attn_impl, plen, bucket, afmoe.PREFILL_BLOCK,
+            [cfg.sliding_window if cfg.is_sliding(li) else None
+             for li in range(cfg.num_hidden_layers)])
 
     def model_args(self):
         return (self.params,)
@@ -1226,7 +1248,9 @@ class AfmoeDecoder:
 
     def prefill(self, margs, cache, slot, prompt, plen):
         """The full forward over one length-bucketed prompt (P,), sliding
-        layers through the banded kernel, then the graft of the slot's
+        layers through the banded kernel, both kernels over the tile pairs
+        up to plen alone (`afmoe.sequence_attention`), then the graft of
+        the slot's
         rows a layer: a full layer's rows 0..P-1 (rows beyond plen hold
         padding garbage that the decode step never reads: it attends rows
         up to `pos`), a sliding layer's ring row r the prompt's position
@@ -1235,7 +1259,8 @@ class AfmoeDecoder:
         read). Returns the logits at plen - 1."""
         (params,) = margs
         cfg = self.cfg
-        x, states = afmoe.encode(cfg, params, prompt, impl=self.attn_impl)
+        x, states = afmoe.encode(cfg, params, prompt, impl=self.attn_impl,
+                                 plen=plen)
         leaves = {name: list(cache[name]) for name in ("k", "v")}
         for li, state in enumerate(states):
             with jax.named_scope(f"layer{li}"), jax.named_scope("attn"), \
@@ -1301,6 +1326,17 @@ class Lfm2MoeDecoder:
         reads the layer's experts (`decoder_common.expert_runs`)."""
         return decoder_common.expert_runs(self.cfg,
                                           _expert_layer(self.params), bucket)
+
+    def prefill_attn_pairs(self, plen, bucket):
+        """(run, static) grid steps of one head of the prefill kernel over
+        an admit of `plen` tokens in `bucket`, the attention layers' causal
+        calls summed (`decoder_common.prefill_attn_pairs`); None off the
+        kernel."""
+        cfg = self.cfg
+        return decoder_common.prefill_attn_pairs(
+            self.attn_impl, plen, bucket, lfm2_moe.PREFILL_BLOCK,
+            [None] * sum(map(cfg.is_attention,
+                             range(cfg.num_hidden_layers))))
 
     def model_args(self):
         return (self.params,)
@@ -1372,7 +1408,8 @@ class Lfm2MoeDecoder:
 
     def prefill(self, margs, cache, slot, prompt, plen):
         """The full forward over one length-bucketed prompt (P,), attention
-        through the causal prefill kernel, then the graft of the slot's
+        through the causal prefill kernel over the tile pairs up to plen
+        alone (`lfm2_moe.sequence_attention`), then the graft of the slot's
         state a layer: an attention layer's K/V rows 0..P-1 (rows beyond
         plen hold padding garbage that the decode step never reads: it
         attends rows up to `pos`), a conv layer's tail as the last REAL

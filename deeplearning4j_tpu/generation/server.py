@@ -510,6 +510,15 @@ class GenerationServer:
         self._expert_runs = (
             {p: decoder.expert_runs(p) for p in self.prompt_buckets}
             if hasattr(decoder, "expert_runs") else None)
+        #: grid steps of one head of the prefill attention kernel over the
+        #: admits so far, summed over layers: those run (the tile pairs up
+        #: to each prompt's length) and those the buckets' static grids
+        #: hold (`prefill_attn_pairs`, reckoned on the host a prompt);
+        #: None where the decoder's prefill takes no such kernel
+        pairs = getattr(decoder, "prefill_attn_pairs", None)
+        self._attn_pairs = (
+            None if pairs is None or pairs(1, self.prompt_buckets[0]) is None
+            else {"run": 0, "static": 0})
         self._counts_seen = None     # the device's counts, last fetched
         self._queue = queue.Queue(maxsize=int(queue_limit))
         self._store = None           # FunctionStore, built at warmup
@@ -1131,9 +1140,16 @@ class GenerationServer:
         # (a replayed record is admitted again: its wait is since then)
         waited = (-1 if req.t_submit is None else
                   int((time.perf_counter() - req.t_submit) * 1e6))
+        pairs = (None if self._attn_pairs is None
+                 else self.decoder.prefill_attn_pairs(plen, pbucket))
+        values = {} if pairs is None else {"attn_pairs": pairs[0],
+                                           "attn_pairs_static": pairs[1]}
         with _mon.span("serve.admit", req=req.seq, prompt_len=plen,
-                       bucket=pbucket, queue_wait_us=waited):
+                       bucket=pbucket, queue_wait_us=waited, **values):
             self._admit_in_slot(rec, prompt, key, plen, pbucket)
+        if pairs is not None:
+            self._attn_pairs["run"] += pairs[0]
+            self._attn_pairs["static"] += pairs[1]
 
     def _admit_in_slot(self, rec, prompt, key, plen, pbucket):
         req = rec.req
@@ -2074,6 +2090,8 @@ class GenerationServer:
             **self.serving_state(),
             **self.stats,
             "expert_runs": self._expert_runs,
+            "prefill_attn_pairs": (None if self._attn_pairs is None
+                                   else dict(self._attn_pairs)),
             "store": (None if self._store is None
                       else self._store.status()),
         }

@@ -35,7 +35,8 @@ _NEG_INF = -1e30
 
 
 def _flash_fwd_kernel(*refs, block_k, causal, scale, tk_actual, has_mask,
-                      native=False, window=None, k_tiles=None):
+                      native=False, window=None, k_tiles=None, steps=None,
+                      heads=None):
     """Grid (BH, q_tiles, k_tiles), k innermost: only one (block_k, d) K/V
     tile is VMEM-resident per step; o/l/m accumulate in VMEM scratch across
     the k dimension and the output tile is written on the last k step.
@@ -56,20 +57,43 @@ def _flash_fwd_kernel(*refs, block_k, causal, scale, tk_actual, has_mask,
     the k axis of the grid runs over the BAND: step j of q tile i is k tile
     `_band_span(i)[0] + j` (`_band_span`), and a step past the band's last
     tile computes nothing and names that tile again, so nothing is
-    fetched for it. Tiles wholly below the band have no grid step."""
+    fetched for it. Tiles wholly below the band have no grid step.
+
+    `steps` (causal, with the prompts' lengths): the grid is (BH, step)
+    over the (q tile, k tile) pairs IN USE (`_pairs_in_use`), whose four
+    vectors of `steps` entries an example come first as scalar-prefetch
+    operands: a step's output q tile, its input q tile, its k tile and
+    its kind (`_RUN`, `_FIRST`, `_LAST`, `_ZERO`). A pair runs the same
+    arithmetic as the step of the static grid that names it; a q tile
+    wholly past its length takes one `_ZERO` step that writes zeros and
+    names the blocks already resident, so nothing is fetched for it."""
+    if steps is not None:
+        qo_ref, _, kt_ref, kind_ref, *refs = refs
+        at = pl.program_id(0) // heads * steps + pl.program_id(1)
+        kind = kind_ref[at]
     if has_mask:
         q_ref, k_ref, v_ref, km_ref, o_ref, lse_ref, acc_ref, l_ref, m_ref = refs
     else:
         q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, l_ref, m_ref = refs
         km_ref = None
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
+    if steps is None:
+        qi = pl.program_id(1)
+        kj = pl.program_id(2)
+    else:
+        qi, kj = qo_ref[at], kt_ref[at]
     block_q = q_ref.shape[1]
-    if window is not None:
+    if window is not None and steps is None:
         first, last = _band_span(qi, block_q, block_k, window, k_tiles)
         band_step, kj = kj, first + kj
 
-    @pl.when((kj if window is None else band_step) == 0)
+    if steps is not None:
+        first_step = (kind & _FIRST) != 0
+    elif window is None:
+        first_step = kj == 0
+    else:
+        first_step = band_step == 0
+
+    @pl.when(first_step)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -113,7 +137,11 @@ def _flash_fwd_kernel(*refs, block_k, causal, scale, tk_actual, has_mask,
             dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    if window is not None:
+    if steps is not None:
+        # only pairs in use have a step; a _ZERO step or one past its
+        # example's table computes nothing
+        pl.when((kind & _RUN) != 0)(_compute)
+    elif window is not None:
         # the band's steps past its last tile repeat that tile's block
         pl.when(kj <= last)(_compute)
     elif causal:
@@ -123,13 +151,94 @@ def _flash_fwd_kernel(*refs, block_k, causal, scale, tk_actual, has_mask,
     else:
         _compute()
 
-    @pl.when((kj if window is None else band_step)
-             == pl.num_programs(2) - 1)
+    if steps is not None:
+        last_step = (kind & _LAST) != 0
+    else:
+        last_step = ((kj if window is None else band_step)
+                     == pl.num_programs(2) - 1)
+
+    @pl.when(last_step)
     def _finalize():
         o_ref[0] = (acc_ref[...] /
                     jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
         lse_ref[0, 0] = (m_ref[...] +
                          jnp.log(jnp.maximum(l_ref[...], 1e-30)))[:, 0]
+
+    if steps is not None:
+        @pl.when((kind & _ZERO) != 0)
+        def _zero():
+            # a q tile wholly past its length: its rows read as zeros
+            o_ref[...] = jnp.zeros_like(o_ref)
+            lse_ref[...] = jnp.zeros_like(lse_ref)
+
+
+#: kinds of a step of the grid over the pairs in use (`_pairs_in_use`), as
+#: bits: the pair computes, it is its q tile's first, its last; a q tile
+#: wholly past its length writes zeros. A step with none does nothing.
+_RUN, _FIRST, _LAST, _ZERO = 1, 2, 4, 8
+
+
+def _row_spans(q_tiles, block_q, block_k, k_tiles, window):
+    """(first, last) k tile of each q tile of a causal attention: the
+    band's (`_band_span`) or, without a window, 0 up to the diagonal."""
+    if window is None:
+        return [(0, min((i * block_q + block_q - 1) // block_k, k_tiles - 1))
+                for i in range(q_tiles)]
+    return [_band_span(i, block_q, block_k, window, k_tiles)
+            for i in range(q_tiles)]
+
+
+def _pairs_in_use(lengths, block_q, spans):
+    """The grid over the (q tile, k tile) pairs in use of a causal attention
+    whose q tiles need the k tiles `spans` (`_row_spans`), from the
+    examples' `lengths` (B,): an example's q tiles that hold a row below its
+    length (at least one) in order, each with its k tiles in order, then
+    one `_ZERO` step for each q tile past them. -> four int32 vectors of
+    `steps` entries an example, examples one after the other: a step's
+    output q tile, the q tile it reads, its k tile and its kind; and the
+    grid's extent, the most steps an example takes. An example's steps
+    past its own count name its last step's blocks and do nothing."""
+    q_tiles = len(spans)
+    pair_q = [i for i, (f, l) in enumerate(spans) for _ in range(f, l + 1)]
+    pair_k = [j for f, l in spans for j in range(f, l + 1)]
+    pair_kind = [_RUN | (_FIRST if j == f else 0) | (_LAST if j == l else 0)
+                 for f, l in spans for j in range(f, l + 1)]
+    starts = [0]
+    for f, l in spans:
+        starts.append(starts[-1] + l - f + 1)
+    steps = starts[-1]
+    used = jnp.clip(-(-lengths // block_q), 1, q_tiles).astype(jnp.int32)
+    paired = jnp.asarray(starts, jnp.int32)[used][:, None]     # (B, 1)
+    last_k = jnp.asarray([l for _, l in spans], jnp.int32)[used - 1]
+    step = jnp.arange(steps, dtype=jnp.int32)[None, :]
+    in_use = step < paired
+    past = used[:, None] + step - paired        # the q tile a _ZERO names
+    pick = functools.partial(jnp.where, in_use)
+    q_out = pick(jnp.asarray([pair_q], jnp.int32),
+                 jnp.minimum(past, q_tiles - 1))
+    q_in = pick(q_out, used[:, None] - 1)
+    k_tile = pick(jnp.asarray([pair_k], jnp.int32), last_k[:, None])
+    kind = pick(jnp.asarray([pair_kind], jnp.int32),
+                jnp.where(past < q_tiles, _ZERO, 0))
+    extent = jnp.max(paired[:, 0] + q_tiles - used)
+    return (*(a.reshape(-1) for a in (q_out, q_in, k_tile, kind)), extent)
+
+
+def prefill_grid_steps(length, t, block_q, block_k, window=None):
+    """Grid steps one head of `flash_attention(..., causal=True, lengths=)`
+    takes over a sequence of `t` rows whose first `length` are real, and
+    the steps the static grid of the same call without `lengths` holds:
+    (run, static), Python ints from the rule `_pairs_in_use` builds the
+    grid by. `run` counts the pairs in use and one step for each q tile
+    past them; `static` the (q tile, k tile) steps of the whole bucket,
+    those above the diagonal or past the band's last tile included."""
+    block_q, block_k = _block_sizes(t, t, block_q, block_k)
+    q_tiles, k_tiles = -(-t // block_q), -(-t // block_k)
+    spans = _row_spans(q_tiles, block_q, block_k, k_tiles, window)
+    used = min(max(-(-int(length) // block_q), 1), q_tiles)
+    run = sum(l - f + 1 for f, l in spans[:used]) + q_tiles - used
+    width = k_tiles if window is None else max(l - f + 1 for f, l in spans)
+    return run, q_tiles * width
 
 
 def _band_span(qi, block_q, block_k, window, k_tiles):
@@ -176,13 +285,19 @@ def _prep_mask(mask, block_k):
 
 
 def _flash_forward(q, k, v, q_mask, kv_mask, causal, block_q, block_k,
-                   interpret, native=False, window=None):
+                   interpret, native=False, window=None, lengths=None):
     """Returns (out (B,H,Tq,Dv), lse (B*H, Tq_padded)). `kv_mask` is an
     optional (B, Tk) KEY-validity mask; `q_mask` an optional (B, Tq)
     QUERY-validity mask — invalid q rows come back zeroed with
     lse = +1e30 so the backward kernels recompute p == 0 for them.
     Self-attention passes the same (B, T) mask for both. V's width Dv may
-    differ from Q's and K's D (the scale is D's)."""
+    differ from Q's and K's D (the scale is D's). With `lengths` (B,) int32
+    (causal, no masks) the grid is (BH, step) over the pairs in use
+    (`_pairs_in_use`), its extent read from the lengths when the call runs;
+    the q tiles wholly past an example's length come back as zeros."""
+    if lengths is not None:
+        return _flash_forward_in_use(q, k, v, block_q, block_k, interpret,
+                                     native, window, lengths)
     b, h, tq_a, d = q.shape
     tk_a = k.shape[2]
     dv = v.shape[3]
@@ -265,6 +380,70 @@ def _flash_forward(q, k, v, q_mask, kv_mask, causal, block_q, block_k,
             jnp.broadcast_to(lse_valid, (b, h, tq)).reshape(b * h, tq),
             lse, 1e30)
     return out, lse
+
+
+def _flash_forward_in_use(q, k, v, block_q, block_k, interpret, native,
+                          window, lengths):
+    """`_flash_forward`'s causal call over the pairs in use of each
+    example's `lengths`: the kernel's blocks and arithmetic, a grid of
+    (BH, step) whose step's q tile, k tile and kind are scalar-prefetched
+    (`_pairs_in_use`, `_flash_fwd_kernel`)."""
+    b, h, t, d = q.shape
+    dv = v.shape[3]
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    block_q, block_k = _block_sizes(t, t, block_q, block_k)
+    qp = _pad_to(q.reshape(b * h, t, d), 1, block_q)
+    kp = _pad_to(k.reshape(b * h, t, d), 1, block_k)
+    vp = _pad_to(v.reshape(b * h, t, dv), 1, block_k)
+    tq, k_tiles = qp.shape[1], kp.shape[1] // block_k
+    spans = _row_spans(tq // block_q, block_q, block_k, k_tiles, window)
+    *table, extent = _pairs_in_use(lengths.astype(jnp.int32), block_q, spans)
+    steps = table[0].shape[0] // b
+
+    def tile(which):
+        def index(bh, s, *refs):
+            return refs[which][bh // h * steps + s]
+        return index
+
+    q_out, q_in, k_tile = tile(0), tile(1), tile(2)
+    out, lse = pl.pallas_call(
+        functools.partial(_flash_fwd_kernel, block_k=block_k, causal=True,
+                          scale=1.0 / (d ** 0.5), tk_actual=t,
+                          has_mask=False, native=native, window=window,
+                          steps=steps, heads=h),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(table),
+            grid=(b * h, extent),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d),
+                             lambda bh, s, *r: (bh, q_in(bh, s, *r), 0)),
+                pl.BlockSpec((1, block_k, d),
+                             lambda bh, s, *r: (bh, k_tile(bh, s, *r), 0)),
+                pl.BlockSpec((1, block_k, dv),
+                             lambda bh, s, *r: (bh, k_tile(bh, s, *r), 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_q, dv),
+                             lambda bh, s, *r: (bh, q_out(bh, s, *r), 0)),
+                pl.BlockSpec((1, 1, block_q),
+                             lambda bh, s, *r: (bh, 0, q_out(bh, s, *r))),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, dv), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+            ]),
+        out_shape=[
+            jax.ShapeDtypeStruct((b * h, tq, dv), q.dtype),
+            jax.ShapeDtypeStruct((b * h, 1, tq), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="flash_fwd",
+    )(*table, qp, kp, vp)
+    return out[:, :t, :].reshape(b, h, t, dv), lse[:, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -501,22 +680,24 @@ def _flash_bwd_rule(causal, block_q, block_k, interpret, res, g):
 _flash_attention_vjp.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
-def _flash_forward_only(q, k, v, q_mask, kv_mask, causal, block_q, block_k,
-                        interpret, native, window):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11))
+def _flash_forward_only(q, k, v, q_mask, kv_mask, lengths, causal, block_q,
+                        block_k, interpret, native, window):
     return _flash_forward(q, k, v, q_mask, kv_mask, causal, block_q,
-                          block_k, interpret, native, window)[0]
+                          block_k, interpret, native, window, lengths)[0]
 
 
-def _forward_only_fwd_rule(q, k, v, q_mask, kv_mask, causal, block_q,
-                           block_k, interpret, native, window):
+def _forward_only_fwd_rule(q, k, v, q_mask, kv_mask, lengths, causal,
+                           block_q, block_k, interpret, native, window):
     raise NotImplementedError(
         f"flash_attention is forward only with native=True, with a sliding "
-        f"window or with values of another width than the keys (native="
-        f"{native}, window={window}, keys of {k.shape[3]}, values of "
-        f"{v.shape[3]}): the backward kernels take float32 products, equal "
-        f"widths and no band. Differentiate through native=False at equal "
-        f"widths without a window, or through a dense attention")
+        f"window, with lengths or with values of another width than the "
+        f"keys (native={native}, window={window}, lengths "
+        f"{'given' if lengths is not None else 'not given'}, keys of "
+        f"{k.shape[3]}, values of {v.shape[3]}): the backward kernels take "
+        f"float32 products, equal widths, no band and the whole grid. "
+        f"Differentiate through native=False at equal widths without a "
+        f"window or lengths, or through a dense attention")
 
 
 _flash_forward_only.defvjp(_forward_only_fwd_rule, lambda *_: None)
@@ -524,7 +705,7 @@ _flash_forward_only.defvjp(_forward_only_fwd_rule, lambda *_: None)
 
 def flash_attention(q, k, v, causal=False, block_q=128, block_k=128,
                     interpret=None, mask=None, kv_mask=None, native=False,
-                    window=None):
+                    window=None, lengths=None):
     """Fused attention: softmax(QKᵀ/√d)·V without materialising (Tq,Tk).
 
     Pallas on TPU (interpret-mode elsewhere); differentiable — backward is
@@ -557,15 +738,36 @@ def flash_attention(q, k, v, causal=False, block_q=128, block_k=128,
     computed, so a prompt of T costs about `T x (window + block)` pairs and
     not `T^2 / 2`. Forward only, without masks. Without it the call is the
     one it was.
+
+    `lengths` (an int32 (B,), with `causal`, with or without `window`):
+    the rows each example really holds, the rest padding. The grid runs
+    over the (q tile, k tile) pairs in use only: a q tile holding a row
+    below its length, with its k tiles up to the diagonal or over its
+    band. No step exists above the diagonal, below the band or in a q tile
+    wholly past the length, so nothing is fetched or computed there; such
+    a q tile's rows come back as exact zeros. Every other row is what the
+    call without `lengths` gives, to the bit: the same tiles in the same
+    order. Forward only, without masks. Without it the call is the one it
+    was.
     """
     tq, tk = q.shape[2], k.shape[2]
+    if lengths is not None:
+        if not causal or tq != tk or mask is not None or kv_mask is not None \
+                or jnp.shape(lengths) != (q.shape[0],):
+            raise ValueError(f"lengths needs causal=True over Tq == Tk, no "
+                             f"masks and one length an example, got lengths "
+                             f"of shape {jnp.shape(lengths)} for q "
+                             f"{q.shape}")
     if window is not None:
         if not causal or tq != tk or int(window) < 1 or mask is not None \
                 or kv_mask is not None:
             raise ValueError(f"window={window!r} needs causal=True over "
                              f"Tq == Tk, a positive width and no masks")
-        return _flash_forward_only(q, k, v, None, None, True, block_q,
-                                   block_k, interpret, native, int(window))
+        window = int(window)
+    if window is not None or lengths is not None:
+        return _flash_forward_only(q, k, v, None, None, lengths, True,
+                                   block_q, block_k, interpret, native,
+                                   window)
     if causal and tq != tk:
         raise ValueError(
             f"causal flash attention requires Tq == Tk, got {tq} != {tk}")
@@ -587,8 +789,8 @@ def flash_attention(q, k, v, causal=False, block_q=128, block_k=128,
         raise ValueError(
             f"kv_mask length {kv_mask.shape[1]} != Tk {tk}")
     if native or v.shape[3] != q.shape[3]:
-        return _flash_forward_only(q, k, v, mask, kv_mask, causal, block_q,
-                                   block_k, interpret, native, None)
+        return _flash_forward_only(q, k, v, mask, kv_mask, None, causal,
+                                   block_q, block_k, interpret, native, None)
     return _flash_attention_vjp(q, k, v, mask, kv_mask, causal, block_q,
                                 block_k, interpret)
 

@@ -44,7 +44,7 @@ from deeplearning4j_tpu.kernels.flash_attention import (
 # (RMSNorm, the final norm and head, and the sigmoid-routed expert layer
 # with its shared SwiGLU are every served decoder's)
 from deeplearning4j_tpu.models.decoder_common import (  # noqa: F401
-    logits, moe, rms_norm, swiglu)
+    logits, moe, real_rows, rms_norm, swiglu)
 
 #: rows of queries and of keys a grid step of a prompt's attention takes,
 #: full layers (causal) and sliding layers (the band) alike. The scores of
@@ -272,7 +272,7 @@ def attention_scope(cfg, li):
     return "window" if cfg.is_sliding(li) else "global"
 
 
-def sequence_attention(cfg, li, q, k, v, impl="auto"):
+def sequence_attention(cfg, li, q, k, v, impl="auto", plen=None):
     """A whole sequence's attention in layer `li`: q (T, Hq, D), k, v (T,
     Hkv·D) -> (T, Hq·D). A sliding layer's band through `flash_attention(
     ..., window=)` (its K/V tiles below the band neither fetched nor
@@ -280,7 +280,10 @@ def sequence_attention(cfg, li, q, k, v, impl="auto"):
     `window`/`global` -> `flash_prefill`. The kernel takes every query
     head's own K/V rows: the KV heads are repeated to the query heads
     first. impl 'auto' (the kernels on a TPU, XLA elsewhere), 'pallas' or
-    'dense'."""
+    'dense'. With `plen`, the sequence's real rows, either kernel runs the
+    tile pairs up to it alone (`flash_attention(..., lengths=)`): the rows
+    of the q tiles past it come back as zeros, every other row as without
+    it."""
     t, nh, d = q.shape
     hkv = cfg.num_key_value_heads
     window = cfg.sliding_window if cfg.is_sliding(li) else None
@@ -295,7 +298,7 @@ def sequence_attention(cfg, li, q, k, v, impl="auto"):
             out = flash_attention(
                 *(a.transpose(1, 0, 2)[None] for a in (q, kh, vh)),
                 causal=True, block_q=block, block_k=block, native=True,
-                window=window)
+                window=window, lengths=real_rows(plen))
             return out[0].transpose(1, 0, 2).reshape(t, -1)
         if impl != "dense":
             raise ValueError(f"unknown attention impl {impl!r}; expected "
@@ -344,22 +347,25 @@ def feed_forward(cfg, li, layer, x):
 
 
 # -- the whole model --------------------------------------------------------
-def apply_layer(cfg, li, layer, x, tables, impl="auto"):
-    """Layer `li` over a whole sequence `x` (T, H); returns (y, the layer's
-    decode state (k, v), each (T, Hkv·D))."""
+def apply_layer(cfg, li, layer, x, tables, impl="auto", plen=None):
+    """Layer `li` over a whole sequence `x` (T, H), its first `plen` rows
+    real (all by default); returns (y, the layer's decode state (k, v),
+    each (T, Hkv·D))."""
     with jax.named_scope("norm"):
         u = rms_norm(x, layer["norm_in"], cfg.rms_norm_eps)
     with jax.named_scope("attn"):
         q, k, v, g = attention_inputs(cfg, li, layer, u, tables)
-        ctx = sequence_attention(cfg, li, q, k, v, impl)
+        ctx = sequence_attention(cfg, li, q, k, v, impl, plen)
         x = attention_output(cfg, layer, x, ctx, g)
     return feed_forward(cfg, li, layer, x)[0], (k, v)
 
 
-def encode(cfg, params, ids, impl="auto"):
-    """The full-sequence forward over ONE sequence `ids` (T,), up to the
-    last layer's output (T, H), and every layer's decode state: the k and
-    v rows (T, Hkv·D) a cache holds."""
+def encode(cfg, params, ids, impl="auto", plen=None):
+    """The full-sequence forward over ONE sequence `ids` (T,), of which the
+    first `plen` (all by default) are real, up to the last layer's output
+    (T, H), and every layer's decode state: the k and v rows (T, Hkv·D) a
+    cache holds. Rows at or past `plen` are padding: what they hold is
+    never read for a real row (`sequence_attention`)."""
     with jax.named_scope("rope"):
         tables = rope_tables(cfg, jnp.arange(ids.shape[0]))
     with jax.named_scope("embed"):
@@ -367,7 +373,7 @@ def encode(cfg, params, ids, impl="auto"):
     states = []
     for li, layer in enumerate(params["layers"]):
         with jax.named_scope(f"layer{li}"):
-            x, state = apply_layer(cfg, li, layer, x, tables, impl)
+            x, state = apply_layer(cfg, li, layer, x, tables, impl, plen)
         states.append(state)
     return x, states
 

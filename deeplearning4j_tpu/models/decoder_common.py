@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from deeplearning4j_tpu.kernels.flash_attention import prefill_grid_steps
 from deeplearning4j_tpu.parallel.moe import (routed_calls, routed_experts,
                                              takes_kernel)
 
@@ -40,6 +41,28 @@ def swiglu(x, gate, up, down):
     dt = x.dtype
     return (jax.nn.silu(x @ gate.astype(dt)) * (x @ up.astype(dt))) \
         @ down.astype(dt)
+
+
+def real_rows(plen):
+    """A prompt's real rows `plen` (a scalar) as the `lengths` of
+    `flash_attention`'s one example; None stays None (the whole bucket)."""
+    return None if plen is None else jnp.reshape(plen, (1,)).astype(
+        jnp.int32)
+
+
+def prefill_attn_pairs(impl, plen, bucket, block, windows):
+    """(run, static): the grid steps of one head of the causal prefill
+    kernel over an admit of `plen` real rows in a bucket of `bucket`, and
+    those the bucket's static grid holds, summed over the layers that call
+    it, each given by its window (None: the whole triangle); tiles of
+    `min(block, bucket)` (`flash_attention.prefill_grid_steps`). None where
+    `impl` does not take the kernel ('dense', or 'auto' off a TPU)."""
+    if impl == "dense" or (impl == "auto"
+                           and jax.default_backend() != "tpu"):
+        return None
+    run, static = zip(*(prefill_grid_steps(plen, bucket, block, block, w)
+                        for w in windows))
+    return sum(run), sum(static)
 
 
 def router_scores(layer, g):

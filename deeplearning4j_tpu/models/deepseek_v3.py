@@ -50,7 +50,7 @@ from deeplearning4j_tpu.kernels.mla_attention import (mla_attention_decode,
 # (RMSNorm, the final norm and head, and the sigmoid-routed expert layer
 # with its shared SwiGLU are every served decoder's)
 from deeplearning4j_tpu.models.decoder_common import (  # noqa: F401
-    MOE_CHUNK, logits, moe, rms_norm, router_scores, swiglu)
+    MOE_CHUNK, logits, moe, real_rows, rms_norm, router_scores, swiglu)
 
 #: rows of queries and of keys a grid step of a prompt's attention takes.
 #: On a v5e at 32 heads, keys of 192 and values of 128, bfloat16 (`PERF.md`,
@@ -251,11 +251,15 @@ def attention_inputs(cfg, layer, u, tables):
     return q[..., :cfg.qk_nope_head_dim], q_rope, c, kr
 
 
-def causal_attention(cfg, q_nope, q_rope, c, kr, layer, impl="auto"):
+def causal_attention(cfg, q_nope, q_rope, c, kr, layer, impl="auto",
+                     plen=None):
     """The expanded form over one sequence: keys and values decompressed
     from the latent (scope `expand`), causal softmax a head (scope
     `flash_prefill`). (T, heads·V) out. impl 'auto' (the Pallas kernel on a
-    TPU, XLA elsewhere), 'pallas' or 'dense'."""
+    TPU, XLA elsewhere), 'pallas' or 'dense'. With `plen`, the sequence's
+    real rows, the kernel runs the tile pairs up to it alone
+    (`flash_attention(..., lengths=)`): the rows of the q tiles past it
+    come back as zeros, every other row as without it."""
     dt = c.dtype
     t, nh, n = q_nope.shape
     if impl == "auto":
@@ -272,7 +276,8 @@ def causal_attention(cfg, q_nope, q_rope, c, kr, layer, impl="auto"):
             block = min(PREFILL_BLOCK, t)
             out = flash_attention(
                 *(a.transpose(1, 0, 2)[None] for a in (q, k, v)),
-                causal=True, block_q=block, block_k=block, native=True)
+                causal=True, block_q=block, block_k=block, native=True,
+                lengths=real_rows(plen))
             return out[0].transpose(1, 0, 2).reshape(t, -1)
         if impl != "dense":
             raise ValueError(f"unknown attention impl {impl!r}; expected "
@@ -322,23 +327,27 @@ def feed_forward(cfg, li, layer, x):
 
 
 # -- the whole model --------------------------------------------------------
-def apply_layer(cfg, li, layer, x, tables, impl="auto"):
-    """Layer `li` over a whole sequence `x` (T, H) in the expanded form;
-    returns (y, the layer's decode state (c, kr))."""
+def apply_layer(cfg, li, layer, x, tables, impl="auto", plen=None):
+    """Layer `li` over a whole sequence `x` (T, H), its first `plen` rows
+    real (all by default), in the expanded form; returns (y, the layer's
+    decode state (c, kr))."""
     with jax.named_scope("norm"):
         u = rms_norm(x, layer["norm1"], cfg.rms_norm_eps)
     with jax.named_scope("attn"):
         q_nope, q_rope, c, kr = attention_inputs(cfg, layer, u, tables)
-        ctx = causal_attention(cfg, q_nope, q_rope, c, kr, layer, impl)
+        ctx = causal_attention(cfg, q_nope, q_rope, c, kr, layer, impl,
+                               plen)
         with jax.named_scope("proj"):
             x = x + ctx @ layer["o"].astype(x.dtype)
     return feed_forward(cfg, li, layer, x)[0], (c, kr)
 
 
-def encode(cfg, params, ids, impl="auto"):
-    """The full-sequence forward over ONE sequence `ids` (T,), up to the
-    last layer's output (T, H), and every layer's decode state: the rows
-    (c (T, L), kr (T, R)) a latent cache holds."""
+def encode(cfg, params, ids, impl="auto", plen=None):
+    """The full-sequence forward over ONE sequence `ids` (T,), of which the
+    first `plen` (all by default) are real, up to the last layer's output
+    (T, H), and every layer's decode state: the rows (c (T, L), kr (T, R))
+    a latent cache holds. Rows at or past `plen` are padding: what they
+    hold is never read for a real row (`causal_attention`)."""
     with jax.named_scope("rope"):
         tables = rope_tables(cfg, jnp.arange(ids.shape[0]))
     with jax.named_scope("embed"):
@@ -346,7 +355,7 @@ def encode(cfg, params, ids, impl="auto"):
     states = []
     for li, layer in enumerate(params["layers"]):
         with jax.named_scope(f"layer{li}"):
-            x, state = apply_layer(cfg, li, layer, x, tables, impl)
+            x, state = apply_layer(cfg, li, layer, x, tables, impl, plen)
         states.append(state)
     return x, states
 

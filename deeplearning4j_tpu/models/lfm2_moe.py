@@ -45,7 +45,7 @@ from deeplearning4j_tpu.kernels.flash_attention import (
 # (RMSNorm, the SwiGLU, the sigmoid-routed expert layer and a causal
 # convolution's tail are every served decoder's)
 from deeplearning4j_tpu.models.decoder_common import (
-    conv_shift, conv_tail, moe, rms_norm, swiglu)
+    conv_shift, conv_tail, moe, real_rows, rms_norm, swiglu)
 
 #: rows of queries and of keys a grid step of a prompt's causal attention
 #: takes (the scores of a 1024 x 1024 tile are 4 MB of float32)
@@ -320,12 +320,15 @@ def attention_inputs(cfg, layer, u, tables):
     return q, k.reshape(t, -1), v
 
 
-def sequence_attention(cfg, q, k, v, impl="auto"):
+def sequence_attention(cfg, q, k, v, impl="auto", plen=None):
     """A whole sequence's causal attention: q (T, Hq, D), k, v (T, Hkv·D)
     -> (T, Hq·D), in scope `flash_prefill`. The kernel takes every query
     head's own K/V rows: the KV heads are repeated to the query heads
     first. impl 'auto' (the kernel on a TPU, XLA elsewhere), 'pallas' or
-    'dense'."""
+    'dense'. With `plen`, the sequence's real rows, the kernel runs the
+    tile pairs up to it alone (`flash_attention(..., lengths=)`): the rows
+    of the q tiles past it come back as zeros, every other row as without
+    it."""
     t, nh, d = q.shape
     hkv = cfg.num_key_value_heads
     if impl == "auto":
@@ -337,7 +340,8 @@ def sequence_attention(cfg, q, k, v, impl="auto"):
                       for a in (k, v))
             out = flash_attention(
                 *(a.transpose(1, 0, 2)[None] for a in (q, kh, vh)),
-                causal=True, block_q=block, block_k=block, native=True)
+                causal=True, block_q=block, block_k=block, native=True,
+                lengths=real_rows(plen))
             return out[0].transpose(1, 0, 2).reshape(t, -1)
         if impl != "dense":
             raise ValueError(f"unknown attention impl {impl!r}; expected "
@@ -377,18 +381,20 @@ def feed_forward(cfg, li, layer, h):
 # -- the whole model --------------------------------------------------------
 def apply_layer(cfg, li, layer, x, tables, plen, impl="auto"):
     """Layer `li` over ONE whole sequence `x` (T, H), its first `plen`
-    positions real; returns (y, the layer's decode state: (k, v) rows (T,
+    positions real (None: all of them); returns (y, the layer's decode state: (k, v) rows (T,
     Hkv·D) of an attention layer, a conv layer's tail (K-1, H))."""
     with jax.named_scope("norm"):
         u = rms_norm(x, layer["norm_op"], cfg.norm_eps)
     if cfg.is_attention(li):
         with jax.named_scope("attn"):
             q, k, v = attention_inputs(cfg, layer, u, tables)
-            x = x + _attn_out(layer, sequence_attention(cfg, q, k, v, impl))
+            x = x + _attn_out(layer, sequence_attention(cfg, q, k, v, impl,
+                                                        plen))
         state = (k, v)
     else:
         with jax.named_scope("shortconv"):
-            out, state = shortconv(cfg, layer, u, plen)
+            out, state = shortconv(cfg, layer, u,
+                                   x.shape[0] if plen is None else plen)
             x = x + out
     return feed_forward(cfg, li, layer, x)[0], state
 
@@ -398,7 +404,6 @@ def encode(cfg, params, ids, plen=None, impl="auto"):
     first `plen` (all by default) are real, up to the last layer's output
     (T, H), and every layer's decode state (`apply_layer`)."""
     t = ids.shape[0]
-    plen = t if plen is None else plen
     with jax.named_scope("rope"):
         tables = rope_tables(cfg, jnp.arange(t))
     with jax.named_scope("embed"):

@@ -18,7 +18,10 @@ the pairs held.
 
 `test_an_admits_expert_layer_in_runs_of_each_length_on_the_chip` needs no
 other tree: LFM2's expert layer over a prompt of 16384 tokens in runs of
-three lengths, timed and held to each other to the bit.
+three lengths, timed and held to each other to the bit. Nor does
+`test_prefill_lengths_kernel_alone_on_the_chip` (`-k prefill_lengths`): the
+prefill attention kernel at 16384 rows over the pairs up to four prompt
+lengths, beside the bucket's static grid.
 """
 import importlib.util
 import json
@@ -231,3 +234,52 @@ def test_an_admits_expert_layer_in_runs_of_each_length_on_the_chip(
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
                                       err_msg=str(run))
         assert int(counts[0]) == int(want_counts[0]) == t * k
+
+
+#: the prefill attention kernel alone at the long-context cells' largest
+#: bucket: id -> heads, keys' width, values' width (32 heads, 16384 rows,
+#: tiles of 1024, bfloat16 products); and the prompt lengths timed
+PREFILL_SHAPES = {"kanana2": (32, 192, 128), "trinity_full": (32, 128, 128)}
+PREFILL_LENGTHS = (16384, 15000, 11819, 9000)
+
+
+@pytest.mark.parametrize("shape", list(PREFILL_SHAPES))
+def test_prefill_lengths_kernel_alone_on_the_chip(shape):
+    """`flash_attention(..., causal=True, lengths=)` over a bucket of 16384
+    at each of `PREFILL_LENGTHS`, beside the call without `lengths` (the
+    bucket's static grid). Each prints one JSON line: milliseconds a call
+    (median of `CALLS`, the calls alternating), the grid steps one head
+    runs and the static grid's. The rows of the q tiles in use are the
+    length-less call's bits; the rest are zeros."""
+    fa = importlib.import_module("deeplearning4j_tpu.kernels.flash_attention")
+    if jax.default_backend() != "tpu":
+        pytest.skip("times the prefill kernel on a TPU only")
+    h, d, dv = PREFILL_SHAPES[shape]
+    t, block = 16384, 1024
+    keys = jax.random.split(jax.random.PRNGKey(t + d), 3)
+    q, k = (jax.random.normal(kk, (1, h, t, d), jnp.bfloat16)
+            for kk in keys[:2])
+    v = jax.random.normal(keys[2], (1, h, t, dv), jnp.bfloat16)
+
+    def call(lengths=None):
+        return jax.jit(lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True, block_q=block, block_k=block,
+            native=True, lengths=lengths))
+
+    fns = [call()] + [call(jnp.asarray([n], jnp.int32))
+                      for n in PREFILL_LENGTHS]
+    with jax.default_matmul_precision("default"):
+        outs = [np.asarray(fn(q, k, v).astype(jnp.float32)) for fn in fns]
+        times = _ms_a_call(fns, (q, k, v))
+    for n, out, ms in zip((None,) + PREFILL_LENGTHS, outs, times):
+        run, static = fa.prefill_grid_steps(t if n is None else n, t, block,
+                                            block)
+        print(json.dumps({"shape": f"{shape}_prefill_16384", "length": n,
+                          "ms": round(ms, 4),
+                          "steps_a_head": static if n is None else run,
+                          "static_steps_a_head": static}))
+        if n is not None:
+            end = -(-n // block) * block
+            np.testing.assert_array_equal(out[:, :, :end],
+                                          outs[0][:, :, :end], err_msg=n)
+            assert not out[:, :, end:].any()

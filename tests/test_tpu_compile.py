@@ -818,6 +818,31 @@ def test_banded_prefill_compiles_for_v5e(compile_for_chip):
     assert len(calls) == 1 and "flash_fwd" in calls[0]
 
 
+@pytest.mark.parametrize("window,d,dv", [
+    (None, 192, 128), (None, 128, 128), (2048, 128, 128), (None, 64, 64)],
+    ids=["kanana2_keys_192_values_128", "trinity_full_layer",
+         "trinity_band", "lfm2_attention_layer"])
+def test_prefill_over_the_pairs_in_use_compiles_for_v5e(compile_for_chip,
+                                                        window, d, dv):
+    """`flash_attention(..., lengths=)` as the three long-context admits
+    call it at their largest bucket: 32 heads, 16384 rows in tiles of
+    1024, the grid (heads, step) over the pairs in use, its extent and
+    its step table read from the prompt's length when the call runs."""
+    from deeplearning4j_tpu.kernels import flash_attention
+
+    def prefill(q, k, v, n):
+        return flash_attention(q, k, v, causal=True, block_q=1024,
+                               block_k=1024, native=True, window=window,
+                               lengths=n, interpret=False)
+
+    text = compile_for_chip(prefill, ((1, 32, 16384, d), bf16),
+                            ((1, 32, 16384, d), bf16),
+                            ((1, 32, 16384, dv), bf16), ((1,), i32))
+    calls = [l for l in text.splitlines()
+             if "tpu_custom_call" in l and " custom-call(" in l]
+    assert len(calls) == 1 and "flash_fwd" in calls[0]
+
+
 def test_window_decode_superstep_keeps_both_leaves_in_place_for_v5e(
         compile_for_chip, one_chip):
     """`AfmoeDecoder`'s superstep at the published widths (48 slots, a rung
