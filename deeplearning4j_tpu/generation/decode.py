@@ -1,7 +1,7 @@
 """Decode-mode forwards: incremental single-token model evaluation over
 donated device state.
 
-Seven adapters expose one contract to the GenerationServer:
+Three kinds of adapter expose one contract to the GenerationServer:
 
 - **BertDecoder** — transformer stacks built on `models/bert.py` params:
   one K and one V cache leaf A LAYER, `(S, C, H·Dh)` (S slots, C =
@@ -30,67 +30,25 @@ Seven adapters expose one contract to the GenerationServer:
   `_forward(carries=...)` path, so decode-step numerics are
   BIT-IDENTICAL to the full-sequence scan (tier-1 asserted).
 
-- **NemotronHDecoder** — hybrid stacks built on `models/nemotron_h.py`
-  params, whose donated cache holds TWO kinds of state side by side: K/V
-  leaves `(S, C, Hkv·Dh)` for the attention layers, written and read as
-  BertDecoder's are (grouped-query: the decode kernel maps a query head
-  to its group's lanes), and for the Mamba-2 layers a float32 state leaf
-  `(S, heads, head_dim, state)` and a convolution-tail leaf `(S, K-1,
-  lanes)` that no rung touches. Its expert layers count what they compute
-  on the device (`counter_names`).
-
-- **KeyeDecoder** — stacks built on `models/keye_vl.py` params, whose
-  cache holds THREE leaves a layer: K and V rows `(S, C, Hkv·Dh)` and the
-  sparse-attention indexer's key rows, PACKED two positions a row, `(S, C
-  / 2, 2·Di)`, written, grown and grafted together. (Di = 64 is half a
-  lane tile, what the first paragraph warns of: the chip gave a `(S, C,
-  64)` leaf a layout of its own and the compiled step copied every one
-  whole, in and out. Packed, its minor dimension is one lane tile:
-  `kernels/indexer.py`.) `step` scores every row in use against the index
-  keys, finds the `topk` best exactly (`kernels/selection.py`) and runs
-  the decode kernel over THOSE rows: the K and V leaves read in place
-  under the selection as a mask, the tiles past a slot's position
-  skipped, on a rung of up to `_IN_PLACE_RUNGS` x `topk` rows; on a longer
-  one the kept rows gathered into a `(S, topk, Hkv·Dh)` rung first;
-  `prefill` does the same selection for every prompt position. Its
-  expert layers count as NemotronHDecoder's do, and the indexer counts the
-  rows it scored and kept and the rows attention read for them.
-
-- **MLADecoder** — stacks built on `models/deepseek_v3.py` params
-  (multi-head latent attention), whose cache holds ONE leaf a layer and in
-  it ONE row a position for all heads' keys AND values: the normed latent
-  and the rotated key lanes, two positions a row, `(S, C / 2, 2·(L + R))`
-  (`kernels/mla_attention.py` says why). `prefill` attends in the EXPANDED
-  form (keys and values decompressed for the prompt) and grafts the latent
-  rows; `step` attends in the ABSORBED form (the key up-projection folded
-  into the query, the value up-projection applied after the softmax) and
-  reads each row in use once. Two algebraically equal paths that meet in
-  the cache. Its expert layers count as NemotronHDecoder's do, and the
-  attention counts the rows it needed and the rows its kernel fetched.
-
-- **AfmoeDecoder** — stacks built on `models/afmoe.py` params, whose
-  layers are of two kinds and whose cache holds leaves of two LENGTHS: a
-  sliding-window layer's K and V rows in a RING of `min(window, C)` rows
-  (position p at row p mod window), which `grow` leaves alone once it has
-  the window's rows, beside a full-attention layer's rows `(S, C,
-  Hkv·Dh)`, which grow with the rung. `step` attends both through
-  `flash_attention_decode(..., lengths=)` (a full ring is attended whole:
-  a softmax does not care in which order rows come); `prefill` runs a
-  sliding layer's prompt through the BANDED prefill kernel and grafts the
-  prompt's last `window` real positions into their ring rows. Its expert
-  layers count as NemotronHDecoder's do, and the attention counts the
-  rows in use of each kind of layer.
-
-- **Lfm2MoeDecoder** — stacks built on `models/lfm2_moe.py` params, whose
-  layers are gated short convolutions or grouped-query attention and whose
-  cache holds state of both kinds: a conv layer's TAIL `(S, K-1, H)`, the
-  last K-1 rows of `B * x` that its convolution reads, which no rung
-  touches, beside an attention layer's K and V rows `(S, C, Hkv·Dh)`,
-  which grow with the rung. `prefill` grafts a tail as the last REAL
-  positions of the padded prompt left it (`decoder_common.conv_tail`);
-  `step` shifts it by the token (`decoder_common.conv_shift`) and attends
-  through `flash_attention_decode(..., lengths=)`. Its expert layers count
-  as NemotronHDecoder's do, and the attention counts its rows in use.
+- **FamilyDecoder** — the served language-model families under
+  `models/` (`NemotronHDecoder`, `KeyeDecoder`, `MLADecoder`,
+  `AfmoeDecoder`, `Lfm2MoeDecoder`, each a few lines naming its family
+  module, its fingerprint's name and its device counters). A family
+  DECLARES what a slot's state is, per layer: its leaves
+  (`decoder_common.Leaf`), each a cache key, a width and a dtype, and
+  rows at a rung of C that are `C / per_row` (two positions packed a row:
+  Keye's index keys, MLA's latent rows), capped at a ring's rows (Trinity's
+  sliding layers) or none (a Mamba-2 state, a convolution tail). It gives
+  the step's hooks (what every layer of a step shares, one layer's decode
+  under its own `layer<i>` scope, the counts after the step) and the
+  prefill's (its forward over the prompt, what of each layer's state its
+  leaves take). From that the generic decoder derives the cache, `grow`,
+  the prefill's graft, the fingerprint and the counters. Adding a family
+  costs a family file with its declaration and hooks, and a subclass of a
+  few lines. BertDecoder and RecurrentDecoder keep classes of their own:
+  BERT's paged pool, int8 scales, `verify` and drafting are not slot leaves
+  of the declared kinds yet, and a recurrent net's state is its layers'
+  carries, not leaves.
 
 The contract (all pure functions, traced into AOT executables by the
 server — nothing here may touch the host):
@@ -147,38 +105,17 @@ import jax.numpy as jnp
 from jax import lax
 
 from deeplearning4j_tpu.kernels.flash_attention import (
-    decode_tile_rows, flash_attention, flash_attention_decode,
-    flash_attention_decode_mq, flash_attention_decode_mq_paged,
-    flash_attention_decode_paged)
-from deeplearning4j_tpu.kernels.indexer import (index_scores_decode,
-                                                pack_rows, write_packed_row)
-from deeplearning4j_tpu.kernels.mla_attention import (latent_tile_positions,
-                                                      pack_latent)
-from deeplearning4j_tpu.kernels.selection import (compact_indices,
-                                                  top_k_mask)
+    flash_attention, flash_attention_decode, flash_attention_decode_mq,
+    flash_attention_decode_mq_paged, flash_attention_decode_paged)
 from deeplearning4j_tpu.models import (afmoe, decoder_common, deepseek_v3,
                                        keye_vl, lfm2_moe, nemotron_h)
 from deeplearning4j_tpu.models.bert import (_ffn, _layer_norm,
                                             bert_mlm_logits)
 from deeplearning4j_tpu.parallel.ring_attention import dense_attention
 
-__all__ = ["AfmoeDecoder", "BertDecoder", "KeyeDecoder", "Lfm2MoeDecoder",
-           "MLADecoder", "NemotronHDecoder", "RecurrentDecoder"]
-
-
-def _write_kv(cache, li, wi, wj, k, v):
-    """Layer `li`'s K/V rows `k`/`v` (..., Hkv·Dh) into its leaves at
-    (`wi`, `wj`) — whole rows, in place. `cache` is a dict of per-layer
-    leaf LISTS."""
-    kc, vc = cache["k"][li], cache["v"][li]
-    cache["k"][li] = kc.at[wi, wj].set(k.astype(kc.dtype))
-    cache["v"][li] = vc.at[wi, wj].set(v.astype(vc.dtype))
-
-
-def _slot_index(pos):
-    """Rows at per-slot positions `pos` ((S,) or an (S, d) block) of a
-    slot-contiguous cache: the slot index to pair with `pos`."""
-    return jnp.arange(pos.shape[0]).reshape((-1,) + (1,) * (pos.ndim - 1))
+__all__ = ["AfmoeDecoder", "BertDecoder", "FamilyDecoder", "KeyeDecoder",
+           "Lfm2MoeDecoder", "MLADecoder", "NemotronHDecoder",
+           "RecurrentDecoder"]
 
 
 def _shape_tree_repr(tree):
@@ -186,11 +123,6 @@ def _shape_tree_repr(tree):
     return repr((str(treedef),
                  tuple((tuple(l.shape), str(jnp.result_type(l)))
                        for l in leaves)))
-
-
-def _expert_layer(params):
-    """The first of a model's layers that routes to experts."""
-    return next(layer for layer in params["layers"] if "router" in layer)
 
 
 class BertDecoder:
@@ -378,7 +310,8 @@ class BertDecoder:
             v, v_sc = self._quantize_heads(v)
             cache["ks"][li] = cache["ks"][li].at[wi, wj].set(k_sc)
             cache["vs"][li] = cache["vs"][li].at[wi, wj].set(v_sc)
-        _write_kv(cache, li, wi, wj, k, v)
+        cache["k"][li], cache["v"][li] = decoder_common.write_rows(
+            (cache["k"][li], cache["v"][li]), wi, wj, (k, v))
 
     def _write_index(self, cache, pos, ptab):
         """Where rows at per-slot positions `pos` ((S,) or an (S, d)
@@ -389,7 +322,7 @@ class BertDecoder:
         dense cache silently DROPS that out-of-range scatter; pages must
         redirect it explicitly or the clamped index would corrupt a
         live row."""
-        ar = _slot_index(pos)
+        ar = decoder_common.slot_index(pos)
         if not self.paged:
             return ar, pos, cache["k"][0].shape[1]
         psz = self.page_size
@@ -610,26 +543,42 @@ class BertDecoder:
         return cache, logits
 
 
-class NemotronHDecoder:
-    """Decode over a `models/nemotron_h.py` parameter tree: K/V leaves for
-    the attention layers beside Mamba-2 state and convolution-tail leaves,
-    in one donated cache.
+#: what every expert layer counts on the device (`routed_experts`)
+_MOE_COUNTERS = ("moe_pairs", "moe_expert_reads", "moe_pairs_max")
 
-    The cache pytree: `{"k": [...], "v": [...]}`, one `(S, C, Hkv·Dh)`
-    leaf an ATTENTION layer in the compute dtype; `{"ssm": [...], "conv":
-    [...]}`, one `(S, heads, head_dim, state)` float32 leaf and one `(S,
-    K-1, conv lanes)` leaf a MAMBA layer; `"counts"`, the expert layers'
-    running counts (`counter_names`). Only the K/V leaves know the rung.
 
-    The full-sequence reference this must match is `nemotron_h.forward`
-    over the same prompt+generated prefix (and, outside the package, the
-    plain reference under `benchmarks/families/`)."""
+class FamilyDecoder:
+    """Decode over a served family's parameter tree from the slot state
+    the family declares. A subclass names the family's module (`family`),
+    the fingerprint's name (`name`) and the counts its step keeps on the
+    device (`counter_names`); the module gives:
+
+    - `decode_leaves(cfg)`: for each layer, the `decoder_common.Leaf`s it
+      owns. A layer's leaf of a name is the cache's leaf at the number of
+      earlier layers that own a leaf of that name;
+    - `embed(cfg, params, ids)` and `logits(cfg, params, x)`;
+    - the step's hooks: `decode_context(cfg, pos, leaves, counts)` ->
+      (what every layer of the step shares, the running tally),
+      `decode_layer(cfg, li, layer, x, ctx, state, tally, impl)` -> (x,
+      the layer's leaves, tally), in its own scope `layer<li>`, and
+      `decode_counts(cfg, ctx, tally)` -> the counts after the step;
+    - the prefill's: `encode_prompt(cfg, params, prompt, plen, impl)` ->
+      (x, every layer's decode state) and `cache_parts(cfg, li, state,
+      plen, leaves)` -> what of a layer's state its `leaves` take, in their
+      order;
+    - `expert_runs(cfg, layer, t)`, and where its prefill takes the causal
+      kernel, `PREFILL_BLOCK` and `prefill_windows(cfg)`.
+
+    The cache pytree: `{name: [one leaf a layer that owns one]}` for every
+    declared name, and `"counts"` (`counter_names`). The full-sequence
+    reference this must match is the family's `forward` over the same
+    prompt+generated prefix (and, outside the package, the plain reference
+    under `benchmarks/families/`)."""
 
     uses_cache_rungs = True
     n_model_args = 1
-    supports_draft = False      # the model's prediction module is not run
+    supports_draft = False      # no model's prediction module is run
     max_cache_len = None        # no position table bounds the length
-    counter_names = ("moe_pairs", "moe_expert_reads", "moe_pairs_max")
 
     def __init__(self, cfg, params, attn_impl="auto"):
         if attn_impl not in ("auto", "dense", "pallas"):
@@ -640,807 +589,162 @@ class NemotronHDecoder:
         self.params = params
         self.attn_impl = attn_impl
         self.vocab_size = int(cfg.vocab_size)
-        # layer index -> index among the layers of its kind (its leaves)
-        self._n, self._leaf = {}, []
-        for kind in cfg.pattern:
-            self._leaf.append(self._n.get(kind, 0))
-            self._n[kind] = self._leaf[-1] + 1
+        # a name -> its leaves, one a layer that owns one; a layer -> each
+        # leaf it owns with its index among the leaves of its name
+        self._leaves, self._owned = {}, []
+        for own in self.family.decode_leaves(cfg):
+            self._owned.append(tuple(
+                (leaf, len(self._leaves.setdefault(leaf.name, [])))
+                for leaf in own))
+            for leaf in own:
+                self._leaves[leaf.name].append(leaf)
 
     def fingerprint(self):
-        parts = ("nemotron-h-decode", repr(self.cfg), self.attn_impl,
+        # the cache tree is part of every executable's signature, at the
+        # least rung every leaf takes
+        rung = max([1] + [leaf.per_row for leaves in self._leaves.values()
+                          for leaf in leaves])
+        parts = (self.name, repr(self.cfg), self.attn_impl,
                  _shape_tree_repr(self.params),
                  _shape_tree_repr(
-                     jax.eval_shape(lambda: self.init_cache(1, 1))))
+                     jax.eval_shape(lambda: self.init_cache(1, rung))))
         return hashlib.sha256(repr(parts).encode()).hexdigest()[:16]
 
     def expert_runs(self, bucket):
         """How many times one expert layer of an admit of `bucket` tokens
-        reads the layer's experts (`nemotron_h.expert_runs`)."""
-        return nemotron_h.expert_runs(self.cfg, _expert_layer(self.params),
-                                      bucket)
-
-    def model_args(self):
-        return (self.params,)
-
-    def init_cache(self, slots, cache_len):
-        cfg = self.cfg
-        dt = cfg.compute_dtype
-        na, nm = self._n.get("*", 0), self._n.get("M", 0)
-
-        def leaves(n, shape, dtype):
-            return [jnp.zeros((slots,) + shape, dtype) for _ in range(n)]
-
-        return {"k": leaves(na, (cache_len, cfg.kv_width), dt),
-                "v": leaves(na, (cache_len, cfg.kv_width), dt),
-                "ssm": leaves(nm, (cfg.mamba_num_heads, cfg.mamba_head_dim,
-                                   cfg.ssm_state_size), jnp.float32),
-                "conv": leaves(nm, (cfg.conv_kernel - 1, cfg.conv_dim), dt),
-                "counts": jnp.zeros((len(self.counter_names),), jnp.int32)}
-
-    def grow(self, cache, new_len):
-        """The K/V leaves padded to the longer rung; the state leaves, the
-        tails and the counts as they are."""
-        def pad(t):
-            return jnp.pad(t, ((0, 0), (0, int(new_len) - t.shape[1]),
-                               (0, 0)))
-        return {**cache, "k": [pad(t) for t in cache["k"]],
-                "v": [pad(t) for t in cache["v"]]}
-
-    def counters(self, cache):
-        return cache["counts"]
-
-    def step(self, margs, cache, tokens, pos):
-        """One decode step for the whole batch: each attention layer
-        writes its K/V row at `pos` and attends rows 0..pos; each Mamba-2
-        layer advances its slots' state and tail by the one token; each
-        expert layer routes the S tokens and adds what it computed to the
-        counts. Returns next-token logits (S, V)."""
-        (params,) = margs
-        cfg = self.cfg
-        s = tokens.shape[0]
-        with jax.named_scope("embed"):
-            x = jnp.take(params["embed"], tokens, axis=0)       # (S, H)
-        cache = {name: list(v) if isinstance(v, (list, tuple)) else v
-                 for name, v in cache.items()}
-        wi = _slot_index(pos)
-        if cache["k"]:
-            # rows 0..pos are valid (the current write included)
-            cmask = jnp.arange(cache["k"][0].shape[1])[None, :] \
-                <= pos[:, None]
-        for li, (kind, layer) in enumerate(zip(cfg.pattern,
-                                               params["layers"])):
-            i = self._leaf[li]
-            with jax.named_scope(f"layer{li}"):
-                with jax.named_scope("norm"):
-                    u = nemotron_h.rms_norm(x, layer["norm"], cfg.norm_eps)
-                if kind == "M":
-                    with jax.named_scope("ssm"):
-                        out, cache["ssm"][i], cache["conv"][i] = \
-                            nemotron_h.mamba_step(cfg, layer, u,
-                                                  cache["ssm"][i],
-                                                  cache["conv"][i])
-                elif kind == "*":
-                    with jax.named_scope("attn"):
-                        with jax.named_scope("qkv"):
-                            q, k, v = nemotron_h.attention_qkv(cfg, layer,
-                                                               u)
-                        with jax.named_scope("kv_write"):
-                            _write_kv(cache, i, wi, pos, k, v)
-                        ctx = flash_attention_decode(
-                            q.reshape(s, cfg.num_attention_heads,
-                                      cfg.head_dim),
-                            cache["k"][i], cache["v"][i], cmask,
-                            impl=self.attn_impl)
-                        with jax.named_scope("proj"):
-                            out = ctx.reshape(s, -1).astype(x.dtype) \
-                                @ layer["o"].astype(x.dtype)
-                else:
-                    with jax.named_scope("moe"):
-                        out, counted = nemotron_h.moe_mixer(cfg, layer, u)
-                        cache["counts"] = cache["counts"] + counted
-                x = x + out.astype(x.dtype)
-        return nemotron_h.logits(cfg, params, x), cache
-
-    def prefill(self, margs, cache, slot, prompt, plen):
-        """The full forward over one length-bucketed prompt (1, P), then
-        the graft of ONE slot's part into both kinds of state: the K/V
-        block for rows 0..P-1 (rows beyond plen hold padding garbage that
-        the decode mask hides, as in BertDecoder), and the Mamba-2 state
-        and tail as the last REAL token left them (`mamba_mixer` holds the
-        state through the padding). Returns the logits at plen - 1."""
-        (params,) = margs
-        cfg = self.cfg
-        x, states = nemotron_h.encode(cfg, params, prompt[None],
-                                      jnp.reshape(plen, (1,)))
-        cache = {name: list(v) if isinstance(v, (list, tuple)) else v
-                 for name, v in cache.items()}
-
-        def graft(name, i, part):
-            leaf = cache[name][i]
-            cache[name][i] = lax.dynamic_update_slice(
-                leaf, part.astype(leaf.dtype),
-                (slot,) + (0,) * (leaf.ndim - 1))
-
-        for li, (kind, state) in enumerate(zip(cfg.pattern, states)):
-            i = self._leaf[li]
-            with jax.named_scope(f"layer{li}"):
-                if kind == "*":
-                    with jax.named_scope("attn"), \
-                            jax.named_scope("kv_write"):
-                        graft("k", i, state[0])
-                        graft("v", i, state[1])
-                elif kind == "M":
-                    with jax.named_scope("ssm"), \
-                            jax.named_scope("state_write"):
-                        graft("ssm", i, state[0])
-                        graft("conv", i, state[1])
-        h_last = jnp.take(x[0], plen - 1, axis=0)               # (H,)
-        return cache, nemotron_h.logits(cfg, params, h_last)
-
-
-#: `KeyeDecoder.step` attends its kept rows where they lie while the rung
-#: holds at most this many times `topk` rows, and gathers them past it.
-#: Two rates measured on a v5e (`PERF.md`, PR 36; K and V rows of 1 KB):
-#: gathered, a kept row costs 38.9 ns (XLA's row gather 2 x 15.2 ns,
-#: the index arithmetic around it 5.8 and the kernel over the gathered
-#: rung 2.7), whatever the context; read in place, a row in use costs 2.9
-#: ns. In place wins while a slot has fewer than 38.9 / 2.9 = 13.4 x
-#: `topk` rows in use, which the rung bounds: 12 leaves a tenth of room
-_IN_PLACE_RUNGS = 12
-
-
-def _attends_in_place(rung, topk):
-    """Whether a sparse decode step over a rung of `rung` rows reads K and
-    V in place under the selection mask (cost: the rows in use, which the
-    rung bounds) or gathers the `topk` kept rows first (cost: 2 x `topk`
-    row copies a slot, whatever the context). Both are one softmax over
-    the same rows; the rung and `topk` are static shapes of the program
-    being compiled, so the program holds one of them."""
-    return rung <= _IN_PLACE_RUNGS * topk
-
-
-class KeyeDecoder:
-    """Decode over a `models/keye_vl.py` parameter tree: grouped-query
-    attention over the cache rows a learned indexer selects.
-
-    The cache pytree: `{"k": [...], "v": [...], "ki": [...]}`, one `(S, C,
-    Hkv·Dh)` K leaf, one V leaf and one `(S, C / 2, 2·Di)` index-key leaf
-    (positions 2r and 2r + 1 side by side in row r: `indexer.pack_rows`)
-    A LAYER, in the compute dtype, rows major; `"counts"`, the running
-    counts (`counter_names`). All three kinds of leaf know the rung, which
-    is an even number of rows.
-
-    The full-sequence reference this must match is `keye_vl.forward` over
-    the same prompt+generated prefix (and, outside the package, the plain
-    reference under `benchmarks/families/`)."""
-
-    uses_cache_rungs = True
-    n_model_args = 1
-    supports_draft = False
-    max_cache_len = None        # rotary positions: no table bounds them
-    counter_names = ("moe_pairs", "moe_expert_reads", "moe_pairs_max",
-                     "dsa_rows_scored", "dsa_rows_selected",
-                     "dsa_rows_read")
-    _LEAVES = ("k", "v", "ki")
-
-    def __init__(self, cfg, params, attn_impl="auto"):
-        if attn_impl not in ("auto", "dense", "pallas"):
-            raise ValueError(
-                f"attn_impl must be 'auto', 'dense' or 'pallas', "
-                f"got {attn_impl!r}")
-        self.cfg = cfg
-        self.params = params
-        self.attn_impl = attn_impl
-        self.vocab_size = int(cfg.vocab_size)
-
-    def fingerprint(self):
-        parts = ("keye-decode", repr(self.cfg), self.attn_impl,
-                 _shape_tree_repr(self.params),
-                 _shape_tree_repr(
-                     jax.eval_shape(lambda: self.init_cache(1, 2))))
-        return hashlib.sha256(repr(parts).encode()).hexdigest()[:16]
-
-    def expert_runs(self, bucket):
-        """How many times one expert layer of an admit of `bucket` tokens
-        reads the layer's experts (`keye_vl.expert_runs`)."""
-        return keye_vl.expert_runs(self.cfg, _expert_layer(self.params),
-                                   bucket)
-
-    def model_args(self):
-        return (self.params,)
-
-    def init_cache(self, slots, cache_len):
-        cfg = self.cfg
-        if cache_len % 2:
-            raise ValueError(f"the index-key leaf packs two positions a "
-                             f"row: cache rung {cache_len} must be even")
-        shapes = {"k": (slots, cache_len, cfg.kv_width),
-                  "v": (slots, cache_len, cfg.kv_width),
-                  "ki": (slots, cache_len // 2, 2 * cfg.indexer_head_dim)}
-        cache = {name: [jnp.zeros(shapes[name], cfg.compute_dtype)
-                        for _ in range(cfg.num_hidden_layers)]
-                 for name in self._LEAVES}
-        cache["counts"] = jnp.zeros((len(self.counter_names),), jnp.int32)
-        return cache
-
-    def grow(self, cache, new_len):
-        """All three kinds of leaf padded to the longer rung, together;
-        the counts as they are."""
-        def pad(t, rows):
-            return jnp.pad(t, ((0, 0), (0, rows - t.shape[1]), (0, 0)))
-        new_len = int(new_len)
-        return {**cache, **{
-            name: [pad(t, new_len // 2 if name == "ki" else new_len)
-                   for t in cache[name]] for name in self._LEAVES}}
-
-    def counters(self, cache):
-        return cache["counts"]
-
-    def step(self, margs, cache, tokens, pos):
-        """One decode step for the whole batch. A layer writes its K, V
-        and index-key rows at `pos`, scores every row in use against the
-        slot's index query, keeps the `min(topk, pos + 1)` best and attends
-        over THOSE rows, read in place under the selection mask or
-        gathered into a `(S, topk, Hkv·Dh)` rung (`_attends_in_place`);
-        then its expert layer routes the S tokens. Returns next-token
-        logits (S, V). Text positions: all three rotary streams are the
-        token's index. Counted a step, slots and layers summed: the rows
-        scored, the rows kept, and the rows of K the attention kernel
-        fetched for them (in place: a slot's rows in use rounded up to the
-        kernel's tile)."""
-        (params,) = margs
-        cfg = self.cfg
-        s = tokens.shape[0]
-        with jax.named_scope("embed"):
-            x = jnp.take(params["embed"], tokens, axis=0)       # (S, H)
-        cache = {name: list(v) if isinstance(v, (list, tuple)) else v
-                 for name, v in cache.items()}
-        wi = _slot_index(pos)
-        c = cache["k"][0].shape[1]
-        width = min(cfg.indexer_topk, c)
-        # rows 0..pos are in use (the current write included); a lane
-        # frozen at the end of its rung (pos == C) keeps all of them
-        in_use = jnp.minimum(pos + 1, c)                        # (S,)
-        keep = jnp.minimum(in_use, width)
-        in_place = _attends_in_place(c, cfg.indexer_topk)
-        if in_place:
-            tile = decode_tile_rows(c, cfg.kv_width, cache["k"][0].dtype)
-            read = (-(-in_use // tile) * tile).sum()
-        else:
-            kept = jnp.arange(width)[None, :] < keep[:, None]   # (S, topk)
-            read = keep.sum()
-        with jax.named_scope("rope"):
-            tables = keye_vl.rope_tables(
-                cfg, jnp.broadcast_to(pos[None], (3, s)))
-        counted = jnp.zeros((3,), jnp.int32)
-        for li, layer in enumerate(params["layers"]):
-            with jax.named_scope(f"layer{li}"):
-                u = keye_vl.rms_norm(x, layer["norm1"], cfg.rms_norm_eps)
-                with jax.named_scope("attn"):
-                    q, k, v, qi, ki, w = keye_vl.attention_inputs(
-                        cfg, layer, u, tables)
-                    with jax.named_scope("kv_write"):
-                        _write_kv(cache, li, wi, pos, k, v)
-                        cache["ki"][li] = write_packed_row(
-                            cache["ki"][li], pos, ki)
-                    with jax.named_scope("indexer"):
-                        with jax.named_scope("score"):
-                            scores = index_scores_decode(
-                                qi, cache["ki"][li], w, pos,
-                                cfg.index_scale, impl=self.attn_impl)
-                        with jax.named_scope("select"):
-                            mask = top_k_mask(scores, keep)
-                            if not in_place:
-                                rows = compact_indices(mask, width)
-                    q = q.reshape(s, cfg.num_attention_heads, cfg.head_dim)
-                    if in_place:
-                        ctx = flash_attention_decode(
-                            q, cache["k"][li], cache["v"][li], mask,
-                            impl=self.attn_impl, lengths=in_use)
-                    else:
-                        with jax.named_scope("gather"):
-                            kg, vg = (jnp.take_along_axis(
-                                cache[name][li], rows[..., None], axis=1)
-                                for name in ("k", "v"))
-                        ctx = flash_attention_decode(q, kg, vg, kept,
-                                                     impl=self.attn_impl)
-                    with jax.named_scope("proj"):
-                        x = x + ctx.reshape(s, -1).astype(x.dtype) \
-                            @ layer["o"].astype(x.dtype)
-                with jax.named_scope("moe"):
-                    out, counts = keye_vl.moe(cfg, layer, keye_vl.rms_norm(
-                        x, layer["norm2"], cfg.rms_norm_eps))
-                    counted = counted + counts
-                x = x + out.astype(x.dtype)
-        layers = cfg.num_hidden_layers
-        cache["counts"] = cache["counts"] + jnp.concatenate([
-            counted, layers * jnp.stack(
-                [in_use.sum(), keep.sum(), read]).astype(jnp.int32)])
-        return keye_vl.logits(cfg, params, x), cache
-
-    def prefill(self, margs, cache, slot, prompt, plen):
-        """The full forward over one length-bucketed prompt (P,) with the
-        indexer's selection at every position, then the graft of the
-        slot's three blocks a layer for rows 0..P-1 (rows beyond plen hold
-        padding garbage that the decode step never scores: it masks rows
-        past `pos`). Returns the logits at plen - 1."""
-        (params,) = margs
-        cfg = self.cfg
-        x, states = keye_vl.encode(cfg, params, prompt,
-                                   impl=self.attn_impl)
-        cache = {name: list(v) if isinstance(v, (list, tuple)) else v
-                 for name, v in cache.items()}
-        for li, state in enumerate(states):
-            with jax.named_scope(f"layer{li}"), jax.named_scope("attn"), \
-                    jax.named_scope("kv_write"):
-                k, v, ki = state
-                for name, part in (("k", k), ("v", v),
-                                   ("ki", pack_rows(ki))):
-                    leaf = cache[name][li]
-                    cache[name][li] = lax.dynamic_update_slice(
-                        leaf, part[None].astype(leaf.dtype), (slot, 0, 0))
-        h_last = jnp.take(x, plen - 1, axis=0)                  # (H,)
-        return cache, keye_vl.logits(cfg, params, h_last)
-
-
-class MLADecoder:
-    """Decode over a `models/deepseek_v3.py` parameter tree: multi-head
-    latent attention over one cached row a position.
-
-    The cache pytree: `{"kv": [...]}`, one `(S, C / 2, 2·(L + R))` leaf A
-    LAYER in the compute dtype, rows major, row r holding positions 2r and
-    2r + 1 as `[c, c', kr, kr']` (`mla_attention.pack_latent`); `"counts"`,
-    the running counts (`counter_names`). The rung is an even number of
-    rows.
-
-    The full-sequence reference this must match is `deeplearning4j_tpu.
-    models.deepseek_v3.forward` over the same prompt+generated prefix
-    (and, outside the package, the plain reference under
-    `benchmarks/families/`)."""
-
-    uses_cache_rungs = True
-    n_model_args = 1
-    supports_draft = False      # the model's prediction module is not run
-    max_cache_len = None        # rotary positions: no table bounds them
-    counter_names = ("moe_pairs", "moe_expert_reads", "moe_pairs_max",
-                     "mla_rows_attended", "mla_rows_read")
-
-    def __init__(self, cfg, params, attn_impl="auto"):
-        if attn_impl not in ("auto", "dense", "pallas"):
-            raise ValueError(
-                f"attn_impl must be 'auto', 'dense' or 'pallas', "
-                f"got {attn_impl!r}")
-        self.cfg = cfg
-        self.params = params
-        self.attn_impl = attn_impl
-        self.vocab_size = int(cfg.vocab_size)
-
-    def fingerprint(self):
-        parts = ("mla-decode", repr(self.cfg), self.attn_impl,
-                 _shape_tree_repr(self.params),
-                 _shape_tree_repr(
-                     jax.eval_shape(lambda: self.init_cache(1, 2))))
-        return hashlib.sha256(repr(parts).encode()).hexdigest()[:16]
-
-    def expert_runs(self, bucket):
-        """How many times one expert layer of an admit of `bucket` tokens
-        reads the layer's experts (`decoder_common.expert_runs`)."""
-        return decoder_common.expert_runs(self.cfg,
-                                          _expert_layer(self.params), bucket)
-
-    def prefill_attn_pairs(self, plen, bucket):
-        """(run, static) grid steps of one head of the prefill kernel over
-        an admit of `plen` tokens in `bucket`, every layer's causal call
-        summed (`decoder_common.prefill_attn_pairs`); None off the
-        kernel."""
-        return decoder_common.prefill_attn_pairs(
-            self.attn_impl, plen, bucket, deepseek_v3.PREFILL_BLOCK,
-            [None] * self.cfg.num_hidden_layers)
-
-    def model_args(self):
-        return (self.params,)
-
-    def init_cache(self, slots, cache_len):
-        cfg = self.cfg
-        if cache_len % 2:
-            raise ValueError(f"the latent leaf packs two positions a row: "
-                             f"cache rung {cache_len} must be even")
-        return {"kv": [jnp.zeros((slots, cache_len // 2,
-                                  2 * cfg.latent_width), cfg.compute_dtype)
-                       for _ in range(cfg.num_hidden_layers)],
-                "counts": jnp.zeros((len(self.counter_names),), jnp.int32)}
-
-    def grow(self, cache, new_len):
-        """Every latent leaf padded to the longer rung; the counts as they
-        are."""
-        def pad(t):
-            return jnp.pad(t, ((0, 0), (0, int(new_len) // 2 - t.shape[1]),
-                               (0, 0)))
-        return {**cache, "kv": [pad(t) for t in cache["kv"]]}
-
-    def counters(self, cache):
-        return cache["counts"]
-
-    def step(self, margs, cache, tokens, pos):
-        """One decode step for the whole batch, in the absorbed form: a
-        layer writes its latent row at `pos` and attends rows 0..pos of its
-        leaf, each read once as key and as value; then its feed-forward,
-        dense or experts. Returns next-token logits (S, V). Counted a step,
-        slots and layers summed: the rows attended (what the mathematics
-        needs) and the rows the kernel fetched for them (a slot's rows in
-        use rounded up to its tile)."""
-        (params,) = margs
-        cfg = self.cfg
-        with jax.named_scope("embed"):
-            x = jnp.take(params["embed"], tokens, axis=0)       # (S, H)
-        leaves = list(cache["kv"])
-        c = 2 * leaves[0].shape[1]
-        # rows 0..pos are in use (the current write included); a lane
-        # frozen at the end of its rung (pos == C) keeps all of them
-        in_use = jnp.minimum(pos + 1, c)                        # (S,)
-        tile = latent_tile_positions(c, cfg.kv_lora_rank, leaves[0].dtype)
-        read = (-(-in_use // tile) * tile).sum()
-        with jax.named_scope("rope"):
-            tables = deepseek_v3.rope_tables(cfg, pos)
-        counted = jnp.zeros((3,), jnp.int32)
-        for li, layer in enumerate(params["layers"]):
-            with jax.named_scope(f"layer{li}"):
-                x, leaves[li], counts = deepseek_v3.decode_layer(
-                    cfg, li, layer, x, tables, leaves[li], pos, in_use,
-                    self.attn_impl)
-                if counts is not None:
-                    counted = counted + counts
-        counts = cache["counts"] + jnp.concatenate([
-            counted, cfg.num_hidden_layers * jnp.stack(
-                [in_use.sum(), read]).astype(jnp.int32)])
-        return deepseek_v3.logits(cfg, params, x), \
-            {"kv": leaves, "counts": counts}
-
-    def prefill(self, margs, cache, slot, prompt, plen):
-        """The full forward over one length-bucketed prompt (P,) in the
-        expanded form, its attention over the tile pairs up to plen alone
-        (`deepseek_v3.causal_attention`), then the graft of the slot's
-        latent rows 0..P-1 a
-        layer (rows beyond plen hold padding garbage that the decode step
-        never reads: it attends rows up to `pos`). Returns the logits at
-        plen - 1."""
-        (params,) = margs
-        cfg = self.cfg
-        x, states = deepseek_v3.encode(cfg, params, prompt,
-                                       impl=self.attn_impl, plen=plen)
-        leaves = list(cache["kv"])
-        for li, (c, kr) in enumerate(states):
-            with jax.named_scope(f"layer{li}"), jax.named_scope("attn"), \
-                    jax.named_scope("kv_write"):
-                leaves[li] = lax.dynamic_update_slice(
-                    leaves[li],
-                    pack_latent(c, kr)[None].astype(leaves[li].dtype),
-                    (slot, 0, 0))
-        h_last = jnp.take(x, plen - 1, axis=0)                  # (H,)
-        return {**cache, "kv": leaves}, \
-            deepseek_v3.logits(cfg, params, h_last)
-
-
-class AfmoeDecoder:
-    """Decode over a `models/afmoe.py` parameter tree: sliding-window and
-    full grouped-query attention layers, each with the cache its kind
-    needs.
-
-    The cache pytree: `{"k": [...], "v": [...]}`, one K and one V leaf A
-    LAYER in the compute dtype, rows major: a sliding layer's `(S, min(W,
-    C), Hkv·Dh)` ring (W the window; position p at row p mod W), a full
-    layer's `(S, C, Hkv·Dh)`; `"counts"`, the running counts
-    (`counter_names`). Only the full layers' leaves, and a ring shorter
-    than W, know the rung.
-
-    The full-sequence reference this must match is `deeplearning4j_tpu.
-    models.afmoe.forward` over the same prompt+generated prefix (and,
-    outside the package, the plain reference under `benchmarks/families/`)."""
-
-    uses_cache_rungs = True
-    n_model_args = 1
-    supports_draft = False
-    max_cache_len = None        # rotary positions: no table bounds them
-    counter_names = ("moe_pairs", "moe_expert_reads", "moe_pairs_max",
-                     "window_rows_attended", "global_rows_attended")
-
-    def __init__(self, cfg, params, attn_impl="auto"):
-        if attn_impl not in ("auto", "dense", "pallas"):
-            raise ValueError(
-                f"attn_impl must be 'auto', 'dense' or 'pallas', "
-                f"got {attn_impl!r}")
-        self.cfg = cfg
-        self.params = params
-        self.attn_impl = attn_impl
-        self.vocab_size = int(cfg.vocab_size)
-
-    def fingerprint(self):
-        parts = ("afmoe-decode", repr(self.cfg), self.attn_impl,
-                 _shape_tree_repr(self.params),
-                 _shape_tree_repr(
-                     jax.eval_shape(lambda: self.init_cache(1, 1))))
-        return hashlib.sha256(repr(parts).encode()).hexdigest()[:16]
-
-    def expert_runs(self, bucket):
-        """How many times one expert layer of an admit of `bucket` tokens
-        reads the layer's experts (`decoder_common.expert_runs`)."""
-        return decoder_common.expert_runs(self.cfg,
-                                          _expert_layer(self.params), bucket)
+        reads the layer's experts (the family's `expert_runs`)."""
+        return self.family.expert_runs(
+            self.cfg, decoder_common.expert_layer(self.params), bucket)
 
     def prefill_attn_pairs(self, plen, bucket):
         """(run, static) grid steps of one head of the prefill kernels over
-        an admit of `plen` tokens in `bucket`, the sliding layers' bands
-        and the full layers' triangles summed
-        (`decoder_common.prefill_attn_pairs`); None off the kernel."""
-        cfg = self.cfg
+        an admit of `plen` tokens in `bucket`, every causal call summed
+        (`decoder_common.prefill_attn_pairs`); None off the kernel, and
+        where the family's prefill takes none."""
+        windows = getattr(self.family, "prefill_windows", None)
+        if windows is None:
+            return None
         return decoder_common.prefill_attn_pairs(
-            self.attn_impl, plen, bucket, afmoe.PREFILL_BLOCK,
-            [cfg.sliding_window if cfg.is_sliding(li) else None
-             for li in range(cfg.num_hidden_layers)])
+            self.attn_impl, plen, bucket, self.family.PREFILL_BLOCK,
+            windows(self.cfg))
 
     def model_args(self):
         return (self.params,)
 
-    def _rows(self, li, cache_len):
-        """Rows of layer `li`'s leaves at rung `cache_len`."""
-        if self.cfg.is_sliding(li):
-            return min(self.cfg.sliding_window, int(cache_len))
-        return int(cache_len)
-
     def init_cache(self, slots, cache_len):
-        cfg = self.cfg
-        cache = {name: [jnp.zeros((slots, self._rows(li, cache_len),
-                                   cfg.kv_width), cfg.compute_dtype)
-                        for li in range(cfg.num_hidden_layers)]
-                 for name in ("k", "v")}
+        cache = {name: [jnp.zeros(leaf.shape(slots, cache_len), leaf.dtype)
+                        for leaf in leaves]
+                 for name, leaves in self._leaves.items()}
         cache["counts"] = jnp.zeros((len(self.counter_names),), jnp.int32)
         return cache
 
     def grow(self, cache, new_len):
-        """A full layer's leaves padded to the longer rung, a ring up to
-        the window's rows while it is shorter (it held positions 0..C - 1
-        at their own rows, none wrapped); the counts as they are."""
-        def pad(li, t):
-            return jnp.pad(t, ((0, 0), (0, self._rows(li, new_len)
-                                        - t.shape[1]), (0, 0)))
-        return {**cache, **{name: [pad(li, t)
-                                   for li, t in enumerate(cache[name])]
-                            for name in ("k", "v")}}
+        """Every leaf that holds rows padded to its rows at the longer rung
+        (a ring up to its cap: it held positions 0..C - 1 at their own
+        rows, none wrapped); the states, the tails and the counts as they
+        are."""
+        def pad(leaf, t):
+            rows = leaf.shape(1, new_len)[1]
+            return jnp.pad(t, ((0, 0), (0, rows - t.shape[1]))
+                           + ((0, 0),) * len(leaf.width))
+        return {**cache, **{
+            name: [pad(leaf, t) for leaf, t in zip(leaves, cache[name])]
+            for name, leaves in self._leaves.items() if leaves[0].per_row}}
 
     def counters(self, cache):
         return cache["counts"]
 
     def step(self, margs, cache, tokens, pos):
-        """One decode step for the whole batch: a layer writes its K and V
-        rows at `pos` (a full layer) or `pos mod W` (a sliding layer's
-        ring) and attends the rows in use, `min(pos + 1, rows)`: on a full
-        ring that is every row, the last W positions; then its
-        feed-forward, dense or experts. Returns next-token logits (S, V).
-        Counted a step, slots and layers summed: the rows in use of the
-        sliding layers and of the full layers."""
+        """One decode step for the whole batch: each layer advances the
+        leaves it owns by the token at `pos` and the tally takes what it
+        counted (`decode_layer`). Returns next-token logits (S, V)."""
         (params,) = margs
-        cfg = self.cfg
+        fam, cfg = self.family, self.cfg
         with jax.named_scope("embed"):
-            x = afmoe.embed(cfg, params, tokens)                # (S, H)
-        with jax.named_scope("rope"):
-            tables = afmoe.rope_tables(cfg, pos)
-        leaves = {name: list(cache[name]) for name in ("k", "v")}
-        counted = jnp.zeros((3,), jnp.int32)
-        rows = jnp.zeros((2,), jnp.int32)       # (sliding, full) in use
-        for li, layer in enumerate(params["layers"]):
-            sliding = cfg.is_sliding(li)
-            r = leaves["k"][li].shape[1]
-            # rows 0..pos are in use (the current write included); a lane
-            # frozen at the end of its rung (pos == C) keeps all of them
-            in_use = jnp.minimum(pos + 1, r)                    # (S,)
-            write = pos % cfg.sliding_window if sliding else pos
-            with jax.named_scope(f"layer{li}"):
-                x, (leaves["k"][li], leaves["v"][li]), counts = \
-                    afmoe.decode_layer(
-                        cfg, li, layer, x, tables,
-                        (leaves["k"][li], leaves["v"][li]), write, in_use,
-                        self.attn_impl)
-            rows = rows.at[0 if sliding else 1].add(in_use.sum())
-            if counts is not None:
-                counted = counted + counts
-        counts = cache["counts"] + jnp.concatenate([counted, rows])
-        return afmoe.logits(cfg, params, x), {**leaves, "counts": counts}
+            x = fam.embed(cfg, params, tokens)                  # (S, H)
+        leaves = {name: list(cache[name]) for name in self._leaves}
+        ctx, tally = fam.decode_context(cfg, pos, leaves, cache["counts"])
+        for li, (layer, own) in enumerate(zip(params["layers"],
+                                              self._owned)):
+            x, state, tally = fam.decode_layer(
+                cfg, li, layer, x, ctx,
+                tuple(leaves[leaf.name][i] for leaf, i in own), tally,
+                self.attn_impl)
+            for (leaf, i), new in zip(own, state):
+                leaves[leaf.name][i] = new
+        counts = fam.decode_counts(cfg, ctx, tally)
+        return fam.logits(cfg, params, x), {**leaves, "counts": counts}
 
     def prefill(self, margs, cache, slot, prompt, plen):
-        """The full forward over one length-bucketed prompt (P,), sliding
-        layers through the banded kernel, both kernels over the tile pairs
-        up to plen alone (`afmoe.sequence_attention`), then the graft of
-        the slot's
-        rows a layer: a full layer's rows 0..P-1 (rows beyond plen hold
-        padding garbage that the decode step never reads: it attends rows
-        up to `pos`), a sliding layer's ring row r the prompt's position
-        `plen - 1 - ((plen - 1 - r) mod W)`, the last W REAL positions (a
-        row whose position would be negative is written before it is
-        read). Returns the logits at plen - 1."""
+        """The family's full forward over one length-bucketed prompt (P,),
+        then the graft of the slot's part of every leaf in the leaf's
+        scope: a leaf that holds rows takes rows 0..P-1 (rows past plen
+        hold padding garbage that the decode step never reads: it attends
+        rows up to `pos`), a state or a tail what the last REAL position
+        left (`cache_parts`). Returns the logits at plen - 1."""
         (params,) = margs
-        cfg = self.cfg
-        x, states = afmoe.encode(cfg, params, prompt, impl=self.attn_impl,
-                                 plen=plen)
-        leaves = {name: list(cache[name]) for name in ("k", "v")}
-        for li, state in enumerate(states):
-            with jax.named_scope(f"layer{li}"), jax.named_scope("attn"), \
-                    jax.named_scope("kv_write"):
-                if cfg.is_sliding(li):
-                    r = jnp.arange(leaves["k"][li].shape[1])
-                    at = plen - 1 - (plen - 1 - r) % cfg.sliding_window
-                    state = tuple(jnp.take(part, jnp.maximum(at, 0), axis=0)
-                                  for part in state)
-                for name, part in zip(("k", "v"), state):
-                    leaf = leaves[name][li]
-                    leaves[name][li] = lax.dynamic_update_slice(
-                        leaf, part[None].astype(leaf.dtype), (slot, 0, 0))
-        h_last = jnp.take(x, plen - 1, axis=0)                  # (H,)
-        return {**cache, **leaves}, afmoe.logits(cfg, params, h_last)
+        fam, cfg = self.family, self.cfg
+        x, states = fam.encode_prompt(cfg, params, prompt, plen,
+                                      self.attn_impl)
+        leaves = {name: list(cache[name]) for name in self._leaves}
+        for li, (state, own) in enumerate(zip(states, self._owned)):
+            if not own:
+                continue
+            with jax.named_scope(f"layer{li}/{own[0][0].scope}"):
+                parts = fam.cache_parts(cfg, li, state, plen, tuple(
+                    leaves[leaf.name][i] for leaf, i in own))
+                for (leaf, i), part in zip(own, parts):
+                    full = leaves[leaf.name][i]
+                    if part.ndim < full.ndim:       # without its slot axis
+                        part = part[None]
+                    leaves[leaf.name][i] = lax.dynamic_update_slice(
+                        full, part.astype(full.dtype),
+                        (slot,) + (0,) * (full.ndim - 1))
+        # (a batched encode hands back its batch of one)
+        h_last = jnp.take(x[0] if x.ndim == 3 else x, plen - 1, axis=0)
+        return {**cache, **leaves}, fam.logits(cfg, params, h_last)
 
 
-class Lfm2MoeDecoder:
-    """Decode over a `models/lfm2_moe.py` parameter tree: gated short
-    convolutions and grouped-query attention layers, each with the state
-    its kind needs.
+class NemotronHDecoder(FamilyDecoder):
+    """`models/nemotron_h.py`: K/V leaves for the attention layers beside
+    Mamba-2 state and convolution-tail leaves, in one donated cache."""
+    family = nemotron_h
+    name = "nemotron-h-decode"
+    counter_names = _MOE_COUNTERS
 
-    The cache pytree: `{"k": [...], "v": [...]}`, one `(S, C, Hkv·Dh)` K
-    and one V leaf an ATTENTION layer in the compute dtype, rows major;
-    `"conv"`, one `(S, K-1, H)` tail a CONV layer in the compute dtype;
-    `"counts"`, the running counts (`counter_names`). Only the K/V leaves
-    know the rung.
 
-    The full-sequence reference this must match is `deeplearning4j_tpu.
-    models.lfm2_moe.forward` over the same prompt+generated prefix (and,
-    outside the package, the plain reference under `benchmarks/families/`)."""
+class KeyeDecoder(FamilyDecoder):
+    """`models/keye_vl.py`: grouped-query attention over the cache rows a
+    learned indexer selects."""
+    family = keye_vl
+    name = "keye-decode"
+    counter_names = _MOE_COUNTERS + ("dsa_rows_scored", "dsa_rows_selected",
+                                     "dsa_rows_read")
 
-    uses_cache_rungs = True
-    n_model_args = 1
-    supports_draft = False
-    max_cache_len = None        # rotary positions: no table bounds them
-    counter_names = ("moe_pairs", "moe_expert_reads", "moe_pairs_max",
-                     "global_rows_attended")
 
-    def __init__(self, cfg, params, attn_impl="auto"):
-        if attn_impl not in ("auto", "dense", "pallas"):
-            raise ValueError(
-                f"attn_impl must be 'auto', 'dense' or 'pallas', "
-                f"got {attn_impl!r}")
-        self.cfg = cfg
-        self.params = params
-        self.attn_impl = attn_impl
-        self.vocab_size = int(cfg.vocab_size)
-        # layer index -> index among the layers of its kind (its leaves)
-        self._leaf = [sum(cfg.is_attention(j) == cfg.is_attention(li)
-                          for j in range(li))
-                      for li in range(cfg.num_hidden_layers)]
+class MLADecoder(FamilyDecoder):
+    """`models/deepseek_v3.py`: multi-head latent attention over one cached
+    row a position."""
+    family = deepseek_v3
+    name = "mla-decode"
+    counter_names = _MOE_COUNTERS + ("mla_rows_attended", "mla_rows_read")
 
-    def fingerprint(self):
-        parts = ("lfm2-moe-decode", repr(self.cfg), self.attn_impl,
-                 _shape_tree_repr(self.params),
-                 _shape_tree_repr(
-                     jax.eval_shape(lambda: self.init_cache(1, 1))))
-        return hashlib.sha256(repr(parts).encode()).hexdigest()[:16]
 
-    def expert_runs(self, bucket):
-        """How many times one expert layer of an admit of `bucket` tokens
-        reads the layer's experts (`decoder_common.expert_runs`)."""
-        return decoder_common.expert_runs(self.cfg,
-                                          _expert_layer(self.params), bucket)
+class AfmoeDecoder(FamilyDecoder):
+    """`models/afmoe.py`: sliding-window and full grouped-query attention
+    layers, each with the cache its kind needs."""
+    family = afmoe
+    name = "afmoe-decode"
+    counter_names = _MOE_COUNTERS + ("window_rows_attended",
+                                     "global_rows_attended")
 
-    def prefill_attn_pairs(self, plen, bucket):
-        """(run, static) grid steps of one head of the prefill kernel over
-        an admit of `plen` tokens in `bucket`, the attention layers' causal
-        calls summed (`decoder_common.prefill_attn_pairs`); None off the
-        kernel."""
-        cfg = self.cfg
-        return decoder_common.prefill_attn_pairs(
-            self.attn_impl, plen, bucket, lfm2_moe.PREFILL_BLOCK,
-            [None] * sum(map(cfg.is_attention,
-                             range(cfg.num_hidden_layers))))
 
-    def model_args(self):
-        return (self.params,)
-
-    def init_cache(self, slots, cache_len):
-        cfg = self.cfg
-        dt = cfg.compute_dtype
-        na = sum(map(cfg.is_attention, range(cfg.num_hidden_layers)))
-        nc = cfg.num_hidden_layers - na
-
-        def leaves(n, shape):
-            return [jnp.zeros((slots,) + shape, dt) for _ in range(n)]
-
-        return {"k": leaves(na, (cache_len, cfg.kv_width)),
-                "v": leaves(na, (cache_len, cfg.kv_width)),
-                "conv": leaves(nc, (cfg.conv_L_cache - 1, cfg.hidden_size)),
-                "counts": jnp.zeros((len(self.counter_names),), jnp.int32)}
-
-    def grow(self, cache, new_len):
-        """The K/V leaves padded to the longer rung; the tails and the
-        counts as they are."""
-        def pad(t):
-            return jnp.pad(t, ((0, 0), (0, int(new_len) - t.shape[1]),
-                               (0, 0)))
-        return {**cache, "k": [pad(t) for t in cache["k"]],
-                "v": [pad(t) for t in cache["v"]]}
-
-    def counters(self, cache):
-        return cache["counts"]
-
-    def step(self, margs, cache, tokens, pos):
-        """One decode step for the whole batch: a conv layer shifts its
-        slots' tails by the token; an attention layer writes its K and V
-        rows at `pos` and attends the rows in use, `min(pos + 1, C)`; then
-        the feed-forward, dense or experts. Returns next-token logits (S,
-        V). Counted a step, slots and layers summed: the attention layers'
-        rows in use."""
-        (params,) = margs
-        cfg = self.cfg
-        with jax.named_scope("embed"):
-            x = lfm2_moe.embed(params, tokens)                  # (S, H)
-        with jax.named_scope("rope"):
-            tables = lfm2_moe.rope_tables(cfg, pos)
-        leaves = {name: list(cache[name]) for name in ("k", "v", "conv")}
-        counted = jnp.zeros((3,), jnp.int32)
-        rows = jnp.zeros((), jnp.int32)
-        for li, layer in enumerate(params["layers"]):
-            i = self._leaf[li]
-            with jax.named_scope(f"layer{li}"):
-                if cfg.is_attention(li):
-                    # rows 0..pos are in use (the current write included);
-                    # a lane frozen at the end of its rung (pos == C)
-                    # keeps all of them
-                    in_use = jnp.minimum(pos + 1, leaves["k"][i].shape[1])
-                    x, (leaves["k"][i], leaves["v"][i]), counts = \
-                        lfm2_moe.decode_layer(
-                            cfg, li, layer, x, tables,
-                            (leaves["k"][i], leaves["v"][i]), pos, in_use,
-                            self.attn_impl)
-                    rows = rows + in_use.sum()
-                else:
-                    x, leaves["conv"][i], counts = lfm2_moe.decode_layer(
-                        cfg, li, layer, x, tables, leaves["conv"][i], pos,
-                        None, self.attn_impl)
-            if counts is not None:
-                counted = counted + counts
-        counts = cache["counts"] + jnp.concatenate([counted, rows[None]])
-        return lfm2_moe.logits(cfg, params, x), {**leaves, "counts": counts}
-
-    def prefill(self, margs, cache, slot, prompt, plen):
-        """The full forward over one length-bucketed prompt (P,), attention
-        through the causal prefill kernel over the tile pairs up to plen
-        alone (`lfm2_moe.sequence_attention`), then the graft of the slot's
-        state a layer: an attention layer's K/V rows 0..P-1 (rows beyond
-        plen hold padding garbage that the decode step never reads: it
-        attends rows up to `pos`), a conv layer's tail as the last REAL
-        positions left it (z of positions plen - K + 1 .. plen - 1, zeros
-        before 0; the bucket's last rows would be padding). Returns the
-        logits at plen - 1."""
-        (params,) = margs
-        cfg = self.cfg
-        x, states = lfm2_moe.encode(cfg, params, prompt, plen,
-                                    impl=self.attn_impl)
-        leaves = {name: list(cache[name]) for name in ("k", "v", "conv")}
-
-        def graft(name, i, part):
-            leaf = leaves[name][i]
-            leaves[name][i] = lax.dynamic_update_slice(
-                leaf, part[None].astype(leaf.dtype), (slot, 0, 0))
-
-        for li, state in enumerate(states):
-            i = self._leaf[li]
-            with jax.named_scope(f"layer{li}"):
-                if cfg.is_attention(li):
-                    with jax.named_scope("attn"), \
-                            jax.named_scope("kv_write"):
-                        graft("k", i, state[0])
-                        graft("v", i, state[1])
-                else:
-                    with jax.named_scope("shortconv"), \
-                            jax.named_scope("tail_write"):
-                        graft("conv", i, state)
-        h_last = jnp.take(x, plen - 1, axis=0)                  # (H,)
-        return {**cache, **leaves}, lfm2_moe.logits(cfg, params, h_last)
+class Lfm2MoeDecoder(FamilyDecoder):
+    """`models/lfm2_moe.py`: gated short convolutions and grouped-query
+    attention layers, each with the state its kind needs."""
+    family = lfm2_moe
+    name = "lfm2-moe-decode"
+    counter_names = _MOE_COUNTERS + ("global_rows_attended",)
 
 
 class RecurrentDecoder:

@@ -30,7 +30,7 @@ sqrt(H)` under `mup_enabled`. For a layer input `x` (T, H) at positions t:
 A position's decode state a layer is its k (normed, and rotated on a
 sliding layer) and v. `generation/decode.py`'s `AfmoeDecoder` keeps a
 sliding layer's in a ring of `sliding_window` rows and a full layer's in
-rows that grow with the cache rung.
+rows that grow with the cache rung (`decode_leaves`).
 """
 from __future__ import annotations
 
@@ -44,7 +44,8 @@ from deeplearning4j_tpu.kernels.flash_attention import (
 # (RMSNorm, the final norm and head, and the sigmoid-routed expert layer
 # with its shared SwiGLU are every served decoder's)
 from deeplearning4j_tpu.models.decoder_common import (  # noqa: F401
-    logits, moe, real_rows, rms_norm, swiglu)
+    Leaf, expert_runs, logits, moe, real_rows, rms_norm, rope_tables, rotate,
+    swiglu, write_rows)
 
 #: rows of queries and of keys a grid step of a prompt's attention takes,
 #: full layers (causal) and sliding layers (the band) alike. The scores of
@@ -226,25 +227,6 @@ def embed(cfg, params, ids):
     return (x * cfg.embed_scale).astype(x.dtype)
 
 
-def rope_tables(cfg, positions):
-    """(cos, sin) (T, D/2) float32 at `positions` (T,): angle j is `t
-    theta^(-2j/D)`."""
-    half = cfg.head_dim // 2
-    ang = positions.astype(jnp.float32)[:, None] * cfg.rope_theta ** (
-        -jnp.arange(half, dtype=jnp.float32) / half)
-    return jnp.cos(ang), jnp.sin(ang)
-
-
-def rotate(x, table):
-    """Rotary in the rotate-half layout over heads `x` (T, heads, D): lane
-    j turns with lane j + D/2 by the table's angle j, in float32."""
-    cos, sin = (a[:, None, :] for a in table)
-    x32 = x.astype(jnp.float32)
-    a, b = jnp.split(x32, 2, axis=-1)
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                           axis=-1).astype(x.dtype)
-
-
 def attention_inputs(cfg, li, layer, u, tables):
     """What layer `li`'s attention reads of tokens `u` (T, H) at the
     positions behind `tables`: q (T, Hq, D), the cache rows k, v (T,
@@ -378,31 +360,87 @@ def encode(cfg, params, ids, impl="auto", plen=None):
     return x, states
 
 
-def decode_layer(cfg, li, layer, x, tables, leaves, write, lengths,
-                 impl="auto"):
-    """Layer `li` for ONE token a slot, `x` (S, H), against its K and V
-    leaves `(S, R, Hkv·D)`: the token's rows written at row `write` (S,)
-    (its position on a full layer, the position mod the window on a
-    sliding layer's ring), rows 0..lengths - 1 attended through
-    `flash_attention_decode(..., lengths=)` in scope `window`/`global`.
-    Returns (y, the leaves, the expert layer's counts or None)."""
+# -- decode (`generation/decode.py`'s `AfmoeDecoder`) --------------------------
+def decode_leaves(cfg):
+    """A K and a V leaf a layer, rows major: a sliding layer's a RING of
+    `min(W, C)` rows at a rung of C (position p at row p mod W), a full
+    layer's C rows."""
+    def kv(li):
+        cap = cfg.sliding_window if cfg.is_sliding(li) else None
+        return tuple(Leaf(name, (cfg.kv_width,), cfg.compute_dtype, cap=cap)
+                     for name in ("k", "v"))
+    return [kv(li) for li in range(cfg.num_hidden_layers)]
+
+
+def decode_context(cfg, pos, leaves, counts):
+    """The rotary tables at `pos` (S,); the tally (the expert layers'
+    counts, the rows in use of the sliding and of the full layers) from
+    zero."""
+    with jax.named_scope("rope"):
+        tables = rope_tables(cfg, pos)
+    return dict(pos=pos, tables=tables, counts=counts), (
+        jnp.zeros((3,), jnp.int32), jnp.zeros((2,), jnp.int32))
+
+
+def decode_layer(cfg, li, layer, x, ctx, state, tally, impl="auto"):
+    """Layer `li` for ONE token a slot, `x` (S, H), against its leaves
+    `state` = (k, v) `(S, R, Hkv·D)`: the token's rows written at its
+    position, or at the position mod W on a ring, and the rows in use,
+    `min(pos + 1, R)`, attended in scope `window`/`global` (a full ring
+    whole: a softmax does not care in which order rows come); then the
+    feed-forward."""
+    pos = ctx["pos"]
     s = x.shape[0]
-    kc, vc = leaves
-    with jax.named_scope("norm"):
-        u = rms_norm(x, layer["norm_in"], cfg.rms_norm_eps)
-    with jax.named_scope("attn"):
-        q, k, v, g = attention_inputs(cfg, li, layer, u, tables)
-        with jax.named_scope("kv_write"):
-            slot = jnp.arange(s)
-            kc = kc.at[slot, write].set(k.astype(kc.dtype))
-            vc = vc.at[slot, write].set(v.astype(vc.dtype))
-        with jax.named_scope(attention_scope(cfg, li)):
-            rows = jnp.arange(kc.shape[1])[None, :] < lengths[:, None]
-            ctx = flash_attention_decode(q, kc, vc, rows, impl=impl,
-                                         lengths=lengths)
-        x = attention_output(cfg, layer, x, ctx.reshape(s, -1), g)
-    y, counts = feed_forward(cfg, li, layer, x)
-    return y, (kc, vc), counts
+    kc, vc = state
+    sliding = cfg.is_sliding(li)
+    # rows 0..pos are in use (the current write included); a lane frozen
+    # at the end of its rung (pos == C) keeps all of them
+    lengths = jnp.minimum(pos + 1, kc.shape[1])                 # (S,)
+    write = pos % cfg.sliding_window if sliding else pos
+    with jax.named_scope(f"layer{li}"):
+        with jax.named_scope("norm"):
+            u = rms_norm(x, layer["norm_in"], cfg.rms_norm_eps)
+        with jax.named_scope("attn"):
+            q, k, v, g = attention_inputs(cfg, li, layer, u, ctx["tables"])
+            with jax.named_scope("kv_write"):
+                kc, vc = write_rows((kc, vc), jnp.arange(s), write, (k, v))
+            with jax.named_scope(attention_scope(cfg, li)):
+                rows = jnp.arange(kc.shape[1])[None, :] < lengths[:, None]
+                out = flash_attention_decode(q, kc, vc, rows, impl=impl,
+                                             lengths=lengths)
+            x = attention_output(cfg, layer, x, out.reshape(s, -1), g)
+        y, counts = feed_forward(cfg, li, layer, x)
+    counted, in_use = tally
+    in_use = in_use.at[0 if sliding else 1].add(lengths.sum())
+    if counts is not None:
+        counted = counted + counts
+    return y, (kc, vc), (counted, in_use)
+
+
+def decode_counts(cfg, ctx, tally):
+    return ctx["counts"] + jnp.concatenate(tally)
+
+
+def encode_prompt(cfg, params, prompt, plen, impl="auto"):
+    return encode(cfg, params, prompt, impl=impl, plen=plen)
+
+
+def cache_parts(cfg, li, state, plen, leaves):
+    """A full layer's rows 0..P-1; a sliding layer's ring row r the
+    prompt's position `plen - 1 - ((plen - 1 - r) mod W)`, the last W REAL
+    positions (a row whose position would be negative is written before it
+    is read)."""
+    if not cfg.is_sliding(li):
+        return state
+    r = jnp.arange(leaves[0].shape[1])
+    at = plen - 1 - (plen - 1 - r) % cfg.sliding_window
+    return tuple(jnp.take(part, jnp.maximum(at, 0), axis=0)
+                 for part in state)
+
+
+def prefill_windows(cfg):
+    return [cfg.sliding_window if cfg.is_sliding(li) else None
+            for li in range(cfg.num_hidden_layers)]
 
 
 def forward(cfg, params, ids, impl="auto"):

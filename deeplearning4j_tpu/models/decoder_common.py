@@ -1,11 +1,14 @@
 """What the served language models of this package share outside their
-attention: the norm, the head, the run of tokens an expert layer takes at
-once, the sigmoid-routed expert layer (with or without a shared SwiGLU)
+attention: the declaration of a slot's decode state (`Leaf`), the
+embedding, the norm, rotary in the rotate-half layout, the head, the run
+of tokens an expert layer takes at once, the sigmoid-routed expert layer (with or without a shared SwiGLU)
 that `deepseek_v3.py`, `afmoe.py` and `lfm2_moe.py` compute, and the two
 steps of a causal convolution's tail that `nemotron_h.py` and
 `lfm2_moe.py` keep. One place, so that no model's file imports
 another's."""
 from __future__ import annotations
+
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +25,54 @@ from deeplearning4j_tpu.parallel.moe import (routed_calls, routed_experts,
 MOE_CHUNK = 2048
 
 
+class Leaf(NamedTuple):
+    """One of a layer's leaves of a slot's decode state, as a family
+    declares it (`decode_leaves`): its cache key, the shape past its row
+    axis and its dtype, and what a cache rung of C positions makes of its
+    rows: `C / per_row` (positions packed `per_row` to a row), at most
+    `cap` (a ring), or none (`per_row=0`: a state or a tail of a fixed
+    shape, `width` all of it past the slot axis, that no rung touches).
+    `scope` is where a prefill grafts a layer's leaves."""
+    name: str
+    width: tuple
+    dtype: object
+    per_row: int = 1
+    cap: int | None = None
+    scope: str = "attn/kv_write"
+
+    def shape(self, slots, rung):
+        """The leaf's shape in a cache of `slots` slots at rung `rung`."""
+        if not self.per_row:
+            return (slots,) + self.width
+        if rung % self.per_row:
+            raise ValueError(
+                f"the {self.name!r} leaf packs {self.per_row} positions a "
+                f"row: cache rung {rung} must be "
+                + ("even" if self.per_row == 2
+                   else f"a multiple of {self.per_row}"))
+        rows = int(rung) // self.per_row
+        return (slots, rows if self.cap is None
+                else min(self.cap, rows)) + self.width
+
+
+def slot_index(pos):
+    """Rows at per-slot positions `pos` ((S,) or an (S, d) block) of a
+    slot-contiguous cache: the slot index to pair with `pos`."""
+    return jnp.arange(pos.shape[0]).reshape((-1,) + (1,) * (pos.ndim - 1))
+
+
+def write_rows(leaves, slot, at, rows):
+    """Each leaf's new rows `rows` (..., W) at (`slot`, `at`): whole rows,
+    in place."""
+    return tuple(leaf.at[slot, at].set(row.astype(leaf.dtype))
+                 for leaf, row in zip(leaves, rows))
+
+
+def embed(cfg, params, ids):
+    """The embedding rows of `ids`, unscaled."""
+    return jnp.take(params["embed"], ids, axis=0)
+
+
 def rms_norm(x, weight, eps):
     x32 = x.astype(jnp.float32)
     y = x32 * lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
@@ -34,6 +85,25 @@ def logits(cfg, params, x):
         u = rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
         return jnp.dot(u, params["head"].astype(u.dtype),
                        preferred_element_type=jnp.float32)
+
+
+def rope_tables(cfg, positions):
+    """(cos, sin) (T, D/2) float32 at `positions` (T,): angle j is `t
+    theta^(-2j/D)` over a head of D = `cfg.head_dim` lanes."""
+    half = cfg.head_dim // 2
+    ang = positions.astype(jnp.float32)[:, None] * cfg.rope_theta ** (
+        -jnp.arange(half, dtype=jnp.float32) / half)
+    return jnp.cos(ang), jnp.sin(ang)
+
+
+def rotate(x, table):
+    """Rotary in the rotate-half layout over heads `x` (T, heads, W): lane
+    i turns with lane i + W/2 by the table's angle i, in float32."""
+    cos, sin = (a[:, None, :] for a in table)
+    x32 = x.astype(jnp.float32)
+    a, b = jnp.split(x32, 2, axis=-1)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
 
 
 def swiglu(x, gate, up, down):
@@ -119,6 +189,11 @@ def _runs(layer, t):
             or t % MOE_CHUNK:
         return 1
     return t // MOE_CHUNK
+
+
+def expert_layer(params):
+    """The first of a model's layers that routes to experts."""
+    return next(layer for layer in params["layers"] if "router" in layer)
 
 
 def expert_runs(cfg, layer, t):

@@ -35,7 +35,8 @@ biases. For a layer input `u = RMSNorm(x)` (T, H) at positions t:
   experts as ONE SwiGLU of `n_shared_experts` x the expert width on every
   token, unweighted.
 
-`generation/decode.py`'s `MLADecoder` serves it.
+`generation/decode.py`'s `MLADecoder` serves it from the decode state
+declared here (`decode_leaves`) and the step's and the prefill's hooks.
 """
 from __future__ import annotations
 
@@ -45,12 +46,14 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.kernels.flash_attention import flash_attention
-from deeplearning4j_tpu.kernels.mla_attention import (mla_attention_decode,
-                                                      write_latent_row)
-# (RMSNorm, the final norm and head, and the sigmoid-routed expert layer
-# with its shared SwiGLU are every served decoder's)
+from deeplearning4j_tpu.kernels.mla_attention import (
+    latent_tile_positions, mla_attention_decode, pack_latent,
+    write_latent_row)
+# (the embedding, RMSNorm, the final norm and head, and the sigmoid-routed
+# expert layer with its shared SwiGLU are every served decoder's)
 from deeplearning4j_tpu.models.decoder_common import (  # noqa: F401
-    MOE_CHUNK, logits, moe, real_rows, rms_norm, router_scores, swiglu)
+    MOE_CHUNK, Leaf, embed, expert_runs, logits, moe, real_rows, rms_norm,
+    router_scores, swiglu)
 
 #: rows of queries and of keys a grid step of a prompt's attention takes.
 #: On a v5e at 32 heads, keys of 192 and values of 128, bfloat16 (`PERF.md`,
@@ -351,7 +354,7 @@ def encode(cfg, params, ids, impl="auto", plen=None):
     with jax.named_scope("rope"):
         tables = rope_tables(cfg, jnp.arange(ids.shape[0]))
     with jax.named_scope("embed"):
-        x = jnp.take(params["embed"], ids, axis=0)
+        x = embed(cfg, params, ids)
     states = []
     for li, layer in enumerate(params["layers"]):
         with jax.named_scope(f"layer{li}"):
@@ -360,24 +363,74 @@ def encode(cfg, params, ids, impl="auto", plen=None):
     return x, states
 
 
-def decode_layer(cfg, li, layer, x, tables, leaf, pos, lengths,
-                 impl="auto"):
-    """Layer `li` for ONE token a slot, `x` (S, H) at positions `pos` (S,),
-    in the absorbed form against the layer's latent leaf: the token's row
-    written at `pos`, rows 0..lengths - 1 attended. Returns (y, the leaf,
-    the expert layer's counts or None)."""
-    with jax.named_scope("norm"):
-        u = rms_norm(x, layer["norm1"], cfg.rms_norm_eps)
-    with jax.named_scope("attn"):
-        q_nope, q_rope, c, kr = attention_inputs(cfg, layer, u, tables)
-        with jax.named_scope("kv_write"):
-            leaf = write_latent_row(leaf, pos, c, kr)
-        ctx = absorbed_attention(cfg, layer, q_nope, q_rope, leaf, lengths,
-                                 impl)
-        with jax.named_scope("proj"):
-            x = x + ctx @ layer["o"].astype(x.dtype)
-    y, counts = feed_forward(cfg, li, layer, x)
-    return y, leaf, counts
+# -- decode (`generation/decode.py`'s `MLADecoder`) ---------------------------
+def decode_leaves(cfg):
+    """ONE latent leaf a layer, `(C / 2, 2·(L + R))` at an even rung of C,
+    row r holding positions 2r and 2r + 1 as `[c, c', kr, kr']`
+    (`mla_attention.pack_latent`)."""
+    leaf = Leaf("kv", (2 * cfg.latent_width,), cfg.compute_dtype, per_row=2)
+    return [(leaf,)] * cfg.num_hidden_layers
+
+
+def decode_context(cfg, pos, leaves, counts):
+    """The rows in use at `pos` (S,), 0..pos (the current write included; a
+    lane frozen at the end of its rung, pos == C, keeps all of them), the
+    rows the kernel fetches for them (rounded up to its tile) and the
+    rotary tables; the expert layers' tally from zero."""
+    leaf = leaves["kv"][0]
+    c = 2 * leaf.shape[1]
+    in_use = jnp.minimum(pos + 1, c)                        # (S,)
+    tile = latent_tile_positions(c, cfg.kv_lora_rank, leaf.dtype)
+    read = (-(-in_use // tile) * tile).sum()
+    with jax.named_scope("rope"):
+        tables = rope_tables(cfg, pos)
+    return dict(pos=pos, in_use=in_use, read=read, tables=tables,
+                counts=counts), jnp.zeros((3,), jnp.int32)
+
+
+def decode_layer(cfg, li, layer, x, ctx, state, tally, impl="auto"):
+    """Layer `li` for ONE token a slot, `x` (S, H), in the absorbed form
+    against its latent leaf `state` = (leaf,): the token's row written at
+    its position, the rows in use attended, each read once as key and as
+    value; then the feed-forward."""
+    (leaf,) = state
+    with jax.named_scope(f"layer{li}"):
+        with jax.named_scope("norm"):
+            u = rms_norm(x, layer["norm1"], cfg.rms_norm_eps)
+        with jax.named_scope("attn"):
+            q_nope, q_rope, c, kr = attention_inputs(cfg, layer, u,
+                                                     ctx["tables"])
+            with jax.named_scope("kv_write"):
+                leaf = write_latent_row(leaf, ctx["pos"], c, kr)
+            out = absorbed_attention(cfg, layer, q_nope, q_rope, leaf,
+                                     ctx["in_use"], impl)
+            with jax.named_scope("proj"):
+                x = x + out @ layer["o"].astype(x.dtype)
+        y, counts = feed_forward(cfg, li, layer, x)
+        if counts is not None:
+            tally = tally + counts
+    return y, (leaf,), tally
+
+
+def decode_counts(cfg, ctx, tally):
+    """The expert layers' tally, then the rows attended (what the
+    mathematics needs) and the rows the kernel fetched, slots and layers
+    summed."""
+    return ctx["counts"] + jnp.concatenate([
+        tally, cfg.num_hidden_layers * jnp.stack(
+            [ctx["in_use"].sum(), ctx["read"]]).astype(jnp.int32)])
+
+
+def encode_prompt(cfg, params, prompt, plen, impl="auto"):
+    return encode(cfg, params, prompt, impl=impl, plen=plen)
+
+
+def cache_parts(cfg, li, state, plen, leaves):
+    return (pack_latent(*state),)
+
+
+def prefill_windows(cfg):
+    return [None] * cfg.num_hidden_layers
 
 
 def forward(cfg, params, ids, impl="auto"):

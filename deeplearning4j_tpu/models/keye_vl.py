@@ -23,7 +23,8 @@ input `u = RMSNorm(x)` (T, H) and position streams `p` (3, T):
 
 `encode` is the full-sequence forward (prefill's arithmetic) and hands back
 each layer's decode state: K, V and index-key rows. `generation/decode.py`'s
-`KeyeDecoder` serves it. The vision tower is not modelled; the three
+`KeyeDecoder` serves it from the decode state declared here
+(`decode_leaves`) and the step's and the prefill's hooks. The vision tower is not modelled; the three
 position streams are, and text gives all three the token's index.
 """
 from __future__ import annotations
@@ -36,12 +37,15 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from deeplearning4j_tpu.kernels.flash_attention import \
-    flash_attention_selected
-from deeplearning4j_tpu.kernels.indexer import index_scores
-from deeplearning4j_tpu.kernels.selection import top_k_mask
+from deeplearning4j_tpu.kernels.flash_attention import (
+    decode_tile_rows, flash_attention_decode, flash_attention_selected)
+from deeplearning4j_tpu.kernels.indexer import (index_scores,
+                                                index_scores_decode,
+                                                pack_rows, write_packed_row)
+from deeplearning4j_tpu.kernels.selection import compact_indices, top_k_mask
 from deeplearning4j_tpu.models.decoder_common import (  # noqa: F401
-    MOE_CHUNK, logits, rms_norm)
+    MOE_CHUNK, Leaf, embed, logits, rms_norm, rotate, slot_index,
+    write_rows)
 from deeplearning4j_tpu.parallel.moe import routed_calls, routed_experts
 
 #: query rows of a prefill whose index scores and selection exist at once
@@ -50,6 +54,15 @@ Q_BLOCK = 4096
 #: rows whose threshold one `top_k_mask` call finds (their scores' unsigned
 #: image is 8 MB at 16384 columns, which 32 counting passes can keep near)
 SELECT_ROWS = 128
+#: a decode step attends its kept rows where they lie while the rung holds
+#: at most this many times `topk` rows, and gathers them past it. Two rates
+#: measured on a v5e (`PERF.md` §6; K and V rows of 1 KB): gathered, a
+#: kept row costs 38.9 ns (XLA's row gather 2 x 15.2 ns, the index
+#: arithmetic around it 5.8 and the kernel over the gathered rung 2.7),
+#: whatever the context; read in place, a row in use costs 2.9 ns. In place
+#: wins while a slot has fewer than 38.9 / 2.9 = 13.4 x `topk` rows in use,
+#: which the rung bounds: 12 leaves a tenth of room
+IN_PLACE_RUNGS = 12
 
 
 @dataclass(frozen=True)
@@ -214,16 +227,6 @@ def rope_tables(cfg, positions):
     return (jnp.cos(ang), jnp.sin(ang)), (jnp.cos(ang_i), jnp.sin(ang_i))
 
 
-def rotate(x, table):
-    """Rotary in the rotate-half layout over heads `x` (T, heads, W): lane
-    i turns with lane i + W/2 by the table's angle i, in float32."""
-    cos, sin = (a[:, None, :] for a in table)
-    x32 = x.astype(jnp.float32)
-    a, b = jnp.split(x32, 2, axis=-1)
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                           axis=-1).astype(x.dtype)
-
-
 def attention_inputs(cfg, layer, u, tables):
     """What a layer's attention reads of tokens `u` (T, H) at the positions
     behind `tables` (`rope_tables`): q (T, Hq·D), the cache rows k, v (T,
@@ -365,7 +368,7 @@ def encode(cfg, params, ids, positions=None, impl="auto", q_block=Q_BLOCK):
         positions = text_positions(ids.shape[0])
     with jax.named_scope("rope"):
         tables = rope_tables(cfg, positions)
-    x = jnp.take(params["embed"], ids, axis=0)
+    x = embed(cfg, params, ids)
     states = []
     for li, layer in enumerate(params["layers"]):
         with jax.named_scope(f"layer{li}"):
@@ -383,3 +386,121 @@ def forward(cfg, params, ids, positions=None, impl="auto", q_block=Q_BLOCK):
                                           q_block)[0])
     return jnp.stack([forward(cfg, params, row, positions, impl, q_block)
                       for row in ids])
+
+
+# -- decode (`generation/decode.py`'s `KeyeDecoder`) ---------------------------
+def decode_leaves(cfg):
+    """THREE leaves a layer: K and V rows `(C, Hkv·D)` at an even rung of
+    C, and the indexer's key rows PACKED two positions a row, `(C / 2,
+    2·Di)` (`indexer.pack_rows`: Di = 64 is half a lane tile, and the chip
+    gave a `(S, C, 64)` leaf a layout of its own and copied it whole, in
+    and out, every step)."""
+    dt = cfg.compute_dtype
+    layer = (Leaf("k", (cfg.kv_width,), dt), Leaf("v", (cfg.kv_width,), dt),
+             Leaf("ki", (2 * cfg.indexer_head_dim,), dt, per_row=2))
+    return [layer] * cfg.num_hidden_layers
+
+
+def attends_in_place(rung, topk):
+    """Whether a sparse decode step over a rung of `rung` rows reads K and
+    V in place under the selection mask (cost: the rows in use, which the
+    rung bounds) or gathers the `topk` kept rows first (cost: 2 x `topk`
+    row copies a slot, whatever the context). Both are one softmax over
+    the same rows; the rung and `topk` are static shapes of the program
+    being compiled, so the program holds one of them."""
+    return rung <= IN_PLACE_RUNGS * topk
+
+
+def decode_context(cfg, pos, leaves, counts):
+    """The rows in use at `pos` (S,), 0..pos (the current write included;
+    a lane frozen at the end of its rung, pos == C, keeps all of them), the
+    `min(topk, pos + 1)` a slot keeps, whether the step reads them in place
+    (`attends_in_place`), the rows of K the kernel fetches for them (in
+    place: the rows in use rounded up to its tile) and the rotary tables
+    (text: all three streams the token's index); the tally from zero."""
+    s = pos.shape[0]
+    slot = slot_index(pos)
+    c = leaves["k"][0].shape[1]
+    width = min(cfg.indexer_topk, c)
+    in_use = jnp.minimum(pos + 1, c)                        # (S,)
+    keep = jnp.minimum(in_use, width)
+    in_place = attends_in_place(c, cfg.indexer_topk)
+    kept = None
+    if in_place:
+        tile = decode_tile_rows(c, cfg.kv_width, leaves["k"][0].dtype)
+        read = (-(-in_use // tile) * tile).sum()
+    else:
+        kept = jnp.arange(width)[None, :] < keep[:, None]   # (S, topk)
+        read = keep.sum()
+    with jax.named_scope("rope"):
+        tables = rope_tables(cfg, jnp.broadcast_to(pos[None], (3, s)))
+    return dict(pos=pos, slot=slot, width=width, in_use=in_use, keep=keep,
+                in_place=in_place, kept=kept, read=read, tables=tables,
+                counts=counts), jnp.zeros((3,), jnp.int32)
+
+
+def decode_layer(cfg, li, layer, x, ctx, state, tally, impl="auto"):
+    """Layer `li` for ONE token a slot, `x` (S, H), against its leaves
+    `state` = (k, v, ki): the token's K, V and index-key rows written at
+    its position, every row in use scored against the slot's index query,
+    the kept best attended, read in place under the selection mask or
+    gathered into a `(S, topk, Hkv·D)` rung; then its expert layer."""
+    s = x.shape[0]
+    pos, in_use, in_place = ctx["pos"], ctx["in_use"], ctx["in_place"]
+    kc, vc, kic = state
+    with jax.named_scope(f"layer{li}"):
+        u = rms_norm(x, layer["norm1"], cfg.rms_norm_eps)
+        with jax.named_scope("attn"):
+            q, k, v, qi, ki, w = attention_inputs(cfg, layer, u,
+                                                  ctx["tables"])
+            with jax.named_scope("kv_write"):
+                kc, vc = write_rows((kc, vc), ctx["slot"], pos, (k, v))
+                kic = write_packed_row(kic, pos, ki)
+            with jax.named_scope("indexer"):
+                with jax.named_scope("score"):
+                    scores = index_scores_decode(qi, kic, w, pos,
+                                                 cfg.index_scale, impl=impl)
+                with jax.named_scope("select"):
+                    mask = top_k_mask(scores, ctx["keep"])
+                    if not in_place:
+                        rows = compact_indices(mask, ctx["width"])
+            q = q.reshape(s, cfg.num_attention_heads, cfg.head_dim)
+            if in_place:
+                out = flash_attention_decode(q, kc, vc, mask, impl=impl,
+                                             lengths=in_use)
+            else:
+                with jax.named_scope("gather"):
+                    kg, vg = (jnp.take_along_axis(leaf, rows[..., None],
+                                                  axis=1)
+                              for leaf in (kc, vc))
+                out = flash_attention_decode(q, kg, vg, ctx["kept"],
+                                             impl=impl)
+            with jax.named_scope("proj"):
+                x = x + out.reshape(s, -1).astype(x.dtype) \
+                    @ layer["o"].astype(x.dtype)
+        with jax.named_scope("moe"):
+            out, counts = moe(cfg, layer, rms_norm(x, layer["norm2"],
+                                                   cfg.rms_norm_eps))
+            tally = tally + counts
+        x = x + out.astype(x.dtype)
+    return x, (kc, vc, kic), tally
+
+
+def decode_counts(cfg, ctx, tally):
+    """The expert layers' tally, then the rows scored, the rows kept and
+    the rows of K the kernel fetched for them, slots and layers summed."""
+    return ctx["counts"] + jnp.concatenate([
+        tally, cfg.num_hidden_layers * jnp.stack(
+            [ctx["in_use"].sum(), ctx["keep"].sum(), ctx["read"]]
+        ).astype(jnp.int32)])
+
+
+def encode_prompt(cfg, params, prompt, plen, impl="auto"):
+    """`encode`: its rows past plen are padding that a decode step never
+    scores (it masks rows past `pos`)."""
+    return encode(cfg, params, prompt, impl=impl)
+
+
+def cache_parts(cfg, li, state, plen, leaves):
+    k, v, ki = state
+    return k, v, pack_rows(ki)

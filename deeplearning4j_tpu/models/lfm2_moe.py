@@ -31,7 +31,7 @@ is pre-norm: `h = x + mixer(RMSNorm_op(x))`, `y = h + ffn(RMSNorm_ffn(h))`.
 A conv layer keeps nothing a position: a SLOT keeps the last K-1 rows of
 z, all that the convolution reads. An attention layer keeps a position's k
 (normed and rotated) and v. `generation/decode.py`'s `Lfm2MoeDecoder`
-holds both kinds in one cache.
+holds both kinds in one cache (`decode_leaves`).
 """
 from __future__ import annotations
 
@@ -42,10 +42,11 @@ import jax.numpy as jnp
 
 from deeplearning4j_tpu.kernels.flash_attention import (
     flash_attention, flash_attention_decode)
-# (RMSNorm, the SwiGLU, the sigmoid-routed expert layer and a causal
-# convolution's tail are every served decoder's)
-from deeplearning4j_tpu.models.decoder_common import (
-    conv_shift, conv_tail, moe, real_rows, rms_norm, swiglu)
+# (the embedding, RMSNorm, the SwiGLU, the sigmoid-routed expert layer and
+# a causal convolution's tail are every served decoder's)
+from deeplearning4j_tpu.models.decoder_common import (  # noqa: F401
+    Leaf, conv_shift, conv_tail, embed, expert_runs, moe, real_rows,
+    rms_norm, rope_tables, rotate, swiglu, write_rows)
 
 #: rows of queries and of keys a grid step of a prompt's causal attention
 #: takes (the scores of a 1024 x 1024 tile are 4 MB of float32)
@@ -224,10 +225,6 @@ def init_params(cfg, key):
 
 
 # -- pieces -----------------------------------------------------------------
-def embed(params, ids):
-    return jnp.take(params["embed"], ids, axis=0)
-
-
 def logits(cfg, params, x):
     """The final norm and the TIED head over hidden rows `x` (..., H):
     `RMSNorm(x) E^T`, float32."""
@@ -236,25 +233,6 @@ def logits(cfg, params, x):
         return jnp.einsum("...h,vh->...v", u,
                           params["embed"].astype(u.dtype),
                           preferred_element_type=jnp.float32)
-
-
-def rope_tables(cfg, positions):
-    """(cos, sin) (T, D/2) float32 at `positions` (T,): angle j is `t
-    theta^(-2j/D)`."""
-    half = cfg.head_dim // 2
-    ang = positions.astype(jnp.float32)[:, None] * cfg.rope_theta ** (
-        -jnp.arange(half, dtype=jnp.float32) / half)
-    return jnp.cos(ang), jnp.sin(ang)
-
-
-def rotate(x, table):
-    """Rotary in halves over heads `x` (T, heads, D): lane j turns with
-    lane j + D/2 by the table's angle j, in float32."""
-    cos, sin = (a[:, None, :] for a in table)
-    x32 = x.astype(jnp.float32)
-    a, b = jnp.split(x32, 2, axis=-1)
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                           axis=-1).astype(x.dtype)
 
 
 # -- the gated short convolution --------------------------------------------
@@ -407,7 +385,7 @@ def encode(cfg, params, ids, plen=None, impl="auto"):
     with jax.named_scope("rope"):
         tables = rope_tables(cfg, jnp.arange(t))
     with jax.named_scope("embed"):
-        x = embed(params, ids)
+        x = embed(cfg, params, ids)
     states = []
     for li, layer in enumerate(params["layers"]):
         with jax.named_scope(f"layer{li}"):
@@ -416,36 +394,87 @@ def encode(cfg, params, ids, plen=None, impl="auto"):
     return x, states
 
 
-def decode_layer(cfg, li, layer, x, tables, state, pos, lengths,
-                 impl="auto"):
-    """Layer `li` for ONE token a slot, `x` (S, H) at positions `pos` (S,):
-    an attention layer writes the token's K and V rows at row `pos` of its
-    leaves `state` = (k, v) `(S, C, Hkv·D)` and attends rows 0..lengths - 1
-    through `flash_attention_decode(..., lengths=)`; a conv layer advances
-    its tail `state` (S, K-1, H) by the token. Returns (y, the state, the
-    expert layer's counts or None)."""
+# -- decode (`generation/decode.py`'s `Lfm2MoeDecoder`) ------------------------
+def decode_leaves(cfg):
+    """An attention layer's K and V leaves, C rows at a rung of C; a conv
+    layer's TAIL `(K-1, H)`, the last K-1 rows of `B * xx` that its
+    convolution reads, which no rung touches."""
+    dt = cfg.compute_dtype
+    kv = tuple(Leaf(name, (cfg.kv_width,), dt) for name in ("k", "v"))
+    tail = (Leaf("conv", (cfg.conv_L_cache - 1, cfg.hidden_size), dt,
+                 per_row=0, scope="shortconv/tail_write"),)
+    return [kv if cfg.is_attention(li) else tail
+            for li in range(cfg.num_hidden_layers)]
+
+
+def decode_context(cfg, pos, leaves, counts):
+    """The rotary tables at `pos` (S,); the tally (the expert layers'
+    counts, the attention layers' rows in use) from zero."""
+    with jax.named_scope("rope"):
+        tables = rope_tables(cfg, pos)
+    return dict(pos=pos, tables=tables, counts=counts), (
+        jnp.zeros((3,), jnp.int32), jnp.zeros((), jnp.int32))
+
+
+def decode_layer(cfg, li, layer, x, ctx, state, tally, impl="auto"):
+    """Layer `li` for ONE token a slot, `x` (S, H): an attention layer
+    writes the token's K and V rows at its position in `state` = (k, v)
+    `(S, C, Hkv·D)` and attends the rows in use, `min(pos + 1, C)`; a conv
+    layer shifts its tail `state` = (tail,) by the token; then the
+    feed-forward."""
+    pos = ctx["pos"]
     s = x.shape[0]
-    with jax.named_scope("norm"):
-        u = rms_norm(x, layer["norm_op"], cfg.norm_eps)
-    if cfg.is_attention(li):
-        kc, vc = state
-        with jax.named_scope("attn"):
-            q, k, v = attention_inputs(cfg, layer, u, tables)
-            with jax.named_scope("kv_write"):
-                slot = jnp.arange(s)
-                kc = kc.at[slot, pos].set(k.astype(kc.dtype))
-                vc = vc.at[slot, pos].set(v.astype(vc.dtype))
-            rows = jnp.arange(kc.shape[1])[None, :] < lengths[:, None]
-            ctx = flash_attention_decode(q, kc, vc, rows, impl=impl,
-                                         lengths=lengths)
-            x = x + _attn_out(layer, ctx.reshape(s, -1).astype(x.dtype))
-        state = (kc, vc)
-    else:
-        with jax.named_scope("shortconv"):
-            out, state = shortconv_step(cfg, layer, u, state)
-            x = x + out
-    y, counts = feed_forward(cfg, li, layer, x)
-    return y, state, counts
+    counted, in_use = tally
+    with jax.named_scope(f"layer{li}"):
+        if cfg.is_attention(li):
+            kc, vc = state
+            # rows 0..pos are in use (the current write included); a lane
+            # frozen at the end of its rung (pos == C) keeps all of them
+            lengths = jnp.minimum(pos + 1, kc.shape[1])
+        with jax.named_scope("norm"):
+            u = rms_norm(x, layer["norm_op"], cfg.norm_eps)
+        if cfg.is_attention(li):
+            with jax.named_scope("attn"):
+                q, k, v = attention_inputs(cfg, layer, u, ctx["tables"])
+                with jax.named_scope("kv_write"):
+                    kc, vc = write_rows((kc, vc), jnp.arange(s), pos,
+                                        (k, v))
+                rows = jnp.arange(kc.shape[1])[None, :] < lengths[:, None]
+                out = flash_attention_decode(q, kc, vc, rows, impl=impl,
+                                             lengths=lengths)
+                x = x + _attn_out(layer, out.reshape(s, -1).astype(x.dtype))
+            state = (kc, vc)
+        else:
+            with jax.named_scope("shortconv"):
+                out, tail = shortconv_step(cfg, layer, u, *state)
+                x = x + out
+            state = (tail,)
+        y, counts = feed_forward(cfg, li, layer, x)
+        if cfg.is_attention(li):
+            in_use = in_use + lengths.sum()
+    if counts is not None:
+        counted = counted + counts
+    return y, state, (counted, in_use)
+
+
+def decode_counts(cfg, ctx, tally):
+    counted, in_use = tally
+    return ctx["counts"] + jnp.concatenate([counted, in_use[None]])
+
+
+def encode_prompt(cfg, params, prompt, plen, impl="auto"):
+    return encode(cfg, params, prompt, plen, impl=impl)
+
+
+def cache_parts(cfg, li, state, plen, leaves):
+    """An attention layer's K/V rows 0..P-1; a conv layer's tail as the
+    last REAL positions left it (z of positions plen - K + 1 .. plen - 1,
+    zeros before 0; the bucket's last rows would be padding)."""
+    return state if cfg.is_attention(li) else (state,)
+
+
+def prefill_windows(cfg):
+    return [None] * sum(map(cfg.is_attention, range(cfg.num_hidden_layers)))
 
 
 def forward(cfg, params, ids, impl="auto"):

@@ -25,7 +25,9 @@ untied head. No biases but the convolution's. The mixers:
 
 `encode` is the full-sequence forward (prefill's arithmetic) and hands back
 each layer's decode state; `generation/decode.py`'s `NemotronHDecoder`
-serves it. Multi-token prediction modules are not modelled.
+serves it from the decode state declared here (`decode_leaves`) and the
+step's and the prefill's hooks. Multi-token prediction modules are not
+modelled.
 """
 from __future__ import annotations
 
@@ -36,8 +38,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from deeplearning4j_tpu.kernels.flash_attention import flash_attention
-from deeplearning4j_tpu.models.decoder_common import conv_shift, conv_tail
+from deeplearning4j_tpu.kernels.flash_attention import (
+    flash_attention, flash_attention_decode)
+# (RMSNorm and the router's sigmoid are every served decoder's)
+from deeplearning4j_tpu.models.decoder_common import (  # noqa: F401
+    Leaf, conv_shift, conv_tail, embed, rms_norm, router_scores, slot_index,
+    write_rows)
 from deeplearning4j_tpu.parallel.moe import routed_calls, routed_experts
 from deeplearning4j_tpu.parallel.ring_attention import dense_attention
 
@@ -203,12 +209,6 @@ def init_params(cfg, key):
 
 
 # -- pieces -----------------------------------------------------------------
-def rms_norm(x, weight, eps):
-    x32 = x.astype(jnp.float32)
-    y = x32 * lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (y * weight).astype(x.dtype)
-
-
 def relu2(x):
     return jnp.square(jax.nn.relu(x))
 
@@ -385,12 +385,6 @@ def _causal_attention(cfg, q, k, v):
     return ctx.transpose(0, 2, 1, 3).reshape(bsz, t, hq * d)
 
 
-def router_scores(layer, u):
-    """The router's sigmoid scores (T, E) of tokens `u` (T, H), float32."""
-    return jax.nn.sigmoid(jnp.dot(u, layer["router"].astype(u.dtype),
-                                  preferred_element_type=jnp.float32))
-
-
 def moe_mixer(cfg, layer, u):
     """The LatentMoE mixer for tokens `u` (T, H): this chip's part of the
     routed experts (those of `cfg.experts_held`) beside the shared expert.
@@ -455,7 +449,7 @@ def encode(cfg, params, ids, plen=None):
     Positions at and after `plen` (B,), by default T, are padding."""
     if plen is None:
         plen = jnp.full((ids.shape[0],), ids.shape[1], jnp.int32)
-    x = jnp.take(params["embed"], ids, axis=0)
+    x = embed(cfg, params, ids)
     states = []
     for li, (kind, layer) in enumerate(zip(cfg.pattern, params["layers"])):
         with jax.named_scope(f"layer{li}"):
@@ -475,3 +469,82 @@ def logits(cfg, params, x):
 def forward(cfg, params, ids):
     """Next-token logits (B, T, V) at every position of `ids` (B, T)."""
     return logits(cfg, params, encode(cfg, params, ids)[0])
+
+
+# -- decode (`generation/decode.py`'s `NemotronHDecoder`) -----------------------
+def decode_leaves(cfg):
+    """An ATTENTION layer's K and V leaves, C rows `(C, Hkv·D)` at a rung
+    of C; a MAMBA layer's float32 state `(heads, head_dim, state)` and
+    convolution tail `(K-1, conv lanes)`, which no rung touches; nothing
+    for an expert layer."""
+    dt = cfg.compute_dtype
+    kv = tuple(Leaf(name, (cfg.kv_width,), dt) for name in ("k", "v"))
+    mamba = (Leaf("ssm", (cfg.mamba_num_heads, cfg.mamba_head_dim,
+                          cfg.ssm_state_size), jnp.float32, per_row=0,
+                  scope="ssm/state_write"),
+             Leaf("conv", (cfg.conv_kernel - 1, cfg.conv_dim), dt,
+                  per_row=0, scope="ssm/state_write"))
+    return [{"*": kv, "M": mamba}.get(kind, ()) for kind in cfg.pattern]
+
+
+def decode_context(cfg, pos, leaves, counts):
+    """The slots' row index and the rows in use at `pos` (S,), 0..pos (the
+    current write included); the tally is the running counts."""
+    ctx = dict(pos=pos, slot=slot_index(pos), cmask=None)
+    if leaves.get("k"):
+        ctx["cmask"] = jnp.arange(leaves["k"][0].shape[1])[None, :] \
+            <= pos[:, None]
+    return ctx, counts
+
+
+def decode_layer(cfg, li, layer, x, ctx, state, tally, impl="auto"):
+    """Layer `li` for ONE token a slot, `x` (S, H): an attention layer
+    writes its K/V row at the position in `state` = (k, v) and attends
+    rows 0..pos; a Mamba-2 layer advances `state` = (state, tail) by the
+    token; an expert layer routes the S tokens and adds what it computed
+    to the tally."""
+    s = x.shape[0]
+    kind = cfg.pattern[li]
+    with jax.named_scope(f"layer{li}"):
+        with jax.named_scope("norm"):
+            u = rms_norm(x, layer["norm"], cfg.norm_eps)
+        if kind == "M":
+            with jax.named_scope("ssm"):
+                out, *state = mamba_step(cfg, layer, u, *state)
+        elif kind == "*":
+            kc, vc = state
+            with jax.named_scope("attn"):
+                with jax.named_scope("qkv"):
+                    q, k, v = attention_qkv(cfg, layer, u)
+                with jax.named_scope("kv_write"):
+                    kc, vc = write_rows((kc, vc), ctx["slot"], ctx["pos"],
+                                        (k, v))
+                o = flash_attention_decode(
+                    q.reshape(s, cfg.num_attention_heads, cfg.head_dim),
+                    kc, vc, ctx["cmask"], impl=impl)
+                with jax.named_scope("proj"):
+                    out = o.reshape(s, -1).astype(x.dtype) \
+                        @ layer["o"].astype(x.dtype)
+            state = (kc, vc)
+        else:
+            with jax.named_scope("moe"):
+                out, counted = moe_mixer(cfg, layer, u)
+                tally = tally + counted
+        x = x + out.astype(x.dtype)
+    return x, tuple(state), tally
+
+
+def decode_counts(cfg, ctx, tally):
+    return tally
+
+
+def encode_prompt(cfg, params, prompt, plen, impl="auto"):
+    """`encode` over the prompt as a batch of one."""
+    return encode(cfg, params, prompt[None], jnp.reshape(plen, (1,)))
+
+
+def cache_parts(cfg, li, state, plen, leaves):
+    """An attention layer's K/V rows 0..P-1, a Mamba-2 layer's state and
+    tail as the last REAL token left them (`mamba_mixer` holds the state
+    through the padding)."""
+    return state

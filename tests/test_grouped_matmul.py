@@ -613,6 +613,7 @@ def test_the_published_expert_layers_take_these_runs(monkeypatch, config):
     import os
 
     from deeplearning4j_tpu.generation import decode
+    from deeplearning4j_tpu.models import decoder_common
     from deeplearning4j_tpu.parallel import moe
     module, decoder, slots, tokens, runs, on_cpu = PUBLISHED[config]
     here = os.path.dirname(os.path.abspath(__file__))
@@ -631,7 +632,7 @@ def test_the_published_expert_layers_take_these_runs(monkeypatch, config):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert {b: dec.expert_runs(b) for b in runs} == runs
     assert dec.expert_runs(slots) == 1
-    layer = decode._expert_layer(params)
+    layer = decoder_common.expert_layer(params)
     w_in, w_out = (layer["w_up"], layer["w_down"]) if "w_up" in layer \
         else (layer["w1"], layer["w2"])
     w_gate = layer.get("w_gate")

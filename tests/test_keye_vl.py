@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.families import keye_vl_serve as family
-from deeplearning4j_tpu.generation import decode
 from deeplearning4j_tpu.generation.decode import KeyeDecoder
 from deeplearning4j_tpu.generation.server import GenerationServer
 from deeplearning4j_tpu.kernels import indexer
@@ -375,12 +374,12 @@ def test_the_rule_reads_in_place_up_to_twelve_times_topk():
     """The benchmark configuration's rung, 18432 = 9 x `topk` 2048, takes
     the in-place read; a rung past the rule keeps the gather. A function
     of the two static shapes alone."""
-    assert decode._attends_in_place(18432, 2048)
-    assert decode._attends_in_place(12 * 2048, 2048)
-    assert not decode._attends_in_place(12 * 2048 + 2, 2048)
-    assert not decode._attends_in_place(32768, 2048)
-    assert decode._attends_in_place(64, 16) \
-        and not decode._attends_in_place(256, 16)
+    assert kv.attends_in_place(18432, 2048)
+    assert kv.attends_in_place(12 * 2048, 2048)
+    assert not kv.attends_in_place(12 * 2048 + 2, 2048)
+    assert not kv.attends_in_place(32768, 2048)
+    assert kv.attends_in_place(64, 16) \
+        and not kv.attends_in_place(256, 16)
     # and the tile the kernel reads that rung in comes from its shapes
     assert fa.decode_tile_rows(18432, 512, jnp.bfloat16) == 1024
     assert fa.decode_tile_rows(64, 32, jnp.float32) == 64
@@ -422,7 +421,7 @@ def test_prefill_then_ten_steps_match_the_full_forward(toy, attn_impl, rung):
     reference's sort selects. A rung of 64 = 4 x `topk` reads them where
     they lie, one of 256 = 16 x `topk` gathers them first."""
     cfg, params = toy
-    assert decode._attends_in_place(rung, cfg.indexer_topk) == (rung == 64)
+    assert kv.attends_in_place(rung, cfg.indexer_topk) == (rung == 64)
     dec = KeyeDecoder(cfg, params, attn_impl=attn_impl)
     margs = dec.model_args()
     prefill, step = jax.jit(dec.prefill), jax.jit(dec.step)
@@ -518,7 +517,7 @@ def test_server_streams_equal_the_decoders_own_and_never_compile(toy, topk):
     _, params = toy
     cfg = kv.KeyeVLConfig.from_dict(
         {**TOY, "sa_config": {**TOY["sa_config"], "topk": topk}})
-    assert [decode._attends_in_place(r, topk) for r in (32, 64)] \
+    assert [kv.attends_in_place(r, topk) for r in (32, 64)] \
         == [topk == 16] * 2
     dec = KeyeDecoder(cfg, params)
     srv = GenerationServer(dec, slots=2, cache_lengths=[32, 64],

@@ -511,6 +511,9 @@ def test_disabled_monitoring_publishes_nothing():
 def test_cluster_prometheus_text_labels_hosts_and_aggregates():
     mon.enable()
     reg = mon.get_registry()
+    # the registry is the process's: an earlier module that served
+    # requests with monitoring on has counted tokens into it already
+    reg.clear()
     reg.counter("dl4j.gen.tokens", help="tokens generated").inc(5)
     reg.gauge("dl4j.gen.active_slots").set(3)
     h = reg.histogram("dl4j.gen.per_token_ms")
